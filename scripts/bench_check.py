@@ -444,8 +444,7 @@ def fused_ratio_keys(parsed: dict) -> Dict[str, float]:
 def fused_floor(name: str, parsed: dict, min_ratio: float) -> List[str]:
     """Absolute floor on the newest run's fused/split step ratio: the
     fused kernel replacing the split pair must not be slower than it
-    (the measurement is same-window interleaved, so the ratio holds
-    even on a contended chip)."""
+    (the measurement is same-window interleaved)."""
     return [
         f"{key}: {v:.3f} < --min-fused-ratio {min_ratio:.3f} ({name}) "
         "— fused tile step slower than the split oracle it replaces"
@@ -461,8 +460,7 @@ def cached_ratio_keys(parsed: dict) -> Dict[str, float]:
 def cached_floor(name: str, parsed: dict, min_ratio: float) -> List[str]:
     """Absolute floor on the newest run's cached/fused step ratio: the
     one-hot cache replay must not fall below its backend's calibrated
-    floor vs the rebuild it skips (same-window interleaved, so the
-    ratio holds even on a contended chip)."""
+    floor vs the rebuild it skips (same-window interleaved)."""
     return [
         f"{key}: {v:.3f} < --min-cached-ratio {min_ratio:.3f} ({name}) "
         "— one-hot cache replay below the floor vs the per-phase "

@@ -120,6 +120,10 @@ def build(spec, stage):
 
 
 def main():
+    from wormhole_tpu.parallel.mesh import (enable_compile_cache,
+                                            require_tpu)
+    enable_compile_cache()
+    require_tpu(__file__)     # a timing harness: no CPU fallback
     from wormhole_tpu.data.crec import default_cap
     spec = tilemm.make_spec(NB, ROWS // tilemm.RSUB, default_cap(NNZ, NB))
     print("spec:", spec)

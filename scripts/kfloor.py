@@ -3,9 +3,8 @@
 Round-4 established the tile kernels are NOT MXU-shape-bound (deleting a
 whole matmul was time-neutral under separate timing). This harness makes
 the diagnosis quantitative: an incremental-deletion series over the fwd
-kernel, every variant timed INTERLEAVED in the same windows (the shared
-chip's bursty contention hits all variants equally; min-of-windows per
-variant), so per-stage deltas are trustworthy:
+kernel, every variant timed INTERLEAVED in the same windows
+(min-of-windows per variant), so per-stage deltas are trustworthy:
 
   F0 full            the production kernel body
   F1 -hist           per-subblock histogram matmuls (+their rhiT builds)
@@ -15,7 +14,7 @@ variant), so per-stage deltas are trustworthy:
   F5 -gather         the OH(hi) @ W matmul
   F6 builds-only     ohhi build + accumulate (the irreducible floor probe)
   I8 i8-gather       ohhi as int8 with an i8xi8 MXU dot on a quantized W
-                     (VERDICT r4's untried lever — timing only; the i8
+                     (an untried lever — timing only; the i8
                      product is NOT numerically usable for f32 models)
   HO hoisted-builds  one-hot builds hoisted out of the tile loop (probes
                      whether builds serialize with the matmuls or overlap)
@@ -286,6 +285,10 @@ def _force(o):
 
 
 def main():
+    from wormhole_tpu.parallel.mesh import (enable_compile_cache,
+                                            require_tpu)
+    enable_compile_cache()
+    require_tpu(__file__)     # a timing harness: no CPU fallback
     reps = int(sys.argv[1]) if len(sys.argv) > 1 else 15
     windows = int(sys.argv[2]) if len(sys.argv) > 2 else 8
     from wormhole_tpu.data.crec import default_cap
@@ -335,7 +338,7 @@ def main():
     _force(o)
     best = {s: float("inf") for s in fns}
     for _ in range(windows):
-        for s in fns:                  # interleaved: same contention
+        for s in fns:                  # interleaved: same windows
             t0 = time.perf_counter()
             for _ in range(reps):
                 o = fns[s](pw, w)

@@ -1,9 +1,8 @@
-"""Interleaved kernel A/B sweep — contention-robust variant comparison.
+"""Interleaved kernel A/B sweep.
 
-The shared chip's bursty contention makes sequential A/B meaningless
-(round-4 finding), so variants are timed in ALTERNATING short windows:
-every variant samples the same contention profile and the per-variant
-MINIMUM approximates its uncontended time. Sweeps cap (pad waste vs
+Variants are timed in ALTERNATING short windows, so whatever disturbs
+the host or the device for a while disturbs every variant alike, and
+the per-variant MINIMUM is compared. Sweeps cap (pad waste vs
 exact-overflow scatter cost) and tiles_step.
 
 Usage: python scripts/ksweep.py [caps] [tbs]   e.g. 1280,1408,1536 8,16
@@ -63,6 +62,10 @@ def build_variant(cap: int, tb: int, rng):
 
 
 def main():
+    from wormhole_tpu.parallel.mesh import (enable_compile_cache,
+                                            require_tpu)
+    enable_compile_cache()
+    require_tpu(__file__)     # a timing harness: no CPU fallback
     caps = [int(c) for c in (sys.argv[1].split(",") if len(sys.argv) > 1
                              else ["1408"])]
     tbs = [int(t) for t in (sys.argv[2].split(",") if len(sys.argv) > 2

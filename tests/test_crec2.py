@@ -329,7 +329,7 @@ def test_cross_format_warm_start_raises(tmp_path, rng):
 def test_crec_v1_mesh_training_converges(tmp_path, rng):
     """AsyncSGD over crec v1 on a data:2,model:2 mesh (the shard_map
     dense-apply step): learns the planted feature like the single-device
-    v1 path — the distributed hole VERDICT r3 flagged."""
+    v1 path."""
     n = 4000
     keys, labels = make_rows(rng, n)
     sel = rng.random(n) < 0.5

@@ -1,7 +1,7 @@
 """FM / wide&deep crec2 tile fast path vs the sparse gather/scatter path.
 
-VERDICT r3 Missing #3: the stretch models previously trained only through
-the sparse step; these tests pin the new multi-channel tile path (pooled
+The stretch models previously trained only through the sparse step;
+these tests pin the new multi-channel tile path (pooled
 pulls + split pushes) to the sparse path's math on identical rows — same
 buckets, same update rule — and prove end-to-end learning through the
 AsyncSGD driver over a real crec2 file.

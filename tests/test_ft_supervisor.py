@@ -66,7 +66,7 @@ def test_detector_skips_final_and_missing(tmp_path):
     assert DeadRankDetector(0.0).check(d, now=1000.0) == []
 
 
-def test_supervisor_exit_code_taxonomy():
+def test_supervisor_exit_code_classes():
     sup = Supervisor(world=4)
     for code in BYSTANDER_CODES:
         sup.record_exit(0, code)

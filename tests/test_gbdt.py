@@ -204,8 +204,8 @@ def _write_libsvm(path, x, y):
 
 
 def test_gbdt_external_matches_in_memory(tmp_path):
-    """External-memory boosting (streamed BinnedCache chunks, VERDICT r3
-    Missing #4) builds the same trees as the in-memory fit on identical
+    """External-memory boosting (streamed BinnedCache chunks) builds
+    the same trees as the in-memory fit on identical
     data: the chunked histogram accumulation and streamed routing must
     reproduce the all-rows scans exactly."""
     from wormhole_tpu.models.gbdt import GBDT, GBDTConfig, load_dense

@@ -283,7 +283,7 @@ def test_s3_unconfigured_is_informative(monkeypatch):
 def test_s3_crashed_writer_publishes_nothing(s3):
     """A with-block exception mid-write to s3:// must NOT publish the
     buffered partial object (the write buffer aborts the PUT-on-close;
-    VERDICT/ADVICE r4: a crashed CRec2Writer would otherwise upload a
+    a crashed CRec2Writer would otherwise upload a
     truncated-but-complete-looking dataset)."""
     from wormhole_tpu.data.crec import CRec2Writer
     from wormhole_tpu.ops import tilemm

@@ -272,7 +272,12 @@ def test_hot_swap_under_load_zero_recompiles(rng, tmp_path):
             while poller.version < ver and time.monotonic() < deadline:
                 time.sleep(0.01)
             assert poller.version == ver, "swap missed a poll interval"
-            time.sleep(0.1)          # let post-swap answers land
+            # wait until the serve thread has ANSWERED from this version
+            # (a fixed sleep loses the race on a loaded host)
+            while (not any(np.isclose(p, versions[ver], rtol=1e-5)
+                           for p, _ in list(seen))
+                   and time.monotonic() < deadline):
+                time.sleep(0.01)
     finally:
         stop.set()
         t.join(timeout=10)

@@ -1,7 +1,7 @@
 """The TCP wire (parallel/socket_wire.py): frame codec over torn
 streams, file/port rendezvous, full-mesh collectives against the
 BusWire byte-semantics oracle, disconnect surfacing through the
-watchdog taxonomy (PEER_LOST), the rejoin side channel, and the
+watchdog exit-code scheme (PEER_LOST), the rejoin side channel, and the
 FilterChain transport stack riding on top bit-identically."""
 
 import hashlib
@@ -216,7 +216,7 @@ def test_disconnect_raises_peer_lost_without_watchdog(tmp_path):
         _close_all(wires)
 
 
-def test_disconnect_trips_watchdog_taxonomy(tmp_path):
+def test_disconnect_trips_watchdog_exit_classes(tmp_path):
     """With a watchdog installed, a detected disconnect takes the SAME
     exit path a timed-out collective would — immediately, without
     waiting out the timeout (the trip() fast path)."""

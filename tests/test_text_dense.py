@@ -1,6 +1,6 @@
 """Dense text fast path: native chunk -> crec-block assembly feeding the
-dense-apply device step (VERDICT r3 Next #2 — the text ingest path whose
-Python localize+pad glue capped criteo text at ~20K rows/s).
+dense-apply device step (the text ingest path whose Python localize+pad
+glue used to cap criteo text).
 
 Pinned two ways: the native assembler must be byte-identical to the
 Python spec (key64_to_key32 + sentinel padding, the text2rec crec

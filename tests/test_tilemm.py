@@ -166,8 +166,7 @@ def test_mesh_tile_step_matches_oracle(algo):
 
 
 def test_mesh_tile_step_large_nb_cap_floor():
-    """Model-axis sharding in the HIGH-nb pad-floor regime (VERDICT r4
-    Missing #3): 128 tiles (nb=2^21) with ~64 pairs per (subblock, tile)
+    """Model-axis sharding in the HIGH-nb pad-floor regime: 128 tiles (nb=2^21) with ~64 pairs per (subblock, tile)
     — cap floors at 128, so the pairs array is ~50% padding — sharded
     model:4 across a data:2,model:4 CPU mesh. The mesh step must still
     match the exact scatter oracle: pad words contribute nothing, tile

@@ -9,8 +9,9 @@ slots AND the packed metric accumulator), across linear / FM /
 wide&deep. Round 8 widens the contract: the phase-shared one-hot cache
 (`tile_onehot_cache`) must replay bitwise-identical planes, capped-
 overflow blocks fuse via the pre-aggregated spill operand, and
-spill-free wide&deep blocks fuse via the in-kernel MLP phase — only
-the mesh shard stays structurally split.
+spill-free wide&deep blocks fuse via the in-kernel MLP phase (that one
+at float tolerance: its dense phase sums in another order) — only the
+mesh shard stays structurally split.
 """
 
 import dataclasses
@@ -442,10 +443,13 @@ def test_fm_store_spill_fused_bitwise():
 
 
 def test_wide_deep_fused_parity():
-    """Round 8: spill-free wide&deep blocks fuse — the MLP forward/vjp
-    runs in-kernel at the phase boundary. Whole-store parity: slots,
-    MLP params, AdaGrad accumulators and metrics all bitwise vs split
-    (both jitted, so the vjp graphs compile identically)."""
+    """Round 8: spill-free wide&deep blocks fuse — the MLP forward and
+    backward run in-kernel at the phase boundary. Whole-store parity
+    vs split: slots, MLP params, AdaGrad accumulators and metrics. At
+    float tolerance, not bitwise: the in-kernel tower walks the grid in
+    1024-row chunks (the only layout the chip's compiler accepts, see
+    tilemm._make_wd_step_kernel), so it sums the same products in
+    another order than the split path's whole-block ``x @ W``."""
     import jax
     import jax.numpy as jnp
     from wormhole_tpu.models.wide_deep import (WideDeepConfig,
@@ -471,11 +475,12 @@ def test_wide_deep_fused_parity():
     s_s, mlp_s, acc_s, m_s, k_s = run("split")
     assert k_f[:2] == ("fused", "")
     assert k_s[0] == "split" and k_s[1] == "forced"
-    np.testing.assert_array_equal(s_f, s_s)
-    np.testing.assert_array_equal(m_f, m_s)
+    tol = dict(rtol=2e-5, atol=1e-7)
+    np.testing.assert_allclose(s_f, s_s, **tol)
+    np.testing.assert_allclose(m_f, m_s, **tol)
     for key in mlp_s:
-        np.testing.assert_array_equal(mlp_f[key], mlp_s[key])
-        np.testing.assert_array_equal(acc_f[key], acc_s[key])
+        np.testing.assert_allclose(mlp_f[key], mlp_s[key], **tol)
+        np.testing.assert_allclose(acc_f[key], acc_s[key], **tol)
 
 
 def test_wide_deep_vmem_fallback_and_spill_split():

@@ -1,0 +1,266 @@
+"""Compile the tile kernels for a DESCRIBED TPU v5e, without a chip.
+
+Every other test drives the Pallas kernels through interpret mode, which
+cannot see what the chip's compiler refuses: VMEM overruns, slices that
+do not align to the (8, 128) tiling, reshapes Mosaic has no lowering for.
+libtpu is installed here and compiles for a topology that is described
+and not attached (``on-chip-measurement`` guide, section 2.3), so these
+tests ask it to compile each kernel a chip run can reach. Nothing
+executes; a pass says "the compiler accepts it", never "it ran".
+
+Tier-1 holds the flagship's main-path kernels at the real per-tile
+widths (``cap=1408, group=4, subblocks=12``) but two tiles, because
+compile time grows with the unrolled ``tiles_step``. The full-geometry
+compiles (``nb=2**22, tiles_step=16``) and the variants off the main
+path are marked ``slow``: minutes each, run by hand before a chip call
+(seconds per case are in CHANGES.md, PR 23).
+
+The cases run one after another in one process — two processes
+compiling for the TPU at once collide on libtpu's lock file — and with
+the persistent compilation cache off: a described-topology executable
+is written to the cache but cannot be read back without a chip.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from wormhole_tpu.ops import histmm, tilemm
+
+NB = 1 << 22                     # the criteo bucket table (bench.py)
+CRITEO = dict(subblocks=12, cap=1408)   # 98,304-row crec2 blocks
+_BUILDERS = (tilemm._build_fwd, tilemm._build_bwd, tilemm._build_step_grad,
+             tilemm._build_step_update, tilemm._build_fwd_multi,
+             tilemm._build_bwd_multi, tilemm._build_fm_step_fused,
+             tilemm._build_wd_step_fused)
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """A described (not attached) v5e host of four chips, 2x2."""
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:   # no libtpu / topology unknown to it
+        pytest.skip(f"cannot describe a v5e topology here: {e!r}")
+
+
+@pytest.fixture(autouse=True)
+def compiled_not_interpreted(monkeypatch):
+    """Steer the kernels to the Mosaic path (the backend here is the
+    CPU, so ``_interpret()`` would pick the interpreter), with the
+    builder caches emptied on both sides so no interpret-mode build
+    leaks in or out, and the persistent cache off around the compile."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    monkeypatch.setattr(tilemm, "_interpret", lambda: False)
+    for b in _BUILDERS:
+        b.cache_clear()
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+    for b in _BUILDERS:
+        b.cache_clear()
+
+
+def _ftrl():
+    from wormhole_tpu.learners.handles import FTRLHandle, LearnRate
+    from wormhole_tpu.ops.penalty import L1L2
+    return FTRLHandle(penalty=L1L2(1.0, 0.1), lr=LearnRate(0.1, 1.0))
+
+
+# -- one builder per kernel: spec -> (jitted fn, argument shapes) -----------
+
+def _pw(spec):
+    return (spec.pairs_shape, jnp.uint32)
+
+
+def _rows(spec, *trail):
+    return ((spec.block_rows, *trail), jnp.float32)
+
+
+def fwd(spec):
+    return tilemm._build_fwd(spec), [_pw(spec), ((spec.nb,), jnp.float32)]
+
+
+def bwd(spec):
+    return tilemm._build_bwd(spec), [_pw(spec), _rows(spec)]
+
+
+def step_grad(spec, cache=False, spill=False, exact_dense=True):
+    fn = tilemm._build_step_grad(spec, "logit", exact_dense, cache, spill)
+    args = [_pw(spec), ((spec.nb,), jnp.float32), _rows(spec), _rows(spec)]
+    return fn, args + ([_rows(spec)] if spill else [])
+
+
+def step_update(spec, cache=False):
+    fn = tilemm._build_step_update(spec, "logit", _ftrl(), cache)
+    return fn, [_pw(spec), ((spec.nb, 3), jnp.float32), _rows(spec),
+                _rows(spec)]
+
+
+def fwd_multi(spec, ch):
+    return (tilemm._build_fwd_multi(spec, ch),
+            [_pw(spec), ((spec.nb, ch), jnp.float32)])
+
+
+def bwd_multi(spec, ch):
+    return tilemm._build_bwd_multi(spec, ch), [_pw(spec), _rows(spec, ch)]
+
+
+def fm_step(spec, k, spill=False):
+    fn = tilemm._build_fm_step_fused(spec, k, "logit", spill)
+    args = [_pw(spec), ((spec.nb, k + 2), jnp.float32), _rows(spec),
+            _rows(spec)]
+    return fn, args + ([_rows(spec, k + 2)] if spill else [])
+
+
+def wd_step(spec, k, hidden):
+    fn = tilemm._build_wd_step_fused(spec, k, tuple(hidden), "logit")
+    sizes = [k, *hidden, 1]
+    mlp = {}
+    for i, (a, b) in enumerate(zip(sizes, sizes[1:])):
+        mlp[f"W{i}"] = ((a, b), jnp.float32)
+        mlp[f"b{i}"] = ((b,), jnp.float32)
+    return fn, [_pw(spec), ((spec.nb, k + 1), jnp.float32), _rows(spec),
+                _rows(spec), mlp]
+
+
+def gbdt_hist(n, feat, nodes, bins):
+    """histmm's matmul level histogram (plain XLA, no Pallas): Higgs is
+    28 features wide, depth 6 is 64 nodes, 256 bins."""
+    from functools import partial
+    fn = partial(histmm._dense_matmul, num_nodes=nodes, num_bins=bins)
+    return fn, [((n, feat), jnp.uint8), ((n,), jnp.int32),
+                ((n,), jnp.float32), ((n,), jnp.float32),
+                ((n,), jnp.float32)]
+
+
+def _criteo(tiles=None):
+    """The flagship geometry; ``tiles`` cuts the table (and with it the
+    unrolled tiles_step) for tier-1, per-tile widths unchanged."""
+    return tilemm.make_spec(tiles * tilemm.TILE if tiles else NB, **CRITEO)
+
+
+def _narrow():
+    """The geometry bench.py's cached A/B uses — one subblock of nnz=16
+    rows — which ``_onehot_cache_decision`` admits under ``auto``."""
+    from wormhole_tpu.data.crec import default_cap
+    spec = tilemm.make_spec(NB, 1, default_cap(16, NB))
+    assert tilemm.resolve_step_kernel("fused", spec=spec).cache
+    return spec
+
+
+def _high_nb():
+    """A K>1 spec from make_spec's cap <= 256 regime (nb = 2**26)."""
+    spec = tilemm.make_spec(1 << 26, 12, 128)
+    assert spec.fuse > 1
+    return spec
+
+
+def _case(build, id, slow=False, pallas=True):
+    return pytest.param(build, pallas, id=id,
+                        marks=[pytest.mark.slow] if slow else [])
+
+
+CASES = [
+    # tier-1: the path chip_smoke.py takes, at two tiles. A crec2 file
+    # from the normal writer carries ovf_cap > 0, so the trainer's step
+    # is step_grad(spill); step_update is the ovf_cap == 0 variant.
+    _case(lambda: fwd(_criteo(2)), "fwd-2tiles"),
+    _case(lambda: bwd(_criteo(2)), "bwd-2tiles"),
+    _case(lambda: step_grad(_criteo(2), spill=True),
+          "step_grad_spill-2tiles"),
+    _case(lambda: step_update(_criteo(2)), "step_update-2tiles"),
+    # by hand before a chip call: full geometry, and the other variants
+    _case(lambda: fwd(_criteo()), "fwd-criteo", slow=True),
+    _case(lambda: bwd(_criteo()), "bwd-criteo", slow=True),
+    _case(lambda: step_grad(_criteo(), spill=True),
+          "step_grad_spill-criteo", slow=True),
+    _case(lambda: step_grad(_criteo()), "step_grad-criteo", slow=True),
+    _case(lambda: step_update(_criteo()), "step_update-criteo", slow=True),
+    _case(lambda: bwd(_high_nb()), "bwd-K8", slow=True),
+    _case(lambda: step_update(_high_nb()), "step_update-K8", slow=True),
+    _case(lambda: step_grad(_narrow(), cache=True), "step_grad-cached",
+          slow=True),
+    _case(lambda: step_update(_narrow(), cache=True), "step_update-cached",
+          slow=True),
+    _case(lambda: fwd_multi(_criteo(), 10), "fwd_multi-fm8", slow=True),
+    _case(lambda: bwd_multi(_criteo(), 10), "bwd_multi-fm8", slow=True),
+    _case(lambda: fm_step(_criteo(), 8), "fm_step-k8", slow=True),
+    _case(lambda: fm_step(_criteo(), 8, spill=True), "fm_step_spill-k8",
+          slow=True),
+    _case(lambda: wd_step(_criteo(), 16, (64, 32)), "wd_step-16x64x32",
+          slow=True),
+    _case(lambda: gbdt_hist(1_000_000, 28, 64, 256), "gbdt_hist-higgs",
+          slow=True, pallas=False),
+]
+
+
+@pytest.mark.parametrize("build,pallas", CASES)
+def test_compiles_for_v5e(build, pallas, v5e):
+    fn, shapes = build()
+    one_chip = SingleDeviceSharding(v5e.devices[0])
+    args = jax.tree.map(
+        lambda sd: jax.ShapeDtypeStruct(sd[0], sd[1], sharding=one_chip),
+        shapes, is_leaf=lambda x: isinstance(x, tuple))
+    compiled = jax.jit(fn).lower(*args).compile()
+    if pallas:
+        assert "tpu_custom_call" in compiled.as_text(), \
+            "no Mosaic kernel in the compiled program"
+    assert compiled.memory_analysis().generated_code_size_in_bytes > 0
+
+
+@pytest.mark.parametrize("nb", [
+    pytest.param(4 * tilemm.TILE, id="2tiles-a-shard"),
+    pytest.param(NB, id="criteo", marks=pytest.mark.slow)])
+def test_mesh_step_compiles_for_v5e_2x2(nb, v5e):
+    """The whole ``data:2,model:2`` train step of the flagship store —
+    shard_map, the split fwd/bwd kernels on each model shard, the psums
+    — for the four described chips, with the NamedShardings the mesh
+    feed places its groups on. What ``chip_smoke.py --chips 4`` runs."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from wormhole_tpu.data.crec import CRec2Info
+    from wormhole_tpu.learners.store import (ShardedStore, StoreConfig,
+                                             TableCheckpoint,
+                                             mesh_step_specs)
+    from wormhole_tpu.parallel.mesh import MeshRuntime, make_mesh
+    shape = "data:2,model:2"
+    # the store places its table when built, which a described device
+    # cannot hold: build it on four host devices, then hand the step
+    # builder the described mesh
+    store = ShardedStore(
+        StoreConfig(num_buckets=nb), _ftrl(),
+        MeshRuntime(mesh=make_mesh(shape, jax.devices()[:4])))
+    store.rt = MeshRuntime(mesh=make_mesh(shape, v5e.devices))
+    spec = tilemm.make_spec(nb, **CRITEO)
+    oc = 1024                                   # CRec2Writer's default
+    info = CRec2Info(nnz=39, block_rows=spec.block_rows,
+                     total_rows=2 * spec.block_rows, nb=nb,
+                     ovf_cap=oc, **CRITEO)
+    step = store._tile_step_mesh(info, "train")
+    mesh = store.rt.mesh
+    Pm, Pblk, _ = mesh_step_specs(True)
+    lane = P("data", None)
+
+    def on(shape, dtype, spec):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    compiled = step.lower(
+        on((nb, 3), jnp.float32, Pm),
+        on((2, *spec.pairs_shape), jnp.uint32, Pblk),
+        on((2, spec.block_rows), jnp.uint8, lane),
+        on((2, oc), jnp.uint32, lane), on((2, oc), jnp.uint32, lane),
+        on((), jnp.int32, P()), on((), jnp.float32, P()),
+        on((TableCheckpoint.MACC_LEN,), jnp.float32, P())).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "all-reduce" in text

@@ -191,10 +191,7 @@ class PagedStore:
                                      np.empty((plan.victim_slots.size, 0)),
                                      self.page_chunk)
                 rows_dev = gather(self.hot.slots, idx_p)
-                try:
-                    rows_dev.copy_to_host_async()
-                except AttributeError:
-                    pass
+                rows_dev.copy_to_host_async()
             self._pending = (plan.victim_buckets, rows_dev,
                              int(plan.victim_slots.size))
         if plan.staged_rows is not None:

@@ -18,6 +18,7 @@ from wormhole_tpu.data.rowblock import RowBlock
 
 _LIB = None
 _TRIED = False
+_BUILD_ERROR = ""   # why the one-shot `make` failed, for build_error()
 
 _LIB_NAMES = ("libwormhole_data.so",)
 
@@ -42,6 +43,7 @@ def _try_build() -> Optional[str]:
     concurrent builders (multi-process launches on a fresh checkout would
     otherwise clobber each other's half-written .so)."""
     import subprocess
+    global _BUILD_ERROR
     here = os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
     ndir = os.path.join(here, "native")
@@ -55,17 +57,20 @@ def _try_build() -> Optional[str]:
             if found:                          # a peer built it first
                 return found
             subprocess.run(["make", "-C", ndir], capture_output=True,
-                           timeout=120, check=True)
-    except Exception as e:
-        import logging
-        logging.getLogger("wormhole_tpu.native").warning(
-            "native build failed (%s); falling back to Python parsers", e)
+                           text=True, timeout=120, check=True)
+    except (OSError, subprocess.SubprocessError) as e:
+        # the compiler's own words, not just the exit status
+        _BUILD_ERROR = f"{e}: {(getattr(e, 'stderr', '') or '')[-2000:]}"
+        from wormhole_tpu.utils.logging import get_logger
+        get_logger("native").warning(
+            "native build FAILED (%s); the Python parsers are live",
+            _BUILD_ERROR)
         return None
     return _find_lib()
 
 
 def _load():
-    global _LIB, _TRIED
+    global _LIB, _TRIED, _BUILD_ERROR
     if _TRIED:
         return _LIB
     _TRIED = True
@@ -76,7 +81,8 @@ def _load():
         return None
     try:
         lib = ctypes.CDLL(path)
-    except OSError:
+    except OSError as e:
+        _BUILD_ERROR = f"cannot load {path}: {e}"
         return None
     # int wh_parse(const char* fmt, const char* buf, int64 len,
     #              ParseOut* out);  see native/parse.cc for the ABI
@@ -175,3 +181,10 @@ def get_parser(fmt: str) -> Optional[Callable[[bytes], RowBlock]]:
 
 def available() -> bool:
     return _load() is not None
+
+
+def build_error() -> str:
+    """Why the one-shot ``make`` failed (empty when it did not run or
+    succeeded) — chip_smoke.py prints it next to the live parser."""
+    _load()
+    return _BUILD_ERROR

@@ -22,7 +22,7 @@ barrier-free checkpoint (the resume-version allreduce-min is the
 cross-rank agreement, so no peer sync is needed while peers may be
 dying), and returns cleanly.
 
-Exit-code taxonomy used to tell a *dead* rank from a *bystander*:
+Exit-code classes used to tell a *dead* rank from a *bystander*:
 0 (done), -15 (SIGTERMed by us), and PEER_LOST (watchdog abandoned a
 collective) are bystanders; anything else marks the rank dead.
 """

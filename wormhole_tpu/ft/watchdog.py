@@ -100,8 +100,8 @@ class CollectiveWatchdog:
         """Fire the exit path immediately, without waiting out the
         timeout. For callers that positively *detect* peer loss (the
         socket wire sees the connection drop) rather than infer it from
-        silence — the taxonomy (flight record + PEER_LOST exit, or the
-        injected test recorder) stays identical either way."""
+        silence — the exit path (flight record + PEER_LOST exit, or
+        the injected test recorder) stays identical either way."""
         self.fired_site = str(site)
         self._exit(str(site))
 

@@ -52,10 +52,10 @@ class WideDeepConfig:
     l2_v: float = 1e-5
     init_scale: float = 0.01
     seed: int = 0
-    tile_step_kernel: str = "auto"  # auto|fused|split: the MLP vjp runs
+    tile_step_kernel: str = "auto"  # auto|fused|split: the MLP runs
                                     # in-kernel at the fused phase
-                                    # boundary when the dense
-                                    # activations fit the VMEM budget
+                                    # boundary when its row-blocked
+                                    # weights fit the VMEM budget
                                     # (ops/tilemm.resolve_step_kernel)
     tile_onehot_cache: str = "auto"  # auto|on|off — accepted for config
                                      # parity; the multi-channel wd
@@ -75,9 +75,9 @@ def init_mlp(sizes: List[int], rng: np.random.Generator):
             jax.tree.map(jnp.asarray, accum))
 
 
-# The deep-tower forward lives in ops/tilemm.py so the fused wd step can
-# run the SAME function (and the same jax.vjp of it) inside the kernel's
-# boundary phase — re-exported here for the split path and external users.
+# The deep-tower forward lives in ops/tilemm.py beside the fused wd step,
+# whose boundary phase runs the same tower over grid-layout chunks —
+# re-exported here for the split path and external users.
 from wormhole_tpu.ops.tilemm import mlp_forward  # noqa: E402,F401
 
 
@@ -203,7 +203,7 @@ class WideDeepStore(TableCheckpoint):
     # build shared). Backward: dual backprops through the MLP via vjp to
     # d pooled (R, k); the embedding grads are plain channel pushes
     # [dual, dpooled_1..k] plus a row-mask count channel for the exact
-    # touched-bucket set. (VERDICT r3 Missing #3.)
+    # touched-bucket set.
 
     def _tile_step(self, info, kind: str):
         key = (info, kind)
@@ -215,8 +215,8 @@ class WideDeepStore(TableCheckpoint):
         from wormhole_tpu.ops.metrics import margin_hist
         cfg = self.cfg
         k = cfg.dim
-        # the MLP vjp runs in-kernel at the fused phase boundary when
-        # the dense activations fit the VMEM budget; spill blocks and
+        # the MLP runs in-kernel at the fused phase boundary when
+        # its row-blocked weights fit the VMEM budget; spill blocks and
         # oversized hidden widths fall back split with a recorded reason
         res = tilemm.resolve_step_kernel(
             getattr(cfg, "tile_step_kernel", "auto"), ovf_cap=info.ovf_cap,
@@ -285,7 +285,7 @@ class WideDeepStore(TableCheckpoint):
                     macc + packed, num_ex)
 
         if fused:
-            # one grid: embedding pulls, in-kernel MLP forward/vjp at
+            # one grid: embedding pulls, in-kernel MLP forward/backward at
             # the phase boundary, dual, channel pushes and MLP param
             # grads in a single dispatch (resolve_step_kernel admits
             # this only for spill-free blocks within the VMEM budget)
@@ -483,7 +483,7 @@ class WideDeepStore(TableCheckpoint):
                         for p in jax.tree.leaves(self.mlp))
         (self.slots, self.mlp, self.mlp_accum, t_new,
          self._macc) = self._mesh_transport().dispatch(
-            step, self.slots, self.mlp, self.mlp_accum,
+            step, self._mesh_table(), self.mlp, self.mlp_accum,
             blocks["pw"], blocks["labels"],
             blocks.get("ovf_b", z), blocks.get("ovf_r", z),
             self._t_device(), self._tau_const(tau), self._macc_buf(),
@@ -501,7 +501,7 @@ class WideDeepStore(TableCheckpoint):
         ch = self.cfg.dim + 1
         return self._mesh_transport().dispatch(
             self._tile_step_mesh(info, "eval"),
-            self.slots, self.mlp, self.mlp_accum, blocks["pw"],
+            self._mesh_table(), self.mlp, self.mlp_accum, blocks["pw"],
             blocks["labels"], blocks.get("ovf_b", z),
             blocks.get("ovf_r", z),
             ici_bytes=mesh_step_ici_bytes(

@@ -1,8 +1,7 @@
 """Step ledger: per-step wall-time attribution from trace spans.
 
-perf.md pins the tile kernels at ~55-65% of the MXU-pass floor and the
-headline step at 7.36 ms — but nothing *attributes* the gap. This module
-folds the spans the repo already records (Timer.scope keys, DeviceFeed
+An end-to-end rate says how long a step took, not where the host's
+time went. This module folds the spans the repo already records (Timer.scope keys, DeviceFeed
 stage spans, collective/checkpoint spans) into a small set of named
 buckets and an explicit ``unattributed`` remainder, so the buckets
 provably sum to the measured wall time instead of silently double- or
@@ -35,8 +34,8 @@ from __future__ import annotations
 import threading
 from typing import Dict, List, Optional, Tuple
 
-__all__ = ["SPAN_TABLE", "BUCKETS", "MXU_PASS_FLOOR_FRAC",
-           "span_bucket", "build", "to_registry"]
+__all__ = ["SPAN_TABLE", "BUCKETS", "span_bucket", "build",
+           "to_registry"]
 
 # Ledger buckets. ``host_prep`` (parse/localize/pad) and ``other``
 # (checkpoint I/O, GBDT chunk reads) extend the core six so the step
@@ -48,13 +47,6 @@ __all__ = ["SPAN_TABLE", "BUCKETS", "MXU_PASS_FLOOR_FRAC",
 BUCKETS = ("encode", "h2d_transfer", "device_compute", "collective_wait",
            "metrics_readback", "host_prep", "residual_stall", "paging",
            "other")
-
-# docs/perf.md: the tile kernels run at ~55-65% of the MXU-pass floor
-# (VPU one-hot builds + f32->bf16 conversion XLA won't overlap). The
-# ledger multiplies its device_compute fraction by this midpoint to
-# report an *estimated* MXU utilization for the whole step — the
-# documented kernel floor applied to the attributed device time.
-MXU_PASS_FLOOR_FRAC = 0.60
 
 # Central span-name table: every instrumentation-site span name (or
 # ``prefix*`` pattern for f-string sites) -> ledger bucket. Timer.scope
@@ -239,11 +231,10 @@ def build(events: List[dict], wall_s: Optional[float] = None,
         "unattributed_s": round(unattributed, 6),
         "frac": frac,
         "attributed_frac": round(attributed / denom, 4),
-        # device-bucket share of the wall, and that share scaled by the
-        # documented kernel floor fraction (docs/perf.md) — how much of
-        # the step is actual MXU work, by the ledger's accounting
+        # share of the wall the HOST spent inside device-bucket spans
+        # (dispatch + wait on the host clock). Not a device metric: busy
+        # share and kernel utilization come from a device trace only.
         "device_frac": round(device_frac, 4),
-        "est_mxu_util": round(device_frac * MXU_PASS_FLOOR_FRAC, 4),
         "spans_attributed": len(spans),
     }
 
@@ -270,6 +261,3 @@ def to_registry(led: dict, reg=None) -> None:
     reg.gauge("ledger/device_frac",
               help="step ledger: device_compute share of wall time"
               ).value = led["device_frac"]
-    reg.gauge("ledger/est_mxu_util",
-              help="device_frac x documented MXU-pass kernel floor "
-                   "fraction (docs/perf.md)").value = led["est_mxu_util"]

@@ -69,7 +69,7 @@ def margin_hist(labels: jax.Array, margin: jax.Array, mask: jax.Array,
     Margins are clipped to [lo, hi]; at lo/hi = +-14, sigma(14) =
     1 - 8e-7, so the clip reorders only rows the model separates to
     one-in-a-million confidence (the +-8 range used through round 3
-    saturated visibly late in training — VERDICT r3 Weak #5; widening
+    saturated visibly late in training; widening
     costs bin resolution 0.055 vs 0.031, invisible at display
     precision)."""
     b = (jnp.clip((margin - lo) / (hi - lo), 0.0, 1.0)

@@ -47,8 +47,10 @@ a criteo missing-value token) overflows to a small (bucket, row) COO list
 handled by the classic scatter path — exact, and empty for hashed
 uniform-ish data.
 
-Kernels run in pallas interpret mode off-TPU so the sharding/CI tests can
-run on the CPU mesh.
+Off the TPU backend the kernels run in Pallas interpret mode, which is
+how the CPU tests drive them; the first such build says so on the log.
+Interpret mode is a correctness tool only — a run that asks for the chip
+calls ``parallel.mesh.require_tpu`` first and fails without one.
 """
 
 from __future__ import annotations
@@ -81,8 +83,26 @@ LO_M, HI_M, RLO_M, RHI_M = 127, 511, 127, 63
 PADWORD = np.uint32(511 << HI_SH)
 
 
+@lru_cache(maxsize=None)
+def _log_interpret(backend: str) -> None:
+    """Once per backend: say that the kernels are being interpreted."""
+    from wormhole_tpu.utils.logging import get_logger
+    get_logger("tilemm").warning(
+        "backend is %r, not tpu: tile kernels build in Pallas INTERPRET "
+        "mode (correctness only; no time or rate from this process is a "
+        "device number)", backend)
+
+
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """True off the TPU backend: the kernels then build in Pallas
+    interpret mode (the CPU tests' path). Never silent — the first
+    build that takes it logs the backend, so no output can be read as
+    a device run by mistake."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    _log_interpret(backend)
+    return True
 
 
 @dataclass(frozen=True)
@@ -354,16 +374,20 @@ def _fwd_kernel(spec: TileSpec, pw_ref, w_ref, mg_ref, t=None):
 
 
 def _fwd_kernel_cached(spec: TileSpec, pw_ref, w_ref, mg_ref,
-                       rep_c, lo_c, rlo_c, t):
-    """_fwd_kernel staging the one-hot cache as it computes: the
-    packed-word lanes->sublanes relayout (rep) and the lo/rlo digit
-    compare planes it already builds per (group, tile) are written to
-    full-tile-set VMEM scratch so phase 2 replays them instead of
-    rebuilding (the round-5 floor model charges the residual VPU time
-    to exactly these rebuilds, docs/perf.md round 8). The compute is
-    bitwise IDENTICAL to _fwd_kernel — the staged planes are the same
-    booleans the uncached body folds into its selects. Only used from
-    the fused step grid, which passes its own grid index ``t``."""
+                       lo_c, rlo_c, t):
+    """_fwd_kernel staging the one-hot cache as it computes: the lo/rlo
+    digit compare planes it already builds per (group, tile) are
+    written to full-tile-set VMEM scratch so phase 2 replays them
+    instead of rebuilding (the round-5 floor model charges the residual
+    VPU time to exactly these rebuilds, docs/perf.md round 8). The
+    packed-word relayout is NOT staged: an (N, 1) i32 column occupies a
+    full 128-lane vreg row in VMEM, 512 B a slot — as much as both
+    planes together — and the chip's compiler refused the scratch for
+    it (PERF.md, PR 23); phase 2 redoes that one relayout per chain.
+    The compute is bitwise IDENTICAL to _fwd_kernel — the staged planes
+    are the same booleans the uncached body folds into its selects.
+    Only used from the fused step grid, which passes its own grid index
+    ``t``."""
     @pl.when(t == 0)
     def _():
         mg_ref[:] = jnp.zeros_like(mg_ref)
@@ -383,7 +407,6 @@ def _fwd_kernel_cached(spec: TileSpec, pw_ref, w_ref, mg_ref,
             # re-visits pairs block j, so nothing is evictable at the
             # phase boundary and the cache spans all T tiles (this is
             # what onehot_cache_bytes budgets against VMEM)
-            rep_c[t * TB + tb, g] = rep
             lo_c[t * TB + tb, g] = cond_lo.astype(jnp.bfloat16)
             rlo_c[t * TB + tb, g] = cond_rlo.astype(jnp.bfloat16)
             ohhi = _oh_rep(rep, HI_SH, HI_M, N, 128)       # pad -> 0 row
@@ -401,10 +424,10 @@ def _fwd_kernel_cached(spec: TileSpec, pw_ref, w_ref, mg_ref,
 
 
 def _bwd_kernel_cached(spec: TileSpec, pw_ref, dual_ref, g_ref,
-                       rep_c, lo_c, rlo_c, tj):
-    """_bwd_kernel replaying the phase-1 one-hot cache: the packed-word
-    relayout and the lo/rlo compare planes load from VMEM instead of
-    being rebuilt — only the joint subblock-parity digit (ohghi, a
+                       lo_c, rlo_c, tj):
+    """_bwd_kernel replaying the phase-1 one-hot cache: the lo/rlo
+    compare planes load from VMEM instead of being rebuilt — the
+    packed-word relayout, the joint subblock-parity digit (ohghi, a
     bwd-only layout) and the lanes-native histogram lhs (ohhiT, no
     relayout to save) are still built here. The staged bf16 0/1 planes
     recover the original booleans exactly (``!= 0``), so the selects —
@@ -421,14 +444,13 @@ def _bwd_kernel_cached(spec: TileSpec, pw_ref, dual_ref, g_ref,
     for tb in range(TB):
         acc = jnp.zeros((A_HI, B_LO), jnp.float32)
         for g in range(S // GS):
-            rep_g = rep_c[tj * TB + tb, g]                 # (N, 1) i32
             lo_g = lo_c[tj * TB + tb, g]                   # (N, 128) 0/1
             rlo_g = rlo_c[tj * TB + tb, g]                 # (N, 128) 0/1
             for h in range(GS // bp):
                 sp = (g * GS) // bp + h
                 sl = slice(h * NC, (h + 1) * NC)
                 pc = pw_ref[tb, g, sl].astype(jnp.int32)
-                rep = rep_g[sl]
+                rep = pc[:, None]                          # one relayout
                 ohghi = ((rep & (RHI_M << RHI_SH))
                          == iota_ghi_sh).astype(jnp.bfloat16)
                 md = jnp.dot(ohghi, dual_ref[sp],
@@ -985,8 +1007,9 @@ def backward_pushes(pw: jax.Array, dual_rows: jax.Array, spec: TileSpec,
 # split forward_margins runs, so parity survives (only the grad-side
 # scatter stays in XLA, where the dual recomputed from the emitted
 # margins is bitwise-equal). Wide&deep fuses by running the MLP
-# forward/vjp at the boundary (a dense third phase between the
-# embedding pulls and pushes), budgeted against VMEM below. Only the
+# forward and backward at the boundary (a dense third phase between
+# the embedding pulls and pushes, in grid-layout chunks; equal to the
+# split path to rounding, not bitwise), budgeted against VMEM below. Only the
 # mesh path stays structurally split: psums over MODEL (margins) and
 # DATA (grads) sit at exactly the two seams the fusion removes.
 #
@@ -1015,25 +1038,32 @@ VMEM_EXTRA_BUDGET = VMEM_LIMIT_BYTES - WORKING_SET_VREGS * _VREG_BYTES
 
 def onehot_cache_bytes(spec: TileSpec) -> int:
     """Bytes of the phase-shared one-hot cache: per (tile, group) the
-    staged planes are the (N, 1) i32 packed-word relayout and two
-    (N, 128) bf16 digit compare planes, held for the FULL tile set
-    (phase 2's grid step nt+j revisits pairs block j, so nothing is
-    evictable at the phase boundary)."""
+    staged planes are two (N, 128) bf16 digit compare planes, held for
+    the FULL tile set (phase 2's grid step nt+j revisits pairs block j,
+    so nothing is evictable at the phase boundary). Both planes are
+    128 lanes wide, so this is also what they occupy in VMEM."""
     SG = spec.subblocks // spec.group
-    return spec.tiles * SG * spec.n * (4 + 2 * B_LO + 2 * RL)
+    return spec.tiles * SG * spec.n * (2 * B_LO + 2 * RL)
+
+
+MLP_ROWS = 8   # grid sublanes (row-hi digits) per in-kernel MLP chunk
 
 
 def mlp_phase_bytes(spec: TileSpec, dim: int, hidden: Tuple[int, ...]
                     ) -> int:
     """VMEM bytes the wide&deep boundary phase holds live: the pulls
-    (f32) and dual (bf16) channel grids plus the MLP activations the
-    in-kernel vjp keeps across block_rows rows (primal + cotangent,
-    f32, one column per pooled input / hidden unit / output)."""
+    (f32) and dual (bf16) channel grids, plus the MLP weights in the
+    row-blocked form the phase multiplies by (_wd_blocked_params:
+    MLP_ROWS**2 times the raw matrix), three times over — the matrix,
+    its transpose for the backward, and its gradient. The activations
+    are one (width * MLP_ROWS, 128) chunk at a time and do not
+    count."""
     rows = spec.block_rows
     ch_in, ch_out = 1 + dim, dim + 2
     grids = rows * (ch_in * 4 + ch_out * 2)
-    acts = rows * (dim + sum(hidden) + 1) * 2 * 4
-    return grids + acts
+    sizes = [dim, *hidden, 1]
+    weights = sum(a * b for a, b in zip(sizes, sizes[1:]))
+    return grids + 3 * MLP_ROWS * MLP_ROWS * weights * 4
 
 
 @dataclass(frozen=True)
@@ -1131,7 +1161,7 @@ def resolve_step_kernel(kernel: str, *, ovf_cap: int = 0,
         if need > VMEM_EXTRA_BUDGET:
             return res("split", (f"wide&deep MLP phase needs ~"
                                  f"{need // 2**20} MB of VMEM for the "
-                                 f"dense activations, over the "
+                                 f"row-blocked weights, over the "
                                  f"{VMEM_EXTRA_BUDGET // 2**20} MB "
                                  f"left beside the working set"))
     if kernel == "split":
@@ -1193,7 +1223,7 @@ def _make_step_kernel(spec: TileSpec, loss: str, exact_dense: bool,
         else:
             mg_ref, g_ref, *scr = rest
         if cache:
-            dual_s, rep_c, lo_c, rlo_c = scr
+            dual_s, lo_c, rlo_c = scr
         else:
             (dual_s,) = scr
         t = pl.program_id(0)
@@ -1202,7 +1232,7 @@ def _make_step_kernel(spec: TileSpec, loss: str, exact_dense: bool,
         def _fwd():
             if cache:
                 _fwd_kernel_cached(spec, pw_ref, wt_ref, mg_ref,
-                                   rep_c, lo_c, rlo_c, t)
+                                   lo_c, rlo_c, t)
             else:
                 _fwd_kernel(spec, pw_ref, wt_ref, mg_ref, t)
 
@@ -1232,7 +1262,7 @@ def _make_step_kernel(spec: TileSpec, loss: str, exact_dense: bool,
             if handle is None:
                 if cache:
                     _bwd_kernel_cached(spec, pw_ref, dual_s, g_ref,
-                                       rep_c, lo_c, rlo_c, t - nt)
+                                       lo_c, rlo_c, t - nt)
                 elif K > 1:
                     _bwd_kernel_fused(spec, pwk_ref, dual_s, ghic_ref,
                                       g_ref)
@@ -1242,7 +1272,7 @@ def _make_step_kernel(spec: TileSpec, loss: str, exact_dense: bool,
             sink = _GradSink()
             if cache:
                 _bwd_kernel_cached(spec, pw_ref, dual_s, sink,
-                                   rep_c, lo_c, rlo_c, t - nt)
+                                   lo_c, rlo_c, t - nt)
             elif K > 1:
                 _bwd_kernel_fused(spec, pwk_ref, dual_s, ghic_ref, sink)
             else:
@@ -1292,13 +1322,11 @@ def _step_grid_specs(spec: TileSpec, spill: bool = False):
 
 
 def _cache_scratch(spec: TileSpec):
-    """The one-hot cache's VMEM scratch: the packed-word relayout
-    column and the two digit compare planes, for every (tile, group) —
-    the shapes onehot_cache_bytes budgets."""
+    """The one-hot cache's VMEM scratch: the two digit compare planes
+    for every (tile, group) — the shapes onehot_cache_bytes budgets."""
     T = spec.tiles
     SG, N = spec.subblocks // spec.group, spec.n
-    return [pltpu.VMEM((T, SG, N, 1), jnp.int32),
-            pltpu.VMEM((T, SG, N, B_LO), jnp.bfloat16),
+    return [pltpu.VMEM((T, SG, N, B_LO), jnp.bfloat16),
             pltpu.VMEM((T, SG, N, RL), jnp.bfloat16)]
 
 
@@ -1574,11 +1602,10 @@ def _build_fm_step_fused(spec: TileSpec, k: int, loss: str,
 
 def mlp_forward(params: dict, x: jax.Array, n_layers: int) -> jax.Array:
     """Dense MLP forward on the pooled embeddings (wide&deep's deep
-    tower; models/wide_deep.py re-exports this). Lives here so the
-    fused wd step can run the SAME function — and the same jax.vjp of
-    it — inside the boundary phase: jit-compiled XLA and the in-kernel
-    trace produce bitwise-identical values for the same graph, which
-    is what keeps fused-vs-split parity a hard contract."""
+    tower; models/wide_deep.py re-exports this): the split step's, the
+    eval step's and the serving path's. The fused wd step runs the
+    same tower over grid-layout chunks (_make_wd_step_kernel), and is
+    held to this function at float tolerance."""
     h = x
     for i in range(n_layers):
         h = h @ params[f"W{i}"] + params[f"b{i}"]
@@ -1587,32 +1614,73 @@ def mlp_forward(params: dict, x: jax.Array, n_layers: int) -> jax.Array:
     return h[:, 0]
 
 
+def _wd_blocked_params(mlp: dict, n_layers: int):
+    """The deep tower's parameters in the form the in-kernel MLP phase
+    multiplies by. The pulls grid keeps a row's channels on LANE blocks
+    and the rows themselves on (sublane, lane), so a plain ``x @ W``
+    over (rows, channels) needs a relayout Mosaic has no lowering for
+    (and would pad every activation to 128 lanes: 50 MB apiece at
+    98,304 rows). Instead one chunk of MLP_ROWS grid sublanes is
+    stacked channel-major on sublanes — index ``c * MLP_ROWS + r`` —
+    and each layer is ``kron(W.T, I) @ chunk``: the identity block
+    keeps the MLP_ROWS rows apart, so row r of channel j comes out at
+    ``j * MLP_ROWS + r``, already in grid layout. Returns per layer
+    (Wk, Wk.T, bk): (b*R, a*R), (a*R, b*R), (b*R, 1)."""
+    eye = jnp.eye(MLP_ROWS, dtype=jnp.float32)
+    out = []
+    for i in range(n_layers):
+        wk = jnp.kron(mlp[f"W{i}"].T, eye)
+        out += [wk, wk.T, jnp.repeat(mlp[f"b{i}"], MLP_ROWS)[:, None]]
+    return out
+
+
+def _wd_unblock_grads(g_blocked, sizes):
+    """Blocked parameter gradients -> the MLP's own shapes: the tied
+    entries of kron(W.T, I) sum back over the identity's diagonal."""
+    R = MLP_ROWS
+    g_mlp = {}
+    for i, (a, b) in enumerate(zip(sizes, sizes[1:])):
+        gwk, gbk = g_blocked[2 * i], g_blocked[2 * i + 1]
+        g_mlp[f"W{i}"] = jnp.einsum("jrcr->cj", gwk.reshape(b, R, a, R))
+        g_mlp[f"b{i}"] = gbk.reshape(b, R).sum(axis=1)
+    return g_mlp
+
+
 def _make_wd_step_kernel(spec: TileSpec, ch_in: int, ch_out: int,
                          k: int, n_layers: int, loss: str, nt: int):
     """Three-phase wide&deep kernel body: phase 1 is the unmodified
     _fwd_multi_kernel accumulating the (S, RH, ch_in*RL) pulls grid in
-    VMEM scratch; the boundary is the DENSE phase — it unpacks the
-    pulls to (rows, ch_in) exactly as the split wrapper does in XLA,
-    runs the MLP forward + vjp on the pooled embeddings (mlp_forward,
-    the same function the split path jits), computes the dual, and
-    packs [dual, g_pooled_j..., mask] back to the channel-major dual
-    grid; phase 2 is the unmodified _bwd_multi_kernel over ch_out push
-    channels. The per-parameter MLP grads leave through constant-index
-    outputs written once at the boundary. No nudge: the split wd path
-    applies none (AdaGrad + explicit touched mask), and parity with it
-    is the contract."""
+    VMEM scratch; the boundary is the DENSE phase — the MLP forward and
+    backward over the pooled embeddings, the dual, and the
+    [dual, g_pooled_j..., mask] push channels written to the
+    channel-major dual grid; phase 2 is the unmodified
+    _bwd_multi_kernel over ch_out push channels. The per-parameter MLP
+    grads leave (row-blocked, see _wd_blocked_params) through
+    constant-index outputs accumulated at the boundary. No nudge: the
+    split wd path applies none (AdaGrad + explicit touched mask).
+
+    The dense phase walks the grid in chunks of MLP_ROWS sublanes x RL
+    lanes (1024 rows), every value in grid layout throughout, so it
+    needs no relayout. It is the same MLP as ``mlp_forward`` (the split
+    path's, and the reference the tests compare against at float
+    tolerance) summed in another order, so the two paths agree to
+    rounding, not bitwise."""
     from .loss import create_loss
     _, dual_fn = create_loss(loss)
     S = spec.subblocks
     bp = _bp(spec)
-    rows = spec.block_rows
+    R = MLP_ROWS
+    NI = RH // R                      # chunks per subblock
+    UN = 2                            # chunks per loop step: the bf16
+    #                                   dual grid stores 16 sublanes
+    NT_DIMS = (((1,), (1,)), ((), ()))
 
     def kernel(*refs):
         pw_ref, wt_ref, lab_ref, msk_ref = refs[:4]
-        p_refs = refs[4:4 + 2 * n_layers]
-        mg_ref, push_ref = refs[4 + 2 * n_layers:6 + 2 * n_layers]
-        g_refs = refs[6 + 2 * n_layers:6 + 4 * n_layers]
-        pulls_s, dual_s = refs[6 + 4 * n_layers:]
+        p_refs = refs[4:4 + 3 * n_layers]
+        mg_ref, push_ref = refs[4 + 3 * n_layers:6 + 3 * n_layers]
+        g_refs = refs[6 + 3 * n_layers:6 + 5 * n_layers]
+        pulls_s, dual_s = refs[6 + 5 * n_layers:]
         t = pl.program_id(0)
 
         @pl.when(t < nt)
@@ -1621,35 +1689,57 @@ def _make_wd_step_kernel(spec: TileSpec, ch_in: int, ch_out: int,
 
         @pl.when(t == nt)
         def _mlp():
-            # channel-major grid -> (rows, ch_in): the same unpack the
-            # split _build_fwd_multi wrapper runs in XLA
-            pg = pulls_s[...]
-            pulls = (pg.reshape(S, RH, ch_in, RL).transpose(0, 1, 3, 2)
-                     .reshape(rows, ch_in))
-            mlp = {}
-            for i in range(n_layers):
-                mlp[f"W{i}"] = p_refs[2 * i][...]
-                mlp[f"b{i}"] = p_refs[2 * i + 1][...][0]
-            pooled = pulls[:, 1:]
-            deep_fn = lambda m, x: mlp_forward(m, x, n_layers)
-            deep, vjp = jax.vjp(deep_fn, mlp, pooled)
-            margin = pulls[:, 0] + deep
-            lab = lab_ref[...].reshape(rows)
-            msk = msk_ref[...].reshape(rows)
-            dual = dual_fn(margin, lab, msk)
-            g_mlp, g_pooled = vjp(dual)
-            for i in range(n_layers):
-                g_refs[2 * i][...] = g_mlp[f"W{i}"]
-                g_refs[2 * i + 1][...] = g_mlp[f"b{i}"][None, :]
-            mg_ref[...] = margin.reshape(S, RH, RL)
-            # [dual, g_pooled..., mask] — the exact dvals concat the
-            # split path builds — packed channel-major for phase 2
-            dvals = jnp.concatenate(
-                [dual[:, None], g_pooled, msk[:, None]], axis=1)
-            dv = (dvals.reshape(S // bp, bp * RH, RL, ch_out)
-                  .transpose(0, 1, 3, 2)
-                  .reshape(S // bp, bp * RH, ch_out * RL))
-            dual_s[...] = dv.astype(jnp.bfloat16)
+            for gr in g_refs:
+                gr[...] = jnp.zeros_like(gr)
+            wk = [p_refs[3 * i][...] for i in range(n_layers)]
+            wkt = [p_refs[3 * i + 1][...] for i in range(n_layers)]
+            bk = [p_refs[3 * i + 2][...] for i in range(n_layers)]
+
+            def chunk(s, r0):
+                g = pulls_s[s, pl.ds(r0, R), :]        # (R, ch_in*RL)
+                lab = lab_ref[s, pl.ds(r0, R), :]
+                msk = msk_ref[s, pl.ds(r0, R), :]
+                # pooled channels stacked channel-major on sublanes
+                acts = [jnp.concatenate(
+                    [g[:, (1 + j) * RL:(2 + j) * RL] for j in range(k)],
+                    axis=0)]                           # (k*R, RL)
+                pre = []
+                for i in range(n_layers):
+                    z = jnp.dot(wk[i], acts[-1],
+                                preferred_element_type=jnp.float32) + bk[i]
+                    pre.append(z)
+                    if i + 1 < n_layers:
+                        acts.append(jnp.maximum(z, 0.0))
+                margin = g[:, 0:RL] + pre[-1]          # wide + deep
+                mg_ref[s, pl.ds(r0, R), :] = margin
+                dual = dual_fn(margin, lab, msk)
+                d = dual
+                for i in reversed(range(n_layers)):
+                    g_refs[2 * i][...] += jax.lax.dot_general(
+                        d, acts[i], NT_DIMS,
+                        preferred_element_type=jnp.float32)
+                    g_refs[2 * i + 1][...] += jnp.sum(d, axis=1,
+                                                      keepdims=True)
+                    d = jnp.dot(wkt[i], d,
+                                preferred_element_type=jnp.float32)
+                    if i:
+                        d = jnp.where(pre[i - 1] > 0.0, d, 0.0)
+                # [dual, g_pooled..., mask], channel-major on lanes
+                return jnp.concatenate(
+                    [dual] + [d[j * R:(j + 1) * R] for j in range(k)]
+                    + [msk], axis=1)                   # (R, ch_out*RL)
+
+            def step(b, carry):
+                s = b // (NI // UN)
+                r0 = pl.multiple_of((b % (NI // UN)) * (UN * R), UN * R)
+                dv = jnp.concatenate(
+                    [chunk(s, r0 + u * R) for u in range(UN)], axis=0)
+                off = pl.multiple_of((s % bp) * RH + r0, UN * R)
+                dual_s[s // bp, pl.ds(off, UN * R), :] = \
+                    dv.astype(jnp.bfloat16)
+                return carry
+
+            jax.lax.fori_loop(0, S * NI // UN, step, 0)
 
         @pl.when(t >= nt)
         def _bwd():
@@ -1675,9 +1765,13 @@ def _build_wd_step_fused(spec: TileSpec, k: int,
     nt = T // TB
     sizes = [k] + list(hidden) + [1]
     n_layers = len(sizes) - 1
+    R = MLP_ROWS
     kernel = _make_wd_step_kernel(spec, ch_in, ch_out, k, n_layers,
                                   loss, nt)
     const_grid = pl.BlockSpec((S, RH, RL), lambda t: (0, 0, 0))
+
+    def whole(shape):
+        return pl.BlockSpec(shape, lambda t: (0, 0))
 
     @jax.jit
     def step(pw, wpull, labels, mask, mlp):
@@ -1685,7 +1779,8 @@ def _build_wd_step_fused(spec: TileSpec, k: int,
         wt = (wpull.reshape(T, A_HI, B_LO, ch_in).transpose(0, 1, 3, 2)
               .reshape(T, A_HI, ch_in * B_LO).astype(jnp.bfloat16))
         args = [pw, wt, labels.reshape(S, RH, RL),
-                mask.reshape(S, RH, RL)]
+                mask.reshape(S, RH, RL)] + _wd_blocked_params(mlp,
+                                                              n_layers)
         in_specs = [
             pl.BlockSpec((TB, SG, N), lambda t: (t % nt, 0, 0)),
             pl.BlockSpec((TB, A_HI, ch_in * B_LO),
@@ -1693,15 +1788,12 @@ def _build_wd_step_fused(spec: TileSpec, k: int,
             const_grid, const_grid,
         ]
         g_specs, g_shapes = [], []
-        for i in range(n_layers):
-            a, b = sizes[i], sizes[i + 1]
-            args += [mlp[f"W{i}"], mlp[f"b{i}"][None, :]]
-            in_specs += [pl.BlockSpec((a, b), lambda t: (0, 0)),
-                         pl.BlockSpec((1, b), lambda t: (0, 0))]
-            g_specs += [pl.BlockSpec((a, b), lambda t: (0, 0)),
-                        pl.BlockSpec((1, b), lambda t: (0, 0))]
-            g_shapes += [jax.ShapeDtypeStruct((a, b), jnp.float32),
-                         jax.ShapeDtypeStruct((1, b), jnp.float32)]
+        for a, b in zip(sizes, sizes[1:]):
+            in_specs += [whole((b * R, a * R)), whole((a * R, b * R)),
+                         whole((b * R, 1))]
+            g_specs += [whole((b * R, a * R)), whole((b * R, 1))]
+            g_shapes += [jax.ShapeDtypeStruct((b * R, a * R), jnp.float32),
+                         jax.ShapeDtypeStruct((b * R, 1), jnp.float32)]
         outs = pl.pallas_call(
             kernel,
             grid=(2 * nt,),
@@ -1726,13 +1818,10 @@ def _build_wd_step_fused(spec: TileSpec, k: int,
             interpret=_interpret(),
         )(*args)
         mg, push = outs[0], outs[1]
-        g_mlp = {}
-        for i in range(n_layers):
-            g_mlp[f"W{i}"] = outs[2 + 2 * i]
-            g_mlp[f"b{i}"] = outs[3 + 2 * i][0]
         pushes = (push.reshape(T, A_HI, ch_out, B_LO)
                   .transpose(0, 1, 3, 2).reshape(spec.nb, ch_out))
-        return mg.reshape(spec.block_rows), pushes, g_mlp
+        return (mg.reshape(spec.block_rows), pushes,
+                _wd_unblock_grads(outs[2:], sizes))
 
     return step
 
@@ -1798,7 +1887,7 @@ def fused_wd_step(pw: jax.Array, wpull: jax.Array, labels: jax.Array,
                   hidden: Tuple[int, ...], loss: str):
     """One-grid wide&deep step: (margins (rows,), pushes (nb, k+2),
     g_mlp param-grad tree) — the embedding pulls, the in-kernel MLP
-    forward/vjp, the dual, and the pushes in one dispatch. Spill-free
+    forward/backward, the dual, and the pushes in one dispatch. Spill-free
     blocks only (resolve_step_kernel sends wd spill to split); the
     sparse/dense updates stay in XLA, identical to the split tail."""
     return _build_wd_step_fused(spec, k, tuple(hidden), loss)(

@@ -43,7 +43,7 @@ Design:
 - **Fault surface.** Blocking waits sit under the stack's
   ``WatchdogLayer`` like every other wire. A disconnect is detected
   immediately by the peer's recv thread; a caller blocked on that peer
-  then takes the SAME taxonomy the supervisor already handles — the
+  then takes the SAME exit path the supervisor already handles — the
   installed watchdog's exit path (flight record + ``PEER_LOST`` 117)
   when one is configured, else :class:`PeerLostError`.
 
@@ -119,7 +119,7 @@ class FrameError(ValueError):
 
 class PeerLostError(RuntimeError):
     """A peer's connection died while a collective was waiting on it.
-    ``exit_code`` mirrors the watchdog taxonomy so callers that map
+    ``exit_code`` mirrors the watchdog's exit codes so callers that map
     errors to process exits use the code the supervisor expects."""
 
     exit_code = _watchdog.PEER_LOST
@@ -550,7 +550,7 @@ class SocketWire(Wire):
             self._cv.notify_all()
 
     def _peer_lost(self, rank: int, site: Optional[str]) -> None:
-        """Surface a disconnect with the taxonomy the supervisor
+        """Surface a disconnect with the exit-code scheme the supervisor
         already handles: the installed watchdog's exit path (flight
         record + PEER_LOST exit) when one is configured — a disconnect
         is a *detected* peer loss, there is nothing to wait out — else

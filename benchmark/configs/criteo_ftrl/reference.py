@@ -12,26 +12,32 @@ kernels round them to bfloat16; ``table`` rounds the stored state after each
 step. Both are for the control of ``correct`` (PERF.md section 2): the
 configuration states bfloat16 operands and a float32 table, so the control is
 ``operands="float8_e4m3fn"``.
+
+``exact_pairs`` (one ``(buckets, rows)`` a step) names the pairs that the
+file's COO overflow list holds: the configuration states float32 for that
+path, so those pairs take the weight and the row's dual unrounded, whatever
+``operands`` is. ``None`` rounds every pair.
 """
 
 from __future__ import annotations
 
 import numpy as np
 
-from benchmark.check import block_pairs, round_to
+from benchmark.check import block_pairs, exact_masks, round_to, take
 
 LEAVES = ("w",)
 
 
 class Reference:
     def __init__(self, config: dict, blocks: list, seed: int,
-                 operands=None, table=None):
+                 operands=None, table=None, exact_pairs=None):
         h = config["hyper"]
         self.l1, self.l2 = float(h["lambda1"]), float(h["lambda2"])
         self.alpha, self.beta = float(h["lr_eta"]), float(h["lr_beta"])
         self.operands, self.table = operands, table
         nb = int(config["num_buckets"])
         self.pairs, self.ids = block_pairs(blocks, nb)
+        self.exact = exact_masks(self.pairs, exact_pairs, nb)
         n = len(self.ids)
         self.w, self.z, self.cg = np.zeros(n), np.zeros(n), np.zeros(n)
         self.first_grad = None
@@ -42,14 +48,17 @@ class Reference:
         """One update from the next block; returns its mean loss."""
         keys, labels = self._blocks[self._step]
         buckets, rows = self.pairs[self._step]
+        exact = self.exact[self._step]
         idx = np.searchsorted(self.ids, buckets)
         n_rows = keys.shape[0]
-        m = np.bincount(rows, weights=round_to(self.w, self.operands)[idx],
+        m = np.bincount(rows, weights=take(self.w, idx, self.operands, exact),
                         minlength=n_rows)
         y = 2.0 * labels - 1.0
         loss = float(np.logaddexp(0.0, -y * m).mean())
-        dual = round_to(-y / (1.0 + np.exp(y * m)), self.operands)
-        grad = np.bincount(idx, weights=dual[rows], minlength=len(self.ids))
+        dual = -y / (1.0 + np.exp(y * m))
+        grad = np.bincount(idx, weights=take(dual, rows, self.operands,
+                                             exact),
+                           minlength=len(self.ids))
         if self.first_grad is None:
             self.first_grad = grad
         cg = np.sqrt(self.cg * self.cg + grad * grad)

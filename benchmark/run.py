@@ -92,13 +92,15 @@ def check_first_steps(sut, hooks, reference, config: dict, limits: dict,
     observed["change_norms"] = hooks.change_norms(sut.app, config, seed)
     program_s = time.perf_counter() - t
 
-    # the reference: host, float64, operands rounded as the configuration
-    # states
+    # the reference: host, float64, each path's operands rounded as the
+    # configuration states (the file's overflow pairs are handed to it
+    # where the configuration states a precision for that path)
     t = time.perf_counter()
     steps = check.merge_groups(sut.check_blocks, sut.group)
-    expected, ref = check.run_reference(
-        reference, config, steps, seed,
-        operands=check.stated_operands(config))
+    stated = check.stated_precision(config, check.merge_exact_pairs(
+        sut.check_overflow, sut.check_blocks, sut.group))
+    expected, ref = check.run_reference(reference, config, steps, seed,
+                                        **stated)
     buckets = check.sample_buckets(ref, seed, int(config["check"]["sample"]))
     expected["state"] = ref.state(buckets)
     reference_s = time.perf_counter() - t
@@ -114,17 +116,30 @@ def check_first_steps(sut, hooks, reference, config: dict, limits: dict,
         f"{observed['change_norms']} (reference {expected['change_norms']})")
     for line in lines:
         say(line)
+    exact = stated.get("exact_pairs")
     say(f"check: reference took {reference_s:.2f}s (not set-up); "
-        f"{len(buckets)} sampled buckets of {len(ref.ids)} touched")
+        f"{len(buckets)} sampled buckets of {len(ref.ids)} touched; pairs "
+        "taken unrounded a step (the file's overflow lists): "
+        f"{[len(b) for b, _r in exact] if exact is not None else 'none'}")
     if control:
         t = time.perf_counter()
         variants = dict(config["check"]["controls"],
                         exact_operands={"operands": None})
         for name, precision in variants.items():
+            # each differs from the reference in what its name says alone
             got, _ = check.run_reference(reference, config, steps, seed,
-                                         buckets=buckets, **precision)
+                                         buckets=buckets,
+                                         **dict(stated, **precision))
             say(f"control {name} {json.dumps(precision)}: "
                 f"{json.dumps(check.numbers(got, expected))}")
+        if exact is not None:
+            # what the program reads against a reference that rounds the
+            # overflow pairs too (the reference of PRs 25-27)
+            rounded, _ = check.run_reference(
+                reference, config, steps, seed, buckets=buckets,
+                **dict(stated, exact_pairs=None))
+            say("program against every pair rounded (not compared): "
+                f"{json.dumps(check.numbers(observed, rounded))}")
         reference_s += time.perf_counter() - t
     return {"correct": correct, "program_s": program_s,
             "reference_s": reference_s, "distinct": expected["distinct"]}
@@ -156,7 +171,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     pass): the script has no option or variable for them. ``control`` also
     computes the lower-precision controls of ``correct`` and prints them."""
     import numpy as np
-    from benchmark import peaks, system, trace_reduce
+    from benchmark import check, peaks, system, trace_reduce
     bench = load_json(os.path.join(root, "BENCHMARK.json"))
     cell = find_cell(bench, workload)
     chips = int(cell["chips"])
@@ -170,9 +185,6 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     hooks = config_module(cell["config"], "system")
     reference = config_module(cell["config"], "reference")
     roofline = config_module(cell["config"], "roofline")
-    limits = dict(config["check"]["limits"],
-                  **config["check"].get("limits_by_traffic", {})
-                  .get(cell["traffic"], {}))
     workdir = workdir or os.path.join(root, "benchmark", ".cache", workload)
     trace_dir = os.path.join(workdir, "trace")
 
@@ -202,8 +214,9 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
             parts["data_s"] = time.perf_counter() - t_data
             say(f"work per block: {json.dumps(work)}")
 
-            checked = check_first_steps(sut, hooks, reference, config,
-                                        limits, seed, control)
+            checked = check_first_steps(
+                sut, hooks, reference, config,
+                check.limits_of(config, cell["traffic"]), seed, control)
             parts["first_steps_s"] = checked["program_s"]
             kernel = sut.kernel_record()
             say(f"step kernel: {json.dumps(kernel)}")

@@ -102,6 +102,9 @@ class TrainSystem:
         self.per_file = 0            # blocks a file, set by begin_data
         self.files: list = []
         self.check_blocks: list = []
+        # the (bucket, row) pairs each of them has on the file's COO
+        # overflow list, set by end_data
+        self.check_overflow: list = []
         self.app = None
         self._pool = None
 
@@ -164,15 +167,23 @@ class TrainSystem:
 
     def end_data(self, pending) -> dict:
         """Wait for the files. A stream cell's file is then read through
-        once, so that the window reads from the page cache. Returns the
-        work counts."""
+        once, so that the window reads from the page cache. Keeps the
+        overflow pairs of the checked blocks, as the file has them, and
+        returns the work counts."""
         from wormhole_tpu.data.crec import iter_packed2
         for f in pending:
             f.result()
         cfg, info = self.config, self._info
         through = self.files if self.regime == STREAM else self.files[:1]
-        ovf = [int((views["ovf_b"] != np.uint32(0xFFFFFFFF)).sum())
-               for path in through for views, _rows in iter_packed2(path)]
+        ovf, self.check_overflow = [], []
+        for path in through:
+            for views, _rows in iter_packed2(path):
+                valid = views["ovf_b"] != np.uint32(0xFFFFFFFF)
+                ovf.append(int(valid.sum()))
+                if len(self.check_overflow) < len(self.check_blocks):
+                    # the checked blocks are the first of the first file
+                    self.check_overflow.append((views["ovf_b"][valid],
+                                                views["ovf_r"][valid]))
         return {"blocks": self.nblocks, "files": self.nfiles,
                 "rows_per_block": self.block_rows,
                 "pairs_per_block": self.block_rows * int(cfg["nnz"]),

@@ -59,8 +59,10 @@ def tiny_patches(config_name: str, traffic_name: str):
 
 def run_tiny(workload: str, tmp_path, trace: bool = False, seed: int = 5,
              root: str = REPO, prelude: str = "", patches=None,
-             seconds: float = 1.0, timeout: int = 900, devices: int = 1):
-    """Run the cell in a child; returns (CompletedProcess, result or None)."""
+             seconds: float = 1.0, timeout: int = 900, devices: int = 1,
+             control: bool = False):
+    """Run the cell in a child; returns (CompletedProcess, result or None).
+    ``control`` also prints the lower-precision controls (``--control 1``)."""
     config, traffic = workload.split(".")
     if patches is None:
         patches = tiny_patches(config, traffic)
@@ -75,7 +77,7 @@ def run_tiny(workload: str, tmp_path, trace: bool = False, seed: int = 5,
         f"r = run.run_cell({workload!r}, {seed}, {seconds}, {trace!r}, "
         f"need_tpu=False, root={root!r}, workdir={str(tmp_path)!r}, "
         f"config_patch={patches[0]!r}, traffic_patch={patches[1]!r}, "
-        f"extra_conf={extra!r})\n"
+        f"extra_conf={extra!r}, control={control!r})\n"
         "print(json.dumps(r))\n")
     flags = (f"--xla_force_host_platform_device_count={devices}"
              if devices > 1 else "")
@@ -87,3 +89,19 @@ def run_tiny(workload: str, tmp_path, trace: bool = False, seed: int = 5,
     if r.returncode == 0:
         result = json.loads(r.stdout.strip().splitlines()[-1])
     return r, result
+
+
+def overflow_of(keys, num_buckets: int, subblocks: int, ovf_cap: int):
+    """The (buckets, rows) that the crec2 writer puts on a block's COO
+    overflow list, through the program's own encoder at the writer's
+    default cap: what ``TrainSystem.end_data`` reads back from the file."""
+    import numpy as np
+    from wormhole_tpu.data.crec import default_cap, encode_tile_block
+    from wormhole_tpu.ops.tilemm import make_spec
+    spec = make_spec(num_buckets, subblocks,
+                     default_cap(keys.shape[1], num_buckets))
+    _pw, ovf_b, ovf_r, n = encode_tile_block(keys, num_buckets, spec,
+                                             ovf_cap)
+    assert n <= ovf_cap
+    valid = ovf_b != np.uint32(0xFFFFFFFF)
+    return ovf_b[valid].astype(np.int64), ovf_r[valid].astype(np.int64)

@@ -1,9 +1,12 @@
 """`correct` has to come out false when the timed path is broken underneath:
 the harness's look for a chip is skipped, the rest of a run is driven."""
 
+import pytest
+
 import bm_helpers
 
 CELL = "criteo_ftrl.replay_uniform"
+STREAM_CELL = "criteo_ftrl.stream_fields"
 
 UNCHANGED_STATE = """
 import jax.numpy as jnp
@@ -38,8 +41,9 @@ def _numbers(stdout):
     return out
 
 
-def test_a_step_that_returns_its_state_unchanged(tmp_path):
-    r, result = bm_helpers.run_tiny(CELL, tmp_path, prelude=UNCHANGED_STATE)
+@pytest.mark.parametrize("cell", [CELL, STREAM_CELL])
+def test_a_step_that_returns_its_state_unchanged(cell, tmp_path):
+    r, result = bm_helpers.run_tiny(cell, tmp_path, prelude=UNCHANGED_STATE)
     assert r.returncode == 0, r.stdout[-3000:] + r.stderr[-3000:]
     assert result["correct"] is False
     ok = _numbers(r.stdout)

@@ -2,6 +2,7 @@
 line's keys, `correct`, and that a device-metric name never carries a CPU
 number (it is left out and the run says "not measured")."""
 
+import json
 import os
 import subprocess
 import sys
@@ -68,6 +69,45 @@ def test_traced_run_on_the_cpu_reports_no_device_metric(cell, tmp_path):
         assert set(result["metrics"]) == {
             "loop_wait_share.stream", "feed_stall_share.stream",
             "feed_put_ms_per_block.stream"}
+
+
+def _printed(stdout, start):
+    """The JSON object that ends the one line starting with ``start``."""
+    line, = [ln for ln in stdout.splitlines() if ln.startswith(start)]
+    return json.loads(line[line.index("{", len(start)):])
+
+
+@pytest.mark.parametrize("seed", [21, 22, 2**31 + 23])
+def test_the_stream_cells_mismatch_was_the_references(seed, tmp_path):
+    """The tiny stream cell (a sixtieth of its pairs on the overflow list)
+    against the reference that takes the file's overflow pairs unrounded,
+    as the configuration states for that path: the interpreted program
+    agrees as closely as in a replay cell. Against the reference of PRs
+    25-27, which rounds every pair, the same program reads a decade or more
+    worse. The controls, overflow pairs exact in them too, fail a limit."""
+    cell = "criteo_ftrl.stream_fields"
+    r, result = bm_helpers.run_tiny(cell, tmp_path, seed=seed, control=True)
+    assert r.returncode == 0, r.stdout[-3000:] + r.stderr[-3000:]
+    assert result["correct"] is True
+    out = r.stdout
+    now = {ln.split()[2]: float(ln.split()[4]) for ln in out.splitlines()
+           if ln.startswith("[bench] check ") and " = " in ln}
+    config = bm_helpers.load("benchmark/configs/criteo_ftrl/config.json")
+    from benchmark import check
+    limits = check.limits_of(config, "stream_fields")
+    assert limits["state_rel_rms"] == config["check"]["limits"][
+        "state_rel_rms"]           # the configuration's own, in the stream too
+    assert set(now) == set(limits)
+    assert now["state_rel_rms"] < 1e-5
+    assert "pairs taken unrounded a step (the file's overflow lists): [" \
+        in out
+    was = _printed(out, "[bench] program against every pair rounded")
+    assert was["state_rel_rms"] > 10 * now["state_rel_rms"]
+    assert was["state_rel_rms"] > 1e-5
+    for control in config["check"]["controls"]:
+        nums = _printed(out, f"[bench] control {control} {{")
+        assert any(nums[k] > limits[k] for k in limits), (control, nums)
+        assert nums["state_rel_rms"] > 2 * limits["state_rel_rms"]
 
 
 def test_without_a_tpu_the_command_fails_and_prints_no_result(tmp_path):

@@ -25,6 +25,23 @@ def test_groups_merge_into_one_update():
     assert (merged[1][0][:2] == 2).all() and (merged[1][0][2:] == 3).all()
 
 
+def test_a_groups_overflow_rows_shift_with_its_blocks():
+    """A group's later blocks sit below the earlier ones in the merged
+    step, so their overflow pairs' rows move down by the rows before."""
+    blocks = [(np.zeros((5, 3), np.uint32), np.zeros(5, np.uint8))
+              for _ in range(4)]
+    overflow = [(np.array([10 + i, 20 + i], np.uint32),
+                 np.array([0, 4], np.uint32)) for i in range(4)]
+    same = check.merge_exact_pairs(overflow, blocks, 1)
+    assert [(list(b), list(r)) for b, r in same] == [
+        ([10 + i, 20 + i], [0, 4]) for i in range(4)]
+    merged = check.merge_exact_pairs(overflow, blocks, 2)
+    assert len(merged) == len(check.merge_groups(blocks, 2)) == 2
+    assert list(merged[1][0]) == [12, 22, 13, 23]
+    assert list(merged[1][1]) == [0, 4, 5, 9]
+    assert merged[1][1].dtype == np.int64
+
+
 def test_the_mesh_cell_runs_tiny_on_four_host_devices(tmp_path):
     root = bm_helpers.copy_benchmark(str(tmp_path))
     bench = bm_helpers.load("BENCHMARK.json")

@@ -166,9 +166,9 @@ _MIN_CACHED_RATIO = 0.05
 # fused, and the cached A/B must run at a geometry whose cache the
 # resolver's auto budget genuinely admits (a forced-past-budget cache
 # would not compile on the TPU backend, so timing one proves nothing).
-# Prefixes, not exact strings: the linear store refines its record to
-# "fused_update" when the in-place FTRL variant dispatches — any
-# fused-family resolution passes, any split fails.
+# Prefixes, not exact strings: any fused-family resolution passes, any
+# split fails (the linear store's in-place FTRL variant records "fused"
+# too, and says "in place" in the record's second field).
 _TILE_RESOLUTION_EXPECT = {
     "resolved_kernel": "fused",
     "spill_resolved_kernel": "fused",

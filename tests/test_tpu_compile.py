@@ -103,8 +103,8 @@ def step_grad(spec, cache=False, spill=False, exact_dense=True):
 
 def step_update(spec, cache=False):
     fn = tilemm._build_step_update(spec, "logit", _ftrl(), cache)
-    return fn, [_pw(spec), ((spec.nb, 3), jnp.float32), _rows(spec),
-                _rows(spec)]
+    plane = ((spec.tiles, tilemm.A_HI, tilemm.B_LO), jnp.float32)
+    return fn, [_pw(spec), [plane, plane, plane], _rows(spec), _rows(spec)]
 
 
 def fwd_multi(spec, ch):

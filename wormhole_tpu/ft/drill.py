@@ -232,7 +232,7 @@ def run_rejoin_drill(
             hi = st["applied_hi"]
             if ckpt_every and hi >= 0 and (hi + 1) % ckpt_every == 0:
                 ckpts[r].save(hi + 1, {
-                    "slots": store.slots, "t": np.int64(store.t),
+                    **store.state_pytree(),
                     "applied_hi": np.int64(hi)}, barrier=False)
 
         stop = False
@@ -297,7 +297,7 @@ def run_rejoin_drill(
     def run_rejoiner(r: int, t_detect: float) -> None:
         store = _make_store(nb)
         ck = ShardCheckpointer(ck_dir, keep=4, rank=r, world=world)
-        ver, st_loaded = ck.load({"slots": store.slots, "t": np.int64(0),
+        ver, st_loaded = ck.load({**store.state_pytree(),
                                   "applied_hi": np.int64(-1)})
         if ver <= 0:
             raise RuntimeError(

@@ -120,7 +120,9 @@ class AsyncSGD:
         # and weight-delta norms at pass boundaries
         self.model_monitor = ModelMonitor()
         self.reporter = TimeReporter(self._emit_row, interval=cfg.disp_itv)
-        self.timer = Timer()  # pipeline stage profile (SURVEY §5.1)
+        # pipeline stage profile (SURVEY §5.1); a store that times its
+        # own table crossings (ShardedStore) shares the one timer
+        self.timer = getattr(store, "timer", None) or Timer()
         # DeviceFeed counters (data/pipeline.py): cumulative consumer-side
         # ring stalls, batches delivered, deepest ring occupancy observed
         self.feed_stats = {"feed_stall": 0.0, "feed_batches": 0,
@@ -488,6 +490,10 @@ class AsyncSGD:
                                             TileOnlineFeed)
         workers = self.cfg.pipeline_workers
         depth = max(self.cfg.pipeline_ring, 3 if workers == 0 else 1)
+        if device_put is None and (fmt == "crec2" or tile_info is not None):
+            # a store that has a say in what of a tile block crosses to
+            # the device ships it itself (ShardedStore.put_block)
+            device_put = getattr(self.store, "put_block", None)
         if tile_info is not None and fmt != "crec2":
             # online tile encoding: the v1/text source feed keeps its
             # packed blocks on host (identity put) and the TileOnlineFeed

@@ -61,6 +61,15 @@ class Handle:
              tau: jax.Array) -> jax.Array:
         raise NotImplementedError
 
+    def push_planes(self, planes: tuple, grad: jax.Array, t: jax.Array,
+                    tau: jax.Array) -> tuple:
+        """push() over a table kept as one plane a slot
+        (learners/table.py), each shaped like ``grad``. The default
+        stacks the planes and splits the result again: inside a jit XLA
+        removes a slice of a concatenate."""
+        new = self.push(jnp.stack(planes, axis=-1), grad, t, tau)
+        return tuple(new[..., k] for k in range(len(planes)))
+
     def warm_start(self, w: jax.Array) -> jax.Array:
         """Slots that make ``w`` a fixed point of a zero-gradient push
         (model_in warm start, linear.cc:115-123). Default: w in slot 0,
@@ -119,11 +128,12 @@ class FTRLHandle(Handle):
             -z_new, (self.lr.beta + cg_new) / self.lr.alpha)
         return w_new, z_new, cg_new
 
+    def push_planes(self, planes, grad, t, tau):
+        return self.update(*planes, grad, opaque_one(grad))
+
     def push(self, slots, grad, t, tau):
-        w, z, cg = slots[..., 0], slots[..., 1], slots[..., 2]
-        w_new, z_new, cg_new = self.update(w, z, cg, grad,
-                                           opaque_one(grad))
-        return jnp.stack([w_new, z_new, cg_new], axis=-1)
+        planes = (slots[..., 0], slots[..., 1], slots[..., 2])
+        return jnp.stack(self.push_planes(planes, grad, t, tau), axis=-1)
 
     def warm_start(self, w):
         """FTRL derives w from z (w = prox(−z)), so a warm start must seed

@@ -2,13 +2,14 @@
 
 The reference shards the model by key range over server processes and moves
 weights/gradients over ZeroMQ (``ps-lite`` ZPush/ZPull, async_sgd.h:84-117).
-Here the model is ONE ``(num_buckets, val_len)`` device array sharded over
-the ``model`` mesh axis; a minibatch's "pull" is a gather of its unique
-bucket rows, the "push" a scatter-add of per-key update deltas — both inside
-the same jitted train step, so XLA turns the key exchange into ICI
-collectives instead of RPC. Keys are hashed into buckets upstream
-(Localizer ``num_buckets`` = the FLAGS_max_key hash kernel; collisions are
-accepted by design, localizer.h:88-96).
+Here the model is ONE ``(num_buckets, val_len)`` device table sharded over
+the ``model`` mesh axis (on one device, where the tile kernels step it, kept
+as one plane a slot in their layout: learners/table.py); a minibatch's
+"pull" is a gather of its unique bucket rows, the "push" a scatter-add of
+per-key update deltas — both inside the same jitted train step, so XLA turns
+the key exchange into ICI collectives instead of RPC. Keys are hashed into
+buckets upstream (Localizer ``num_buckets`` = the FLAGS_max_key hash kernel;
+collisions are accepted by design, localizer.h:88-96).
 
 The scatter applies ``new_rows − old_rows`` (a delta add) rather than
 writing rows: padded keys carry mask 0 → delta 0, so they are no-ops even
@@ -33,11 +34,13 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from wormhole_tpu.data.feed import SparseBatch
+from wormhole_tpu.learners import table as tbl
 from wormhole_tpu.learners.handles import FTRLHandle, Handle
 from wormhole_tpu.ops.loss import create_loss
 from wormhole_tpu.ops.spmv import spmv_times, spmv_trans_times
 from wormhole_tpu.ops.metrics import accuracy, auc
 from wormhole_tpu.parallel.mesh import MODEL_AXIS, MeshRuntime
+from wormhole_tpu.utils.timer import Timer
 
 
 def put_like(template: jax.Array, full: np.ndarray) -> jax.Array:
@@ -133,6 +136,19 @@ def masked_push(handle: Handle, s32, grad, t, tau, exact_dense: bool):
     if not exact_dense:
         new = jnp.where((grad != 0.0)[:, None], new, s32)
     return new
+
+
+def masked_push_planes(handle: Handle, planes: tuple, grad, t, tau,
+                       exact_dense: bool):
+    """:func:`masked_push` over the table's planes (learners/table.py),
+    one elementwise pass: (new planes, Σ(w_new − w_old)²). ``grad`` is
+    shaped like a plane. The same nudge-and-mask contract holds."""
+    new = handle.push_planes(planes, grad, t, tau)
+    if not exact_dense:
+        touched = grad != 0.0
+        new = tuple(jnp.where(touched, n, p) for n, p in zip(new, planes))
+    d0 = new[0] - planes[0]
+    return new, jnp.sum(d0 * d0)
 
 
 # the FIXING_FLOAT quantizer lives in parallel/filters.py (one
@@ -258,6 +274,10 @@ def mesh_ovf_zeros(D: int, oc: int) -> np.ndarray:
 
 
 _OVF_ZEROS: dict = {}
+
+
+# step_kernel's second field when the fused tile step is the in-place one
+IN_PLACE = "in place: the FTRL update runs inside the kernel"
 
 
 @dataclass
@@ -401,11 +421,78 @@ class ShardedStore(TableCheckpoint):
         if self.dtype not in (jnp.float32, jnp.bfloat16):
             raise ValueError(f"param_dtype {cfg.param_dtype!r}: want "
                              "float32 or bfloat16")
-        self.slots = shard_param_table(
-            handle.init(cfg.num_buckets).astype(self.dtype), runtime)
+        from wormhole_tpu.ops.tilemm import TILE
+        nb = cfg.num_buckets
+        # crossings of the table's format (scope "table_cross"); a learner
+        # that owns this store reads it as its own timer
+        self.timer = Timer()
+        # One device, a float32 table, whole tiles: the tile steps can take
+        # this table, so it is built in THEIR form (one plane a slot,
+        # learners/table.py) and never as (nb, val_len), which the compiler
+        # lays out four wide. Every other path asks _stacked() for the
+        # (nb, val_len) array and gets it, counted; a table on a mesh or in
+        # bfloat16 stays stacked and the tile steps slice it as they go.
+        self._planar = ((runtime is None or runtime.mesh.size == 1)
+                        and self.dtype == jnp.float32 and nb % TILE == 0)
+        if self._planar:
+            self._table = jax.jit(
+                lambda: tbl.PlaneTable(tbl.split(handle.init(nb))))()
+        else:
+            self._table = shard_param_table(
+                handle.init(nb).astype(self.dtype), runtime)
         self._step = self._build_step()
         self._eval = self._build_eval()
         self.t = 1  # global update counter (SGD eta schedule)
+
+    # -- the table and its two forms (learners/table.py) --------------------
+
+    @property
+    def slots(self):
+        """The table as it stands: a ``(nb, val_len)`` array, or a
+        :class:`~wormhole_tpu.learners.table.PlaneTable` that answers the
+        same reads (shape, dtype, astype, indexing, ``np.asarray``,
+        ``jax.block_until_ready``) from its planes. Assigning a plain
+        array is always legal; the next tile step takes it across."""
+        return self._table
+
+    @slots.setter
+    def slots(self, table) -> None:
+        self._table = table
+
+    def _cross(self, convert) -> None:
+        """Change the table's form: a pass over the whole table, counted
+        (calls and seconds) under ``table_cross`` in the timer and, as
+        every timer scope, in the trace. A run whose window shows none
+        never rebuilt the table."""
+        with self.timer.scope("table_cross"):
+            self._table = jax.block_until_ready(convert(self._table))
+
+    def _stacked(self) -> jax.Array:
+        """The table as one ``(nb, val_len)`` array, for every path but
+        the single-device tile steps; it stays so until one of those
+        runs."""
+        if isinstance(self._table, tbl.PlaneTable):
+            self._cross(tbl.to_stacked)
+        return self._table
+
+    def _tile_table(self):
+        """The table as the single-device tile steps take it: planes
+        where this store keeps them (``_planar``)."""
+        if self._planar and not isinstance(self._table, tbl.PlaneTable):
+            self._cross(tbl.to_planes)
+        return self._table
+
+    def _mesh_table(self):
+        self._stacked()
+        return super()._mesh_table()
+
+    def state_pytree(self):
+        state = super().state_pytree()
+        if isinstance(self._table, tbl.PlaneTable):
+            # the checkpoint holds (nb, val_len): planes are stacked on
+            # the host, where the bytes go anyway, not on the device
+            state["slots"] = np.asarray(self._table)
+        return state
 
     def with_num_buckets(self, nb: int) -> "ShardedStore":
         """A fresh store over the same config/handle/runtime at ``nb``
@@ -467,7 +554,7 @@ class ShardedStore(TableCheckpoint):
         """Live model params for the pull-only forward (serve/forward.py).
         Keys must match state_pytree's so a checkpoint restores straight
         into a serve swap."""
-        return {"slots": self.slots}
+        return {"slots": self._stacked()}
 
     def build_serve_margin(self):
         """margin_fn(params, batch) -> (mb,) margins: pull (gather) +
@@ -580,13 +667,13 @@ class ShardedStore(TableCheckpoint):
         buffer."""
         step = self._dense_step(block_rows, nnz, "train")
         self.slots, t_new, metrics = step(
-            self.slots, packed, self._t_device(), self._tau_const(tau))
+            self._stacked(), packed, self._t_device(), self._tau_const(tau))
         self._advance_t(t_new)
         return metrics
 
     def dense_eval_step(self, packed: jax.Array, block_rows: int, nnz: int):
         return self._dense_step(block_rows, nnz, "eval")(
-            self.slots, packed)
+            self._stacked(), packed)
 
     # -- dense-apply over a data x model mesh -------------------------------
     #
@@ -713,12 +800,39 @@ class ShardedStore(TableCheckpoint):
     # encoded (bucket, row) pairs grouped by 16K-bucket tile, so pull and
     # push both run as dense one-hot matmuls on the MXU instead of
     # serialized gather/scatter (see tilemm module docstring). Same
-    # dense-apply semantics as the v1 crec path: the handle sweeps the
-    # whole table, with the touched-bucket mask when a zero-grad push is
-    # not the identity (zero_grad_push_is_identity).
+    # dense-apply semantics as the v1 crec path, over every bucket, with
+    # the touched-bucket mask when a zero-grad push is not the identity
+    # (zero_grad_push_is_identity). Where the store keeps its table as
+    # planes (_planar) they are the steps' state as they are: the in-place
+    # FTRL variant touches the table only inside its kernel, the others in
+    # one elementwise pass over the planes and the gradient.
 
-    def _tile_step(self, info, kind: str):
-        key = (info, kind)
+    def put_block(self, block):
+        """Ship one tile block's host arrays to the device (the feed's
+        ``device_put``). Where the table is planes, an overflow list
+        with no pair in it stays behind: the block then takes the tile
+        step that has no spill to scatter, which for FTRL is the
+        in-place one. (A stacked table keeps its one step: its no-spill
+        programs slice the planes out of ``(nb, slots)`` and compile for
+        six to nine minutes at 2**28, PERF.md.)"""
+        if self._planar and isinstance(block, dict) and "ovf_b" in block:
+            ovf, unused = block["ovf_b"], np.uint32(0xFFFFFFFF)
+            # writers fill the list from the front: one look settles
+            # a list that has pairs, a scan only one that seems empty
+            if not (ovf[:1] != unused).any() and not (ovf != unused).any():
+                block = {k: v for k, v in block.items()
+                         if k not in ("ovf_b", "ovf_r")}
+        return jax.device_put(block)
+
+    def _tile_step(self, info, kind: str, spill: bool = True):
+        """The jitted single-device tile step for a block geometry:
+        ``step(table, block, t, tau, macc)`` (train) or ``step(table,
+        block)`` (eval). ``spill``: the block brings a COO overflow
+        list. Every variant computes on the float32 (T, A_HI, B_LO)
+        planes; a planar table IS those planes and is returned as such,
+        a stacked one (bfloat16, on a mesh, a serving snapshot) is
+        sliced into them and stacked again inside the step."""
+        key = (info, kind, spill)
         fn = getattr(self, "_tile_cache", {}).get(key)
         if fn is not None:
             self.step_kernel = self._tile_kernel[key]
@@ -728,7 +842,7 @@ class ShardedStore(TableCheckpoint):
         from wormhole_tpu.ops.metrics import margin_hist
         handle, objv_fn, dual_fn = self.handle, self.objv_fn, self.dual_fn
         spec = info.spec
-        oc = info.ovf_cap
+        oc = info.ovf_cap if spill else 0
         loss_name = self.cfg.loss
         # The fused one-grid step replaces the fwd/bwd pallas pair when
         # the geometry admits it; the in-place slot update additionally
@@ -746,6 +860,16 @@ class ShardedStore(TableCheckpoint):
                         and isinstance(handle, FTRLHandle)
                         and jax.process_count() == 1)
 
+        def planes_of(table):
+            if isinstance(table, tbl.PlaneTable):
+                return table.planes
+            return tbl.split(table.astype(jnp.float32))
+
+        def table_of(planes, like):
+            if isinstance(like, tbl.PlaneTable):
+                return tbl.PlaneTable(planes)
+            return tbl.join(planes).astype(like.dtype)
+
         def decode(block):
             lab_u8 = block["labels"]
             row_mask = (lab_u8 != jnp.uint8(255)).astype(jnp.float32)
@@ -754,100 +878,79 @@ class ShardedStore(TableCheckpoint):
             ovf_r = block["ovf_r"] if oc else None
             return block["pw"], labels, row_mask, ovf_b, ovf_r
 
-        def finish(slots, s32, new, margin, labels, row_mask, t, macc):
+        def finish(new, wdelta2, margin, labels, row_mask, t, macc):
             # shared metric tail — identical ops downstream of the
-            # margin/slot buffers in every variant, so the fused paths
-            # keep the split path's metric bits
+            # margin buffer in every variant, so the fused paths keep
+            # the split path's metric bits
             objv = objv_fn(margin, labels, row_mask)
             num_ex = jnp.sum(row_mask)
             acc = accuracy(labels, margin, row_mask)
             pos, neg = margin_hist(labels, margin, row_mask)
-            d0 = new[:, 0] - s32[:, 0]
             packed = jnp.concatenate([
-                jnp.stack([objv, num_ex, acc, jnp.sum(d0 * d0)]),
-                pos, neg])
+                jnp.stack([objv, num_ex, acc, wdelta2]), pos, neg])
             # num_ex rides along as the caller's completion ticket:
             # unlike t+1/macc it never re-enters the donated step
             # chain, so block_until_ready on it stays legal after
             # later steps dispatch (donation is real on committed
             # multi-device layouts, not just TPU)
-            return (new.astype(slots.dtype), t + 1, macc + packed,
-                    num_ex)
+            return new, t + 1, macc + packed, num_ex
 
         if fused_update:
             @partial(jax.jit, donate_argnums=(0, 2, 4))
-            def step(slots, block, t, tau, macc):
+            def step(table, block, t, tau, macc):
                 pw, labels, row_mask, _ovf_b, _ovf_r = decode(block)
-                s32 = slots.astype(jnp.float32)
-                margin, new = tilemm.fused_step_update(
-                    pw, s32, labels, row_mask, spec, loss_name, handle,
-                    cache=cache)
-                return finish(slots, s32, new, margin, labels, row_mask,
-                              t, macc)
-        elif fused and oc:
-            # fused spill branch: the pre-aggregated spill margins ride
-            # into the kernel as one extra operand (summed into the
-            # phase-boundary dual); the spill pairs' grad contributions
-            # scatter in XLA from the emitted margins — the dual
-            # recompute is elementwise, so the scattered duals are
-            # bitwise the kernel's own
-            @partial(jax.jit, donate_argnums=(0, 2, 4))
-            def step(slots, block, t, tau, macc):
-                pw, labels, row_mask, ovf_b, ovf_r = decode(block)
-                s32 = slots.astype(jnp.float32)
-                w = handle.weights(s32)
-                sp = tilemm.spill_margin_rows(w, ovf_b, ovf_r, spec)
-                margin, grad = tilemm.fused_step_grad(
-                    pw, w, labels, row_mask, spec, loss_name, exact_dense,
-                    cache=cache, spill_margins=sp)
-                dual = dual_fn(margin, labels, row_mask)
-                if not exact_dense:
-                    dual = _nudge_zero_dual(dual, labels, row_mask)
-                grad = tilemm.spill_grad_scatter(grad, dual, ovf_b,
-                                                 ovf_r, spec)
-                new = masked_push(handle, s32, grad,
-                                  t.astype(jnp.float32), tau, exact_dense)
-                return finish(slots, s32, new, margin, labels, row_mask,
-                              t, macc)
-        elif fused:
-            @partial(jax.jit, donate_argnums=(0, 2, 4))
-            def step(slots, block, t, tau, macc):
-                pw, labels, row_mask, _ovf_b, _ovf_r = decode(block)
-                s32 = slots.astype(jnp.float32)
-                w = handle.weights(s32)
-                margin, grad = tilemm.fused_step_grad(
-                    pw, w, labels, row_mask, spec, loss_name, exact_dense,
-                    cache=cache)
-                new = masked_push(handle, s32, grad,
-                                  t.astype(jnp.float32), tau, exact_dense)
-                return finish(slots, s32, new, margin, labels, row_mask,
-                              t, macc)
+                margin, new, wdelta2 = tilemm.fused_step_update(
+                    pw, planes_of(table), labels, row_mask, spec,
+                    loss_name, handle, cache=cache)
+                return finish(table_of(new, table), wdelta2, margin,
+                              labels, row_mask, t, macc)
         elif kind == "train":
             # per-step metrics ADD into a donated on-device accumulator:
             # the step returns no host-visible value at all, so the
             # steady-state loop fetches ONE (4+2*bins,) buffer per display
             # window instead of stacking per-step vectors
             @partial(jax.jit, donate_argnums=(0, 2, 4))
-            def step(slots, block, t, tau, macc):
+            def step(table, block, t, tau, macc):
                 pw, labels, row_mask, ovf_b, ovf_r = decode(block)
-                s32 = slots.astype(jnp.float32)
-                w = handle.weights(s32)
-                margin = tilemm.forward_margins(pw, w, spec,
+                planes = planes_of(table)
+                w = handle.weights(tbl.PlaneTable(planes))
+                if not fused:
+                    margin = tilemm.forward_margins(pw, w, spec,
+                                                    ovf_b, ovf_r)
+                    dual = dual_fn(margin, labels, row_mask)
+                    if not exact_dense:
+                        dual = _nudge_zero_dual(dual, labels, row_mask)
+                    grad = tilemm.backward_grad(pw, dual, spec,
                                                 ovf_b, ovf_r)
-                dual = dual_fn(margin, labels, row_mask)
-                if not exact_dense:
-                    dual = _nudge_zero_dual(dual, labels, row_mask)
-                grad = tilemm.backward_grad(pw, dual, spec,
-                                            ovf_b, ovf_r)
-                new = masked_push(handle, s32, grad,
-                                  t.astype(jnp.float32), tau, exact_dense)
-                return finish(slots, s32, new, margin, labels, row_mask,
-                              t, macc)
+                else:
+                    # fused spill: the pre-aggregated spill margins ride
+                    # into the kernel as one extra operand (summed into
+                    # the phase-boundary dual); the spill pairs' grad
+                    # contributions scatter in XLA from the emitted
+                    # margins — the dual recompute is elementwise, so
+                    # the scattered duals are bitwise the kernel's own
+                    sp = (tilemm.spill_margin_rows(w, ovf_b, ovf_r, spec)
+                          if oc else None)
+                    margin, grad = tilemm.fused_step_grad(
+                        pw, w, labels, row_mask, spec, loss_name,
+                        exact_dense, cache=cache, spill_margins=sp)
+                    if oc:
+                        dual = dual_fn(margin, labels, row_mask)
+                        if not exact_dense:
+                            dual = _nudge_zero_dual(dual, labels,
+                                                    row_mask)
+                        grad = tilemm.spill_grad_scatter(
+                            grad, dual, ovf_b, ovf_r, spec)
+                new, wdelta2 = masked_push_planes(
+                    handle, planes, grad.reshape(planes[0].shape),
+                    t.astype(jnp.float32), tau, exact_dense)
+                return finish(table_of(new, table), wdelta2, margin,
+                              labels, row_mask, t, macc)
         else:
             @jax.jit
-            def step(slots, block):
+            def step(table, block):
                 pw, labels, row_mask, ovf_b, ovf_r = decode(block)
-                w = handle.weights(slots.astype(jnp.float32))
+                w = handle.weights(tbl.PlaneTable(planes_of(table)))
                 margin = tilemm.forward_margins(pw, w, spec,
                                                 ovf_b, ovf_r)
                 objv = objv_fn(margin, labels, row_mask)
@@ -864,13 +967,13 @@ class ShardedStore(TableCheckpoint):
             resolved, why = "split", "eval is forward-only"
             cache_rec = "onehot_cache=off:eval is forward-only"
         else:
+            # the record names the kernel the knob names, fused or split;
+            # the fused step that updates the table in place says so
+            # where the split step gives its reason
             why, cache_rec = res.why, res.cache_record
+            resolved = "fused" if fused else "split"
             if fused_update:
-                resolved = "fused_update"
-            elif fused:
-                resolved = "fused"
-            else:
-                resolved = "split"
+                why = IN_PLACE
         self._tile_kernel[key] = (resolved, why, cache_rec)
         self.step_kernel = self._tile_kernel[key]
         self._tile_cache[key] = step
@@ -1011,28 +1114,29 @@ class ShardedStore(TableCheckpoint):
         only so callers can gate the staleness window on real completion
         — the clock itself is donated into the next step, so it is NOT
         safe to block on."""
-        step = self._tile_step(info, "train")
+        step = self._tile_step(info, "train", "ovf_b" in block)
         if self.step_kernel[0].startswith("fused"):
             from wormhole_tpu.obs import trace
             if self.step_kernel[2] == "onehot_cache=on":
                 with trace.span("tilemm:fused_cached", cat="tile"):
                     self.slots, t_new, self._macc, ticket = step(
-                        self.slots, block, self._t_device(),
+                        self._tile_table(), block, self._t_device(),
                         self._tau_const(tau), self._macc_buf())
             else:
                 with trace.span("tilemm:fused_step", cat="tile"):
                     self.slots, t_new, self._macc, ticket = step(
-                        self.slots, block, self._t_device(),
+                        self._tile_table(), block, self._t_device(),
                         self._tau_const(tau), self._macc_buf())
         else:
             self.slots, t_new, self._macc, ticket = step(
-                self.slots, block, self._t_device(), self._tau_const(tau),
-                self._macc_buf())
+                self._tile_table(), block, self._t_device(),
+                self._tau_const(tau), self._macc_buf())
         self._advance_t(t_new)
         return ticket
 
     def tile_eval_step(self, block: dict, info):
-        return self._tile_step(info, "eval")(self.slots, block)
+        return self._tile_step(info, "eval", "ovf_b" in block)(
+            self._tile_table(), block)
 
     # -- split pull/push pipeline (delay-tolerant DT2 path) -----------------
     #
@@ -1080,12 +1184,12 @@ class ShardedStore(TableCheckpoint):
         metrics) for a later dt2_push of the same batch."""
         if not hasattr(self, "_dt2"):
             self._dt2 = self._build_dt2()
-        return self._dt2[0](self.slots, batch)
+        return self._dt2[0](self._stacked(), batch)
 
     def dt2_push(self, batch: SparseBatch, grad, snap) -> None:
         """ZPush: apply the delayed gradient with its pull-time snapshot."""
         self.slots = self._dt2[1](
-            self.slots, batch.uniq_keys, batch.key_mask, grad, snap)
+            self._stacked(), batch.uniq_keys, batch.key_mask, grad, snap)
         self.t += 1
 
     # -- dense global-delta apply (ps engine path) --------------------------
@@ -1117,7 +1221,7 @@ class ShardedStore(TableCheckpoint):
         if not hasattr(self, "_ps_push_fn"):
             self._ps_push_fn = self._build_ps_push()
         self.slots, t_new = self._ps_push_fn(
-            self.slots, jnp.asarray(grad, jnp.float32),
+            self._stacked(), jnp.asarray(grad, jnp.float32),
             self._t_device(), self._tau_const(tau))
         self._advance_t(t_new)
 
@@ -1126,12 +1230,12 @@ class ShardedStore(TableCheckpoint):
     def train_step(self, batch: SparseBatch, tau: float = 0.0):
         """Dispatch one fused step; returns the (async) metrics tuple."""
         self.slots, t_new, metrics = self._step(
-            self.slots, batch, self._t_device(), self._tau_const(tau))
+            self._stacked(), batch, self._t_device(), self._tau_const(tau))
         self._advance_t(t_new)
         return metrics
 
     def eval_step(self, batch: SparseBatch):
-        return self._eval(self.slots, batch)
+        return self._eval(self._stacked(), batch)
 
     def pull(self, keys: np.ndarray) -> np.ndarray:
         """Debug/oracle surface: weights for explicit bucket ids."""
@@ -1160,19 +1264,19 @@ class ShardedStore(TableCheckpoint):
         if rank is None:
             rank = jax.process_index()
         if getattr(self.slots, "is_fully_addressable", True):
-            shards = [(0, np.asarray(self.slots))]
+            shards = [(0, self.slots)]
         else:
             parts = {}
             for s in self.slots.addressable_shards:
                 start = s.index[0].start or 0
-                parts[start] = np.asarray(s.data)
+                parts[start] = s.data
             shards = sorted(parts.items())
         with open_stream(f"{path}_{rank}", "w") as f:
             if key_fold:
                 f.write(f"# key_fold={key_fold}\n")
             for start, block in shards:
                 w = np.asarray(self.handle.weights(
-                    jnp.asarray(block).astype(jnp.float32)))
+                    block.astype(jnp.float32)))
                 for i in np.nonzero(w)[0]:
                     f.write(f"{start + i}\t{w[i]:.6g}\n")
 

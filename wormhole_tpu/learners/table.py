@@ -1,0 +1,118 @@
+"""The parameter table's format on the device — the one module that owns it.
+
+A table-backed store holds ``slots`` values a bucket (FTRL: w, z, cg). Two
+forms exist, and this module is the door between them:
+
+  * stacked — one ``(nb, slots)`` array: what the sparse step, the v1 dense
+    step, the mesh steps, serving, the pager and the checkpoint read;
+  * planar — one float32 ``(T, A_HI, B_LO)`` plane a slot (:class:`PlaneTable`):
+    the tile kernels' own layout (ops/tilemm.py), so a tile step hands the
+    planes to ``pallas_call`` as they are, aliased onto its outputs, and gets
+    the next step's state back — no slice, no stack, no padding lane (the
+    compiler lays ``f32[nb, 3]`` out four wide).
+
+A (T, A_HI, B_LO) plane and the flat ``(nb,)`` column are the same bytes:
+reshapes between them are free. Crossing between the FORMS is a pass over the
+whole table; the store that crosses counts it (``ShardedStore._cross``).
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from wormhole_tpu.ops.tilemm import A_HI, B_LO, TILE
+
+
+def split(stacked: jax.Array) -> tuple:
+    """The (T, A_HI, B_LO) planes of a stacked ``(nb, slots)`` table, one a
+    slot (traceable)."""
+    nb, slots = stacked.shape
+    return tuple(stacked[:, k].reshape(nb // TILE, A_HI, B_LO)
+                 for k in range(slots))
+
+
+def join(planes) -> jax.Array:
+    """The stacked ``(nb, slots)`` table of the planes (traceable; a slice
+    of the result folds back to the plane it came from)."""
+    return jnp.stack([p.reshape(-1) for p in planes], axis=-1)
+
+
+@jax.jit
+def to_stacked(table: "PlaneTable") -> jax.Array:
+    """The crossing planes -> ``(nb, slots)``, on the device."""
+    return join(table.planes)
+
+
+@jax.jit
+def to_planes(stacked: jax.Array) -> "PlaneTable":
+    """The crossing ``(nb, slots)`` -> planes, on the device."""
+    return PlaneTable(split(stacked))
+
+
+@jax.tree_util.register_pytree_node_class
+class PlaneTable:
+    """A table as one plane a slot, a pytree of those planes. It answers
+    the reads a ``(nb, slots)`` array gets from the code around the stores
+    (``shape``, ``dtype``, ``astype``, ``np.asarray``, the indexings in use)
+    without building that array; anything that writes through ``.at`` gets
+    the stacked form."""
+
+    def __init__(self, planes):
+        self.planes = tuple(planes)
+
+    def tree_flatten(self):
+        return self.planes, None
+
+    @classmethod
+    def tree_unflatten(cls, _aux, planes):
+        return cls(planes)
+
+    @property
+    def shape(self) -> tuple:
+        return (self.planes[0].size, len(self.planes))
+
+    ndim = 2
+
+    @property
+    def dtype(self):
+        return self.planes[0].dtype
+
+    def astype(self, dtype) -> "PlaneTable":
+        if jnp.dtype(dtype) == self.dtype:
+            return self
+        return PlaneTable(p.astype(dtype) for p in self.planes)
+
+    @property
+    def at(self):
+        return join(self.planes).at
+
+    def __array__(self, dtype=None, copy=None):
+        # stacked on the host: no (nb, slots) copy on the device
+        out = np.stack([np.asarray(p).reshape(-1) for p in self.planes],
+                       axis=-1)
+        return out if dtype is None else out.astype(dtype, copy=False)
+
+    def __getitem__(self, idx):
+        """``[rows]`` and ``[rows, col]`` (``...`` may stand for all rows;
+        ``col`` an int, a slice or a traced scalar), as the stacked table
+        would answer them."""
+        idx = idx if isinstance(idx, tuple) else (idx,)
+        if len(idx) > 2:
+            raise IndexError(f"a table has two axes, got index {idx!r}")
+        rows = slice(None) if idx[0] is Ellipsis else idx[0]
+        n = len(self.planes)
+
+        def column(k):
+            return self.planes[k].reshape(-1)[rows]
+
+        col = idx[1] if len(idx) == 2 else slice(None)
+        if isinstance(col, slice):
+            return jnp.stack([column(k) for k in range(n)[col]], axis=-1)
+        if isinstance(col, (int, np.integer)):
+            return column(col)
+        # a traced column: an elementwise select, which fuses into its
+        # consumer where a dynamic slice of a stack would build the stack
+        return jax.lax.select_n(jnp.clip(col, 0, n - 1),
+                                *[column(k) for k in range(n)])

@@ -350,7 +350,10 @@ def _fwd_kernel(spec: TileSpec, pw_ref, w_ref, mg_ref, t=None):
     for g in range(S // GS):
         mgs = [mg_ref[g * GS + j] for j in range(GS)]
         for tb in range(spec.tiles_step):
-            wt = w_ref[tb]                                 # (128,128) bf16
+            # the float32 table tile, rounded to the kernel operand
+            # here (round-to-nearest-even, as astype in XLA): no
+            # bfloat16 copy of the table exists in HBM
+            wt = w_ref[tb].astype(jnp.bfloat16)            # (128,128)
             pc = pw_ref[tb, g].astype(jnp.int32)           # (N,)
             rep = pc[:, None]                              # ONE relayout
             ohhi = _oh_rep(rep, HI_SH, HI_M, N, 128)       # pad -> 0 row
@@ -398,7 +401,7 @@ def _fwd_kernel_cached(spec: TileSpec, pw_ref, w_ref, mg_ref,
     for g in range(S // GS):
         mgs = [mg_ref[g * GS + j] for j in range(GS)]
         for tb in range(TB):
-            wt = w_ref[tb]                                 # (128,128) bf16
+            wt = w_ref[tb].astype(jnp.bfloat16)   # as in _fwd_kernel
             pc = pw_ref[tb, g].astype(jnp.int32)           # (N,)
             rep = pc[:, None]                              # ONE relayout
             cond_lo = _digit_cond(rep, LO_SH, LO_M, N, B_LO)
@@ -600,7 +603,7 @@ def _build_fwd(spec: TileSpec):
 
     @jax.jit
     def fwd(pw, w):
-        wt = w.reshape(T, A_HI, B_LO).astype(jnp.bfloat16)
+        wt = w.reshape(T, A_HI, B_LO)      # rounded tile by tile in-kernel
         mg = pl.pallas_call(
             partial(_fwd_kernel, spec),
             grid=(T // TB,),
@@ -996,6 +999,13 @@ def backward_pushes(pw: jax.Array, dual_rows: jax.Array, spec: TileSpec,
 #                       the elementwise FTRL update writes the w/z/cg
 #                       slot planes in place via input_output_aliases.
 #
+# The table's weights enter every scalar kernel as float32 tiles of the
+# (T, A_HI, B_LO) weight plane (learners/table.py) and are rounded to
+# the bfloat16 operand tile by tile inside phase 1; the in-place variant
+# reads that one aliased plane in both phases (index map t % nt) and
+# sums (w_new - w_old)^2, the step's progress number, where both are in
+# registers. So no XLA op around the call touches the table.
+#
 # Reusing the split kernel BODIES (not re-deriving them) is what makes
 # the split path a bit-parity oracle: both paths run the same bf16
 # one-hot matmuls over the same blocks in the same order, and the dual/
@@ -1210,15 +1220,17 @@ def _make_step_kernel(spec: TileSpec, loss: str, exact_dense: bool,
 
     def kernel(*refs):
         if K > 1:
-            pw_ref, wt_ref, lab_ref, msk_ref, pwk_ref, ghic_ref = refs[:6]
+            pw_ref, w_ref, lab_ref, msk_ref, pwk_ref, ghic_ref = refs[:6]
             rest = refs[6:]
         else:
-            pw_ref, wt_ref, lab_ref, msk_ref = refs[:4]
+            pw_ref, w_ref, lab_ref, msk_ref = refs[:4]
             rest = refs[4:]
         if spill:
             sp_ref, rest = rest[0], rest[1:]
         if handle is not None:
-            (wp_ref, zp_ref, np_ref, mg_ref, wo_ref, zo_ref, no_ref,
+            # w_ref walks the tiles in BOTH phases (it is the one plane
+            # aliased onto wo_ref): rounded in phase 1, updated in phase 2
+            (zp_ref, np_ref, mg_ref, wo_ref, zo_ref, no_ref, wd_ref,
              *scr) = rest
         else:
             mg_ref, g_ref, *scr = rest
@@ -1231,10 +1243,10 @@ def _make_step_kernel(spec: TileSpec, loss: str, exact_dense: bool,
         @pl.when(t < nt)
         def _fwd():
             if cache:
-                _fwd_kernel_cached(spec, pw_ref, wt_ref, mg_ref,
+                _fwd_kernel_cached(spec, pw_ref, w_ref, mg_ref,
                                    lo_c, rlo_c, t)
             else:
-                _fwd_kernel(spec, pw_ref, wt_ref, mg_ref, t)
+                _fwd_kernel(spec, pw_ref, w_ref, mg_ref, t)
 
         @pl.when(t == nt)
         def _dual():
@@ -1256,6 +1268,8 @@ def _make_step_kernel(spec: TileSpec, loss: str, exact_dense: bool,
                                 jnp.float32(1e-30))
                 dual = jnp.where((dual == 0.0) & (msk > 0), eps, dual)
             dual_s[...] = dual.reshape(dual_s.shape).astype(jnp.bfloat16)
+            if handle is not None:
+                wd_ref[...] = jnp.zeros_like(wd_ref)
 
         @pl.when(t >= nt)
         def _bwd():
@@ -1278,25 +1292,34 @@ def _make_step_kernel(spec: TileSpec, loss: str, exact_dense: bool,
             else:
                 _bwd_kernel(spec, pw_ref, dual_s, sink)
             one = opaque_one(msk_ref[0, 0, 0])
+            wd = wd_ref[...]
             for tb in range(spec.tiles_step):
+                w_old = w_ref[tb]
                 w_new, z_new, cg_new = handle.update(
-                    wp_ref[tb], zp_ref[tb], np_ref[tb],
-                    sink.tiles[tb], one)
+                    w_old, zp_ref[tb], np_ref[tb], sink.tiles[tb], one)
                 wo_ref[tb] = w_new
                 zo_ref[tb] = z_new
                 no_ref[tb] = cg_new
+                # the progress number's partial sums, a lane apiece
+                # (summed by the wrapper): old and new w are in registers
+                d = w_new - w_old
+                wd = wd + d * d
+            wd_ref[...] = wd
 
     return kernel
 
 
-def _step_grid_specs(spec: TileSpec, spill: bool = False):
+def _step_grid_specs(spec: TileSpec, spill: bool = False,
+                     w_both_phases: bool = False):
     """(grid, in_specs, nt) shared by both fused scalar variants: pairs
-    + bf16 weight tiles stream through phase 1 (and, at K == 1, phase 2
-    re-streams the pairs exactly as the split bwd call would), the
-    label/mask grids sit at a constant index, and the K > 1 variant
+    + float32 weight-plane tiles stream through phase 1 (and, at K == 1,
+    phase 2 re-streams the pairs exactly as the split bwd call would),
+    the label/mask grids sit at a constant index, and the K > 1 variant
     adds the re-viewed pairs + the joint-digit compare constant for
     _bwd_kernel_fused. ``spill`` appends the constant-index
-    pre-aggregated spill-margin grid the boundary phase consumes."""
+    pre-aggregated spill-margin grid the boundary phase consumes.
+    ``w_both_phases`` (the in-place variant) walks the weight tiles
+    again in phase 2, where that variant updates them."""
     T, TB, K = spec.tiles, spec.tiles_step, spec.fuse
     SG, N, S = spec.subblocks // spec.group, spec.n, spec.subblocks
     GS = spec.group
@@ -1306,7 +1329,8 @@ def _step_grid_specs(spec: TileSpec, spill: bool = False):
     in_specs = [
         pl.BlockSpec((TB, SG, N), pw_map),
         pl.BlockSpec((TB, A_HI, B_LO),
-                     lambda t: (jnp.minimum(t, nt - 1), 0, 0)),
+                     (lambda t: (t % nt, 0, 0)) if w_both_phases
+                     else (lambda t: (jnp.minimum(t, nt - 1), 0, 0))),
         pl.BlockSpec((S, RH, RL), lambda t: (0, 0, 0)),
         pl.BlockSpec((S, RH, RL), lambda t: (0, 0, 0)),
     ]
@@ -1369,7 +1393,7 @@ def _build_step_grad(spec: TileSpec, loss: str, exact_dense: bool,
 
     @jax.jit
     def step(pw, w, labels, mask, *spill_rows):
-        wt = w.reshape(T, A_HI, B_LO).astype(jnp.bfloat16)
+        wt = w.reshape(T, A_HI, B_LO)      # rounded tile by tile in-kernel
         args = ([pw, wt, labels.reshape(S, RH, RL),
                  mask.reshape(S, RH, RL)] + _step_extra_args(pw, spec)
                 + [s.reshape(S, RH, RL) for s in spill_rows])
@@ -1400,54 +1424,54 @@ def _build_step_grad(spec: TileSpec, loss: str, exact_dense: bool,
 @lru_cache(maxsize=None)
 def _build_step_update(spec: TileSpec, loss: str, handle,
                        cache: bool = False):
-    """Fused step, in-place FTRL variant: (margins, new_slots32). The
-    w/z/cg planes enter as operands aliased onto the outputs, so the
-    (nb,) gradient never exists in HBM — each tile's grad goes straight
-    from the bwd accumulator into the elementwise slot update. FTRL is
-    exact-dense (zero_grad_push_is_identity), so there is no nudge and
-    no touched mask to apply. ``handle`` is the (frozen, hashable)
-    FTRLHandle — the kernel runs its update() verbatim."""
-    T, TB = spec.tiles, spec.tiles_step
+    """Fused step, in-place FTRL variant: (margins, (w, z, cg) planes,
+    Σ(Δw)²). The three (T, A_HI, B_LO) planes of the table
+    (learners/table.py) go into the call as they are, aliased onto its
+    outputs, and come back as the next step's state; the weight plane is
+    the forward phase's operand too (rounded in-kernel). The (nb,)
+    gradient never exists in HBM — each tile's grad goes straight from
+    the bwd accumulator into the elementwise slot update — and no XLA
+    op on either side of the call reads or writes a table-sized array.
+    FTRL is exact-dense (zero_grad_push_is_identity), so there is no
+    nudge and no touched mask to apply. ``handle`` is the (frozen,
+    hashable) FTRLHandle — the kernel runs its update() verbatim."""
     S = spec.subblocks
-    grid, in_specs, nt = _step_grid_specs(spec)
+    grid, in_specs, nt = _step_grid_specs(spec, w_both_phases=True)
     kernel = _make_step_kernel(spec, loss, True, handle, nt, cache=cache)
     n_in = len(in_specs)
-    plane = pl.BlockSpec((TB, A_HI, B_LO),
+    plane = pl.BlockSpec((spec.tiles_step, A_HI, B_LO),
                          lambda t: (jnp.maximum(t - nt, 0), 0, 0))
-    in_specs = in_specs + [plane, plane, plane]
+    plane_shape = jax.ShapeDtypeStruct((spec.tiles, A_HI, B_LO),
+                                       jnp.float32)
 
     @jax.jit
-    def step(pw, s32, labels, mask):
-        wt = s32[:, 0].reshape(T, A_HI, B_LO).astype(jnp.bfloat16)
-        args = ([pw, wt, labels.reshape(S, RH, RL),
+    def step(pw, planes, labels, mask):
+        w, z, cg = planes
+        args = ([pw, w, labels.reshape(S, RH, RL),
                  mask.reshape(S, RH, RL)] + _step_extra_args(pw, spec)
-                + [s32[:, 0].reshape(T, A_HI, B_LO),
-                   s32[:, 1].reshape(T, A_HI, B_LO),
-                   s32[:, 2].reshape(T, A_HI, B_LO)])
-        mg, wn, zn, nn = pl.pallas_call(
+                + [z, cg])
+        mg, wn, zn, nn, wd = pl.pallas_call(
             kernel,
             grid=grid,
-            in_specs=in_specs,
+            in_specs=in_specs + [plane, plane],
             out_specs=[
                 pl.BlockSpec((S, RH, RL), lambda t: (0, 0, 0)),
                 plane, plane, plane,
+                pl.BlockSpec((A_HI, B_LO), lambda t: (0, 0)),
             ],
             out_shape=[
                 jax.ShapeDtypeStruct((S, RH, RL), jnp.float32),
-                jax.ShapeDtypeStruct((T, A_HI, B_LO), jnp.float32),
-                jax.ShapeDtypeStruct((T, A_HI, B_LO), jnp.float32),
-                jax.ShapeDtypeStruct((T, A_HI, B_LO), jnp.float32),
+                plane_shape, plane_shape, plane_shape,
+                jax.ShapeDtypeStruct((A_HI, B_LO), jnp.float32),
             ],
-            input_output_aliases={n_in: 1, n_in + 1: 2, n_in + 2: 3},
+            input_output_aliases={1: 1, n_in: 2, n_in + 1: 3},
             scratch_shapes=([_step_dual_scratch(spec)]
                             + (_cache_scratch(spec) if cache else [])),
             compiler_params=None if _interpret() else pltpu.CompilerParams(
                 vmem_limit_bytes=100 * 1024 * 1024),
             interpret=_interpret(),
         )(*args)
-        new = jnp.stack([wn.reshape(spec.nb), zn.reshape(spec.nb),
-                         nn.reshape(spec.nb)], axis=-1)
-        return mg.reshape(spec.block_rows), new
+        return mg.reshape(spec.block_rows), (wn, zn, nn), jnp.sum(wd)
 
     return step
 
@@ -1850,18 +1874,18 @@ def fused_step_grad(pw: jax.Array, w: jax.Array, labels: jax.Array,
         pw, w, labels, mask, spill_margins)
 
 
-def fused_step_update(pw: jax.Array, s32: jax.Array, labels: jax.Array,
+def fused_step_update(pw: jax.Array, planes, labels: jax.Array,
                       mask: jax.Array, spec: TileSpec, loss: str,
-                      handle, cache: bool = False
-                      ) -> Tuple[jax.Array, jax.Array]:
-    """One-grid margins + dual + grad + in-place FTRL: (margins,
-    new_slots (nb, 3) f32). ``handle`` is the FTRLHandle whose update()
+                      handle, cache: bool = False):
+    """One-grid margins + dual + grad + in-place FTRL over the table's
+    (w, z, cg) planes (learners/table.py): (margins, new planes,
+    Σ(w_new − w_old)²). ``handle`` is the FTRLHandle whose update()
     runs in-kernel. The gradient never exists in HBM — single-process,
     spill-free blocks only (multihost gradients must cross the wire
     first and spill scatters need the grad in HBM; use
     fused_step_grad)."""
     return _build_step_update(spec, loss, handle, cache)(
-        pw, s32, labels, mask)
+        pw, tuple(planes), labels, mask)
 
 
 def fused_fm_step(pw: jax.Array, wpull: jax.Array, labels: jax.Array,

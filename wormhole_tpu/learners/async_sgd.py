@@ -982,6 +982,8 @@ class AsyncSGD:
                 with self.timer.scope(pfx + "wait"):
                     drain_spill()
 
+        tx = self.store.mesh_transport()
+        steps_before, ici_before = tx.dispatches, tx.bytes_ici
         inner = self._make_feed(file, part, nparts, fmt,
                                 device_put=lambda x: x,
                                 tile_info=info if online else None)
@@ -1022,6 +1024,11 @@ class AsyncSGD:
             drain_pending()
         self.timer.add(pfx + "put", feed.put_time)
         self._merge_pipe_snap(feed.drain_pipe_stats(None), pfx, local)
+        # counts, not seconds: the mesh dispatches of this part and the
+        # ICI bytes one chip moved for them as the store's model books
+        # them (store.mesh_step_ici_bytes)
+        self.timer.add(pfx + "mesh_steps", tx.dispatches - steps_before)
+        self.timer.add(pfx + "ici_bytes", tx.bytes_ici - ici_before)
         if use_ring:
             self._export_mesh_feed_stats(feed)
         return local

@@ -66,20 +66,39 @@ def put_like(template: jax.Array, full: np.ndarray) -> jax.Array:
     return jax.make_array_from_process_local_data(template.sharding, local)
 
 
-def shard_param_table(arr: jax.Array,
-                      runtime: Optional[MeshRuntime]) -> jax.Array:
-    """Place a (num_buckets, val_len) parameter table over the ``model``
-    mesh axis (validating divisibility), or leave it on the default device.
-    Shared by ShardedStore / FMStore / WideDeepStore."""
+def _table_sharding(num_buckets: int, runtime: Optional[MeshRuntime]):
+    """Where a (num_buckets, val_len) parameter table lives: rows over the
+    ``model`` mesh axis (validating divisibility), or None for the default
+    device."""
     if runtime is None or MODEL_AXIS not in runtime.mesh.axis_names \
             or runtime.model_axis_size <= 1:
-        return arr
-    if arr.shape[0] % runtime.model_axis_size:
+        return None
+    if num_buckets % runtime.model_axis_size:
         raise ValueError(
-            f"num_buckets {arr.shape[0]} not divisible by model axis "
+            f"num_buckets {num_buckets} not divisible by model axis "
             f"{runtime.model_axis_size}")
-    return jax.device_put(
-        arr, NamedSharding(runtime.mesh, P(MODEL_AXIS, None)))
+    return NamedSharding(runtime.mesh, P(MODEL_AXIS, None))
+
+
+def shard_param_table(arr: jax.Array,
+                      runtime: Optional[MeshRuntime]) -> jax.Array:
+    """Place a built parameter table (FMStore / WideDeepStore, whose
+    tables come from the host)."""
+    sharding = _table_sharding(arr.shape[0], runtime)
+    return arr if sharding is None else jax.device_put(arr, sharding)
+
+
+def build_param_table(make, num_buckets: int,
+                      runtime: Optional[MeshRuntime]) -> jax.Array:
+    """``make()`` -> the (num_buckets, val_len) table, built where it is
+    to live: with a model axis every chip writes its own shard and nothing
+    else. (Built on the default device and placed afterwards, the whole
+    table is on one chip first: at 2**29 buckets 8.6 GB beside that chip's
+    own 4.3 GB shard, which stays its ``peak_bytes_in_use`` for good.)"""
+    sharding = _table_sharding(num_buckets, runtime)
+    if sharding is None:
+        return make()
+    return jax.jit(make, out_shardings=sharding)()
 
 
 def mix32(h: jax.Array) -> jax.Array:
@@ -396,7 +415,7 @@ class TableCheckpoint:
                 jnp.asarray(tau * theta, jnp.float32))
         return v
 
-    def _mesh_transport(self):
+    def mesh_transport(self):
         """The shared intra-host transport leg every mesh dispatcher
         routes through (parallel/transport.MeshTransport): site/seq
         stamping, the collective:mesh span, chaos/watchdog, and
@@ -438,8 +457,8 @@ class ShardedStore(TableCheckpoint):
             self._table = jax.jit(
                 lambda: tbl.PlaneTable(tbl.split(handle.init(nb))))()
         else:
-            self._table = shard_param_table(
-                handle.init(nb).astype(self.dtype), runtime)
+            self._table = build_param_table(
+                lambda: handle.init(nb).astype(self.dtype), nb, runtime)
         self._step = self._build_step()
         self._eval = self._build_eval()
         self.t = 1  # global update counter (SGD eta schedule)
@@ -777,7 +796,7 @@ class ShardedStore(TableCheckpoint):
         (fetch_metrics); returns the step-clock scalar."""
         step = self._dense_step_mesh(block_rows, nnz, "train")
         nb_local = self.cfg.num_buckets // max(self.rt.model_axis_size, 1)
-        self.slots, t_new, self._macc = self._mesh_transport().dispatch(
+        self.slots, t_new, self._macc = self.mesh_transport().dispatch(
             step, self._mesh_table(), packed, self._t_device(),
             self._tau_const(tau), self._macc_buf(),
             ici_bytes=mesh_step_ici_bytes(
@@ -787,7 +806,7 @@ class ShardedStore(TableCheckpoint):
 
     def dense_eval_step_mesh(self, packed: jax.Array, block_rows: int,
                              nnz: int):
-        return self._mesh_transport().dispatch(
+        return self.mesh_transport().dispatch(
             self._dense_step_mesh(block_rows, nnz, "eval"),
             self._mesh_table(), packed,
             ici_bytes=mesh_step_ici_bytes(
@@ -1004,23 +1023,29 @@ class ShardedStore(TableCheckpoint):
                                                               spec)
         oc, R = info.ovf_cap, info.block_rows
 
-        def body(slots_l, pw_l, lab_l, ovb_l, ovr_l, t, tau, macc):
+        # The phases carry jax.named_scope names, so that the device
+        # trace's ops say which phase they belong to: mesh_forward,
+        # mesh_psum_margin, mesh_backward, mesh_psum_grad, mesh_push.
+        def mesh_step(slots_l, pw_l, lab_l, ovb_l, ovr_l, t, tau, macc):
             pw1 = pw_l[0].reshape(spec_local.pairs_shape)
             lab = lab_l[0]
             row_mask = (lab != jnp.uint8(255)).astype(jnp.float32)
             labels = jnp.minimum(lab, 1).astype(jnp.float32)
             s32 = slots_l.astype(jnp.float32)
-            w = handle.weights(s32)
-            mg = tilemm.forward_margins(pw1, w, spec_local)
-            off = (jax.lax.axis_index(MODEL_AXIS) * nb_local
-                   if have_model else 0)
-            if oc:
-                ovb, ovr = ovb_l[0], ovr_l[0]
-                valid, idx = shard_range_mask(ovb, off, nb_local)
-                wv = jnp.where(valid, w[idx], 0.0)
-                # scatter-fallback: COO overflow spill, O(ovf_cap)
-                mg = mg.at[ovr.astype(jnp.int32)].add(wv)
-            margin = (jax.lax.psum(mg, MODEL_AXIS) if have_model else mg)
+            with jax.named_scope("mesh_forward"):
+                w = handle.weights(s32)
+                mg = tilemm.forward_margins(pw1, w, spec_local)
+                off = (jax.lax.axis_index(MODEL_AXIS) * nb_local
+                       if have_model else 0)
+                if oc:
+                    ovb, ovr = ovb_l[0], ovr_l[0]
+                    valid, idx = shard_range_mask(ovb, off, nb_local)
+                    wv = jnp.where(valid, w[idx], 0.0)
+                    # scatter-fallback: COO overflow spill, O(ovf_cap)
+                    mg = mg.at[ovr.astype(jnp.int32)].add(wv)
+            with jax.named_scope("mesh_psum_margin"):
+                margin = (jax.lax.psum(mg, MODEL_AXIS) if have_model
+                          else mg)
             objv = objv_fn(margin, labels, row_mask)
             num_ex = jnp.sum(row_mask)
             acc = accuracy(labels, margin, row_mask)
@@ -1029,21 +1054,24 @@ class ShardedStore(TableCheckpoint):
                 objv, num_ex, acc, pos, neg)
             if kind == "eval":
                 return objv_g, tot_ex, acc_frac, pos_g, neg_g, margin
-            dual = dual_fn(margin, labels, row_mask)
-            if not exact_dense:
-                dual = _nudge_zero_dual(dual, labels, row_mask)
-            g = tilemm.backward_grad(pw1, dual, spec_local)
-            if oc:
-                dv = jnp.where(valid, dual[ovr.astype(jnp.int32)], 0.0)
-                # scatter-fallback: COO overflow spill, O(ovf_cap)
-                g = g.at[idx].add(dv)
-            g = jax.lax.psum(g, DATA_AXIS)
-            new = masked_push(handle, s32, g, t.astype(jnp.float32), tau,
-                              exact_dense)
-            d0 = new[:, 0] - s32[:, 0]
-            wdelta2 = jnp.sum(d0 * d0)
-            if have_model:
-                wdelta2 = jax.lax.psum(wdelta2, MODEL_AXIS)
+            with jax.named_scope("mesh_backward"):
+                dual = dual_fn(margin, labels, row_mask)
+                if not exact_dense:
+                    dual = _nudge_zero_dual(dual, labels, row_mask)
+                g = tilemm.backward_grad(pw1, dual, spec_local)
+                if oc:
+                    dv = jnp.where(valid, dual[ovr.astype(jnp.int32)], 0.0)
+                    # scatter-fallback: COO overflow spill, O(ovf_cap)
+                    g = g.at[idx].add(dv)
+            with jax.named_scope("mesh_psum_grad"):
+                g = jax.lax.psum(g, DATA_AXIS)
+            with jax.named_scope("mesh_push"):
+                new = masked_push(handle, s32, g, t.astype(jnp.float32),
+                                  tau, exact_dense)
+                d0 = new[:, 0] - s32[:, 0]
+                wdelta2 = jnp.sum(d0 * d0)
+                if have_model:
+                    wdelta2 = jax.lax.psum(wdelta2, MODEL_AXIS)
             packed = mesh_macc_row(objv_g, tot_ex, acc_frac, wdelta2,
                                    pos_g, neg_g)
             return new.astype(slots_l.dtype), t + 1, macc + packed
@@ -1052,17 +1080,18 @@ class ShardedStore(TableCheckpoint):
         if kind == "train":
             in_specs = data_specs + (P(), P(), P())
             out_specs = (Pm, P(), P())
-            fn = body
+            fn = mesh_step
         else:
             # eval takes no clock args (the t/tau params are train-only)
             in_specs = data_specs
             out_specs = (P(), P(), P(), P(), P(), P(DATA_AXIS))
 
-            def fn(s, pw_, lab_, ovb_, ovr_):
-                # body's eval branch returns before touching t/tau/macc
-                return body(s, pw_, lab_, ovb_, ovr_,
-                            jnp.float32(0), jnp.float32(0),
-                            jnp.float32(0))
+            def mesh_eval_step(s, pw_, lab_, ovb_, ovr_):
+                # the eval branch returns before touching t/tau/macc
+                return mesh_step(s, pw_, lab_, ovb_, ovr_,
+                                 jnp.float32(0), jnp.float32(0),
+                                 jnp.float32(0))
+            fn = mesh_eval_step
         step = jax.jit(
             shard_map_compat(fn, mesh=mesh, in_specs=in_specs,
                              out_specs=out_specs),
@@ -1086,7 +1115,7 @@ class ShardedStore(TableCheckpoint):
         step = self._tile_step_mesh(info, "train")
         z = mesh_ovf_zeros(D, oc)
         nb_local = mesh_tile_geometry(self.rt, info.spec)[0]
-        self.slots, t_new, self._macc = self._mesh_transport().dispatch(
+        self.slots, t_new, self._macc = self.mesh_transport().dispatch(
             step, self._mesh_table(), blocks["pw"], blocks["labels"],
             blocks.get("ovf_b", z), blocks.get("ovf_r", z),
             self._t_device(), self._tau_const(tau), self._macc_buf(),
@@ -1100,7 +1129,7 @@ class ShardedStore(TableCheckpoint):
         oc = info.ovf_cap
         D = self.rt.data_axis_size
         z = mesh_ovf_zeros(D, oc)
-        return self._mesh_transport().dispatch(
+        return self.mesh_transport().dispatch(
             self._tile_step_mesh(info, "eval"),
             self._mesh_table(), blocks["pw"], blocks["labels"],
             blocks.get("ovf_b", z), blocks.get("ovf_r", z),

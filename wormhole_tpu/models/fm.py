@@ -463,7 +463,7 @@ class FMStore(TableCheckpoint):
         # pull/push channels: w, v[dim], sum(v*v) / dual row-mask ticket
         ch = self.cfg.dim + 2
         nb_local = mesh_tile_geometry(self.rt, info.spec)[0]
-        self.slots, t_new, self._macc = self._mesh_transport().dispatch(
+        self.slots, t_new, self._macc = self.mesh_transport().dispatch(
             step, self._mesh_table(), blocks["pw"], blocks["labels"],
             blocks.get("ovf_b", z), blocks.get("ovf_r", z),
             self._t_device(), self._tau_const(tau), self._macc_buf(),
@@ -478,7 +478,7 @@ class FMStore(TableCheckpoint):
         D = self.rt.data_axis_size
         z = mesh_ovf_zeros(D, oc)
         ch = self.cfg.dim + 2
-        return self._mesh_transport().dispatch(
+        return self.mesh_transport().dispatch(
             self._tile_step_mesh(info, "eval"),
             self._mesh_table(), blocks["pw"], blocks["labels"],
             blocks.get("ovf_b", z), blocks.get("ovf_r", z),

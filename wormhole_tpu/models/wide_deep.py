@@ -482,7 +482,7 @@ class WideDeepStore(TableCheckpoint):
         mlp_elems = sum(int(np.asarray(p).size)
                         for p in jax.tree.leaves(self.mlp))
         (self.slots, self.mlp, self.mlp_accum, t_new,
-         self._macc) = self._mesh_transport().dispatch(
+         self._macc) = self.mesh_transport().dispatch(
             step, self._mesh_table(), self.mlp, self.mlp_accum,
             blocks["pw"], blocks["labels"],
             blocks.get("ovf_b", z), blocks.get("ovf_r", z),
@@ -499,7 +499,7 @@ class WideDeepStore(TableCheckpoint):
         D = self.rt.data_axis_size
         z = mesh_ovf_zeros(D, oc)
         ch = self.cfg.dim + 1
-        return self._mesh_transport().dispatch(
+        return self.mesh_transport().dispatch(
             self._tile_step_mesh(info, "eval"),
             self._mesh_table(), self.mlp, self.mlp_accum, blocks["pw"],
             blocks["labels"], blocks.get("ovf_b", z),

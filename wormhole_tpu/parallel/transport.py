@@ -705,6 +705,11 @@ class MeshTransport:
                  ici_bytes_per_call: int = 0) -> None:
         self.site = str(site)
         self.ici_bytes_per_call = int(ici_bytes_per_call)
+        # this leg's own running totals (the registry's comm/bytes_ici is
+        # process-wide): a pass loop takes their change over a part into
+        # its Timer, where a reader finds it beside the part's seconds
+        self.dispatches = 0
+        self.bytes_ici = 0
 
     def dispatch(self, fn: Callable, *args,
                  ici_bytes: Optional[int] = None):
@@ -718,6 +723,8 @@ class MeshTransport:
             _chaos.on_collective(self.site)
             with _watchdog.guard(self.site):
                 out = fn(*args)
+        self.dispatches += 1
+        self.bytes_ici += b
         if b:
             c = _ici_counter()
             if c is not None:

@@ -6,6 +6,8 @@ path (both fold keys with hashing.fold_keys32), up to the tile kernels'
 bf16 value quantization.
 """
 
+import os
+
 import jax
 import numpy as np
 import pytest
@@ -394,3 +396,135 @@ def test_crec_v1_mesh_matches_single_device(tmp_path, rng):
     live = (np.abs(w_single) > 1e-6) | (np.abs(w_mesh) > 1e-6)
     assert live.any()
     assert np.allclose(w_single[live], w_mesh[live], rtol=1e-4, atol=1e-5)
+
+
+# -- the block source: a local crec2 file is mapped, any other stream read --
+
+
+def _three_blocks(tmp_path, rng):
+    n = 2 * 4 * tilemm.RSUB + 17    # 3 blocks (subblocks=4)
+    keys, labels = make_rows(rng, n)
+    path = tmp_path / "m.crec2"
+    write_file(path, keys, labels, cap=33024)
+    assert read_header2(str(path)).num_blocks == 3
+    return str(path)
+
+
+def _same_blocks(got, want):
+    assert len(got) == len(want)
+    for (a, ra), (b, rb) in zip(got, want):
+        assert ra == rb and sorted(a) == sorted(b)
+        for k in b:
+            assert a[k].dtype == b[k].dtype and np.array_equal(a[k], b[k]), k
+
+
+@pytest.mark.parametrize("workers", [0, 2])
+def test_local_file_blocks_are_views_of_a_mapping(tmp_path, rng, workers):
+    """Over a local file the feed's blocks are read-only views of one
+    mapping, equal to ``iter_packed2``'s (which reads into fresh memory)
+    for every block over two passes, and the feed copies no byte."""
+    path = _three_blocks(tmp_path, rng)
+    want = list(iter_packed2(path))
+    feed = PackedFeed(path, fmt="crec2", device_put=lambda x: x,
+                      workers=workers)
+    for _pass in range(2):
+        got = [(host, rows) for _dev, host, rows in feed]
+        _same_blocks(got, want)
+        assert not any(v.flags.writeable for h, _r in got
+                       for v in h.values())
+    assert feed.host_copy_bytes == 0
+    assert feed.bytes_read == 2 * 3 * read_header2(path).block_bytes
+
+
+@pytest.mark.parametrize("workers", [0, 2])
+def test_stream_without_fileno_is_read_into_memory(tmp_path, rng, workers):
+    """A stream that is no local file (here an in-memory one behind a
+    registered scheme, with no descriptor) keeps ``readinto``: the same
+    blocks, and every byte of them counted as copied on the host."""
+    import io
+    from wormhole_tpu.data import stream
+    path = _three_blocks(tmp_path, rng)
+    raw = open(path, "rb").read()
+
+    class MemFS(stream.FileSystem):
+        def open(self, uri, mode="rb"):
+            return io.BytesIO(raw)
+
+    stream.register_filesystem("mem", MemFS())
+    try:
+        feed = PackedFeed("mem://m.crec2", fmt="crec2",
+                          device_put=lambda x: x, workers=workers)
+        got = [(host, rows) for _dev, host, rows in feed]
+    finally:
+        del stream._REGISTRY["mem"]
+    _same_blocks(got, list(iter_packed2(path)))
+    assert all(v.flags.writeable for h, _r in got for v in h.values())
+    assert feed.host_copy_bytes == feed.bytes_read \
+        == 3 * read_header2(path).block_bytes
+
+
+def test_mapping_is_closed_with_the_source(tmp_path, rng):
+    """``close`` (the feed's ``on_close``) closes the mapping at once
+    when no view of it is left; a view still held keeps it mapped, and
+    readable, until the view goes."""
+    import gc
+    import weakref
+    from wormhole_tpu.data.crec import BlockSource
+    path = _three_blocks(tmp_path, rng)
+    src = BlockSource(path)
+    assert src.mapped
+    m = src._map
+    src.read(0)
+    src.close()
+    assert m.closed and not src.mapped
+
+    src = BlockSource(path)
+    views, _rows = src.read(2)
+    alive = weakref.ref(src._map)
+    src.close()
+    assert not src.mapped and not alive().closed
+    _same_blocks([(views, _rows)], list(iter_packed2(path))[2:])
+    del views
+    gc.collect()
+    assert alive() is None
+
+
+@pytest.mark.parametrize("workers", [0, 2])
+def test_feed_closes_its_mapping_every_pass(tmp_path, rng, monkeypatch,
+                                            workers):
+    """A pass maps the file once and its end (``on_close``, or the
+    serial stream's ``finally``) lets go of that mapping: with the
+    blocks dropped, nothing stays mapped behind the feed."""
+    import gc
+    import weakref
+    from wormhole_tpu.data import crec
+    path = _three_blocks(tmp_path, rng)
+    maps = []
+    real = crec._map_local
+
+    def recording(p):
+        m = real(p)
+        maps.append(weakref.ref(m))
+        return m
+
+    monkeypatch.setattr(crec, "_map_local", recording)
+    feed = PackedFeed(path, fmt="crec2", device_put=lambda x: x,
+                      workers=workers)
+    for _pass in range(2):
+        for item in feed:
+            assert maps[-1]() is not None and not maps[-1]().closed
+        del item
+    gc.collect()
+    assert len(maps) == 2 and all(m() is None for m in maps)
+
+
+def test_truncated_mapped_block_raises(tmp_path, rng):
+    from wormhole_tpu.data.crec import BlockSource
+    path = _three_blocks(tmp_path, rng)
+    with open(path, "r+b") as f:
+        f.truncate(os.path.getsize(path) - 64)
+    src = BlockSource(path)
+    src.read(1)
+    with pytest.raises(IOError, match="truncated block 2"):
+        src.read(2)
+    src.close()

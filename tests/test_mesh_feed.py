@@ -1,29 +1,36 @@
 """Sharded multichip feed (data/crec.MeshGroupFeed + cfg.mesh_feed).
 
-The scale-out PR moves the mesh dispatch loop's group stacking onto the
-feed's prep workers and its H2D onto the transfer ring (device_put onto
-the (data, model) NamedSharding). Three contracts pinned here:
+The mesh feed forms data-axis groups off the dispatch thread and hands
+every chip its slice of a group from the transfer ring, on the (data,
+model) NamedSharding the step takes, with no stacked copy of the group.
+The contracts pinned here:
 
   * worker/mode determinism — the pipelined ring (workers=N) is
     bit-identical to the serial inline feed (workers=0), and the ring
     path trains the same table as the legacy synchronous
     stack-in-the-loop dispatch (``mesh_feed=sync``): same groups, same
-    padding, same step order — only WHERE the stack/transfer happen
+    padding, same step order — only WHERE the bytes are gathered
     moves;
   * short-tail PAD parity — an eval pass whose tail group is mostly
     PAD filler blocks pools exactly the same (margin, label) rows as
     the single-device path over the same file: PAD lanes (label 255)
-    are invisible, and the pooled labels come from the stacked group
-    views, not a per-dispatch host concatenate;
+    are invisible;
   * spill accounting — an online-encoded block whose COO overflow
     exceeds the cap rides the SAME ring as the groups (passthrough, no
     group flush) to the audited scatter step: every row credited once,
     and the mesh/spill_blocks + feed/tile_fallback_blocks counters
-    tick.
+    tick;
+  * direct placement — every shard of a group assembled chip by chip
+    holds the bytes of the same index of the stacked group, under the
+    step's shape, dtype and sharding (tile and v1; full and padded;
+    workers 0 and 2), and the pass books the host bytes it copied.
 """
+
+import os
 
 import jax
 import numpy as np
+import pytest
 
 from wormhole_tpu.data.crec import CRec2Writer, CRecWriter
 from wormhole_tpu.ops import tilemm
@@ -163,3 +170,112 @@ def test_online_spill_blocks_ride_the_ring(tmp_path, rng):
     w2 = train(2)
     w0 = train(0)
     assert np.array_equal(w2, w0)
+
+
+def _group_feed(path, fmt, workers, want_labels=False):
+    """A MeshGroupFeed on a data:2,model:2 mesh over ``path``, with the
+    oracle's ingredients: (feed, info, pads, shardings, is_tile)."""
+    from wormhole_tpu.data.crec import (MeshGroupFeed, PackedFeed,
+                                        mesh_pads, read_header,
+                                        read_header2)
+    from wormhole_tpu.learners.store import mesh_group_shardings
+    from wormhole_tpu.parallel.mesh import MeshRuntime, make_mesh
+    rt = MeshRuntime.create()
+    rt.mesh = make_mesh("data:2,model:2", jax.devices()[:4])
+    is_tile = fmt == "crec2"
+    info = read_header2(str(path)) if is_tile else read_header(str(path))
+    shardings = mesh_group_shardings(rt, is_tile)
+    inner = PackedFeed(str(path), fmt=fmt, device_put=lambda x: x,
+                       workers=workers)
+    feed = MeshGroupFeed(inner, 2, shardings, info, is_tile,
+                         workers=workers, want_labels=want_labels)
+    return feed, info, mesh_pads(info, is_tile), shardings, is_tile
+
+
+@pytest.mark.parametrize("workers", [0, 2])
+@pytest.mark.parametrize("member", ["full", "tail"])
+@pytest.mark.parametrize("fmt", ["crec2", "crec"])
+def test_placed_group_equals_stacked_group(tmp_path, rng, fmt, member,
+                                           workers):
+    """Three blocks on data:2,model:2: a full group, then a tail of one
+    block and the shared PAD block. Every addressable shard of the
+    group the feed assembles chip by chip holds the bytes of the same
+    index of ``stack_mesh_group``'s array, and the global array has the
+    shape, dtype and sharding that a ``device_put`` of the stacked group
+    gives the step; the pooled label lane is the stacked one."""
+    from wormhole_tpu.data.crec import (iter_packed, iter_packed2,
+                                        stack_mesh_group)
+    n = 2 * BR + 1000
+    keys, labels = make_rows(rng, n)
+    path = tmp_path / f"g.{fmt}"
+    if fmt == "crec2":
+        write_file(path, keys, labels)
+        blocks = [v for v, _r in iter_packed2(str(path))]
+    else:
+        with CRecWriter(str(path), nnz=NNZ, block_rows=BR) as w:
+            w.append(keys, labels)
+        blocks = [b for b, _r in iter_packed(str(path))]
+    assert len(blocks) == 3
+    feed, info, pads, shardings, is_tile = _group_feed(
+        path, fmt, workers, want_labels=True)
+    groups = list(feed)
+    assert [g[0] for g in groups] == ["group", "group"]
+    assert [g[3] for g in groups] == [2 * BR, 1000]
+    k = 0 if member == "full" else 1
+    _tag, placed, lab, _rows = groups[k]
+    want, want_lab = stack_mesh_group(blocks[2 * k:2 * k + 2], 2, info,
+                                      pads, is_tile, want_labels=True)
+    assert np.array_equal(lab, want_lab)
+    oracle = jax.device_put(want, shardings)
+    flat = (lambda t: sorted(t.items())) if is_tile \
+        else (lambda t: [("blocks", t)])
+    for (name, got), (_, exp), (_, host) in zip(flat(placed), flat(oracle),
+                                                flat(want)):
+        assert got.shape == exp.shape == host.shape, name
+        assert got.dtype == exp.dtype == host.dtype, name
+        assert got.sharding == exp.sharding, name
+        assert len(got.addressable_shards) == 4
+        for shard, other in zip(got.addressable_shards,
+                                exp.addressable_shards):
+            assert shard.device == other.device
+            assert shard.index == other.index
+            assert np.array_equal(np.asarray(shard.data),
+                                  host[shard.index]), (name, shard.index)
+    if member == "tail":            # the pad member is the PAD block
+        pw_or_bytes = np.asarray(placed["pw"] if is_tile else placed)[1]
+        assert np.array_equal(pw_or_bytes, pads["pw"] if is_tile else pads)
+
+
+def test_mesh_pass_books_host_copy_bytes(tmp_path, rng):
+    """The mesh pass adds the bytes its feed copied on the host to the
+    Timer, a count beside mesh_steps: nothing on the ring over a local
+    crec2 file (blocks are views of a mapping and no group is stacked),
+    every stacked byte under mesh_feed=sync."""
+    n = 4 * BR
+    keys, labels = make_rows(rng, n)
+    path = tmp_path / "c.crec2"
+    write_file(path, keys, labels)
+
+    def copied(mode):
+        app = make_app(path, "data:2", mesh_feed=mode)
+        assert app.run().num_ex == n
+        assert app.timer.totals["mesh_steps"] == 2
+        return app.timer.totals["host_copy_bytes"]
+
+    assert copied("ring") == 0
+    assert copied("sync") == os.path.getsize(path) - 48   # less the header
+
+
+@pytest.mark.parametrize("blocks", [2, 3])
+def test_pad_block_built_only_for_a_short_tail(tmp_path, rng, blocks):
+    """A pass makes a new feed; the shared PAD block (a block's worth of
+    memory to fill) is built when a short tail asks for it, not with
+    the feed."""
+    n = (blocks - 1) * BR + 500
+    keys, labels = make_rows(rng, n)
+    path = tmp_path / "p.crec2"
+    write_file(path, keys, labels)
+    feed = _group_feed(path, "crec2", 2)[0]
+    assert sum(g[3] for g in feed) == n
+    assert ("_pads" in vars(feed)) == (blocks % 2 == 1)
+    assert feed.skew_snapshot()["pad_blocks"] == blocks % 2

@@ -32,6 +32,7 @@ fixed-size and seekable.
 
 from __future__ import annotations
 
+import functools
 import struct
 import threading
 import queue
@@ -465,11 +466,112 @@ def iter_packed2(path: str, part: int = 0,
             yield _read_block2(f, path, info, i)
 
 
+def _map_local(path: str):
+    """A read-only mapping of ``path``, or None where the stream behind
+    it is not a plain local file (a buffered raw ``FileIO`` with a real
+    descriptor): s3, hdfs, a registered filesystem, a compressed or
+    in-memory stream."""
+    import io
+    import mmap
+    from wormhole_tpu.data.stream import open_stream
+    with open_stream(path, "rb") as f:
+        if not isinstance(getattr(f, "raw", None), io.FileIO):
+            return None
+        try:
+            return mmap.mmap(f.fileno(), 0, access=mmap.ACCESS_READ)
+        except (OSError, ValueError):
+            return None
+
+
+class BlockSource:
+    """The blocks of one crec/crec2 file for one pass of a feed:
+    ``read(i) -> (block, rows)``, from any thread.
+
+    A local crec2 file is mapped read-only, once, and a block is
+    :func:`block2_views` of the mapping: no byte is copied on the host,
+    and whoever consumes a view (``device_put``) reads the page cache
+    itself. Reading into ``np.empty`` instead first-touches a block's
+    worth of fresh pages every time, because glibc hands memory of that
+    size back to the kernel on free: 0.29 s a 201 MB block on the
+    four-chip host (PERF.md). Nothing ever writes a read-only mapping
+    and it lives as long as a view of it does, so no buffer is handed
+    back or reused: on the CPU backend ``device_put`` may alias its
+    source, and on a TPU the source must not change until the transfer
+    completes. Every other stream, and crec v1 (whose tail block is
+    padded on read), keeps one handle a thread and ``readinto`` fresh
+    memory; ``mapped`` tells which, and the feed counts the copies.
+
+    A mapped block's views are read-only (writing one raises). On a
+    file the page cache does not hold, ``read`` does no I/O: the disk
+    reads are page faults in whoever consumes the views, the one
+    transfer thread's ``device_put``. ``MADV_WILLNEED`` on the block
+    does not move them to the reader: the kernel cuts the advice to one
+    read-ahead window (8 MB of a 201 MB block) and a cold pass measured
+    slower with it than without (PERF.md, PR 31). The file's size is
+    taken when the source is made, once a pass: a file shortened or
+    replaced in place while a pass maps it is a SIGBUS in whoever
+    touches the lost pages, where ``readinto`` raised a short read.
+    Write a new file and rename it."""
+
+    def __init__(self, path: str, fmt: str = "crec2"):
+        self.path = path
+        v1 = fmt == "crec"
+        self.info = read_header(path) if v1 else read_header2(path)
+        self._reader = _read_block if v1 else _read_block2
+        self._map = None if v1 else _map_local(path)
+        self._tls = threading.local()
+        self._handles: list = []
+        self._lock = threading.Lock()
+
+    @property
+    def mapped(self) -> bool:
+        return self._map is not None
+
+    def part_range(self, part: int, nparts: int) -> range:
+        return _part_block_range(self.info, part, nparts)
+
+    def read(self, i: int):
+        info = self.info
+        if self._map is not None:
+            try:
+                buf = np.frombuffer(self._map, np.uint8, info.block_bytes,
+                                    info.block_offset(i))
+            except ValueError:
+                raise IOError(f"{self.path}: truncated block {i}") from None
+            return block2_views(info, buf), info.rows_in_block(i)
+        f = getattr(self._tls, "f", None)
+        if f is None:
+            from wormhole_tpu.data.stream import open_stream
+            f = self._tls.f = open_stream(self.path, "rb")
+            with self._lock:
+                self._handles.append(f)
+        return self._reader(f, self.path, info, i)
+
+    def close(self) -> None:
+        """Close the handles and let go of the mapping, which is
+        unmapped at once where no view of it is left, and else when the
+        last view goes."""
+        with self._lock:
+            handles, self._handles = self._handles, []
+        for f in handles:
+            try:
+                f.close()
+            except Exception:
+                pass
+        m, self._map = self._map, None
+        if m is not None:
+            try:
+                m.close()
+            except BufferError:
+                pass
+
+
 class PackedFeed:
     """Prefetching device feed: a producer thread reads blocks and issues
     ``device_put`` so transfer overlaps the consumer's dispatch loop (the
     ThreadedParser of this path, minibatch_iter.h:50). Yields
-    ``(device_packed, host_packed, rows)``.
+    ``(device_packed, host_packed, rows)``. ``host_packed`` is read-only
+    where the file is mapped (:class:`BlockSource`): copy it to change it.
 
     ``cache``: keep every block's device buffer and replay from HBM on
     subsequent iterations — multi-pass training then reads the dataset at
@@ -488,8 +590,12 @@ class PackedFeed:
         self.read_time = 0.0
         self.put_time = 0.0
         self.bytes_read = 0
+        # bytes this feed copied into host memory on their way to the
+        # device (a count; the pass loops add it to their Timer)
+        self.host_copy_bytes = 0
+        self._mapped = False    # this pass's blocks are views of a mapping
         self._device_put = device_put
-        self._iter_blocks = iter_packed if fmt == "crec" else iter_packed2
+        self._iter_blocks = self._source_blocks
         self._cache: Optional[list] = [] if cache else None
         self._cache_full = False
         self._pipe = None  # last DeviceFeed, for stall-counter draining
@@ -536,10 +642,11 @@ class PackedFeed:
                 self._cache = []
 
     def _account(self, packed) -> None:
-        if isinstance(packed, dict):
-            self.bytes_read += sum(v.nbytes for v in packed.values())
-        else:
-            self.bytes_read += packed.nbytes
+        n = (sum(v.nbytes for v in packed.values())
+             if isinstance(packed, dict) else packed.nbytes)
+        self.bytes_read += n
+        if not self._mapped:
+            self.host_copy_bytes += n
 
     def _stream_serial(self):
         import time as _time
@@ -588,43 +695,30 @@ class PackedFeed:
         finally:
             stop.set()
 
+    def _open_source(self) -> BlockSource:
+        src = BlockSource(self.path, self.fmt)
+        self._mapped = src.mapped
+        return src
+
+    def _source_blocks(self, path: str, part: int, nparts: int):
+        """The serial stream's blocks, in order, on the producer
+        thread."""
+        src = self._open_source()
+        try:
+            for i in src.part_range(part, nparts):
+                yield src.read(i)
+        finally:
+            src.close()
+
     def _pipeline_spec(self):
         """(source, prep, collate, on_close) for the parallel read path:
-        block indices dispatch to workers that each read with their OWN
-        stream handle (crec blocks are independent fixed-size seekable
-        ranges, so block-index parallelism is exact)."""
-        from wormhole_tpu.data.stream import open_stream
-        if self.fmt == "crec":
-            info = read_header(self.path)
-            reader = _read_block
-        else:
-            info = read_header2(self.path)
-            reader = _read_block2
-        nb = info.num_blocks
-        lo = self.part * nb // self.nparts
-        hi = (self.part + 1) * nb // self.nparts
-        tls = threading.local()
-        handles: list = []
-        hlock = threading.Lock()
-
-        def prep(i, _ctx):
-            f = getattr(tls, "f", None)
-            if f is None:
-                f = tls.f = open_stream(self.path, "rb")
-                with hlock:
-                    handles.append(f)
-            return reader(f, self.path, info, i)
-
-        def on_close():
-            with hlock:
-                for f in handles:
-                    try:
-                        f.close()
-                    except Exception:
-                        pass
-                handles.clear()
-
-        return iter(range(lo, hi)), prep, None, on_close
+        block indices dispatch to workers that read through one
+        :class:`BlockSource` (crec blocks are independent fixed-size
+        seekable ranges, so block-index parallelism is exact; a mapped
+        source leaves the workers nothing to copy)."""
+        src = self._open_source()
+        return (iter(src.part_range(self.part, self.nparts)),
+                lambda i, _ctx: src.read(i), None, src.close)
 
     def _stream_pipelined(self):
         """DeviceFeed-backed stream: parallel block reads/assembly, one
@@ -858,6 +952,10 @@ class TileOnlineFeed:
     def bytes_read(self) -> int:
         return self.inner.bytes_read
 
+    @property
+    def host_copy_bytes(self) -> int:
+        return self.inner.host_copy_bytes
+
     def __iter__(self):
         if self._cache_full:
             yield from self._cache
@@ -977,8 +1075,9 @@ class TileOnlineFeed:
 
 
 # ---------------------------------------------------------------------------
-# sharded multi-device group feed: stack + pre-place data-axis groups on
-# the pipeline workers so the mesh step never waits on host copies
+# sharded multi-device group feed: each chip of the mesh is handed its
+# slice of a block as the reader returned it, so the mesh step never
+# waits on a host copy
 # ---------------------------------------------------------------------------
 
 
@@ -1004,11 +1103,14 @@ def stack_mesh_group(views: list, D: int, info, pads, is_tile: bool,
                      want_labels: bool = False):
     """Stack one data-axis group of host blocks into the mesh step's
     stacked operands, padding a short group to ``D`` with ``pads``
-    (:func:`mesh_pads`). Returns ``(blocks, labels_u8)`` where
-    ``labels_u8`` — only materialized when ``want_labels`` (eval
-    pooling) — is a flat view of the ALREADY-stacked label lanes, not a
-    per-block concatenate: the global (D*R,) row order matches the mesh
-    eval step's margin output, PAD rows carried as 255."""
+    (:func:`mesh_pads`): a copy of every byte of the group into fresh
+    memory, which ``mesh_feed=sync`` alone still makes, on the dispatch
+    thread (the tests hold :func:`place_mesh_group` against it).
+    Returns ``(blocks, labels_u8)`` where ``labels_u8`` — only
+    materialized when ``want_labels`` (eval pooling) — is a flat view
+    of the ALREADY-stacked label lanes, not a per-block concatenate:
+    the global (D*R,) row order matches the mesh eval step's margin
+    output, PAD rows carried as 255."""
     if len(views) < D:
         views = views + [pads] * (D - len(views))
     if is_tile:
@@ -1031,22 +1133,56 @@ def stack_mesh_group(views: list, D: int, info, pads, is_tile: bool,
     return blocks, labels
 
 
+def place_mesh_group(views: list, shardings):
+    """One whole data-axis group of host blocks (a short one already
+    filled up with the PAD block) as the mesh step's operands, on their
+    devices, with no stacked copy: the arrays that
+    ``jax.device_put(stack_mesh_group(...)[0], shardings)`` gives (same
+    shape, dtype, ``NamedSharding`` and bytes), assembled shard by
+    shard. Under ``learners.store.mesh_group_shardings`` every chip
+    holds a contiguous slice of ONE block (``pw[d, a:b]``, row ``d`` of
+    a lane: a group has as many members as the data axis), so each chip
+    is sent that slice of the block's own view."""
+    import jax
+    D = len(views)
+
+    def place(sharding, *members):
+        def shard(index):
+            d = index[0].indices(D)[0]
+            return members[d][index[1:]][None]
+        return jax.make_array_from_callback(
+            (D,) + members[0].shape, sharding, shard)
+
+    # tile blocks are dicts of lanes and so are their shardings; a v1
+    # block is one array
+    return jax.tree.map(place, shardings, *views)
+
+
+def mesh_group_labels(views: list, info, is_tile: bool) -> np.ndarray:
+    """The label lanes of one whole group, concatenated in the global
+    ``(D * R,)`` row order of the mesh eval step's margins, PAD rows
+    carried as 255 (eval pooling; 98 KB a block)."""
+    return np.concatenate([v["labels"] if is_tile
+                           else unpack_block(v, info)[1] for v in views])
+
+
 class MeshGroupFeed:
     """Sharded DeviceFeed for the multi-device crec/crec2 path: the
     mesh counterpart of PackedFeed/TileOnlineFeed.
 
-    The pre-scale-out mesh loop stacked D host blocks with ``np.stack``
-    on the dispatch thread and let jit transfer the group synchronously
-    — the exact host work the single-device path moved onto the PR 1
-    pipeline long ago. This feed restores the split: the DeviceFeed
-    dispatcher forms data-axis groups in stream order
+    The DeviceFeed dispatcher forms data-axis groups in stream order
     (``pipeline.group_blocks``, recording per-group arrival skew — the
-    straggler telemetry), the prep workers stack + pad each group
-    (:func:`stack_mesh_group`), and the transfer thread ``device_put``s
-    the stacked operands directly onto their (data, model)
-    NamedSharding (``learners.store.mesh_group_shardings``) so the H2D
-    copy overlaps the previous group's mesh step and the step consumes
-    pre-placed arrays with zero re-layout.
+    straggler telemetry), and the transfer thread hands every chip its
+    slice of the group (:func:`place_mesh_group`): under the (data,
+    model) NamedSharding of ``learners.store.mesh_group_shardings`` that
+    slice is a contiguous part of ONE block's buffer as the inner feed
+    returned it, so no stacked copy of the group is made. The H2D copy
+    overlaps the previous group's mesh step, and the step consumes the
+    same pre-placed arrays (shape, dtype, sharding) a ``device_put`` of
+    the stacked group would give, with zero re-layout. What is left for
+    the ``stack`` workers is the label lanes of an eval pass
+    (``want_labels``). The feed copies no byte itself:
+    ``host_copy_bytes`` is the inner feed's.
 
     Encode-overflow spill batches (online mode: the inner TileOnlineFeed
     yields a SparseBatch for a block whose COO overflow exceeds the cap)
@@ -1074,7 +1210,6 @@ class MeshGroupFeed:
         self.depth = depth
         self.name = name
         self._shardings = shardings
-        self._pads = mesh_pads(info, is_tile)
         self.put_time = 0.0
         # dispatcher-thread counters (single writer; consumers read via
         # skew_snapshot after iteration)
@@ -1085,6 +1220,18 @@ class MeshGroupFeed:
     @property
     def bytes_read(self) -> int:
         return self.inner.bytes_read
+
+    @property
+    def host_copy_bytes(self) -> int:
+        return self.inner.host_copy_bytes
+
+    @functools.cached_property
+    def _pads(self):
+        """The shared PAD block, built when a short tail first asks for
+        it: a pass makes a new feed, and filling a block's worth of
+        fresh memory (201 MB at 2**29 buckets) at every pass start cost
+        the four-chip cell 0.2 s of each 1.04 s pass (PERF.md)."""
+        return mesh_pads(self.info, self.is_tile)
 
     def skew_snapshot(self) -> dict:
         return dict(self.skew)
@@ -1113,15 +1260,17 @@ class MeshGroupFeed:
                    sum(p[2] for p in payload))
 
     def _assemble(self, item, _ctx):
-        """Worker-side stage: pad + stack one group (the host copy the
-        old loop paid on the dispatch thread)."""
+        """Worker-side stage, all that is left of group assembly: a
+        short tail takes the shared PAD block as its missing members,
+        and an eval pass its label lanes."""
         if item[0] == "spill":
             return item
         _tag, views, rows = item
-        blocks, labels = stack_mesh_group(views, self.D, self.info,
-                                          self._pads, self.is_tile,
-                                          self.want_labels)
-        return ("group", blocks, labels, rows)
+        if len(views) < self.D:
+            views = views + [self._pads] * (self.D - len(views))
+        labels = (mesh_group_labels(views, self.info, self.is_tile)
+                  if self.want_labels else None)
+        return ("group", views, labels, rows)
 
     def _transfer(self, item):
         import time as _time
@@ -1132,8 +1281,8 @@ class MeshGroupFeed:
             dev = jax.device_put(batch)
             self.put_time += _time.perf_counter() - t0
             return ("spill", dev, lab, rows)
-        _tag, blocks, labels, rows = item
-        dev = jax.device_put(blocks, self._shardings)
+        _tag, views, labels, rows = item
+        dev = place_mesh_group(views, self._shardings)
         self.put_time += _time.perf_counter() - t0
         return ("group", dev, labels, rows)
 

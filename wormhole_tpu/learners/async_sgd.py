@@ -751,7 +751,7 @@ class AsyncSGD:
         pfx = "" if kind == TRAIN else "eval_"
         feed = self._feed(file, part, nparts, fmt,
                           tile_info=info if online else None)
-        put_before = feed.put_time
+        put_before, copied_before = feed.put_time, feed.host_copy_bytes
         # snapshot BEFORE iterating: the feed flips _cache_full as its
         # stream exhausts, which is mid-way through THIS part
         replay = getattr(feed, "_cache_full", False)
@@ -820,6 +820,9 @@ class AsyncSGD:
             else:
                 drain_pending()
         self.timer.add(pfx + "put", feed.put_time - put_before)
+        # a count, not seconds: bytes the feed copied on the host
+        self.timer.add(pfx + "host_copy_bytes",
+                       feed.host_copy_bytes - copied_before)
         self._merge_pipe_snap(feed.drain_pipe_stats(None), pfx, local)
         return local
 
@@ -839,18 +842,25 @@ class AsyncSGD:
         Two feed modes (cfg.mesh_feed):
 
         - ``ring`` — the sharded DeviceFeed path
-          (data/crec.MeshGroupFeed): prep workers pad+stack each D-group
-          off the dispatch thread, the transfer ring ``device_put``s it
-          onto its (data, model) NamedSharding so H2D overlaps the mesh
-          step, and encode-overflow spill batches ride the same ring in
-          stream position;
-        - ``sync`` — the pre-scale-out loop (stack on the dispatch
-          thread, jit-time transfer, synchronous spill scatter), kept as
-          the measured baseline for ``bench.py --phases multichip``.
+          (data/crec.MeshGroupFeed): the transfer thread hands each chip
+          its slice of a group's blocks as the reader returned them, on
+          the (data, model) NamedSharding the step takes, with no
+          stacked copy of the group (a short tail takes the shared PAD
+          block as its missing members), so H2D overlaps the mesh step;
+          encode-overflow spill batches ride the same ring in stream
+          position;
+        - ``sync`` — the pre-scale-out loop (``np.stack`` of the group
+          on the dispatch thread, jit-time transfer, synchronous spill
+          scatter), kept as the measured baseline for ``bench.py
+          --phases multichip`` and as the oracle the ring is held
+          against, bit for bit.
 
         Either way spill/eval metrics are folded from batched device
-        fetches, and eval pooling reuses the stacked label lanes instead
-        of re-concatenating per-block labels per group."""
+        fetches, and an eval pass pools one label lane a group (the
+        ring concatenates the blocks' lanes, 98 KB a block; sync views
+        the stacked ones). The part's Timer takes three counts beside
+        its seconds: ``mesh_steps``, ``ici_bytes``,
+        ``host_copy_bytes``."""
         from wormhole_tpu.data.crec import (MeshGroupFeed, mesh_pads,
                                             stack_mesh_group)
         from wormhole_tpu.learners.store import mesh_group_shardings
@@ -984,6 +994,7 @@ class AsyncSGD:
 
         tx = self.store.mesh_transport()
         steps_before, ici_before = tx.dispatches, tx.bytes_ici
+        stacked = [0]        # bytes the sync mode copied into stacked groups
         inner = self._make_feed(file, part, nparts, fmt,
                                 device_put=lambda x: x,
                                 tile_info=info if online else None)
@@ -1007,6 +1018,8 @@ class AsyncSGD:
                 with obs.trace.span("mesh:stack", cat="mesh"):
                     blocks, labels_u8 = stack_mesh_group(
                         group, D, info, pads, is_tile, want_labels)
+                stacked[0] += sum(a.nbytes
+                                  for a in jax.tree_util.tree_leaves(blocks))
                 run_group(blocks, labels_u8)
 
             for dev, host, _rows in feed:
@@ -1024,11 +1037,15 @@ class AsyncSGD:
             drain_pending()
         self.timer.add(pfx + "put", feed.put_time)
         self._merge_pipe_snap(feed.drain_pipe_stats(None), pfx, local)
-        # counts, not seconds: the mesh dispatches of this part and the
+        # counts, not seconds: the mesh dispatches of this part, the
         # ICI bytes one chip moved for them as the store's model books
-        # them (store.mesh_step_ici_bytes)
+        # them (store.mesh_step_ici_bytes), and the bytes the feed
+        # copied on the host (0 from a mapped local file on the ring;
+        # a fall-back to readinto, or sync's stack, shows as a number)
         self.timer.add(pfx + "mesh_steps", tx.dispatches - steps_before)
         self.timer.add(pfx + "ici_bytes", tx.bytes_ici - ici_before)
+        self.timer.add(pfx + "host_copy_bytes",
+                       feed.host_copy_bytes + stacked[0])
         if use_ring:
             self._export_mesh_feed_stats(feed)
         return local

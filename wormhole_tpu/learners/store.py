@@ -265,12 +265,15 @@ def mesh_step_ici_bytes(rt: "MeshRuntime", *, margin_elems: int,
 
 
 def mesh_group_shardings(rt: MeshRuntime, is_tile: bool):
-    """NamedSharding pytree for ONE stacked D-group, matching the mesh
-    steps' in_specs exactly — the layout the sharded feed
-    (data/crec.MeshGroupFeed) ``device_put``s onto, so a pre-placed
+    """NamedSharding pytree for ONE D-group, matching the mesh steps'
+    in_specs exactly — the layout the sharded feed
+    (data/crec.MeshGroupFeed) assembles a group on, so a pre-placed
     group enters shard_map with zero re-layout copies. Tile groups are
-    the {pw, labels, ovf_b, ovf_r} dict; v1 groups the stacked
-    (D, block_bytes) u8 array."""
+    the {pw, labels, ovf_b, ovf_r} dict; v1 groups the (D, block_bytes)
+    u8 array. Chip ``(d, m)`` holds ``pw[d, m*T/M:(m+1)*T/M]`` and row
+    ``d`` of every lane, each a contiguous slice of ONE block, which is
+    what lets the feed send the block's own bytes with no stacked copy
+    (``crec.place_mesh_group``)."""
     from wormhole_tpu.parallel.mesh import DATA_AXIS
     lane = rt.sharding(DATA_AXIS, None)
     if not is_tile:

@@ -473,8 +473,8 @@ def mesh_feed_gauges(reg: Optional[Registry] = None):
                         help="data-axis block groups dispatched through "
                              "the sharded mesh feed"),
             reg.counter("mesh/pad_blocks",
-                        help="all-PAD filler blocks stacked into short "
-                             "tail groups"),
+                        help="all-PAD filler blocks standing in for the "
+                             "missing members of short tail groups"),
             reg.counter("mesh/spill_blocks",
                         help="encode-overflow spill batches that rode "
                              "the mesh feed ring to the scatter step"))

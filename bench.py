@@ -1939,13 +1939,12 @@ def bench_rejoin() -> dict:
 
 
 MULTICHIP_ROWS = 163_840     # 10 blocks x 16384 rows (subblocks=2)
-MULTICHIP_WINDOW = 6.0       # timed window per (shape, mode) run
+MULTICHIP_WINDOW = 6.0       # timed window per shape
 
 
 def _mc_app(path: str, shape: str, n_dev: int):
-    """One app per mesh shape: both feed modes run on the SAME app so
-    the jitted mesh step (each store instance owns its jit closures)
-    compiles once per shape, not once per (shape, mode)."""
+    """One app per mesh shape (each store instance owns its jit
+    closures)."""
     import jax
     from wormhole_tpu.learners.async_sgd import AsyncSGD
     from wormhole_tpu.parallel.mesh import MeshRuntime, make_mesh
@@ -1959,15 +1958,14 @@ def _mc_app(path: str, shape: str, n_dev: int):
     return AsyncSGD(cfg, rt)
 
 
-def _mc_timed(app, path: str, mode: str, mesh: bool) -> dict:
-    """One timed feed-mode segment on a warmed app: stream passes until
+def _mc_timed(app, path: str, mesh: bool) -> dict:
+    """One timed segment on a warmed app: stream passes until
     the window closes. The rate is rows/elapsed with the deferred-metric
     flush and a forced D2H read inside the clock (same honesty rules as
     the e2e phases); the mesh feed telemetry is read back as registry
     deltas because the registry is process-global across segments."""
     import jax
     from wormhole_tpu.obs.metrics import mesh_feed_gauges
-    app.cfg.mesh_feed = mode
     gauges = mesh_feed_gauges(app.obs.registry)
     gauges[0].value = 0.0                # skew mean: set per process()
     gauges[1].value = 0.0                # skew max (agg=max): reset
@@ -2010,12 +2008,10 @@ def _mc_warm(app, path: str) -> None:
 
 def _bench_multichip_inline() -> dict:
     """Mesh scale-out sweep over the local devices: for each mesh shape
-    (pure data-parallel, then data x model splits) run BOTH feed modes —
-    ``ring`` (sharded DeviceFeed: prep workers stack the D-group off the
-    dispatch thread, the transfer ring device_puts it onto its
-    (data, model) NamedSharding so H2D overlaps the mesh step) and
-    ``sync`` (the pre-scale-out stack-in-loop baseline) — over the SAME
-    crec2 rows. Reports per-shape ex/s for both modes, ring/sync,
+    (pure data-parallel, then data x model splits) run the mesh pass
+    (sharded DeviceFeed: the transfer ring hands each chip its slice of
+    a D-group on its (data, model) NamedSharding so H2D overlaps the
+    mesh step) over the SAME crec2 rows. Reports per-shape ex/s,
     speedup and scaling efficiency vs a single-chip anchor (the
     single-device process() path on devices[0]), per-group dispatch-skew
     straggler telemetry, and comm/bytes_wire (0 in single-process runs
@@ -2038,7 +2034,7 @@ def _bench_multichip_inline() -> dict:
     try:
         app0 = _mc_app(path, "data:1", 1)
         _mc_warm(app0, path)
-        anchor = _mc_timed(app0, path, "ring", mesh=False)
+        anchor = _mc_timed(app0, path, mesh=False)
         del app0
         rate0 = anchor["ex_per_sec"]
         out["anchor_ex_per_sec"] = round(rate0, 1)
@@ -2055,22 +2051,14 @@ def _bench_multichip_inline() -> dict:
             if _deadline_passed():
                 out["budget_truncated"] = True
                 break
-            # Both feed modes run on ONE app (same jit closures): the
-            # shape compiles once, the modes differ only host-side.
             app = _mc_app(path, shape, nd)
             _mc_warm(app, path)
-            ring = _mc_timed(app, path, "ring", mesh=True)
-            sync = _mc_timed(app, path, "sync", mesh=True)
+            ring = _mc_timed(app, path, mesh=True)
             del app
             print(f"[bench] multichip {shape} ring "
-                  f"{ring['ex_per_sec']:,.0f} sync "
-                  f"{sync['ex_per_sec']:,.0f} ex/s",
+                  f"{ring['ex_per_sec']:,.0f} ex/s",
                   file=sys.stderr, flush=True)
             rec = {"ring_ex_per_sec": round(ring["ex_per_sec"], 1),
-                   "sync_ex_per_sec": round(sync["ex_per_sec"], 1),
-                   "ring_vs_sync": round(
-                       ring["ex_per_sec"] / max(sync["ex_per_sec"],
-                                                1e-9), 3),
                    "speedup_vs_anchor": round(
                        ring["ex_per_sec"] / max(rate0, 1e-9), 3),
                    "scaling_efficiency": round(
@@ -2104,7 +2092,7 @@ def _needs_devices(n: int):
 
 def bench_multichip() -> dict:
     """Sharded multichip scale-out (tentpole of the mesh-feed PR): the
-    shape x feed-mode sweep over this process's devices, so the mesh
+    shape sweep over this process's devices, so the mesh
     feed, NamedSharding device_put and shard_map step span real
     devices. With one device it reports that it needs more."""
     return _needs_devices(2) or _bench_multichip_inline()

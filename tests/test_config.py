@@ -38,6 +38,9 @@ def test_colon_style_and_bool():
     assert c.loss is Loss.SQUARE_HINGE
 
 
-def test_unknown_key_raises():
+@pytest.mark.parametrize("token", ["no_such_key=1", "mesh_feed=sync"])
+def test_unknown_key_raises(token):
+    # mesh_feed went with its fork (PR 32): it is rejected as any
+    # unknown key is, not ignored
     with pytest.raises(ValueError):
-        load_config(None, ["no_such_key=1"])
+        load_config(None, [token])

@@ -214,7 +214,7 @@ def test_crec2_metric_accounting_exact(tmp_path, rng):
     D = max(app.rt.data_axis_size, 1)
     assert count == passes * -(-3 // D)
     assert np.isfinite(objv_sum) and objv_sum > 0
-    assert not app._crec_tickets and app._crec_count == 0
+    assert not app._crec_acc.tickets and app._crec_acc.count == 0
 
 
 def test_crec2_adagrad_l1_learns(tmp_path, rng):

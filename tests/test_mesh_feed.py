@@ -1,4 +1,4 @@
-"""Sharded multichip feed (data/crec.MeshGroupFeed + cfg.mesh_feed).
+"""Sharded multichip feed (data/crec.MeshGroupFeed).
 
 The mesh feed forms data-axis groups off the dispatch thread and hands
 every chip its slice of a group from the transfer ring, on the (data,
@@ -7,10 +7,10 @@ The contracts pinned here:
 
   * worker/mode determinism — the pipelined ring (workers=N) is
     bit-identical to the serial inline feed (workers=0), and the ring
-    path trains the same table as the legacy synchronous
-    stack-in-the-loop dispatch (``mesh_feed=sync``): same groups, same
-    padding, same step order — only WHERE the bytes are gathered
-    moves;
+    path trains the same table as a synchronous stack-in-the-loop
+    dispatch written in the test (``stack_mesh_group`` + the store's
+    mesh step): same groups, same padding, same step order — only
+    WHERE the bytes are gathered moves;
   * short-tail PAD parity — an eval pass whose tail group is mostly
     PAD filler blocks pools exactly the same (margin, label) rows as
     the single-device path over the same file: PAD lanes (label 255)
@@ -78,23 +78,37 @@ def make_app(path, mesh_spec, fmt="crec2", **over):
 def test_ring_workers_and_sync_mode_bit_identical(tmp_path, rng):
     """data:8 over 11 blocks (one full group + a 3-block padded tail):
     the pipelined ring, the serial ring (workers=0, the inline oracle)
-    and the synchronous legacy dispatch all produce the SAME slots,
-    bit for bit, and credit every row."""
+    and the synchronous stack-in-the-loop dispatch (written out here:
+    ``stack_mesh_group`` on the dispatch thread, jit-time transfer) all
+    produce the SAME slots, bit for bit, and credit every row."""
+    from wormhole_tpu.data.crec import (iter_packed2, mesh_pads,
+                                        read_header2, stack_mesh_group)
     n = 10 * BR + 4000
     keys, labels = make_rows(rng, n)
     path = tmp_path / "det.crec2"
     write_file(path, keys, labels)
 
-    def train(mode, workers):
-        app = make_app(path, "data:8", mesh_feed=mode,
-                       pipeline_workers=workers)
+    def train(workers):
+        app = make_app(path, "data:8", pipeline_workers=workers)
         prog = app.run()
-        assert prog.num_ex == n, (mode, workers)
+        assert prog.num_ex == n, workers
         return np.asarray(app.store.slots)
 
-    ring2 = train("ring", 2)
-    ring0 = train("ring", 0)
-    sync = train("sync", 2)
+    def train_sync():
+        app = make_app(path, "data:8")
+        info = read_header2(str(path))
+        pads = mesh_pads(info, True)
+        blocks = [v for v, _rows in iter_packed2(str(path))]
+        for k in range(0, len(blocks), 8):
+            stacked, _lab = stack_mesh_group(blocks[k:k + 8], 8, info,
+                                             pads, True)
+            app.store.tile_train_step_mesh(stacked, info)
+        assert int(app.store.fetch_metrics()[1]) == n
+        return np.asarray(app.store.slots)
+
+    ring2 = train(2)
+    ring0 = train(0)
+    sync = train_sync()
     assert np.array_equal(ring2, ring0)
     assert np.array_equal(ring2, sync)
 
@@ -160,7 +174,7 @@ def test_online_spill_blocks_ride_the_ring(tmp_path, rng):
         gauges = mesh_feed_gauges(reg)
         spills0, fb0 = gauges[4].value, fallback.value
         app = make_app(path, "data:2", fmt="crec", tile_online="on",
-                       mesh_feed="ring", pipeline_workers=workers)
+                       pipeline_workers=workers)
         prog = app.run()
         assert prog.num_ex == n, workers
         assert gauges[4].value == spills0 + 1.0    # mesh/spill_blocks
@@ -248,22 +262,27 @@ def test_placed_group_equals_stacked_group(tmp_path, rng, fmt, member,
 
 def test_mesh_pass_books_host_copy_bytes(tmp_path, rng):
     """The mesh pass adds the bytes its feed copied on the host to the
-    Timer, a count beside mesh_steps: nothing on the ring over a local
-    crec2 file (blocks are views of a mapping and no group is stacked),
-    every stacked byte under mesh_feed=sync."""
+    Timer, a count beside mesh_steps: nothing over a local crec2 file
+    (blocks are views of a mapping and no group is stacked), every
+    block's bytes over a stream the reader cannot map (crec v1, which
+    PackedFeed reads into memory)."""
+    from wormhole_tpu.data.crec import HEADER_SIZE
     n = 4 * BR
     keys, labels = make_rows(rng, n)
-    path = tmp_path / "c.crec2"
-    write_file(path, keys, labels)
 
-    def copied(mode):
-        app = make_app(path, "data:2", mesh_feed=mode)
+    def copied(path, fmt):
+        app = make_app(path, "data:2", fmt=fmt)
         assert app.run().num_ex == n
         assert app.timer.totals["mesh_steps"] == 2
         return app.timer.totals["host_copy_bytes"]
 
-    assert copied("ring") == 0
-    assert copied("sync") == os.path.getsize(path) - 48   # less the header
+    mapped = tmp_path / "c.crec2"
+    write_file(mapped, keys, labels)
+    assert copied(mapped, "crec2") == 0
+    read = tmp_path / "c.crec"
+    with CRecWriter(str(read), nnz=NNZ, block_rows=BR) as w:
+        w.append(keys, labels)
+    assert copied(read, "crec") == os.path.getsize(read) - HEADER_SIZE
 
 
 @pytest.mark.parametrize("blocks", [2, 3])

@@ -47,6 +47,13 @@ HOT_PATHS = {
         "AsyncSGD.process",
         "AsyncSGD._process_crec",
     ),
+    # the crec passes' deferred metric fetches, batched or windowed,
+    # which the pass loops above call from inside their wait scopes
+    "wormhole_tpu/learners/window.py": (
+        "MetricWindow.drain",
+        "MetricWindow._fold_list",
+        "MetricWindow._harvest_macc",
+    ),
     "wormhole_tpu/serve/frontend.py": (
         "ServeFrontend._flush",
     ),

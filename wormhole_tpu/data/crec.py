@@ -1104,8 +1104,9 @@ def stack_mesh_group(views: list, D: int, info, pads, is_tile: bool,
     """Stack one data-axis group of host blocks into the mesh step's
     stacked operands, padding a short group to ``D`` with ``pads``
     (:func:`mesh_pads`): a copy of every byte of the group into fresh
-    memory, which ``mesh_feed=sync`` alone still makes, on the dispatch
-    thread (the tests hold :func:`place_mesh_group` against it).
+    memory, which the multihost pass makes of a host's members before
+    the hosts' groups become one global array (the tests hold
+    :func:`place_mesh_group` against it).
     Returns ``(blocks, labels_u8)`` where ``labels_u8`` — only
     materialized when ``want_labels`` (eval pooling) — is a flat view
     of the ALREADY-stacked label lanes, not a per-block concatenate:

@@ -39,8 +39,9 @@ from wormhole_tpu.data.localizer import Localizer
 from wormhole_tpu.data.minibatch import MinibatchIter
 from wormhole_tpu.learners.handles import LearnRate, create_handle
 from wormhole_tpu.learners.store import ShardedStore, StoreConfig
+from wormhole_tpu.learners.window import (MetricAccumulator, MetricWindow,
+                                          fold_row, pool_margins)
 from wormhole_tpu.ops.penalty import L1L2
-from wormhole_tpu.ops.tilemm import PADWORD
 from wormhole_tpu.parallel.mesh import DATA_AXIS, MeshRuntime
 from wormhole_tpu.sched.workload_pool import (TEST, TRAIN, VAL,
                                               ReplicatedRounds,
@@ -127,13 +128,9 @@ class AsyncSGD:
         # ring stalls, batches delivered, deepest ring occupancy observed
         self.feed_stats = {"feed_stall": 0.0, "feed_batches": 0,
                            "ring_max": 0}
-        # deferred crec2 metric window: per-step metrics accumulate ON
-        # DEVICE (store.fetch_metrics); the host only counts dispatched
-        # steps and fetches one buffer at disp_itv / flush — fetching
-        # per part (let alone per step) costs a device round trip each
-        self._crec_count = 0
-        self._crec_tickets: list = []   # in-flight async accumulator reads
-        self._crec_hist = [np.zeros(512), np.zeros(512)]
+        # the one-device tile TRAIN passes' deferred metric accumulator
+        # (learners/window.py): it survives parts; flush_metrics drains it
+        self._crec_acc = MetricAccumulator()
         from wormhole_tpu.parallel.checkpoint import Checkpointer
         self.ckpt = Checkpointer(cfg.checkpoint_dir)
         self._warned_ckpt = False
@@ -537,53 +534,22 @@ class AsyncSGD:
     # device (each fetch is one async ticket, resolved a window later)
     CREC_DRAIN_CHUNK = 64   # max steps dispatched ahead of a metric fetch
 
-    def _harvest_macc(self, local: Progress, hist: list, n_new: int,
-                      final: bool) -> None:
-        """Harvest the on-device metric accumulator into ``local`` — one
-        device read per window, and that read is ASYNC: ``n_new`` pending
-        steps start a fetch immediately (the device never stalls), while
-        the previous window's ticket — which has had a full window of
-        wall-clock to fly home — is resolved. ``final`` resolves
-        everything, blocking (flush/part boundaries). AUC comes from the
-        RUNNING margin histograms in ``hist``, stored as auc*count so
-        Progress merges reproduce the pass-level number. The packed row
-        layout is ShardedStore's: [objv, num_ex, acc, wdelta2, pos, neg]."""
-        from wormhole_tpu.ops.metrics import auc_from_hist
-        if n_new:
-            self._crec_tickets.append(
-                (self.store.fetch_metrics_async(), n_new))
-        resolved = False
-        while self._crec_tickets and (final or len(self._crec_tickets) > 1):
-            ticket, n = self._crec_tickets.pop(0)
-            # the fetched accumulator is the psum'd metric buffer — this
-            # resolve IS the collective boundary on the device step path
-            with obs.trace.span("collective:metrics_window",
-                                cat="collective",
-                                args={"site": "async_sgd/metrics_window"}):
-                row = np.asarray(ticket)
-            local.objv += float(row[0])
-            local.num_ex += int(row[1])
-            local.count += n
-            local.acc += float(row[2])
-            local.wdelta2 += float(row[3])
-            bins = (len(row) - 4) // 2
-            hist[0] += row[4:4 + bins]
-            hist[1] += row[4 + bins:]
-            resolved = True
-        if resolved:
-            local.auc = auc_from_hist(*hist) * local.count
-            self._display(local)
+    @property
+    def _crec_hist(self) -> list:
+        """The pass-level AUC histograms of the app's accumulator; a
+        caller that ends a pass itself assigns fresh ones, as ``run``
+        does."""
+        return self._crec_acc.hist
 
-    def _drain_crec2_train(self, local: Progress,
-                           final: bool = True) -> None:
-        self._harvest_macc(local, self._crec_hist, self._crec_count, final)
-        self._crec_count = 0
+    @_crec_hist.setter
+    def _crec_hist(self, hist: list) -> None:
+        self._crec_acc.hist = hist
 
     def flush_metrics(self) -> Progress:
         """Drain any deferred crec2 metrics; returns the tail Progress
         (callers merge it into their running totals)."""
         tail = Progress()
-        self._drain_crec2_train(tail)
+        MetricWindow(self, tail, TRAIN, None, acc=self._crec_acc).drain()
         return tail
 
     def _process_crec(self, file: str, part: int, nparts: int,
@@ -598,7 +564,6 @@ class AsyncSGD:
         (ops/tilemm) whose AUC display stat comes from merged margin
         histograms rather than per-block sorts."""
         from wormhole_tpu.data.crec import (read_header, read_header2)
-        from wormhole_tpu.ops.metrics import auc_from_hist
         cfg = self.cfg
         fmt = cfg.data_format
         online = fmt != "crec2" and self._tile_online(fmt, file)
@@ -636,110 +601,11 @@ class AsyncSGD:
                                 block_rows=cfg.text_block_rows,
                                 total_rows=0)
             lab_off = info.block_rows * info.nnz * 4
-        max_delay = cfg.max_delay if kind == TRAIN else 1 << 30
-        tau_cap = float(max(cfg.max_delay - 1, 0))
-        inflight: deque = deque()
-        # tile-train metrics accumulate ON DEVICE (store.fetch_metrics;
-        # the app-level deferred window survives across parts); eval/v1
-        # metrics ride per-step vectors in the part-local pending list
-        acc_metrics = tile and kind == TRAIN
-        pending: list = []
-        # overflow-fallback scatter steps (online blocks whose COO spill
-        # exceeded ovf_cap): their metrics ride the sparse-path layout
-        spill: list = []
-        local = Progress()
-
-        def drain_spill() -> None:
-            """Resolve overflow-fallback steps: sparse-path metric tuple
-            layout — [objv, num_ex, auc, acc, wdelta2|margin]."""
-            if not spill:
-                return
-            # host-sync: one batched fetch drains the whole spill window
-            fetched = jax.device_get([s[0] for s in spill])
-            for (_m, labels_u8), metrics in zip(spill, fetched):
-                local.objv += float(metrics[0])
-                local.num_ex += int(metrics[1])
-                local.count += 1
-                local.auc += float(metrics[2])
-                local.acc += float(metrics[3])
-                if kind == TRAIN:
-                    local.wdelta2 += float(metrics[4])
-                elif pooled is not None and labels_u8 is not None:
-                    # host-sync: metrics fetched above — already host
-                    margin = np.asarray(metrics[4])
-                    real = labels_u8 != 255
-                    pooled.append((margin[real],
-                                   np.minimum(labels_u8[real], 1)
-                                   .astype(np.float32),
-                                   np.ones(int(real.sum()), np.float32)))
-            spill.clear()
-
-        def drain_pending(final: bool = True) -> None:
-            """Harvest metrics with minimal host<->device round trips —
-            per-leaf fetches cost one blocking round trip each, and each
-            one drains the dispatch pipeline. tile-train drains the on-device
-            accumulator (async ticket when ``final`` is False, so the
-            device never stalls mid-stream); eval/v1 paths batch-fetch
-            their per-step metric vectors."""
-            drain_spill()
-            if acc_metrics:
-                self._drain_crec2_train(local, final)
-                return
-            if not pending:
-                return
-            # host-sync: one batched fetch drains the display window
-            fetched = jax.device_get([p[0] for p in pending])
-            for (mdev, labels_u8), metrics in zip(pending, fetched):
-                local.objv += float(metrics[0])
-                local.num_ex += int(metrics[1])
-                local.count += 1
-                if tile:
-                    local.acc += float(metrics[2])
-                    local.auc += auc_from_hist(metrics[3], metrics[4])
-                    margin_ix = 5  # eval: margins ride in slot 5
-                else:
-                    local.auc += float(metrics[2])
-                    local.acc += float(metrics[3])
-                    margin_ix = 4
-                if kind == TRAIN and len(metrics) > margin_ix:
-                    local.wdelta2 += float(metrics[margin_ix])
-                if pooled is not None and labels_u8 is not None:
-                    # host-sync: metrics fetched above — already host
-                    margin = np.asarray(metrics[margin_ix])
-                    real = labels_u8 != 255
-                    pooled.append((margin[real],
-                                   np.minimum(labels_u8[real], 1)
-                                   .astype(np.float32),
-                                   np.ones(int(real.sum()), np.float32)))
-            pending.clear()
-            if kind == TRAIN:
-                self._display(local)
-
-        def harvest(item) -> None:
-            m, labels, is_spill = item
-            # host-sync: completion gate on a step dispatched last window
-            jax.block_until_ready(m[0] if isinstance(m, tuple) else m)
-            if is_spill:
-                spill.append((m, labels))
-            elif not acc_metrics:
-                pending.append((m, labels))
-            if kind == TRAIN and self.reporter.due():
-                # mid-stream display drain: non-final for the accumulator
-                # path — a blocking fetch of the just-started window costs
-                # ~100 ms of device idle (part-end/flush drains are final)
-                drain_pending(final=not acc_metrics)
-
-        def _labels_of(host) -> np.ndarray:
-            if isinstance(host, dict):
-                return host["labels"].copy()
-            if host.nbytes == info.block_rows:
-                return host            # cached item: already labels-only
-            return host[lab_off:lab_off + info.block_rows].copy()
-
         has_mesh_step = hasattr(
             self.store, "tile_train_step_mesh" if tile
             else "dense_train_step_mesh") \
             and getattr(self.store, "rt", None) is not None
+        local = Progress()
         # text formats ride the dense mesh step; the linear, FM and
         # wide&deep stores all provide mesh steps — a custom store
         # without one (or built without a runtime) falls through to the
@@ -748,6 +614,46 @@ class AsyncSGD:
             return self._process_crec_mesh(file, part, nparts, kind,
                                            pooled, info, local, fmt,
                                            online)
+        max_delay = cfg.max_delay if kind == TRAIN else 1 << 30
+        tau_cap = float(max(cfg.max_delay - 1, 0))
+        inflight: deque = deque()
+        # tile-train metrics accumulate ON DEVICE (store.fetch_metrics;
+        # the app's accumulator survives across parts); eval/v1 metrics
+        # ride per-step vectors in the part's window, and so do
+        # overflow-fallback scatter steps (online blocks whose COO spill
+        # exceeded ovf_cap)
+        acc_metrics = tile and kind == TRAIN
+        win = MetricWindow(self, local, kind, pooled,
+                           acc=self._crec_acc if acc_metrics else None)
+        step, layout = self._crec_step(kind, "tile" if tile else "dense",
+                                       info)
+        spill_step, _ = self._crec_step(kind, "spill", info)
+
+        def record(item) -> None:
+            m, labels, spilled = item
+            if spilled:
+                win.add_spill(m, labels)
+            elif not acc_metrics:
+                win.add_step(m, labels, layout)
+
+        def harvest(item) -> None:
+            m = item[0]
+            # host-sync: completion gate on a step dispatched last window
+            jax.block_until_ready(m[0] if isinstance(m, tuple) else m)
+            record(item)
+            if kind == TRAIN and self.reporter.due():
+                # mid-stream display drain: non-final for the accumulator
+                # path — a blocking fetch of the just-started window costs
+                # ~100 ms of device idle (part-end/flush drains are final)
+                win.drain(final=not acc_metrics)
+
+        def _labels_of(host) -> np.ndarray:
+            if isinstance(host, dict):
+                return host["labels"].copy()
+            if host.nbytes == info.block_rows:
+                return host            # cached item: already labels-only
+            return host[lab_off:lab_off + info.block_rows].copy()
+
         pfx = "" if kind == TRAIN else "eval_"
         feed = self._feed(file, part, nparts, fmt,
                           tile_info=info if online else None)
@@ -768,57 +674,34 @@ class AsyncSGD:
                 while len(inflight) > max(max_delay - 1, 0):
                     harvest(inflight.popleft())
             with self.timer.scope(pfx + "dispatch"):
-                if tile and isinstance(dev, dict):
-                    if kind == TRAIN:
-                        m = self.store.tile_train_step(
-                            dev, info,
-                            tau=min(float(len(inflight)), tau_cap))
-                        self._crec_count += 1
-                        inflight.append((m, None, False))
-                    else:
-                        m = self.store.tile_eval_step(dev, info)
-                        inflight.append((m, _labels_of(host), False))
-                elif tile:
-                    # online overflow fallback: the block arrived as a
-                    # SparseBatch — audited scatter step, counted
-                    obs.metrics.encode_counters(
-                        self.obs.registry)[1].inc(1)
-                    if kind == TRAIN:
-                        m = self.store.train_step(
-                            dev, tau=min(float(len(inflight)), tau_cap))
-                        inflight.append((m, None, True))
-                    else:
-                        m = self.store.eval_step(dev)
-                        inflight.append((m, _labels_of(host), True))
-                elif kind == TRAIN:
-                    m = self.store.dense_train_step(
-                        dev, info.block_rows, info.nnz,
-                        tau=min(float(len(inflight)), tau_cap))
-                    inflight.append((m, None, False))
-                else:
-                    m = self.store.dense_eval_step(dev, info.block_rows,
-                                                   info.nnz)
-                    inflight.append((m, _labels_of(host), False))
+                # online overflow fallback: the block arrived as a
+                # SparseBatch — audited scatter step, counted
+                spilled = tile and not isinstance(dev, dict)
+                if spilled:
+                    obs.metrics.encode_counters(self.obs.registry)[1].inc(1)
+                m = (spill_step if spilled else step)(
+                    dev, min(float(len(inflight)), tau_cap))
+                if acc_metrics and not spilled:
+                    win.count_step()
+                inflight.append(
+                    (m, None if kind == TRAIN else _labels_of(host),
+                     spilled))
         with self.timer.scope(pfx + "wait"):
-            # no per-item block_until_ready here: drain_pending's
-            # device fetch synchronizes
+            # no per-item block_until_ready here: the window's device
+            # fetch synchronizes
             while inflight:
-                m, labels, is_spill = inflight.popleft()
-                if is_spill:
-                    spill.append((m, labels))
-                elif not acc_metrics:
-                    pending.append((m, labels))
+                record(inflight.popleft())
             if acc_metrics and replay:
-                drain_spill()
-                # HBM-resident replay: leave the window deferred — the
-                # end-of-part fetch is a round trip per part; the
+                # HBM-resident replay: leave the accumulator deferred —
+                # the end-of-part fetch is a round trip per part; the
                 # caller's flush_metrics()/disp_itv drains it — but bound
-                # the window (pipelined, non-final) so dispatch can't run
+                # it (pipelined, non-final) so dispatch can't run
                 # unboundedly ahead of the device
-                if self._crec_count >= self.CREC_DRAIN_CHUNK:
-                    self._drain_crec2_train(local, final=False)
+                win.fold()
+                if self._crec_acc.count >= self.CREC_DRAIN_CHUNK:
+                    win.drain(final=False)
             else:
-                drain_pending()
+                win.drain()
         self.timer.add(pfx + "put", feed.put_time - put_before)
         # a count, not seconds: bytes the feed copied on the host
         self.timer.add(pfx + "host_copy_bytes",
@@ -826,46 +709,57 @@ class AsyncSGD:
         self._merge_pipe_snap(feed.drain_pipe_stats(None), pfx, local)
         return local
 
+    def _crec_step(self, kind: str, form: str, info):
+        """The step table of the crec passes: the store call for one
+        block (or, ``*_mesh``, one data-axis group) as ``step(operand,
+        tau)``, with the name of the metric-row layout it returns
+        (learners/window.fold_row). ``tile`` takes crec2-typed blocks,
+        ``dense`` packed crec v1 blocks, ``spill`` the SparseBatch of an
+        online block whose overflow passed the cap (the audited scatter
+        step, on a mesh too). Mesh steps and eval steps take no tau."""
+        s, r, n = self.store, info.block_rows, info.nnz
+        train, evl, layout = {
+            "tile": (lambda x, tau: s.tile_train_step(x, info, tau=tau),
+                     lambda x, tau: s.tile_eval_step(x, info), "tile"),
+            "dense": (lambda x, tau: s.dense_train_step(x, r, n, tau=tau),
+                      lambda x, tau: s.dense_eval_step(x, r, n), "sparse"),
+            "tile_mesh": (lambda x, tau: s.tile_train_step_mesh(x, info),
+                          lambda x, tau: s.tile_eval_step_mesh(x, info),
+                          "tile"),
+            "dense_mesh": (lambda x, tau: s.dense_train_step_mesh(x, r, n),
+                           lambda x, tau: s.dense_eval_step_mesh(x, r, n),
+                           "tile"),
+            "spill": (lambda b, tau: s.train_step(b, tau=tau),
+                      lambda b, tau: s.eval_step(b), "sparse"),
+        }[form]
+        return (train if kind == TRAIN else evl), layout
+
     def _process_crec_mesh(self, file: str, part: int, nparts: int,
                            kind: str, pooled: Optional[list],
                            info, local: Progress,
                            fmt: str = "crec2",
                            online: bool = False) -> Progress:
         """crec/crec2 over a multi-device mesh: feed blocks in groups of
-        ``data_axis_size`` (stacked on a leading axis; short tails pad
-        with all-PAD blocks) through the shard_map step — crec2 runs the
-        tile step (model axis shards bucket tiles), crec v1 the mesh
-        dense-apply step (model axis range-shards the folded table); data
-        axis shards blocks either way. ``online`` routes a v1/text stream
-        through the online tile encoder (same typed blocks as crec2).
+        ``data_axis_size`` (short tails pad with all-PAD blocks) through
+        the shard_map step — crec2 runs the tile step (model axis shards
+        bucket tiles), crec v1 the mesh dense-apply step (model axis
+        range-shards the folded table); data axis shards blocks either
+        way. ``online`` routes a v1/text stream through the online tile
+        encoder (same typed blocks as crec2).
 
-        Two feed modes (cfg.mesh_feed):
+        The feed is data/crec.MeshGroupFeed: groups come placed on the
+        (data, model) NamedSharding the step takes, and encode-overflow
+        spill batches ride the same ring in stream position, through
+        the audited scatter step (the replicated-table sparse path: the
+        on-device accumulator never sees such a block).
 
-        - ``ring`` — the sharded DeviceFeed path
-          (data/crec.MeshGroupFeed): the transfer thread hands each chip
-          its slice of a group's blocks as the reader returned them, on
-          the (data, model) NamedSharding the step takes, with no
-          stacked copy of the group (a short tail takes the shared PAD
-          block as its missing members), so H2D overlaps the mesh step;
-          encode-overflow spill batches ride the same ring in stream
-          position;
-        - ``sync`` — the pre-scale-out loop (``np.stack`` of the group
-          on the dispatch thread, jit-time transfer, synchronous spill
-          scatter), kept as the measured baseline for ``bench.py
-          --phases multichip`` and as the oracle the ring is held
-          against, bit for bit.
-
-        Either way spill/eval metrics are folded from batched device
-        fetches, and an eval pass pools one label lane a group (the
-        ring concatenates the blocks' lanes, 98 KB a block; sync views
-        the stacked ones). The part's Timer takes three counts beside
-        its seconds: ``mesh_steps``, ``ici_bytes``,
-        ``host_copy_bytes``."""
-        from wormhole_tpu.data.crec import (MeshGroupFeed, mesh_pads,
-                                            stack_mesh_group)
+        Spill/eval metrics are folded from batched device fetches
+        (learners/window.py), and an eval pass pools one label lane a
+        group (the blocks' lanes concatenated, 98 KB a block). The
+        part's Timer takes three counts beside its seconds:
+        ``mesh_steps``, ``ici_bytes``, ``host_copy_bytes``."""
+        from wormhole_tpu.data.crec import MeshGroupFeed
         from wormhole_tpu.learners.store import mesh_group_shardings
-        from wormhole_tpu.ops.metrics import auc_from_hist
-        from wormhole_tpu.utils.config import check_choice
         if jax.process_count() > 1:
             # unreachable from run() (run_multihost handles crec/crec2
             # via _multihost_pass_crec); direct process() callers must go
@@ -873,184 +767,54 @@ class AsyncSGD:
             raise RuntimeError(
                 f"call run()/run_multihost for multi-process {fmt} — "
                 "process() is single-process only")
-        check_choice("mesh_feed", self.cfg.mesh_feed, ("ring", "sync"))
-        use_ring = self.cfg.mesh_feed == "ring"
         is_tile = fmt == "crec2" or online
-        D = self.rt.data_axis_size
         pfx = "" if kind == TRAIN else "eval_"
-        want_labels = kind != TRAIN and pooled is not None
-
-        nsteps = [0]         # train steps since the last accumulator fetch
-        hist_tot = [np.zeros(512), np.zeros(512)]
-        # deferred metric windows: eval steps and overflow-fallback
-        # scatter steps batch their device fetches (a per-step
-        # float(np.asarray(...)) forces a full round trip each and
-        # serializes the async dispatch pipeline)
-        eval_pending: list = []
-        spill_pending: list = []
-
-        def drain_spill() -> None:
-            """Resolve overflow-fallback steps: sparse-path metric tuple
-            layout — [objv, num_ex, auc, acc, wdelta2|margin]."""
-            if not spill_pending:
-                return
-            fetched = jax.device_get([s[0] for s in spill_pending])
-            for (_m, labels_u8), metrics in zip(spill_pending, fetched):
-                local.objv += float(metrics[0])
-                local.num_ex += int(metrics[1])
-                local.count += 1
-                local.auc += float(metrics[2])
-                local.acc += float(metrics[3])
-                if kind == TRAIN:
-                    local.wdelta2 += float(metrics[4])
-                elif pooled is not None and labels_u8 is not None:
-                    # host-sync: metrics fetched above — already host
-                    margin = np.asarray(metrics[4])
-                    real = labels_u8 != 255
-                    pooled.append((margin[real],
-                                   np.minimum(labels_u8[real], 1)
-                                   .astype(np.float32),
-                                   np.ones(int(real.sum()), np.float32)))
-            spill_pending.clear()
-
-        def drain_eval() -> None:
-            """Resolve grouped mesh eval steps: [objv_g, tot_ex,
-            acc_frac, pos, neg, margin] with the margin global over the
-            (D*R,) stacked row order — exactly the label-lane order
-            ``stack_mesh_group`` recorded."""
-            if not eval_pending:
-                return
-            fetched = jax.device_get([p[0] for p in eval_pending])
-            for (_m, labels_u8), m in zip(eval_pending, fetched):
-                local.objv += float(m[0])
-                local.num_ex += int(m[1])
-                local.count += 1
-                local.acc += float(m[2])
-                local.auc += auc_from_hist(m[3], m[4])
-                if pooled is not None and labels_u8 is not None:
-                    margins = np.asarray(m[5])
-                    real = labels_u8 != 255
-                    pooled.append((margins[real],
-                                   np.minimum(labels_u8[real], 1)
-                                   .astype(np.float32),
-                                   np.ones(int(real.sum()), np.float32)))
-            eval_pending.clear()
-
-        def drain_pending(final: bool = True) -> None:
-            """Harvest everything outstanding: the on-device train
-            accumulator rides the async ticket pipeline (mid-part
-            windows are non-final so the device never drains waiting on
-            a metrics round trip); eval/spill windows batch-fetch."""
-            drain_spill()
-            if kind == TRAIN:
-                self._harvest_macc(local, hist_tot, nsteps[0], final)
-                nsteps[0] = 0
-            else:
-                drain_eval()
-
-        def run_group(blocks, labels_u8) -> None:
-            with self.timer.scope(pfx + "dispatch"):
-                with obs.trace.span("mesh:dispatch", cat="mesh"):
-                    if kind == TRAIN:
-                        if is_tile:
-                            self.store.tile_train_step_mesh(blocks, info)
-                        else:
-                            self.store.dense_train_step_mesh(
-                                blocks, info.block_rows, info.nnz)
-                    else:
-                        m = (self.store.tile_eval_step_mesh(blocks, info)
-                             if is_tile else
-                             self.store.dense_eval_step_mesh(
-                                 blocks, info.block_rows, info.nnz))
-            if kind == TRAIN:
-                nsteps[0] += 1
-                if (self.reporter.due()
-                        or nsteps[0] >= self.CREC_DRAIN_CHUNK):
-                    with self.timer.scope(pfx + "wait"):
-                        drain_pending(final=False)
-            else:
-                eval_pending.append((m, labels_u8))
-                if (not use_ring
-                        or len(eval_pending) >= self.CREC_DRAIN_CHUNK):
-                    with self.timer.scope(pfx + "wait"):
-                        drain_eval()
-
-        def run_spill(batch, labels_u8) -> None:
-            """Encode-overflow block through the audited scatter step
-            (the replicated-table sparse path) — the on-device tile
-            accumulator never sees this block. ``ring`` mode defers the
-            metric fetch with the other spills; ``sync`` keeps the
-            legacy synchronous round trip."""
-            obs.metrics.encode_counters(self.obs.registry)[1].inc(1)
-            with self.timer.scope(pfx + "dispatch"):
-                with obs.trace.span("mesh:spill", cat="mesh"):
-                    m = (self.store.train_step(batch, tau=0.0)
-                         if kind == TRAIN else self.store.eval_step(batch))
-            spill_pending.append((m, labels_u8))
-            if (not use_ring
-                    or len(spill_pending) >= self.CREC_DRAIN_CHUNK):
-                with self.timer.scope(pfx + "wait"):
-                    drain_spill()
-
+        win = MetricWindow(self, local, kind, pooled, bounded=True)
+        step, layout = self._crec_step(
+            kind, "tile_mesh" if is_tile else "dense_mesh", info)
+        spill_step, _ = self._crec_step(kind, "spill", info)
         tx = self.store.mesh_transport()
         steps_before, ici_before = tx.dispatches, tx.bytes_ici
-        stacked = [0]        # bytes the sync mode copied into stacked groups
         inner = self._make_feed(file, part, nparts, fmt,
                                 device_put=lambda x: x,
                                 tile_info=info if online else None)
-        if use_ring:
-            feed = MeshGroupFeed(
-                inner, D, mesh_group_shardings(self.rt, is_tile), info,
-                is_tile, workers=self.cfg.pipeline_workers,
-                depth=max(self.cfg.pipeline_ring, 1), online=online,
-                want_labels=want_labels)
-            for tag, payload, labels_u8, _rows in feed:
-                if tag == "spill":
-                    run_spill(payload, labels_u8)
-                else:
-                    run_group(payload, labels_u8)
-        else:
-            feed = inner
-            pads = mesh_pads(info, is_tile)
-            group: list = []
-
-            def flush() -> None:
-                with obs.trace.span("mesh:stack", cat="mesh"):
-                    blocks, labels_u8 = stack_mesh_group(
-                        group, D, info, pads, is_tile, want_labels)
-                stacked[0] += sum(a.nbytes
-                                  for a in jax.tree_util.tree_leaves(blocks))
-                run_group(blocks, labels_u8)
-
-            for dev, host, _rows in feed:
-                if online and not isinstance(dev, dict):
-                    # the online feed's host item is the labels-only array
-                    run_spill(dev, np.asarray(host))
-                    continue
-                group.append(dev)
-                if len(group) == D:
-                    flush()
-                    group = []
-            if group:
-                flush()
+        feed = MeshGroupFeed(
+            inner, self.rt.data_axis_size,
+            mesh_group_shardings(self.rt, is_tile), info, is_tile,
+            workers=self.cfg.pipeline_workers,
+            depth=max(self.cfg.pipeline_ring, 1), online=online,
+            want_labels=kind != TRAIN and pooled is not None)
+        for tag, payload, labels_u8, _rows in feed:
+            if tag == "spill":
+                obs.metrics.encode_counters(self.obs.registry)[1].inc(1)
+                with self.timer.scope(pfx + "dispatch"):
+                    with obs.trace.span("mesh:spill", cat="mesh"):
+                        m = spill_step(payload, 0.0)
+                win.add_spill(m, labels_u8)
+                continue
+            with self.timer.scope(pfx + "dispatch"):
+                with obs.trace.span("mesh:dispatch", cat="mesh"):
+                    m = step(payload, 0.0)
+            if kind == TRAIN:
+                win.count_step()
+            else:
+                win.add_step(m, labels_u8, layout)
         with self.timer.scope(pfx + "wait"):
-            drain_pending()
+            win.drain()
         self.timer.add(pfx + "put", feed.put_time)
         self._merge_pipe_snap(feed.drain_pipe_stats(None), pfx, local)
         # counts, not seconds: the mesh dispatches of this part, the
         # ICI bytes one chip moved for them as the store's model books
         # them (store.mesh_step_ici_bytes), and the bytes the feed
-        # copied on the host (0 from a mapped local file on the ring;
-        # a fall-back to readinto, or sync's stack, shows as a number)
+        # copied on the host (0 from a mapped local file; a fall-back
+        # to readinto shows as a number)
         self.timer.add(pfx + "mesh_steps", tx.dispatches - steps_before)
         self.timer.add(pfx + "ici_bytes", tx.bytes_ici - ici_before)
-        self.timer.add(pfx + "host_copy_bytes",
-                       feed.host_copy_bytes + stacked[0])
-        if use_ring:
-            self._export_mesh_feed_stats(feed)
+        self.timer.add(pfx + "host_copy_bytes", feed.host_copy_bytes)
+        self._export_group_feed_stats(feed)
         return local
 
-    def _export_mesh_feed_stats(self, feed) -> None:
+    def _export_group_feed_stats(self, feed) -> None:
         """Fold a MeshGroupFeed's dispatcher-side counters into the obs
         registry (obs.metrics.mesh_feed_gauges): per-group arrival skew
         — the per-device straggler signal the multichip bench reports —
@@ -1562,12 +1326,13 @@ class AsyncSGD:
         block this round contributes all-PAD blocks, which vanish from
         every product."""
         from jax.sharding import PartitionSpec as P
-        from wormhole_tpu.data.crec import (PackedFeed, read_header,
-                                            read_header2)
+        from wormhole_tpu.data.crec import (PackedFeed, mesh_pads,
+                                            read_header, read_header2,
+                                            stack_mesh_group)
         from wormhole_tpu.data.stream import list_files
-        from wormhole_tpu.ops.metrics import auc_from_hist
         cfg = self.cfg
         fmt = cfg.data_format
+        is_tile = fmt == "crec2"
         world = self.rt.world
         dpa = self.rt.data_axis_size
         dlocal = dpa // world          # data-axis indices per host
@@ -1579,15 +1344,18 @@ class AsyncSGD:
         my_skip = 0
         # headers are geometry-identical across a dataset's files (the
         # check below re-verifies per opened file)
-        read_hdr = read_header2 if fmt == "crec2" else read_header
+        read_hdr = read_header2 if is_tile else read_header
         info = read_hdr(list_files(pattern)[0].path)
         my_it = None
         my_wl = None
         drained = False
         finished_id = -1
         local = Progress()
-        hist_tot = [np.zeros(512), np.zeros(512)]
         pfx = "" if kind == TRAIN else "eval_"
+        win = MetricWindow(self, local, kind, pooled, bounded=True)
+        step, layout = self._crec_step(
+            kind, "tile_mesh" if is_tile else "dense_mesh", info)
+        pads = mesh_pads(info, is_tile)
 
         def feed_iter(wl, skip=0):
             hdr = read_hdr(wl.file)
@@ -1615,30 +1383,6 @@ class AsyncSGD:
                 from itertools import islice
                 it = islice(it, skip, None)
             return it
-
-        if fmt == "crec2":
-            spec = info.spec
-            oc = max(info.ovf_cap, 1)
-            pads = (np.full(spec.pairs_shape, PADWORD, np.uint32),
-                    np.full(info.block_rows, 255, np.uint8),
-                    np.full(oc, 0xFFFFFFFF, np.uint32),
-                    np.zeros(oc, np.uint32))
-
-            def pad_block():
-                return {"pw": pads[0], "labels": pads[1],
-                        "ovf_b": pads[2], "ovf_r": pads[3]}
-        else:
-            # one all-0xFF buffer: sentinel keys AND pad labels are 0xFF
-            v1_pad = np.full(info.block_bytes, 0xFF, np.uint8)
-
-            def pad_block():
-                return v1_pad
-
-        nsteps = [0]   # train steps since the last accumulator fetch
-
-        def drain_pending(final: bool = True) -> None:
-            self._harvest_macc(local, hist_tot, nsteps[0], final)
-            nsteps[0] = 0
 
         def collect(group):
             nonlocal my_it, finished_id
@@ -1709,54 +1453,25 @@ class AsyncSGD:
                 if bool(np.all(status[:, 2])) and not any_claimed:
                     break
                 continue
-            while len(group) < dlocal:
-                group.append(pad_block())
-            if fmt == "crec2":
-                blocks = {k: np.stack([v.get(k, pads[2] if k == "ovf_b"
-                                             else pads[3])
-                                       for v in group])
-                          for k in ("pw", "labels", "ovf_b", "ovf_r")}
-            else:
-                blocks = np.stack(group)
+            blocks, labels_u8 = stack_mesh_group(
+                group, dlocal, info, pads, is_tile,
+                want_labels=kind != TRAIN and pooled is not None)
             gblocks = host_local_to_global(blocks, self.rt.mesh,
                                            P(DATA_AXIS))
             with self.timer.scope(pfx + "dispatch"):
+                m = step(gblocks, 0.0)
                 if kind == TRAIN:
-                    if fmt == "crec2":
-                        self.store.tile_train_step_mesh(gblocks, info)
-                    else:
-                        self.store.dense_train_step_mesh(
-                            gblocks, info.block_rows, info.nnz)
-                    nsteps[0] += 1
-                    if (self.reporter.due()
-                            or nsteps[0] >= self.CREC_DRAIN_CHUNK):
-                        with self.timer.scope(pfx + "wait"):
-                            drain_pending(final=False)
+                    win.count_step()
                 else:
-                    m = (self.store.tile_eval_step_mesh(gblocks, info)
-                         if fmt == "crec2" else
-                         self.store.dense_eval_step_mesh(
-                             gblocks, info.block_rows, info.nnz))
-                    local.objv += float(np.asarray(m[0]))
-                    local.num_ex += int(np.asarray(m[1]))
-                    local.count += 1
-                    local.acc += float(np.asarray(m[2]))
-                    local.auc += auc_from_hist(np.asarray(m[3]),
-                                               np.asarray(m[4]))
+                    # an eval round folds as it comes: its margins
+                    # are a global array, read shard by shard
+                    fold_row(local, [np.asarray(v) for v in m[:5]],
+                             layout, kind)
                     if pooled is not None:
-                        margins = self._my_shard_rows(m[5])
-                        from wormhole_tpu.data.crec import unpack_block
-                        labs = np.concatenate(
-                            [v["labels"] if fmt == "crec2"
-                             else unpack_block(v, info)[1]
-                             for v in group])
-                        real = labs != 255
-                        pooled.append(
-                            (margins[real],
-                             np.minimum(labs[real], 1).astype(np.float32),
-                             np.ones(int(real.sum()), np.float32)))
+                        pool_margins(pooled, self._my_shard_rows(m[5]),
+                                     labels_u8)
         with self.timer.scope(pfx + "wait"):
-            drain_pending()
+            win.drain()
         return local
 
     def run_multihost(self) -> Progress:

@@ -67,15 +67,14 @@ SPAN_TABLE: Dict[str, str] = {
     "dispatch": "device_compute",
     "wait": "device_compute",
     # multi-device mesh path: group dispatch and the spill scatter step
-    # are device work; the sync-mode hot-loop group stack is host prep
-    # (the ring mode moves it into the feed's ``stack`` stage below)
+    # are device work; what is left of group assembly is the feed's
+    # ``stack`` stage below
     "mesh:dispatch": "device_compute",
     # transport-wrapped mesh dispatch (MeshTransport.dispatch); same
     # bucket as mesh:dispatch so routing through the transport layer
     # does not shift ledger attribution
     "collective:mesh": "device_compute",
     "mesh:spill": "device_compute",
-    "mesh:stack": "host_prep",
     "stack": "host_prep",
     # metrics ticket readback on the host
     "read": "metrics_readback",

@@ -285,15 +285,6 @@ class Config:
     # hold); "off" always rebuilds. The resolution is recorded as
     # onehot_cache=on|off:<why> in store.step_kernel.
     tile_onehot_cache: str = "auto"
-    # multi-device crec/crec2 feed (data/crec.MeshGroupFeed): "ring"
-    # hands each chip its slice of a data-axis group of D blocks from
-    # the transfer thread, on the (data, model) NamedSharding the step
-    # takes and with no stacked copy, so H2D overlaps the mesh step;
-    # "sync" keeps the synchronous stack+jit-transfer dispatch (the
-    # pre-scale-out path, kept as the measured baseline for bench.py
-    # --phases multichip and the only caller of stack_mesh_group).
-    # Single-device runs ignore this knob.
-    mesh_feed: str = "ring"
     seed: int = 0
     checkpoint_dir: str = ""
     checkpoint_every: int = 1   # save a checkpoint every N data passes

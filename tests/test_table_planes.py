@@ -17,6 +17,7 @@ from wormhole_tpu.learners import table as tbl
 from wormhole_tpu.learners.handles import LearnRate, create_handle
 from wormhole_tpu.learners.store import (IN_PLACE, ShardedStore,
                                          StoreConfig, TableCheckpoint)
+from wormhole_tpu.models import fm as fm_model
 from wormhole_tpu.ops import tilemm
 from wormhole_tpu.ops.penalty import L1L2
 
@@ -32,6 +33,25 @@ def _store(nb, kernel="fused", algo="ftrl", **cfg):
         StoreConfig(num_buckets=nb, loss="logit", tile_step_kernel=kernel,
                     **cfg),
         create_handle(algo, L1L2(0.05, 0.1), LearnRate(0.1, 1.0)))
+
+
+def _fm_store(nb, kernel="fused", dim=4, rt=None):
+    from wormhole_tpu.models.fm import FMConfig, FMStore
+    return FMStore(FMConfig(num_buckets=nb, dim=dim, loss="logit", l1=0.5,
+                            l2=0.05, seed=7, tile_step_kernel=kernel), rt)
+
+
+# the cases that hold for any store that keeps planes
+MAKERS = {"ftrl": _store, "fm": _fm_store}
+BOTH = pytest.mark.parametrize("make", [
+    pytest.param(_store, id="ftrl"), pytest.param(_fm_store, id="fm")])
+
+
+def _as_it_was(store):
+    """The same store on the (nb, slots) array it kept before the planes."""
+    store._planar = False
+    store.slots = jnp.asarray(np.asarray(store.slots))
+    return store
 
 
 def _crossings(store) -> int:
@@ -99,6 +119,61 @@ def test_planar_steps_match_the_split_oracle(spec, spill, why):
     assert np.any(np.asarray(fused.slots) != start)
 
 
+@pytest.mark.parametrize("spill,hosts", [
+    pytest.param(False, 1, id="fused_update"),
+    pytest.param(True, 1, id="fused_spill"),
+    pytest.param(False, 2, id="fused_pushes")])
+def test_planar_fm_steps_match_the_stacked_split_oracle(spill, hosts,
+                                                        monkeypatch):
+    """Three FM steps from a random table: the planar fused steps (operand
+    formed in VMEM from the w and v planes; without an overflow list the
+    update inside the kernel, with one a push plane a channel and one
+    elementwise update over planes, which a step of a multi-process run
+    takes without a list too) against the split kernel pair on the
+    stacked (nb, 2(1+k)) array: margins and the metric row to the bit
+    (the progress number to rounding) and all 2(1+k) channels to the bit
+    (FMAdaGrad's guarded products). The planar table stays planes."""
+    rng = np.random.default_rng(31)
+    k = 4
+    monkeypatch.setattr(jax, "process_count", lambda: hosts)
+    info = make_info(SPEC, ovf_cap=OC if spill else 0)
+    start = (rng.standard_normal((SPEC.nb, 2 * (1 + k))) * 0.1).astype(
+        np.float32)
+    start[:, 1 + k:] = np.abs(start[:, 1 + k:])
+    planar = _fm_store(SPEC.nb, "fused")
+    oracle = _as_it_was(_fm_store(SPEC.nb, "split"))
+    for st in (planar, oracle):
+        st.slots = jnp.asarray(start)
+    for blk in _blocks(rng, SPEC, 3, spill):
+        dev = jax.device_put(blk)
+        rows = []
+        for st in (planar, oracle):
+            st.tile_train_step(dev, info)
+            rows.append(st.fetch_metrics())
+        # the progress number's sum runs over a plane here, a column of
+        # the stacked array there: equal to rounding, all else to the bit
+        np.testing.assert_array_equal(np.delete(rows[0], 3),
+                                      np.delete(rows[1], 3))
+        assert rows[1][3] > 0
+        np.testing.assert_allclose(rows[0][3], rows[1][3], rtol=1e-6)
+        np.testing.assert_array_equal(
+            np.asarray(planar.tile_eval_step(dev, info)[5]),
+            np.asarray(oracle.tile_eval_step(dev, info)[5]))
+    planar._tile_step(info, "train", spill)
+    in_place = not spill and hosts == 1
+    assert planar.step_kernel[:2] == (
+        "fused", fm_model.IN_PLACE if in_place else "")
+    assert isinstance(planar.slots, tbl.PlaneTable)
+    assert len(planar.slots.planes) == 2 * (1 + k)
+    assert not isinstance(oracle.slots, tbl.PlaneTable)
+    assert _crossings(planar) == 1 and _crossings(oracle) == 0
+    got = np.asarray(planar.slots)
+    np.testing.assert_array_equal(got, np.asarray(oracle.slots))
+    changed = np.any(got != start, axis=1)
+    assert 0 < changed.sum() < SPEC.nb        # touched buckets only
+    assert np.all(got[changed][:, 1 + k] > 0)
+
+
 def test_other_handles_step_on_planes():
     """A handle without an unstacked update goes through push() on the
     stacked planes inside the step; the touched-bucket mask holds."""
@@ -138,9 +213,11 @@ def test_a_stacked_table_keeps_its_overflow_lists():
     assert _crossings(store) == 0
 
 
-def test_an_empty_overflow_list_stays_on_the_host():
+@BOTH
+def test_an_empty_overflow_list_stays_on_the_host(make):
     """put_block leaves an overflow list with no pair behind, and the
-    block then takes the in-place step; one pair keeps the spill step."""
+    block then takes the step that has no spill to scatter (FTRL: the
+    in-place one, and so for FM); one pair keeps the spill step."""
     rng = np.random.default_rng(5)
     info = make_info(SPEC, ovf_cap=OC)
     pw, labels = make_block(rng, SPEC)
@@ -148,18 +225,22 @@ def test_an_empty_overflow_list_stays_on_the_host():
              "ovf_b": np.full(OC, 0xFFFFFFFF, np.uint32),
              "ovf_r": np.zeros(OC, np.uint32)}
     (spilled,) = _blocks(rng, SPEC, 1, True)
-    a, b = _store(SPEC.nb), _store(SPEC.nb)
+    a, b = make(SPEC.nb), make(SPEC.nb)
+    no_spill = ("fused", IN_PLACE if make is _store else fm_model.IN_PLACE)
     dev = a.put_block(empty)
     assert sorted(dev) == ["labels", "pw"]
     a.tile_train_step(dev, info)
-    assert a.step_kernel[:2] == ("fused", IN_PLACE)
+    assert a.step_kernel[:2] == no_spill
+    assert [key[2] for key in a._tile_cache] == [False]
     b.tile_train_step(jax.device_put(empty), info)       # the spill step
     assert b.step_kernel[:2] == ("fused", "")
+    assert [key[2] for key in b._tile_cache] == [True]
     np.testing.assert_array_equal(np.asarray(a.slots), np.asarray(b.slots))
     dev = a.put_block(spilled)
     assert "ovf_b" in dev
     a.tile_train_step(dev, info)
     assert a.step_kernel[:2] == ("fused", "")
+    assert _crossings(a) == 0 and _crossings(b) == 0
 
 
 # -- (b) the crossing and the checkpoint -------------------------------------
@@ -201,7 +282,8 @@ def test_a_plane_table_reads_like_the_stacked_array(form):
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
-def test_checkpoint_bytes_are_those_of_the_stacked_table(tmp_path):
+@BOTH
+def test_checkpoint_bytes_are_those_of_the_stacked_table(make, tmp_path):
     """A planar store's checkpoint file is byte for byte what the same
     state written as one (nb, slots) array gives (the format before the
     planes), and it loads back into a store that goes on stepping."""
@@ -209,7 +291,7 @@ def test_checkpoint_bytes_are_those_of_the_stacked_table(tmp_path):
     rng = np.random.default_rng(7)
     info = make_info(SPEC)
     blocks = [jax.device_put(b) for b in _blocks(rng, SPEC, 3, False)]
-    store = _store(SPEC.nb)
+    store = make(SPEC.nb)
     for blk in blocks[:2]:
         store.tile_train_step(blk, info)
     assert isinstance(store.slots, tbl.PlaneTable)
@@ -222,7 +304,7 @@ def test_checkpoint_bytes_are_those_of_the_stacked_table(tmp_path):
     assert (planar / name).read_bytes() == (stacked / name).read_bytes()
     assert _crossings(store) == 0         # stacked on the host, not here
 
-    fresh = _store(SPEC.nb)
+    fresh = make(SPEC.nb)
     ver, state = Checkpointer(str(planar)).load(fresh.state_pytree())
     assert ver == 2
     fresh.restore_pytree(state)
@@ -236,7 +318,8 @@ def test_checkpoint_bytes_are_those_of_the_stacked_table(tmp_path):
                                   np.asarray(store.slots))
 
 
-def test_paths_that_want_the_array_cross_and_are_counted():
+@BOTH
+def test_paths_that_want_the_array_cross_and_are_counted(make):
     """The sparse step asks for (nb, slots) and gets it, once; the next
     tile step takes the table back; both crossings are on the timer and
     the state is what a store that never left the array computes."""
@@ -251,10 +334,8 @@ def test_paths_that_want_the_array_cross_and_are_counted():
         labels=jnp.asarray(rng.integers(0, 2, 32), jnp.float32),
         row_mask=jnp.ones(32, jnp.float32), uniq_keys=jnp.asarray(keys),
         key_mask=jnp.ones(64, jnp.float32))
-    planar = _store(SPEC.nb)
-    stacked = _store(SPEC.nb, param_dtype="float32")
-    stacked._planar = False                      # the table as it was
-    stacked.slots = jnp.asarray(np.asarray(stacked.slots))
+    planar = make(SPEC.nb)
+    stacked = _as_it_was(make(SPEC.nb))
     for st in (planar, stacked):
         st.tile_train_step(blocks[0], info)
         st.train_step(batch)
@@ -263,6 +344,34 @@ def test_paths_that_want_the_array_cross_and_are_counted():
     assert planar.timer.totals["table_cross"] > 0
     np.testing.assert_array_equal(np.asarray(planar.slots),
                                   np.asarray(stacked.slots))
+
+
+@BOTH
+def test_the_pager_crosses_once_and_is_counted(make):
+    """bigmodel/paged.py moves rows of the hot table by index: it asks a
+    planar store for the array (PagedStore._table), one counted crossing,
+    and the table stays the array while the pager owns it; the next tile
+    step takes it back."""
+    from wormhole_tpu.bigmodel import PagedStore
+    rng = np.random.default_rng(23)
+    hot = make(SPEC.nb)
+    width = hot.slots.shape[1]
+    cold = rng.standard_normal((2 * SPEC.nb, width)).astype(np.float32)
+    paged = PagedStore(hot, 2 * SPEC.nb, cold_init=cold)
+    assert isinstance(hot.slots, tbl.PlaneTable) and _crossings(hot) == 0
+    buckets = np.array([5, SPEC.nb + 7, 2 * SPEC.nb - 1])
+    plan = paged.pager.plan(buckets)
+    paged.stage_fresh(plan)
+    paged.apply_plan(plan)                       # the fill's scatter
+    assert not isinstance(hot.slots, tbl.PlaneTable)
+    assert _crossings(hot) == 1
+    np.testing.assert_array_equal(np.asarray(hot.slots)[plan.slots],
+                                  cold[buckets])
+    np.testing.assert_array_equal(paged.flush(), cold)   # the gather
+    assert _crossings(hot) == 1                  # already the array
+    info = make_info(SPEC)
+    hot.tile_train_step(jax.device_put(_blocks(rng, SPEC, 1, False)[0]), info)
+    assert isinstance(hot.slots, tbl.PlaneTable) and _crossings(hot) == 2
 
 
 # -- (c) the benchmark's probes, as they are ---------------------------------
@@ -305,7 +414,117 @@ def test_benchmark_state_probe_and_fence_cross_nothing(probed):
     assert _crossings(app.store) == 0
 
 
-# -- (d) what the in-place step does around its kernel -----------------------
+def test_fm_benchmark_reads_and_writes_of_a_planar_table():
+    """Everything benchmark/configs/criteo_fm/system.py does to
+    ``store.slots``, on a planar FMStore: it reads ``.sharding``, replaces
+    the table by a donated jit of ``slots.at[:, 1:1+k].set(v0)`` with that
+    ``out_shardings`` and assigns the stacked result (ONE crossing, at the
+    next tile step), then probes with ``astype``, ``s[:, 0]``,
+    ``s[:, 1:1+k]``, ``s[:, 1+k]``, ``s[:, 2+k:]`` and ``slots[idx, :1+k]``
+    (no crossing)."""
+    if REPO not in sys.path:
+        sys.path.insert(0, REPO)
+    from benchmark.configs.criteo_fm import system as hooks
+    k, nb, scale, seed = 4, SPEC.nb, 0.01, (1 << 31) + 12345
+    store = _fm_store(nb)
+    assert isinstance(store.slots, tbl.PlaneTable)
+    sharding = store.slots.sharding
+    assert sharding == jax.sharding.SingleDeviceSharding(jax.devices()[0])
+
+    def seeded(slots, salt):               # make_app's, line for line
+        return slots.at[:, 1:1 + k].set(hooks._v0(nb, k, salt, scale))
+
+    salt = jnp.uint32(hooks._salt(seed))
+    store.slots = jax.jit(seeded, donate_argnums=(0,),
+                          out_shardings=sharding)(store.slots, salt)
+    assert store.slots.shape == (nb, 2 * (1 + k))
+    assert not isinstance(store.slots, tbl.PlaneTable)
+    v0 = np.asarray(hooks._v0(nb, k, salt, scale))
+    np.testing.assert_array_equal(np.asarray(store.slots)[:, 1:1 + k], v0)
+    assert _crossings(store) == 0
+
+    rng = np.random.default_rng(17)
+    info = make_info(SPEC)
+    for blk in _blocks(rng, SPEC, 3, False):
+        store.tile_train_step(jax.device_put(blk), info)
+    assert isinstance(store.slots, tbl.PlaneTable)
+    assert _crossings(store) == 1          # the assigned array, once
+
+    app = types.SimpleNamespace(store=store)
+    config = {"dim": k, "num_buckets": nb, "hyper": {"init_scale": scale}}
+    full = np.asarray(store.slots, np.float64)
+    got = hooks.change_norms(app, config, seed)
+    np.testing.assert_allclose(got["w"], np.linalg.norm(full[:, 0]),
+                               rtol=1e-6)
+    np.testing.assert_allclose(
+        got["v"], np.linalg.norm(full[:, 1:1 + k] - v0), rtol=1e-5)
+    got = hooks.grad_norms(app, config, seed)
+    np.testing.assert_allclose(got["w"], np.linalg.norm(full[:, 1 + k]),
+                               rtol=1e-6)
+    np.testing.assert_allclose(got["v"], np.linalg.norm(full[:, 2 + k:]),
+                               rtol=1e-6)
+    assert got["w"] > 0 and got["v"] > 0
+    buckets = rng.integers(0, nb, 4096)
+    rows = hooks.state(app, config, seed, buckets)
+    np.testing.assert_array_equal(rows["w"], full[buckets, 0])
+    np.testing.assert_array_equal(rows["v"], full[buckets, 1:1 + k])
+    jax.block_until_ready(store.slots)           # benchmark/system.py fence
+    assert isinstance(store.slots, tbl.PlaneTable)
+    assert _crossings(store) == 1
+
+
+def test_fm_paths_that_want_the_array_cross_and_are_counted(tmp_path):
+    """serve_params, save_model/load_model and the mesh step ask a planar
+    FMStore for (nb, 2(1+k)) and get it, one counted crossing each time
+    the table is planes; the next tile step takes it back."""
+    from wormhole_tpu.parallel.mesh import MeshRuntime, make_mesh
+    rng = np.random.default_rng(19)
+    info = make_info(SPEC)
+    blocks = [jax.device_put(b) for b in _blocks(rng, SPEC, 4, False)]
+    rt = MeshRuntime(mesh=make_mesh("data:1", jax.devices()[:1]))
+    store = _fm_store(SPEC.nb, rt=rt)
+    k = store.cfg.dim
+    assert isinstance(store.slots, tbl.PlaneTable)
+
+    store.tile_train_step(blocks[0], info)
+    before = np.asarray(store.slots)
+    params = store.serve_params()                          # serving
+    assert params["slots"].shape == (SPEC.nb, 2 * (1 + k))
+    np.testing.assert_array_equal(np.asarray(params["slots"]), before)
+    assert _crossings(store) == 1
+    store.serve_params()
+    assert _crossings(store) == 1                # already the array
+
+    store.tile_train_step(blocks[1], info)                 # and back
+    assert isinstance(store.slots, tbl.PlaneTable) and _crossings(store) == 2
+    store.save_model(str(tmp_path / "fm"), rank=0)         # the export
+    assert _crossings(store) == 3
+    saved = np.load(tmp_path / "fm_0.npz")
+    np.testing.assert_array_equal(saved["w"], np.asarray(store.slots)[:, 0])
+    np.testing.assert_array_equal(saved["v"],
+                                  np.asarray(store.slots)[:, 1:1 + k])
+    store.tile_train_step(blocks[2], info)
+    assert _crossings(store) == 4
+    fresh = _fm_store(SPEC.nb)
+    fresh.load_model(str(tmp_path / "fm_0.npz"))           # the import
+    assert _crossings(fresh) == 1
+    np.testing.assert_array_equal(np.asarray(fresh.slots)[:, :1 + k],
+                                  np.column_stack([saved["w"], saved["v"]]))
+    fresh.tile_train_step(blocks[2], info)
+    assert isinstance(fresh.slots, tbl.PlaneTable) and _crossings(fresh) == 2
+
+    group = {key: val[None] for key, val in blocks[3].items()}
+    store.tile_train_step_mesh(group, info)                # the mesh step
+    assert _crossings(store) == 5
+    assert store.slots.shape == (SPEC.nb, 2 * (1 + k))
+    twin = _as_it_was(_fm_store(SPEC.nb))
+    for blk in blocks:
+        twin.tile_train_step(blk, info)
+    np.testing.assert_allclose(np.asarray(store.slots),
+                               np.asarray(twin.slots), rtol=1e-5, atol=1e-7)
+
+
+# -- (d) what the steps do around their kernels ------------------------------
 
 def _leaf_eqns(jaxpr):
     """Equations of a jaxpr with calls opened, kernels left closed."""
@@ -354,3 +573,65 @@ def test_nothing_table_sized_outside_the_kernel():
            for v in e.outvars if v.aval.size >= nb]
     assert big == []
     assert sum(v.aval.size >= nb for v in kernels[0].outvars) == 3
+
+
+def test_fm_step_forms_nothing_table_sized_outside_the_kernel():
+    """The one-device FM train step over channel planes, traced at a
+    table whose plane outgrows every block-sized array. Without an
+    overflow list: ONE pallas_call takes the 2(1+k) planes as they are
+    and gives them back, and no other equation has a result of nb
+    elements or more — no concatenate, pad, slice, transpose, cast or
+    (nb, ch) array, not even the pushes. With one: the call takes the
+    nine w and v planes and gives ten push planes, and outside it every
+    plane-sized result is a plane, made by the spill scatters and the
+    elementwise AdaGrad pass. (What the v5e compiler makes of it is
+    test_tpu_compile's to say.)"""
+    from wormhole_tpu.data.crec import CRec2Info
+    k = 8
+    nb = 1024 * tilemm.TILE
+    info = CRec2Info(nnz=0, block_rows=2 * tilemm.RSUB,
+                     total_rows=2 * tilemm.RSUB, nb=nb, subblocks=2,
+                     cap=128, ovf_cap=1024)
+    spec = info.spec
+    store = _fm_store(SPEC.nb, dim=k)
+
+    def like(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    plane = like(tbl.plane_shape(nb), jnp.float32)
+
+    def trace(spill):
+        step = store._tile_step(info, "train", spill)
+        block = {"pw": like(spec.pairs_shape, jnp.uint32),
+                 "labels": like((spec.block_rows,), jnp.uint8)}
+        if spill:
+            block.update(ovf_b=like((1024,), jnp.uint32),
+                         ovf_r=like((1024,), jnp.uint32))
+        jaxpr = jax.make_jaxpr(step)(
+            tbl.PlaneTable([plane] * (2 * (1 + k))), block,
+            like((), jnp.int32), like((), jnp.float32),
+            like((TableCheckpoint.MACC_LEN,), jnp.float32))
+        eqns = list(_leaf_eqns(jaxpr.jaxpr))
+        (call,) = [e for e in eqns if e.primitive.name == "pallas_call"]
+        big = [e for e in eqns if e.primitive.name != "pallas_call"
+               and any(v.aval.size >= nb for v in e.outvars)]
+        planes_of = lambda vs: sum(v.aval.shape == plane.shape for v in vs)
+        return call, big, planes_of
+
+    call, big, planes_of = trace(False)
+    assert store.step_kernel[:2] == ("fused", fm_model.IN_PLACE)
+    assert planes_of(call.invars) == planes_of(call.outvars) == 2 * (1 + k)
+    assert big == []
+
+    call, big, planes_of = trace(True)
+    assert store.step_kernel[:2] == ("fused", "")
+    assert planes_of(call.invars) == 1 + k
+    assert planes_of(call.outvars) == k + 2
+    shapes = {v.aval.shape for e in big for v in e.outvars
+              if v.aval.size >= nb}
+    assert shapes <= {plane.shape, (nb,)}     # a plane, or the same bytes
+    allowed = {"add", "sub", "mul", "div", "sqrt", "gt", "select_n",
+               "sign", "abs", "max", "neg", "integer_pow", "square",
+               "reshape", "scatter-add", "scatter_add"}
+    names = {e.primitive.name for e in big}
+    assert names <= allowed, names - allowed

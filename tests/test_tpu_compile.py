@@ -37,7 +37,7 @@ CRITEO = dict(subblocks=12, cap=1408)   # 98,304-row crec2 blocks
 _BUILDERS = (tilemm._build_fwd, tilemm._build_bwd, tilemm._build_step_grad,
              tilemm._build_step_update, tilemm._build_fwd_multi,
              tilemm._build_bwd_multi, tilemm._build_fm_step_fused,
-             tilemm._build_wd_step_fused)
+             tilemm._build_fm_step_update, tilemm._build_wd_step_fused)
 
 
 @pytest.fixture(scope="module")
@@ -118,9 +118,19 @@ def bwd_multi(spec, ch):
 
 def fm_step(spec, k, spill=False):
     fn = tilemm._build_fm_step_fused(spec, k, "logit", spill)
-    args = [_pw(spec), ((spec.nb, k + 2), jnp.float32), _rows(spec),
-            _rows(spec)]
+    plane = ((spec.tiles, tilemm.A_HI, tilemm.B_LO), jnp.float32)
+    args = [_pw(spec), [plane] * (1 + k), _rows(spec), _rows(spec)]
     return fn, args + ([_rows(spec, k + 2)] if spill else [])
+
+
+def fm_step_update(spec, k):
+    from wormhole_tpu.models.fm import FMAdaGrad
+    from wormhole_tpu.ops.penalty import L1L2
+    fn = tilemm._build_fm_step_update(
+        spec, k, "logit", FMAdaGrad(0.05, 1.0, 1e-4, L1L2(0.0, 0.0)))
+    plane = ((spec.tiles, tilemm.A_HI, tilemm.B_LO), jnp.float32)
+    return fn, [_pw(spec), [plane] * (2 * (1 + k)), _rows(spec),
+                _rows(spec)]
 
 
 def wd_step(spec, k, hidden):
@@ -198,6 +208,8 @@ CASES = [
     _case(lambda: fm_step(_criteo(), 8), "fm_step-k8", slow=True),
     _case(lambda: fm_step(_criteo(), 8, spill=True), "fm_step_spill-k8",
           slow=True),
+    _case(lambda: fm_step_update(_criteo(), 8), "fm_step_update-k8",
+          slow=True),
     _case(lambda: wd_step(_criteo(), 16, (64, 32)), "wd_step-16x64x32",
           slow=True),
     _case(lambda: gbdt_hist(1_000_000, 28, 64, 256), "gbdt_hist-higgs",
@@ -264,3 +276,58 @@ def test_mesh_step_compiles_for_v5e_2x2(nb, v5e):
         on((TableCheckpoint.MACC_LEN,), jnp.float32, P())).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "all-reduce" in text
+
+
+def test_fm_train_step_on_planes_compiles_for_v5e(v5e):
+    """The whole one-device FM train step of a planar ``FMStore`` at the
+    widths of ``criteo_fm`` (cap 256), two tiles a grid step: the fused
+    10-channel kernel with the AdaGrad update inside, all 18 planes
+    aliased onto its outputs. Around the Mosaic call the v5e compiler
+    leaves nothing that touches a plane: no fusion, no copy, no
+    concatenate, pad, slice or transpose. What
+    ``criteo_fm.replay_uniform`` steps."""
+    import re
+    from wormhole_tpu.data.crec import CRec2Info
+    from wormhole_tpu.learners import table as tbl
+    from wormhole_tpu.learners.store import TableCheckpoint
+    from wormhole_tpu.models.fm import IN_PLACE, FMConfig, FMStore
+    # two tiles a grid step (tiles_step divides the tile count) keep the
+    # unrolled kernel short; 1018 tiles keep a plane out of VMEM, as at
+    # the cell's 2048
+    k, nb = 8, 2 * 509 * tilemm.TILE
+    store = FMStore(FMConfig(num_buckets=2 * tilemm.TILE, dim=k,
+                             tile_step_kernel="fused"))
+    info = CRec2Info(nnz=39, block_rows=12 * tilemm.RSUB,
+                     total_rows=12 * tilemm.RSUB, nb=nb, ovf_cap=1024,
+                     subblocks=12, cap=256)      # the cell's, at 2**25
+    spec = info.spec
+    step = store._tile_step(info, "train", False)
+    assert store.step_kernel[:2] == ("fused", IN_PLACE)
+    one_chip = SingleDeviceSharding(v5e.devices[0])
+
+    def on(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    plane = on(tbl.plane_shape(nb), jnp.float32)
+    compiled = step.lower(
+        tbl.PlaneTable([plane] * (2 * (1 + k))),
+        {"pw": on(spec.pairs_shape, jnp.uint32),
+         "labels": on((spec.block_rows,), jnp.uint8)},
+        on((), jnp.int32), on((), jnp.float32),
+        on((TableCheckpoint.MACC_LEN,), jnp.float32)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 1
+    plane_txt = "f32[%d,%d,%d]" % plane.shape
+    entry = text[text.index("ENTRY"):]
+    makers = set()
+    for line in entry.splitlines()[1:]:
+        m = re.match(r"\s*(?:ROOT )?%[\w.\-]+ = (.+?) ([\w\-]+)\(", line)
+        if m and plane_txt in m.group(1):
+            makers.add(m.group(2))
+    assert makers == {"parameter", "custom-call", "get-tuple-element",
+                      "tuple"}, makers
+    # the 18 planes are donated onto the 18 results; the pushes have no
+    # buffer at all
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * (1 + k) * 4 * nb
+    assert mem.temp_size_in_bytes < 4 * nb

@@ -158,6 +158,13 @@ class PagedStore:
             self._gather, self._scatter = gather, scatter
         return self._gather, self._scatter
 
+    def _table(self):
+        """The hot table as the ``(rows, slots)`` array the row moves
+        index. A store that keeps planes (learners/table.py) crosses to
+        it here, counted under ``table_cross``, and stays stacked."""
+        stacked = getattr(self.hot, "_stacked", None)
+        return stacked() if stacked is not None else self.hot.slots
+
     # -- tier moves (consumer thread, stream order) ---------------------
 
     def _resolve_pending(self) -> None:  # owner-thread: consumer
@@ -190,19 +197,19 @@ class PagedStore:
                 idx_p, _ = _pad_pair(plan.victim_slots,
                                      np.empty((plan.victim_slots.size, 0)),
                                      self.page_chunk)
-                rows_dev = gather(self.hot.slots, idx_p)
+                rows_dev = gather(self._table(), idx_p)
                 rows_dev.copy_to_host_async()
             self._pending = (plan.victim_buckets, rows_dev,
                              int(plan.victim_slots.size))
         if plan.staged_rows is not None:
             idx_d, rows_d = plan.staged_rows
-            self.hot.slots = scatter(self.hot.slots, idx_d, rows_d)
+            self.hot.slots = scatter(self._table(), idx_d, rows_d)
         if n_late:
             idx_p, rows_p = _pad_pair(plan.miss_slots[late], late_rows,
                                       self.page_chunk)
             dev = self._ring.prepare((idx_p, rows_p),
                                      put_label="page:h2d")
-            self.hot.slots = scatter(self.hot.slots, dev[0], dev[1])
+            self.hot.slots = scatter(self._table(), dev[0], dev[1])
             with self._lock:
                 self._bytes_h2d += n_late * self._row_bytes
 
@@ -239,7 +246,7 @@ class PagedStore:
             with trace.span("page:d2h", cat="page"):
                 # host-sync: flush is the stream-end barrier — cold
                 # must hold the final rows before readers touch it
-                rows = np.asarray(gather(self.hot.slots, idx_p))
+                rows = np.asarray(gather(self._table(), idx_p))
             self.cold[buckets] = rows[:occ.size]
             with self._lock:
                 self._bytes_d2h += occ.size * self._row_bytes

@@ -322,11 +322,85 @@ class StoreConfig:
 
 class TableCheckpoint:
     """Checkpointable {slots, t} state shared by the table-backed stores
-    (rabit Serializable analogue). Stores with extra state (wide&deep's
-    MLP) extend the pytree."""
+    (rabit Serializable analogue), and the door between the table's two
+    forms (learners/table.py). Stores with extra state (wide&deep's MLP)
+    extend the pytree."""
+
+    # A store whose single-device tile steps take the table as planes
+    # sets this from what it can see of itself: one device, a float32
+    # table, whole tiles. Crossings are counted in ``self.timer``, which
+    # such a store makes (a learner that owns it reads it as its own).
+    _planar = False
+
+    @staticmethod
+    def can_be_planar(runtime: Optional[MeshRuntime], dtype,
+                      num_buckets: int) -> bool:
+        return ((runtime is None or runtime.mesh.size == 1)
+                and jnp.dtype(dtype) == jnp.float32
+                and num_buckets % tbl.TILE == 0)
+
+    # -- the table and its two forms (learners/table.py) --------------------
+
+    @property
+    def slots(self):
+        """The table as it stands: a ``(nb, val_len)`` array, or a
+        :class:`~wormhole_tpu.learners.table.PlaneTable` that answers the
+        same reads (shape, dtype, astype, indexing, ``np.asarray``,
+        ``jax.block_until_ready``) from its planes. Assigning a plain
+        array is always legal; the next tile step takes it across."""
+        return self._table
+
+    @slots.setter
+    def slots(self, table) -> None:
+        self._table = table
+
+    def _cross(self, convert) -> None:
+        """Change the table's form: a pass over the whole table, counted
+        (calls and seconds) under ``table_cross`` in the timer and, as
+        every timer scope, in the trace. A run whose window shows none
+        never rebuilt the table."""
+        with self.timer.scope("table_cross"):
+            self._table = jax.block_until_ready(convert(self._table))
+
+    def _stacked(self) -> jax.Array:
+        """The table as one ``(nb, val_len)`` array, for every path but
+        the single-device tile steps; it stays so until one of those
+        runs."""
+        if isinstance(self._table, tbl.PlaneTable):
+            self._cross(tbl.to_stacked)
+        return self._table
+
+    def _tile_table(self):
+        """The table as the single-device tile steps take it: planes
+        where this store keeps them (``_planar``)."""
+        if self._planar and not isinstance(self._table, tbl.PlaneTable):
+            self._cross(tbl.to_planes)
+        return self._table
+
+    def put_block(self, block):
+        """Ship one tile block's host arrays to the device (the feed's
+        ``device_put``). Where the table is planes, an overflow list
+        with no pair in it stays behind: the block then takes the tile
+        step that has no spill to scatter, which for FTRL and FM is
+        the in-place one. (A stacked table keeps its one step: its no-spill
+        programs slice the planes out of ``(nb, slots)`` and compile for
+        six to nine minutes at 2**28, PERF.md.)"""
+        if self._planar and isinstance(block, dict) and "ovf_b" in block:
+            ovf, unused = block["ovf_b"], np.uint32(0xFFFFFFFF)
+            # writers fill the list from the front: one look settles
+            # a list that has pairs, a scan only one that seems empty
+            if not (ovf[:1] != unused).any() and not (ovf != unused).any():
+                block = {k: v for k, v in block.items()
+                         if k not in ("ovf_b", "ovf_r")}
+        return jax.device_put(block)
 
     def state_pytree(self):
-        return {"slots": self.slots, "t": np.int64(self.t)}
+        slots = self._table
+        if isinstance(slots, tbl.PlaneTable):
+            # the checkpoint holds (nb, val_len): planes are stacked on
+            # the host, where the bytes go anyway, not on the device
+            slots = np.asarray(slots)
+        return {"slots": slots, "t": np.int64(self.t)}
 
     def restore_pytree(self, state) -> None:
         slots = state["slots"]
@@ -368,9 +442,9 @@ class TableCheckpoint:
         single-device steps want it there) while a mesh step returns it
         replicated over the mesh: the same double compile as in
         _step_operand, so commit it at its first mesh step."""
-        if not isinstance(getattr(self.slots, "sharding", None),
-                          NamedSharding):
-            self.slots = jax.device_put(self.slots, self.rt.replicated())
+        slots = self._stacked()
+        if not isinstance(getattr(slots, "sharding", None), NamedSharding):
+            self.slots = jax.device_put(slots, self.rt.replicated())
         return self.slots
 
     def _macc_buf(self):
@@ -443,7 +517,6 @@ class ShardedStore(TableCheckpoint):
         if self.dtype not in (jnp.float32, jnp.bfloat16):
             raise ValueError(f"param_dtype {cfg.param_dtype!r}: want "
                              "float32 or bfloat16")
-        from wormhole_tpu.ops.tilemm import TILE
         nb = cfg.num_buckets
         # crossings of the table's format (scope "table_cross"); a learner
         # that owns this store reads it as its own timer
@@ -454,8 +527,7 @@ class ShardedStore(TableCheckpoint):
         # lays out four wide. Every other path asks _stacked() for the
         # (nb, val_len) array and gets it, counted; a table on a mesh or in
         # bfloat16 stays stacked and the tile steps slice it as they go.
-        self._planar = ((runtime is None or runtime.mesh.size == 1)
-                        and self.dtype == jnp.float32 and nb % TILE == 0)
+        self._planar = self.can_be_planar(runtime, self.dtype, nb)
         if self._planar:
             self._table = jax.jit(
                 lambda: tbl.PlaneTable(tbl.split(handle.init(nb))))()
@@ -465,56 +537,6 @@ class ShardedStore(TableCheckpoint):
         self._step = self._build_step()
         self._eval = self._build_eval()
         self.t = 1  # global update counter (SGD eta schedule)
-
-    # -- the table and its two forms (learners/table.py) --------------------
-
-    @property
-    def slots(self):
-        """The table as it stands: a ``(nb, val_len)`` array, or a
-        :class:`~wormhole_tpu.learners.table.PlaneTable` that answers the
-        same reads (shape, dtype, astype, indexing, ``np.asarray``,
-        ``jax.block_until_ready``) from its planes. Assigning a plain
-        array is always legal; the next tile step takes it across."""
-        return self._table
-
-    @slots.setter
-    def slots(self, table) -> None:
-        self._table = table
-
-    def _cross(self, convert) -> None:
-        """Change the table's form: a pass over the whole table, counted
-        (calls and seconds) under ``table_cross`` in the timer and, as
-        every timer scope, in the trace. A run whose window shows none
-        never rebuilt the table."""
-        with self.timer.scope("table_cross"):
-            self._table = jax.block_until_ready(convert(self._table))
-
-    def _stacked(self) -> jax.Array:
-        """The table as one ``(nb, val_len)`` array, for every path but
-        the single-device tile steps; it stays so until one of those
-        runs."""
-        if isinstance(self._table, tbl.PlaneTable):
-            self._cross(tbl.to_stacked)
-        return self._table
-
-    def _tile_table(self):
-        """The table as the single-device tile steps take it: planes
-        where this store keeps them (``_planar``)."""
-        if self._planar and not isinstance(self._table, tbl.PlaneTable):
-            self._cross(tbl.to_planes)
-        return self._table
-
-    def _mesh_table(self):
-        self._stacked()
-        return super()._mesh_table()
-
-    def state_pytree(self):
-        state = super().state_pytree()
-        if isinstance(self._table, tbl.PlaneTable):
-            # the checkpoint holds (nb, val_len): planes are stacked on
-            # the host, where the bytes go anyway, not on the device
-            state["slots"] = np.asarray(self._table)
-        return state
 
     def with_num_buckets(self, nb: int) -> "ShardedStore":
         """A fresh store over the same config/handle/runtime at ``nb``
@@ -828,23 +850,6 @@ class ShardedStore(TableCheckpoint):
     # planes (_planar) they are the steps' state as they are: the in-place
     # FTRL variant touches the table only inside its kernel, the others in
     # one elementwise pass over the planes and the gradient.
-
-    def put_block(self, block):
-        """Ship one tile block's host arrays to the device (the feed's
-        ``device_put``). Where the table is planes, an overflow list
-        with no pair in it stays behind: the block then takes the tile
-        step that has no spill to scatter, which for FTRL is the
-        in-place one. (A stacked table keeps its one step: its no-spill
-        programs slice the planes out of ``(nb, slots)`` and compile for
-        six to nine minutes at 2**28, PERF.md.)"""
-        if self._planar and isinstance(block, dict) and "ovf_b" in block:
-            ovf, unused = block["ovf_b"], np.uint32(0xFFFFFFFF)
-            # writers fill the list from the front: one look settles
-            # a list that has pairs, a scan only one that seems empty
-            if not (ovf[:1] != unused).any() and not (ovf != unused).any():
-                block = {k: v for k, v in block.items()
-                         if k not in ("ovf_b", "ovf_r")}
-        return jax.device_put(block)
 
     def _tile_step(self, info, kind: str, spill: bool = True):
         """The jitted single-device tile step for a block geometry:

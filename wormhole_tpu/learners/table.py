@@ -1,19 +1,38 @@
 """The parameter table's format on the device — the one module that owns it.
 
-A table-backed store holds ``slots`` values a bucket (FTRL: w, z, cg). Two
-forms exist, and this module is the door between them:
+A table-backed store holds ``slots`` values a bucket (FTRL: w, z, cg; FM:
+w, v_1..v_k and their AdaGrad accumulators). Two forms exist, and this
+module is the door between them:
 
   * stacked — one ``(nb, slots)`` array: what the sparse step, the v1 dense
-    step, the mesh steps, serving, the pager and the checkpoint read;
+    step, the mesh steps, serving, the pager, ``save_model``/``load_model``
+    and the checkpoint read;
   * planar — one float32 ``(T, A_HI, B_LO)`` plane a slot (:class:`PlaneTable`):
     the tile kernels' own layout (ops/tilemm.py), so a tile step hands the
-    planes to ``pallas_call`` as they are, aliased onto its outputs, and gets
-    the next step's state back — no slice, no stack, no padding lane (the
-    compiler lays ``f32[nb, 3]`` out four wide).
+    planes to ``pallas_call`` as they are and gets the next step's state
+    back — no slice, no stack, no transpose, no padding lane (the compiler
+    lays ``f32[nb, 3]`` out four wide, ``f32[nb, 18]`` twenty-four).
 
-A (T, A_HI, B_LO) plane and the flat ``(nb,)`` column are the same bytes:
-reshapes between them are free. Crossing between the FORMS is a pass over the
-whole table; the store that crosses counts it (``ShardedStore._cross``).
+Which stores keep planes: ``ShardedStore`` (learners/store.py) and ``FMStore``
+(models/fm.py), each when it can see that it can — one device, a float32
+table, whole tiles (``TableCheckpoint.can_be_planar``). FTRL's in-place
+kernel updates its three planes itself, aliased onto its outputs, and so does
+FM's with its 2(1+k) (the bfloat16 operand [w, v, Σv²] is put together in
+VMEM from the w and v tiles); a block with a COO overflow list takes the
+kernel that writes the gradient (FM: a push plane a channel) and ONE
+elementwise pass over planes onto the donated state. The dense-tower store
+(models/wide_deep.py), a table on a mesh and a bfloat16 table stay stacked.
+
+Which paths cross: every one in the first list asks the store's
+``_stacked()`` (the pager through ``PagedStore._table``) and the
+single-device tile steps ask ``_tile_table()`` (``TableCheckpoint``, shared
+by the stores); anything that writes through
+``PlaneTable.at`` gets the stacked form as well. A (T, A_HI, B_LO) plane and
+the flat ``(nb,)`` column are the same bytes: reshapes between them are
+free. Crossing between the FORMS is a pass over the whole table; the store
+that crosses counts it (``TableCheckpoint._cross``, timer scope
+``table_cross``). The checkpoint does not cross: planes are stacked on the
+host, where the bytes go anyway.
 """
 
 from __future__ import annotations
@@ -23,6 +42,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from wormhole_tpu.ops.tilemm import A_HI, B_LO, TILE
+
+
+def plane_shape(nb: int) -> tuple:
+    """A plane of ``nb`` buckets."""
+    return (nb // TILE, A_HI, B_LO)
 
 
 def split(stacked: jax.Array) -> tuple:
@@ -37,6 +61,21 @@ def join(planes) -> jax.Array:
     """The stacked ``(nb, slots)`` table of the planes (traceable; a slice
     of the result folds back to the plane it came from)."""
     return jnp.stack([p.reshape(-1) for p in planes], axis=-1)
+
+
+def planes_of(table) -> tuple:
+    """The float32 planes a tile step computes on: a planar table's own,
+    or a stacked table's, sliced inside the step (traceable)."""
+    if isinstance(table, PlaneTable):
+        return table.planes
+    return split(table.astype(jnp.float32))
+
+
+def table_like(planes, like):
+    """``planes`` in the form (and dtype) of the table ``like``."""
+    if isinstance(like, PlaneTable):
+        return PlaneTable(planes)
+    return join(planes).astype(like.dtype)
 
 
 @jax.jit
@@ -78,6 +117,11 @@ class PlaneTable:
     @property
     def dtype(self):
         return self.planes[0].dtype
+
+    @property
+    def sharding(self):
+        """Where the table lives: every plane is on the one device."""
+        return self.planes[0].sharding
 
     def astype(self, dtype) -> "PlaneTable":
         if jnp.dtype(dtype) == self.dtype:

@@ -5,7 +5,9 @@ Criteo — stretch param-server to TPU embedding tables"): second-order FM
 over the same hashed-bucket key space as the linear learner. Each bucket
 row holds ``[w, v_1..v_k, cg_w, cg_v1..cg_vk]`` — a weight, a k-dim latent
 factor, and their AdaGrad accumulators — so the "parameter server" is now a
-genuine sharded embedding table over the ``model`` mesh axis.
+genuine sharded embedding table over the ``model`` mesh axis (on one device,
+where the tile kernels step it, kept as one plane a channel in their layout:
+learners/table.py).
 
 Forward (Rendle 2010):  margin = Σ wᵢxᵢ + ½ Σ_f [(Σᵢ v_{if}xᵢ)² − Σᵢ v²_{if}x²ᵢ]
 
@@ -28,6 +30,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from wormhole_tpu.data.feed import SparseBatch
+from wormhole_tpu.learners import table as tbl
 from wormhole_tpu.learners.store import (TableCheckpoint,
                                           mesh_ovf_zeros,
                                           mesh_step_ici_bytes,
@@ -38,6 +41,7 @@ from wormhole_tpu.ops.metrics import accuracy, auc
 from wormhole_tpu.ops.penalty import L1L2
 from wormhole_tpu.ops.spmv import spmv_times
 from wormhole_tpu.parallel.mesh import MeshRuntime
+from wormhole_tpu.utils.timer import Timer
 
 
 @dataclass
@@ -62,6 +66,54 @@ class FMConfig:
                                      # off (tilemm.resolve_step_kernel)
 
 
+@dataclass(frozen=True)
+class FMAdaGrad:
+    """The tile path's update of a bucket's channels, elementwise over
+    arrays of one shape — whole (T, A_HI, B_LO) planes in XLA, or a tile
+    of each inside the kernel (hashable: the kernel builder caches on
+    it). AdaGrad + L1L2 prox on w (the rule of AdaGradHandle), AdaGrad
+    with weight decay on v, on the buckets the block touched (the count
+    channel) and no others."""
+    lr_alpha: float
+    lr_beta: float
+    l2_v: float
+    penalty: L1L2
+
+    def __call__(self, theta, cg, push, one):
+        """(w, v_1..v_k), their accumulators and the k+2 pushes
+        [Σdual, Σdual·s_j.., count] -> (theta', cg'). ``one`` is
+        ``opaque_one(...)`` of a value the compiler cannot see through
+        (a float it loads, not a compare it made): every product that
+        feeds an add is ``*one``-guarded, so the kernel and XLA round it
+        alike (FTRLHandle.update's contract; ops/loss.opaque_one)."""
+        (w, *vs), (cg_w, *cg_vs) = theta, cg
+        g_w, touched = push[0], push[-1] > 0
+
+        def adagrad(acc, g):
+            acc = jnp.where(
+                touched, jnp.sqrt((acc * acc) * one + (g * g) * one), acc)
+            return acc, self.lr_alpha / (self.lr_beta + acc)
+
+        cg_w, eta = adagrad(cg_w, g_w)
+        theta_new = [jnp.where(
+            touched,
+            self.penalty.solve((w / eta) * one - g_w, 1.0 / eta), w)]
+        cg_new = [cg_w]
+        for v, acc, p in zip(vs, cg_vs, push[1:-1]):
+            # p - v*g_w + l2_v*v as ONE guarded product: XLA folds the
+            # guard of a constant's product into the constant
+            # ((l2_v*v)*one -> v*(l2_v*one)), which leaves it bare
+            g_v = p - (v * (g_w - self.l2_v)) * one
+            acc, eta = adagrad(acc, g_v)
+            theta_new.append(jnp.where(touched, v - (eta * g_v) * one, v))
+            cg_new.append(acc)
+        return tuple(theta_new), tuple(cg_new)
+
+
+# step_kernel's second field when the fused tile step is the in-place one
+IN_PLACE = "in place: the AdaGrad update runs inside the kernel"
+
+
 def fm_margin(theta: jax.Array, batch: SparseBatch) -> jax.Array:
     """theta (kpad, 1+k): col 0 = w, cols 1: = v. Returns (mb,) margins."""
     w = theta[:, 0]
@@ -82,13 +134,34 @@ class FMStore(TableCheckpoint):
         self.cfg = cfg
         self.rt = runtime
         self.objv_fn, self.dual_fn = create_loss(cfg.loss)
-        k = cfg.dim
+        k, nb = cfg.dim, cfg.num_buckets
         rng = np.random.default_rng(cfg.seed)
-        slots = np.zeros((cfg.num_buckets, 2 * (1 + k)), np.float32)
         # v must break symmetry; w and accumulators start at 0
-        slots[:, 1:1 + k] = (cfg.init_scale
-                             * rng.standard_normal((cfg.num_buckets, k)))
-        self.slots = shard_param_table(jnp.asarray(slots), runtime)
+        v0 = (cfg.init_scale * rng.standard_normal((nb, k))).astype(
+            np.float32)
+        # crossings of the table's format (scope "table_cross"), as
+        # ShardedStore counts them
+        self.timer = Timer()
+        # One device and whole tiles: the tile steps take this table as
+        # one float32 (T, A_HI, B_LO) plane a channel (w, v_1..v_k, cg_w,
+        # cg_v_1..k; learners/table.py), the multi-channel kernel's own
+        # layout, so it is built so and the step never re-forms it. Every
+        # other path asks _stacked() for (nb, 2(1+k)) and gets it, counted.
+        self._planar = self.can_be_planar(runtime, np.float32, nb)
+        if self._planar:
+            shape = tbl.plane_shape(nb)
+
+            def zeros(n):      # a buffer each: the steps donate them
+                return [jnp.zeros(shape, jnp.float32) for _ in range(n)]
+
+            self.slots = tbl.PlaneTable(
+                zeros(1) + [jnp.asarray(col.reshape(shape))
+                            for col in np.ascontiguousarray(v0.T)]
+                + zeros(1 + k))
+        else:
+            slots = np.zeros((nb, 2 * (1 + k)), np.float32)
+            slots[:, 1:1 + k] = v0
+            self.slots = shard_param_table(jnp.asarray(slots), runtime)
         self._step = self._build_step()
         self._eval = self._build_eval()
         self.t = 1
@@ -145,7 +218,7 @@ class FMStore(TableCheckpoint):
     # -- pull-only serving surface (serve/forward.py; see ShardedStore) -----
 
     def serve_params(self):
-        return {"slots": self.slots}
+        return {"slots": self._stacked()}
 
     def build_serve_margin(self):
         k = self.cfg.dim
@@ -181,12 +254,31 @@ class FMStore(TableCheckpoint):
     # row-side push channel (dual·s_j) and a bucket-side correction
     # (v_j ⊙ push(dual)) computed OUTSIDE the kernel; a row-mask "count"
     # channel gives the exact touched-bucket set, so update masking
-    # matches the sparse path's update-only-batch-keys semantics. This is
+    # matches the sparse path's update-only-batch-keys semantics.
     # (crec2 used to reject FM; the reference served every model from
-    # one data path, async_sgd.h:84-117).
+    # one data path, async_sgd.h:84-117.)
+    #
+    # On one device the table is 2(1+k) float32 channel planes in the
+    # kernels' (T, A_HI, B_LO) layout (learners/table.py) and the step
+    # never re-forms it: the fused kernel reads the w and v planes and
+    # rounds the operand [w, v, Σv²] tile by tile in VMEM (Σv² summed in
+    # float32 from the unrounded v, then rounded), and applies AdaGrad
+    # (FMAdaGrad) to the touched buckets of each tile from the tile's
+    # push accumulator, all planes aliased onto its outputs. A block
+    # with an overflow list needs the pushes in HBM for the COO scatter:
+    # its kernel writes a push plane a channel, and ONE elementwise pass
+    # over planes updates the donated state. A list with no pair in it
+    # stays on the host (put_block), so its block takes the first step.
 
-    def _tile_step(self, info, kind: str):
-        key = (info, kind)
+    def _tile_step(self, info, kind: str, spill: bool = True):
+        """The jitted single-device tile step for a block geometry:
+        ``step(table, block, t, tau, macc)`` (train) or ``step(table,
+        block)`` (eval). ``spill``: the block brings a COO overflow
+        list. Every variant computes on the float32 (T, A_HI, B_LO)
+        channel planes; a planar table IS those planes and is returned
+        as such, a stacked one is sliced into them and stacked again
+        inside the step (ShardedStore._tile_step's contract)."""
+        key = (info, kind, spill)
         fn = getattr(self, "_tile_cache", {}).get(key)
         if fn is not None:
             self.step_kernel = self._tile_kernel[key]
@@ -197,14 +289,18 @@ class FMStore(TableCheckpoint):
         cfg = self.cfg
         k = cfg.dim
         objv_fn, dual_fn = self.objv_fn, self.dual_fn
-        penalty = L1L2(cfg.l1, cfg.l2)
+        adagrad = FMAdaGrad(cfg.lr_alpha, cfg.lr_beta, cfg.l2_v,
+                            L1L2(cfg.l1, cfg.l2))
         spec = info.spec
-        oc = info.ovf_cap
+        oc = info.ovf_cap if spill else 0
         res = tilemm.resolve_step_kernel(
             getattr(cfg, "tile_step_kernel", "auto"), ovf_cap=oc,
             spec=spec, channels=k + 2,
             onehot_cache=getattr(cfg, "tile_onehot_cache", "auto"))
         fused = res.kernel == "fused" and kind == "train"
+        # without an overflow list the update runs inside the kernel, a
+        # tile at a time (the COO scatter needs the pushes in HBM)
+        in_place = fused and oc == 0 and jax.process_count() == 1
 
         def decode(block):
             lab_u8 = block["labels"]
@@ -214,106 +310,117 @@ class FMStore(TableCheckpoint):
             ovf_r = block["ovf_r"] if oc else None
             return block["pw"], labels, row_mask, ovf_b, ovf_r
 
-        def make_wpull(s32):
-            w, v = s32[:, 0], s32[:, 1:1 + k]
-            return jnp.concatenate(
-                [w[:, None], v, jnp.sum(v * v, 1, keepdims=True)], axis=1)
-
-        def forward(s32, block):
+        def forward(planes, block):
+            # the split kernel pair's forward half: the operand is ONE
+            # XLA op over the w and v planes (fm_operand, which the fused
+            # step runs tile by tile in VMEM)
             pw, labels, row_mask, ovf_b, ovf_r = decode(block)
-            pulls = tilemm.forward_pulls(pw, make_wpull(s32), spec,
-                                         ovf_b, ovf_r)
+            one = opaque_one(row_mask)
+            theta = planes[:1 + k]
+            pulls = tilemm.plane_pulls(
+                pw, tilemm.fm_operand(theta[0], theta[1:], one), spec)
+            if oc:
+                pulls = pulls + tilemm.fm_spill_pull_rows(
+                    theta, ovf_b, ovf_r, spec, one)
             s = pulls[:, 1:1 + k]
             # same guarded channel-by-channel sum the fused kernel runs
             # at its phase boundary — keeps split/fused margins bitwise
             margin = tilemm.fm_margin_math(
                 pulls[:, 0], [s[:, j] for j in range(k)], pulls[:, 1 + k],
-                opaque_one(row_mask))
+                one)
             return pw, labels, row_mask, ovf_b, ovf_r, s, margin
 
-        def update(s32, push, margin, labels, row_mask, slots, t, macc):
-            # everything downstream of the push buffer — structurally
-            # identical XLA in the fused and split programs, so the
-            # update/metric bits agree between them
-            theta, cg = s32[:, :1 + k], s32[:, 1 + k:]
-            w, v = theta[:, 0], theta[:, 1:]
+        def update(table, planes, push, margin, labels, row_mask, t, macc):
+            # everything downstream of the push planes: ONE elementwise
+            # pass, 10 push and 18 state planes in, 18 out onto the
+            # donated state — AdaGrad on the touched buckets (the count
+            # channel) and the progress number. The same guarded
+            # FMAdaGrad the in-place kernel runs on tiles, so the table's
+            # bits agree between every variant. Its ``one`` comes from a
+            # push plane: one made of row_mask is a compare in this same
+            # program, which the CPU compiler folds to 1.0, guards and all
+            theta_new, cg_new = adagrad(planes[:1 + k], planes[1 + k:],
+                                        push, opaque_one(push[-1]))
+            d0 = theta_new[0] - planes[0]
+            return finish(tbl.table_like(theta_new + cg_new, table),
+                          jnp.sum(d0 * d0), margin, labels, row_mask, t,
+                          macc)
+
+        def finish(new, wdelta2, margin, labels, row_mask, t, macc):
+            # the metric tail: identical ops downstream of the margins in
+            # every variant
             objv = objv_fn(margin, labels, row_mask)
-            g_w = push[:, 0]
-            touched = push[:, 1 + k] > 0
-            g_v = push[:, 1:1 + k] - v * g_w[:, None] \
-                + cfg.l2_v * v * touched[:, None]
-            grads = jnp.concatenate([g_w[:, None], g_v], axis=1)
-            cg_new = jnp.where(touched[:, None],
-                               jnp.sqrt(cg * cg + grads * grads), cg)
-            eta = cfg.lr_alpha / (cfg.lr_beta + cg_new)
-            w_new = penalty.solve(w / eta[:, 0] - g_w, 1.0 / eta[:, 0])
-            v_new = v - eta[:, 1:] * g_v
-            theta_new = jnp.where(
-                touched[:, None],
-                jnp.concatenate([w_new[:, None], v_new], axis=1),
-                theta)
-            new = jnp.concatenate([theta_new, cg_new], axis=1)
             num_ex = jnp.sum(row_mask)
             from wormhole_tpu.ops.metrics import accuracy
             acc = accuracy(labels, margin, row_mask)
             pos, neg = margin_hist(labels, margin, row_mask)
-            d0 = theta_new[:, 0] - w
             packed = jnp.concatenate([
-                jnp.stack([objv, num_ex, acc, jnp.sum(d0 * d0)]),
-                pos, neg])
+                jnp.stack([objv, num_ex, acc, wdelta2]), pos, neg])
             # num_ex = completion ticket; the clock/macc outputs are
             # donated into the next step (see ShardedStore._tile_step)
-            return (new.astype(slots.dtype), t + 1, macc + packed,
-                    num_ex)
+            return new, t + 1, macc + packed, num_ex
 
-        if fused and oc:
-            # fused spill branch: pre-aggregated spill pulls ride into
-            # the kernel as an extra grid operand (summed into the
-            # boundary pulls); the kernel emits the (rows, ch) dual
-            # channels so the spill pairs' pushes scatter in XLA
+        if in_place:
+            # all 2(1+k) planes go into the kernel as they are, aliased
+            # onto its outputs: phase 1 rounds the operand from the w and
+            # v tiles, phase 2 updates each tile from its accumulator.
+            # Neither the pushes nor anything else table-sized exists
+            # outside the call (chip: 74.7 ms a step against 81.1 with
+            # the update as an XLA pass, PERF.md section 6, PR 33)
             @partial(jax.jit, donate_argnums=(0, 2, 4))
-            def step(slots, block, t, tau, macc):
-                s32 = slots.astype(jnp.float32)
-                pw, labels, row_mask, ovf_b, ovf_r = decode(block)
-                wpull = make_wpull(s32)
-                sp = tilemm.spill_pull_rows(wpull, ovf_b, ovf_r, spec)
-                margin, push, dv = tilemm.fused_fm_step(
-                    pw, wpull, labels, row_mask, spec, k, cfg.loss,
-                    spill_pulls=sp)
-                push = tilemm.spill_push_scatter(push, dv, ovf_b,
-                                                 ovf_r, spec)
-                return update(s32, push, margin, labels, row_mask,
-                              slots, t, macc)
-        elif fused:
-            @partial(jax.jit, donate_argnums=(0, 2, 4))
-            def step(slots, block, t, tau, macc):
-                s32 = slots.astype(jnp.float32)
+            def step(table, block, t, tau, macc):
                 pw, labels, row_mask, _ovf_b, _ovf_r = decode(block)
-                margin, push = tilemm.fused_fm_step(
-                    pw, make_wpull(s32), labels, row_mask, spec, k,
-                    cfg.loss)
-                return update(s32, push, margin, labels, row_mask,
-                              slots, t, macc)
+                margin, new, wdelta2 = tilemm.fused_fm_step_update(
+                    pw, tbl.planes_of(table), labels, row_mask, spec, k,
+                    cfg.loss, adagrad)
+                return finish(tbl.table_like(new, table), wdelta2, margin,
+                              labels, row_mask, t, macc)
+        elif fused:
+            # the kernel reads the w and v planes and writes a push plane
+            # a channel, for the one update pass in XLA. With an overflow
+            # list the pre-aggregated spill pulls ride in as an extra grid
+            # operand (summed into the boundary pulls) and the kernel
+            # emits the (rows, ch) dual channels, so the spill pairs'
+            # pushes scatter in XLA first
+            @partial(jax.jit, donate_argnums=(0, 2, 4))
+            def step(table, block, t, tau, macc):
+                planes = tbl.planes_of(table)
+                pw, labels, row_mask, ovf_b, ovf_r = decode(block)
+                theta = planes[:1 + k]
+                if oc:
+                    sp = tilemm.fm_spill_pull_rows(
+                        theta, ovf_b, ovf_r, spec, opaque_one(row_mask))
+                    margin, push, dv = tilemm.fused_fm_step(
+                        pw, theta, labels, row_mask, spec, k, cfg.loss,
+                        spill_pulls=sp)
+                    push = tilemm.spill_push_scatter_planes(
+                        push, dv, ovf_b, ovf_r, spec)
+                else:
+                    margin, push = tilemm.fused_fm_step(
+                        pw, theta, labels, row_mask, spec, k, cfg.loss)
+                return update(table, planes, push, margin, labels,
+                              row_mask, t, macc)
         elif kind == "train":
             @partial(jax.jit, donate_argnums=(0, 2, 4))
-            def step(slots, block, t, tau, macc):
-                s32 = slots.astype(jnp.float32)
+            def step(table, block, t, tau, macc):
+                planes = tbl.planes_of(table)
                 (pw, labels, row_mask, ovf_b, ovf_r, s,
-                 margin) = forward(s32, block)
+                 margin) = forward(planes, block)
                 dual = dual_fn(margin, labels, row_mask)
                 dvals = jnp.concatenate(
                     [dual[:, None], dual[:, None] * s,
                      row_mask[:, None]], axis=1)
-                push = tilemm.backward_pushes(pw, dvals, spec,
-                                              ovf_b, ovf_r)
-                return update(s32, push, margin, labels, row_mask,
-                              slots, t, macc)
+                push = tilemm.plane_pushes(pw, dvals, spec)
+                if oc:
+                    push = tilemm.spill_push_scatter_planes(
+                        push, dvals, ovf_b, ovf_r, spec)
+                return update(table, planes, push, margin, labels,
+                              row_mask, t, macc)
         else:
             @jax.jit
-            def step(slots, block):
-                s32 = slots.astype(jnp.float32)
+            def step(table, block):
                 (_, labels, row_mask, _, _, _,
-                 margin) = forward(s32, block)
+                 margin) = forward(tbl.planes_of(table), block)
                 objv = objv_fn(margin, labels, row_mask)
                 num_ex = jnp.sum(row_mask)
                 from wormhole_tpu.ops.metrics import accuracy
@@ -331,7 +438,8 @@ class FMStore(TableCheckpoint):
                 "onehot_cache=off:eval is forward-only")
         else:
             self._tile_kernel[key] = ("fused" if fused else "split",
-                                      res.why, res.cache_record)
+                                      IN_PLACE if in_place else res.why,
+                                      res.cache_record)
         self.step_kernel = self._tile_kernel[key]
         self._tile_cache[key] = step
         return step
@@ -490,33 +598,34 @@ class FMStore(TableCheckpoint):
         """Fused crec2-block FM step; metrics accumulate ON DEVICE
         (fetch_metrics, same harvest pipeline as ShardedStore). Returns
         the non-donated completion ticket, never the clock."""
-        step = self._tile_step(info, "train")
+        step = self._tile_step(info, "train", "ovf_b" in block)
         if self.step_kernel[0] == "fused":
             from wormhole_tpu.obs import trace
             with trace.span("tilemm:fused_multi", cat="tile"):
                 self.slots, t_new, self._macc, ticket = step(
-                    self.slots, block, self._t_device(),
+                    self._tile_table(), block, self._t_device(),
                     self._tau_const(tau), self._macc_buf())
         else:
             self.slots, t_new, self._macc, ticket = step(
-                self.slots, block, self._t_device(), self._tau_const(tau),
-                self._macc_buf())
+                self._tile_table(), block, self._t_device(),
+                self._tau_const(tau), self._macc_buf())
         self._advance_t(t_new)
         return ticket
 
     def tile_eval_step(self, block: dict, info):
-        return self._tile_step(info, "eval")(self.slots, block)
+        return self._tile_step(info, "eval", "ovf_b" in block)(
+            self._tile_table(), block)
 
     # -- ShardedStore surface ------------------------------------------------
 
     def train_step(self, batch: SparseBatch, tau: float = 0.0):
         self.slots, t_new, metrics = self._step(
-            self.slots, batch, self._t_device(), self._tau_const(tau))
+            self._stacked(), batch, self._t_device(), self._tau_const(tau))
         self._advance_t(t_new)
         return metrics
 
     def eval_step(self, batch: SparseBatch):
-        return self._eval(self.slots, batch)
+        return self._eval(self._stacked(), batch)
 
     def nnz_weight(self) -> int:
         return int(jnp.sum(self.slots[:, 0] != 0))
@@ -529,17 +638,17 @@ class FMStore(TableCheckpoint):
         if rank is None:
             rank = jax.process_index()
         k = self.cfg.dim
-        arr = np.asarray(self.slots[:, :1 + k])
+        arr = np.asarray(self._stacked()[:, :1 + k])
         np.savez_compressed(f"{path}_{rank}.npz", w=arr[:, 0],
                             v=arr[:, 1:])
 
     def load_model(self, path: str, expect_key_fold: str = "") -> None:
         data = np.load(path)
-        slots = np.array(self.slots)
+        like = self._stacked()
+        slots = np.array(like)
         slots[:, 0] = data["w"]
         slots[:, 1:1 + self.cfg.dim] = data["v"]
-        self.slots = jax.device_put(jnp.asarray(slots),
-                                    self.slots.sharding)
+        self.slots = jax.device_put(jnp.asarray(slots), like.sharding)
 
 
 def main(argv=None) -> int:

@@ -823,7 +823,10 @@ def _multi_spec(spec: TileSpec, ch: int) -> TileSpec:
 
 
 @lru_cache(maxsize=None)
-def _build_fwd_multi(spec: TileSpec, ch: int):
+def _build_fwd_multi(spec: TileSpec, ch: int, tiled: bool = False):
+    """``tiled``: ``w`` is the kernel's own operand already, the
+    bfloat16 (T, A_HI, ch*B_LO) tiles (a table kept as channel planes,
+    learners/table.py, forms it without a transpose)."""
     spec = _multi_spec(spec, ch)
     T, TB = spec.tiles, spec.tiles_step
     SG, N, S = spec.subblocks // spec.group, spec.n, spec.subblocks
@@ -831,8 +834,9 @@ def _build_fwd_multi(spec: TileSpec, ch: int):
     @jax.jit
     def fwd(pw, w):
         # (nb, ch) -> (T, A_HI, ch*B_LO): channel-major contiguous lanes
-        wt = (w.reshape(T, A_HI, B_LO, ch).transpose(0, 1, 3, 2)
-              .reshape(T, A_HI, ch * B_LO).astype(jnp.bfloat16))
+        wt = w if tiled else (
+            w.reshape(T, A_HI, B_LO, ch).transpose(0, 1, 3, 2)
+            .reshape(T, A_HI, ch * B_LO).astype(jnp.bfloat16))
         mg = pl.pallas_call(
             partial(_fwd_multi_kernel, spec, ch),
             grid=(T // TB,),
@@ -854,7 +858,9 @@ def _build_fwd_multi(spec: TileSpec, ch: int):
 
 
 @lru_cache(maxsize=None)
-def _build_bwd_multi(spec: TileSpec, ch: int):
+def _build_bwd_multi(spec: TileSpec, ch: int, tiled: bool = False):
+    """``tiled``: the pushes stay as the kernel wrote them, float32
+    (T, A_HI, ch*B_LO) tiles (a channel is a lane slice)."""
     spec = _multi_spec(spec, ch)
     T, TB = spec.tiles, spec.tiles_step
     SG, N, S = spec.subblocks // spec.group, spec.n, spec.subblocks
@@ -882,6 +888,8 @@ def _build_bwd_multi(spec: TileSpec, ch: int):
                 vmem_limit_bytes=100 * 1024 * 1024),
             interpret=_interpret(),
         )(pw, dg)
+        if tiled:
+            return g
         # (T, A_HI, ch*B_LO) channel-major lanes -> (nb, ch)
         return (g.reshape(T, A_HI, ch, B_LO).transpose(0, 1, 3, 2)
                 .reshape(spec.nb, ch))
@@ -939,8 +947,8 @@ def spill_grad_scatter(g: jax.Array, dual_rows: jax.Array,
 def spill_push_scatter(g: jax.Array, dual_rows: jax.Array,
                        ovf_b: jax.Array, ovf_r: jax.Array,
                        spec: TileSpec) -> jax.Array:
-    """(nb, ch) variant of spill_grad_scatter (backward_pushes' tail
-    and the fused FM spill branch)."""
+    """(nb, ch) variant of spill_grad_scatter (backward_pushes' tail;
+    the FM steps on planes use spill_push_scatter_planes)."""
     valid = ovf_b != jnp.uint32(0xFFFFFFFF)
     d = jnp.where(valid[:, None],
                   dual_rows[ovf_r.astype(jnp.int32) % spec.block_rows],
@@ -971,6 +979,75 @@ def backward_pushes(pw: jax.Array, dual_rows: jax.Array, spec: TileSpec,
     if ovf_b is not None and ovf_b.shape[0]:
         g = spill_push_scatter(g, dual_rows, ovf_b, ovf_r, spec)
     return g
+
+
+# -- the multi-channel path over a table kept as channel planes --------------
+#
+# A store that keeps one float32 (T, A_HI, B_LO) plane a channel
+# (learners/table.py) never forms the (nb, ch) array: the kernels' operand
+# is the planes' tiles side by side on the lanes, rounded (fm_operand, in
+# VMEM inside the fused step, one XLA op before the split pair), and a push
+# channel is a plane as the kernel wrote it.
+
+def fm_pull_channels(w, vs, one):
+    """FM's pull channels ``[w, v_1..v_k, Σ_j v_j²]`` of float32 ``w``
+    and ``vs`` of one shape (tiles in a kernel, planes or gathered
+    values in XLA). The sum runs over the unrounded factors in float32,
+    in order, every product ``*one``-guarded (loss.opaque_one) so that
+    it has the same bits in every context."""
+    q = (vs[0] * vs[0]) * one
+    for v in vs[1:]:
+        q = q + (v * v) * one
+    return [w, *vs, q]
+
+
+def fm_operand(w, vs, one) -> jax.Array:
+    """The FM kernels' bfloat16 operand of (..., A_HI, B_LO) float32
+    tiles: the pull channels rounded, channel-major on the lanes."""
+    return jnp.concatenate(
+        [c.astype(jnp.bfloat16) for c in fm_pull_channels(w, vs, one)],
+        axis=-1)
+
+
+def plane_pulls(pw: jax.Array, wt: jax.Array, spec: TileSpec) -> jax.Array:
+    """forward_pulls from the operand as the kernel takes it, bfloat16
+    (T, A_HI, ch*B_LO)."""
+    return _build_fwd_multi(spec, wt.shape[-1] // B_LO, True)(pw, wt)
+
+
+def plane_pushes(pw: jax.Array, dual_rows: jax.Array,
+                 spec: TileSpec) -> tuple:
+    """backward_pushes as one (T, A_HI, B_LO) plane a channel."""
+    ch = dual_rows.shape[1]
+    g = _build_bwd_multi(spec, ch, True)(pw, dual_rows)
+    return tuple(g[..., c * B_LO:(c + 1) * B_LO] for c in range(ch))
+
+
+def fm_spill_pull_rows(planes, ovf_b: jax.Array, ovf_r: jax.Array,
+                       spec: TileSpec, one) -> jax.Array:
+    """spill_pull_rows from the w and v planes: the listed buckets'
+    values are gathered plane by plane and their pull channels formed
+    from those (float32, unrounded, as the stacked path pulls them)."""
+    valid = ovf_b != jnp.uint32(0xFFFFFFFF)
+    idx = jnp.where(valid, ovf_b, 0).astype(jnp.int32)
+    got = [p.reshape(-1)[idx] for p in planes]
+    wv = jnp.where(valid[:, None],
+                   jnp.stack(fm_pull_channels(got[0], got[1:], one), axis=1),
+                   0.0)
+    return jnp.zeros((spec.block_rows, wv.shape[1]), jnp.float32).at[
+        ovf_r.astype(jnp.int32) % spec.block_rows].add(wv)
+
+
+def spill_push_scatter_planes(push, dual_rows: jax.Array, ovf_b: jax.Array,
+                              ovf_r: jax.Array, spec: TileSpec) -> tuple:
+    """spill_push_scatter into push planes, a channel at a time."""
+    valid = ovf_b != jnp.uint32(0xFFFFFFFF)
+    idx = jnp.where(valid, ovf_b, 0).astype(jnp.int32)
+    d = jnp.where(valid[:, None],
+                  dual_rows[ovf_r.astype(jnp.int32) % spec.block_rows],
+                  0.0)
+    return tuple(p.reshape(-1).at[idx].add(d[:, c]).reshape(p.shape)
+                 for c, p in enumerate(push))
 
 
 # ---------------------------------------------------------------------------
@@ -1004,7 +1081,12 @@ def backward_pushes(pw: jax.Array, dual_rows: jax.Array, spec: TileSpec,
 # the bfloat16 operand tile by tile inside phase 1; the in-place variant
 # reads that one aliased plane in both phases (index map t % nt) and
 # sums (w_new - w_old)^2, the step's progress number, where both are in
-# registers. So no XLA op around the call touches the table.
+# registers. So no XLA op around the call touches the table. The FM step
+# over channel planes has the same two forms (_build_fm_step_update, all
+# 2(1+k) planes aliased, the model's update run on each tile;
+# _build_fm_step_fused, push planes out for blocks with a spill): its
+# margins are the split pair's to the bit and so is its in-kernel update
+# (models/fm.FMAdaGrad guards its products as FTRLHandle.update does).
 #
 # Reusing the split kernel BODIES (not re-deriving them) is what makes
 # the split path a bit-parity oracle: both paths run the same bf16
@@ -1488,36 +1570,101 @@ def fm_margin_math(lin, s_parts, q, one):
     return lin + (jnp.float32(0.5) * (ss - q)) * one
 
 
+def _lane_channels(acc):
+    """The channels of an (A_HI, ch*B_LO) tile: lane slices."""
+    return [acc[:, c * B_LO:(c + 1) * B_LO]
+            for c in range(acc.shape[1] // B_LO)]
+
+
+class _PlaneSink:
+    """Stands in for ``g_ref`` when the multi-channel bwd body writes a
+    tile's (A_HI, ch*B_LO) accumulator: channel c goes to push plane c's
+    block."""
+
+    def __init__(self, refs):
+        self.refs = refs
+
+    def __setitem__(self, tb, acc):
+        for ref, x in zip(self.refs, _lane_channels(acc)):
+            ref[tb] = x
+
+
+class _UpdateSink:
+    """Stands in for ``g_ref`` in the in-place FM step: a tile's pushes
+    go from the accumulator straight into ``update`` with the tile's
+    state, and the new state into the aliased output blocks — the push
+    never reaches HBM. ``wd`` sums (w_new − w_old)² over the tiles."""
+
+    def __init__(self, update, one, theta, cg, theta_out, cg_out):
+        self.update, self.one = update, one
+        self.theta, self.cg = theta, cg
+        self.out = tuple(theta_out) + tuple(cg_out)
+        self.wd = 0.0
+
+    def __setitem__(self, tb, acc):
+        theta = [r[tb] for r in self.theta]
+        theta_new, cg_new = self.update(theta, [r[tb] for r in self.cg],
+                                        _lane_channels(acc), self.one)
+        for ref, x in zip(self.out, theta_new + cg_new):
+            ref[tb] = x
+        d = theta_new[0] - theta[0]
+        self.wd = self.wd + d * d
+
+
 def _make_fm_step_kernel(spec: TileSpec, ch: int, k: int, loss: str,
-                         nt: int, spill: bool = False):
-    """Two-phase multi-channel kernel body for the FM step: phase 1 is
-    the unmodified _fwd_multi_kernel accumulating the (S, RH, ch*RL)
-    pulls grid in VMEM scratch (it never reaches HBM at all); the
-    boundary computes the FM margin (lin + 0.5*(Σ s_j² − q), summed
-    sequentially — the split path mirrors the same order), the dual,
-    and the [dual, dual*s_j..., mask] push channels; phase 2 is the
-    unmodified _bwd_multi_kernel. ``spill`` adds (a) a pre-aggregated
-    COO spill-pulls grid operand summed into the pulls before the
-    margin (the same elementwise add the split forward_pulls runs) and
-    (b) an extra f32 output carrying the dual-channel grid, so the
-    caller can run the spill push scatter in XLA — in-kernel it is
-    bitwise what the split path's XLA dvals would be."""
+                         nt: int, spill: bool = False, update=None):
+    """Two-phase multi-channel kernel body for the FM step over a table
+    kept as channel planes. Phase 1 puts the bfloat16 operand tiles of
+    this grid step together in VMEM from the float32 w and v plane
+    blocks (fm_operand) and runs the unmodified _fwd_multi_kernel over
+    them, accumulating the (S, RH, ch*RL) pulls grid in VMEM scratch (it
+    never reaches HBM at all); the boundary computes the FM margin
+    (lin + 0.5*(Σ s_j² − q), summed sequentially — the split path
+    mirrors the same order), the dual, and the [dual, dual*s_j..., mask]
+    push channels; phase 2 is the unmodified _bwd_multi_kernel, its
+    tiles landing a channel a push plane (_PlaneSink). ``spill`` adds
+    (a) a pre-aggregated COO spill-pulls grid operand summed into the
+    pulls before the margin (the same elementwise add the split path
+    runs) and (b) an extra f32 output carrying the dual-channel grid, so
+    the caller can run the spill push scatter in XLA — in-kernel it is
+    bitwise what the split path's XLA dvals would be. ``update`` (the
+    in-place variant, no spill: the COO scatter needs the pushes in HBM)
+    is the model's elementwise update of a tile's channels: the w and v
+    planes then walk the tiles in BOTH phases, the accumulator planes in
+    phase 2, all aliased onto the outputs, and phase 2 updates each
+    tile from its accumulator (_UpdateSink) and sums (w_new − w_old)²."""
     from .loss import create_loss, opaque_one
     _, dual_fn = create_loss(loss)
+    n_theta = 1 + k
+    assert not (spill and update is not None), \
+        "spill blocks use the push-emitting variant"
 
     def kernel(*refs):
-        pw_ref, wt_ref, lab_ref, msk_ref = refs[:4]
-        rest = refs[4:]
+        pw_ref, theta, (lab_ref, msk_ref) = (
+            refs[0], refs[1:1 + n_theta], refs[1 + n_theta:3 + n_theta])
+        rest = refs[3 + n_theta:]
         if spill:
             sp_ref, rest = rest[0], rest[1:]
-            mg_ref, push_ref, dv_ref, pulls_s, dual_s = rest
+        if update is not None:
+            cg, rest = rest[:n_theta], rest[n_theta:]
+            mg_ref, theta_out, cg_out, wd_ref, rest = (
+                rest[0], rest[1:1 + n_theta],
+                rest[1 + n_theta:1 + 2 * n_theta], rest[1 + 2 * n_theta],
+                rest[2 + 2 * n_theta:])
         else:
-            mg_ref, push_ref, pulls_s, dual_s = rest
+            mg_ref, push, rest = rest[0], rest[1:1 + ch], rest[1 + ch:]
+        if spill:
+            dv_ref, rest = rest[0], rest[1:]
+        wt_s, pulls_s, dual_s = rest
         t = pl.program_id(0)
 
         @pl.when(t < nt)
         def _fwd():
-            _fwd_multi_kernel(spec, ch, pw_ref, wt_ref, pulls_s, t)
+            one = opaque_one(msk_ref[0, 0, 0])
+            for tb in range(spec.tiles_step):
+                wt_s[tb] = fm_operand(theta[0][tb],
+                                      [v[tb] for v in theta[1:]], one)
+            _fwd_multi_kernel(spec, ch, pw_ref, wt_s, pulls_s, t)
 
         @pl.when(t == nt)
         def _dual():
@@ -1542,49 +1689,68 @@ def _make_fm_step_kernel(spec: TileSpec, ch: int, k: int, loss: str,
             if spill:
                 dv_ref[...] = dv
             dual_s[...] = dv.reshape(dual_s.shape).astype(jnp.bfloat16)
+            if update is not None:
+                wd_ref[...] = jnp.zeros_like(wd_ref)
 
         @pl.when(t >= nt)
         def _bwd():
-            _bwd_multi_kernel(spec, ch, pw_ref, dual_s, push_ref)
+            if update is None:
+                _bwd_multi_kernel(spec, ch, pw_ref, dual_s, _PlaneSink(push))
+                return
+            sink = _UpdateSink(update, opaque_one(msk_ref[0, 0, 0]), theta,
+                               cg, theta_out, cg_out)
+            _bwd_multi_kernel(spec, ch, pw_ref, dual_s, sink)
+            # the progress number's partial sums, a lane apiece (summed
+            # by the wrapper)
+            wd_ref[...] += sink.wd
 
     return kernel
+
+
+def _fm_step_scratch(spec: TileSpec, ch: int):
+    """VMEM scratch of the fused FM steps: a grid step's bfloat16
+    operand tiles, the pulls grid, and the dual channels shaped as the
+    split bwd wrapper reshapes them."""
+    S, bp = spec.subblocks, _bp(spec)
+    return [pltpu.VMEM((spec.tiles_step, A_HI, ch * B_LO), jnp.bfloat16),
+            pltpu.VMEM((S, RH, ch * RL), jnp.float32),
+            pltpu.VMEM((S // bp, bp * RH, ch * RL), jnp.bfloat16)]
 
 
 @lru_cache(maxsize=None)
 def _build_fm_step_fused(spec: TileSpec, k: int, loss: str,
                          spill: bool = False):
+    """Fused FM step over channel planes: ``step(pw, theta, labels, mask
+    [, spill_pulls])`` with ``theta`` the float32 (T, A_HI, B_LO) planes
+    ``(w, v_1..v_k)`` as the table holds them -> (margins, the ch push
+    planes[, the (rows, ch) dual channels]). No XLA op on either side of
+    the call forms an operand or re-forms the pushes: nothing table-sized
+    but the call's own reads and writes."""
     ch = k + 2
     spec = _multi_spec(spec, ch)       # same compile-budget rule as split
     T, TB = spec.tiles, spec.tiles_step
     SG, N, S = spec.subblocks // spec.group, spec.n, spec.subblocks
-    bp = _bp(spec)
     nt = T // TB
     kernel = _make_fm_step_kernel(spec, ch, k, loss, nt, spill=spill)
     const_grid = pl.BlockSpec((S, RH, RL), lambda t: (0, 0, 0))
     const_wide = pl.BlockSpec((S, RH, ch * RL), lambda t: (0, 0, 0))
+    plane_shape = jax.ShapeDtypeStruct((T, A_HI, B_LO), jnp.float32)
 
     @jax.jit
-    def step(pw, wpull, labels, mask, *spill_pulls):
-        # (nb, ch) -> (T, A_HI, ch*B_LO): channel-major contiguous lanes
-        wt = (wpull.reshape(T, A_HI, B_LO, ch).transpose(0, 1, 3, 2)
-              .reshape(T, A_HI, ch * B_LO).astype(jnp.bfloat16))
-        args = [pw, wt, labels.reshape(S, RH, RL),
+    def step(pw, theta, labels, mask, *spill_pulls):
+        args = [pw, *theta, labels.reshape(S, RH, RL),
                 mask.reshape(S, RH, RL)]
-        in_specs = [
-            pl.BlockSpec((TB, SG, N), lambda t: (t % nt, 0, 0)),
-            pl.BlockSpec((TB, A_HI, ch * B_LO),
-                         lambda t: (jnp.minimum(t, nt - 1), 0, 0)),
-            const_grid, const_grid,
-        ]
-        out_specs = [
-            const_grid,
-            pl.BlockSpec((TB, A_HI, ch * B_LO),
-                         lambda t: (jnp.maximum(t - nt, 0), 0, 0)),
-        ]
-        out_shape = [
-            jax.ShapeDtypeStruct((S, RH, RL), jnp.float32),
-            jax.ShapeDtypeStruct((T, A_HI, ch * B_LO), jnp.float32),
-        ]
+        in_specs = (
+            [pl.BlockSpec((TB, SG, N), lambda t: (t % nt, 0, 0))]
+            + [pl.BlockSpec((TB, A_HI, B_LO),
+                            lambda t: (jnp.minimum(t, nt - 1), 0, 0))
+               ] * (1 + k)
+            + [const_grid, const_grid])
+        out_specs = [const_grid] + [
+            pl.BlockSpec((TB, A_HI, B_LO),
+                         lambda t: (jnp.maximum(t - nt, 0), 0, 0))] * ch
+        out_shape = ([jax.ShapeDtypeStruct((S, RH, RL), jnp.float32)]
+                     + [plane_shape] * ch)
         if spill:
             # (rows, ch) pre-aggregated spill pulls -> the channel-major
             # grid layout the pulls scratch carries
@@ -1601,25 +1767,69 @@ def _build_fm_step_fused(spec: TileSpec, k: int, loss: str,
             in_specs=in_specs,
             out_specs=out_specs,
             out_shape=out_shape,
-            scratch_shapes=[
-                pltpu.VMEM((S, RH, ch * RL), jnp.float32),
-                pltpu.VMEM((S // bp, bp * RH, ch * RL), jnp.bfloat16),
-            ],
+            scratch_shapes=_fm_step_scratch(spec, ch),
             compiler_params=None if _interpret() else pltpu.CompilerParams(
                 vmem_limit_bytes=100 * 1024 * 1024),
             interpret=_interpret(),
         )(*args)
-        mg, push = outs[0], outs[1]
-        # (T, A_HI, ch*B_LO) channel-major lanes -> (nb, ch)
-        pushes = (push.reshape(T, A_HI, ch, B_LO).transpose(0, 1, 3, 2)
-                  .reshape(spec.nb, ch))
+        mg, push = outs[0].reshape(spec.block_rows), tuple(outs[1:1 + ch])
         if spill:
             # dual-channel grid -> (rows, ch), for the caller's XLA
             # spill push scatter — the inverse of the pulls transpose
-            dv_rows = (outs[2].reshape(S, RH, ch, RL)
+            dv_rows = (outs[1 + ch].reshape(S, RH, ch, RL)
                        .transpose(0, 1, 3, 2).reshape(spec.block_rows, ch))
-            return mg.reshape(spec.block_rows), pushes, dv_rows
-        return mg.reshape(spec.block_rows), pushes
+            return mg, push, dv_rows
+        return mg, push
+
+    return step
+
+
+@lru_cache(maxsize=None)
+def _build_fm_step_update(spec: TileSpec, k: int, loss: str, update):
+    """Fused FM step, in-place variant: ``step(pw, planes, labels, mask)``
+    with all 2(1+k) planes of the table -> (margins, the new planes,
+    Σ(Δw)²). The planes go into the call as they are, aliased onto its
+    outputs; the pushes never exist in HBM and no XLA op on either side
+    reads or writes a table-sized array (FTRL's _build_step_update, for
+    ten channels). ``update`` is the model's hashable elementwise update
+    (models/fm.FMAdaGrad), run verbatim on tiles."""
+    ch = k + 2
+    spec = _multi_spec(spec, ch)
+    T, TB = spec.tiles, spec.tiles_step
+    SG, N, S = spec.subblocks // spec.group, spec.n, spec.subblocks
+    nt = T // TB
+    kernel = _make_fm_step_kernel(spec, ch, k, loss, nt, update=update)
+    const_grid = pl.BlockSpec((S, RH, RL), lambda t: (0, 0, 0))
+    both = pl.BlockSpec((TB, A_HI, B_LO), lambda t: (t % nt, 0, 0))
+    second = pl.BlockSpec((TB, A_HI, B_LO),
+                          lambda t: (jnp.maximum(t - nt, 0), 0, 0))
+    plane_shape = jax.ShapeDtypeStruct((T, A_HI, B_LO), jnp.float32)
+    n = 1 + k
+
+    @jax.jit
+    def step(pw, planes, labels, mask):
+        outs = pl.pallas_call(
+            kernel,
+            grid=(2 * nt,),
+            in_specs=([pl.BlockSpec((TB, SG, N), lambda t: (t % nt, 0, 0))]
+                      + [both] * n + [const_grid, const_grid]
+                      + [second] * n),
+            out_specs=([const_grid] + [second] * (2 * n)
+                       + [pl.BlockSpec((A_HI, B_LO), lambda t: (0, 0))]),
+            out_shape=([jax.ShapeDtypeStruct((S, RH, RL), jnp.float32)]
+                       + [plane_shape] * (2 * n)
+                       + [jax.ShapeDtypeStruct((A_HI, B_LO), jnp.float32)]),
+            input_output_aliases={
+                **{1 + i: 1 + i for i in range(n)},
+                **{3 + n + i: 1 + n + i for i in range(n)}},
+            scratch_shapes=_fm_step_scratch(spec, ch),
+            compiler_params=None if _interpret() else pltpu.CompilerParams(
+                vmem_limit_bytes=100 * 1024 * 1024),
+            interpret=_interpret(),
+        )(pw, *planes[:n], labels.reshape(S, RH, RL),
+          mask.reshape(S, RH, RL), *planes[n:])
+        return (outs[0].reshape(spec.block_rows), tuple(outs[1:1 + 2 * n]),
+                jnp.sum(outs[1 + 2 * n]))
 
     return step
 
@@ -1888,22 +2098,34 @@ def fused_step_update(pw: jax.Array, planes, labels: jax.Array,
         pw, tuple(planes), labels, mask)
 
 
-def fused_fm_step(pw: jax.Array, wpull: jax.Array, labels: jax.Array,
+def fused_fm_step(pw: jax.Array, theta, labels: jax.Array,
                   mask: jax.Array, spec: TileSpec, k: int, loss: str,
                   spill_pulls: Optional[jax.Array] = None):
-    """One-grid FM step: (margins (block_rows,), pushes (nb, k+2)) from
-    the (nb, k+2) channel table [w, v_j..., Σv²]. Neither the pulls nor
-    the dual-channel grid touches HBM; the AdaGrad update stays in XLA
-    (it is elementwise over buckets either way). With ``spill_pulls``
-    (the pre-aggregated (rows, k+2) grid from spill_pull_rows) the
-    boundary sums it into the pulls and a third result — the (rows,
-    k+2) dual-channel values — comes back for the caller's XLA
-    spill_push_scatter."""
+    """One-grid FM step: (margins (block_rows,), the k+2 push planes)
+    from ``theta``, the table's float32 (T, A_HI, B_LO) planes
+    ``(w, v_1..v_k)``; the operand [w, v_j..., Σv²] is formed in VMEM.
+    Neither the pulls nor the dual-channel grid touches HBM; the AdaGrad
+    update stays in XLA (it is elementwise over buckets either way).
+    With ``spill_pulls`` (the pre-aggregated (rows, k+2) grid from
+    fm_spill_pull_rows) the boundary sums it into the pulls and a third
+    result — the (rows, k+2) dual-channel values — comes back for the
+    caller's XLA spill_push_scatter_planes."""
+    theta = tuple(theta)
     if spill_pulls is None:
         return _build_fm_step_fused(spec, k, loss)(
-            pw, wpull, labels, mask)
+            pw, theta, labels, mask)
     return _build_fm_step_fused(spec, k, loss, True)(
-        pw, wpull, labels, mask, spill_pulls)
+        pw, theta, labels, mask, spill_pulls)
+
+
+def fused_fm_step_update(pw: jax.Array, planes, labels: jax.Array,
+                         mask: jax.Array, spec: TileSpec, k: int,
+                         loss: str, update):
+    """One-grid FM step with the update inside: (margins, the 2(1+k)
+    updated planes, Σ(Δw)²) from the table's planes, which go back as
+    the next step's state."""
+    return _build_fm_step_update(spec, k, loss, update)(
+        pw, tuple(planes), labels, mask)
 
 
 def fused_wd_step(pw: jax.Array, wpull: jax.Array, labels: jax.Array,

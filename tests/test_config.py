@@ -44,3 +44,17 @@ def test_unknown_key_raises(token):
     # unknown key is, not ignored
     with pytest.raises(ValueError):
         load_config(None, [token])
+
+
+def test_apply_kvs_parses_a_tuple_field():
+    """``hidden=1024,512,256``: the one parse of a Tuple[int, ...] field,
+    for wide&deep's CLI and the benchmark's hook alike."""
+    from wormhole_tpu.models.wide_deep import WideDeepConfig
+    from wormhole_tpu.utils.config import apply_kvs
+    cfg = WideDeepConfig()
+    apply_kvs(cfg, ["hidden=1024,512,256", "dim=32"])
+    assert cfg.hidden == (1024, 512, 256) and cfg.dim == 32
+    apply_kvs(cfg, ["hidden = 64 32"])
+    assert cfg.hidden == (64, 32)
+    apply_kvs(cfg, ["hidden="])
+    assert cfg.hidden == ()

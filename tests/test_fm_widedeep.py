@@ -121,3 +121,29 @@ def test_wide_deep_save_load(rng, tmp_path):
     for k in wd.mlp:
         np.testing.assert_allclose(np.asarray(wd2.mlp[k]),
                                    np.asarray(wd.mlp[k]), atol=1e-6)
+
+
+def test_wide_deep_cli_builds_the_app_from_tokens(tmp_path):
+    """``build_app`` is what ``main`` runs and what anything that wants the
+    same app calls (the benchmark's hook): model keys go to WideDeepConfig,
+    ``hidden`` through the one tuple parse, the rest to the driver, with
+    ``num_buckets`` mirrored."""
+    from wormhole_tpu.learners.async_sgd import AsyncSGD
+    from wormhole_tpu.models import wide_deep
+    conf = tmp_path / "wd.conf"
+    conf.write_text("data_format = crec2\nnum_buckets = 32768\n"
+                    "max_delay = 2\n")
+    app = wide_deep.build_app([str(conf), "dim=4", "hidden=16,8",
+                               "lr_alpha_dense=0.002", "cache_device=1"])
+    assert isinstance(app, AsyncSGD)
+    assert isinstance(app.store, WideDeepStore)
+    mcfg = app.store.cfg
+    assert (mcfg.dim, mcfg.hidden, mcfg.lr_alpha_dense) == (4, (16, 8), 0.002)
+    assert mcfg.num_buckets == app.cfg.num_buckets == 32768
+    assert app.cfg.max_delay == 2 and app.cfg.cache_device
+    assert app.store.mlp["W0"].shape == (4, 16)
+    assert app.store.mlp["W2"].shape == (8, 1)
+    # the store's timer is the app's: the tower's counters reach the driver
+    assert app.timer is app.store.timer
+    with pytest.raises(ValueError):
+        wide_deep.build_app([str(conf), "no_such_key=1"])

@@ -160,6 +160,12 @@ def _criteo(tiles=None):
     return tilemm.make_spec(tiles * tilemm.TILE if tiles else NB, **CRITEO)
 
 
+def _wide_deep(tiles):
+    """``criteo_wide_deep``'s geometry (cap 384 at 2**24 buckets), cut to
+    ``tiles`` tiles."""
+    return tilemm.make_spec(tiles * tilemm.TILE, subblocks=12, cap=384)
+
+
 def _narrow():
     """The geometry bench.py's cached A/B uses — one subblock of nnz=16
     rows — which ``_onehot_cache_decision`` admits under ``auto``."""
@@ -190,6 +196,10 @@ CASES = [
     _case(lambda: step_grad(_criteo(2), spill=True),
           "step_grad_spill-2tiles"),
     _case(lambda: step_update(_criteo(2)), "step_update-2tiles"),
+    # the wide&deep cell's split pair at its own per-tile widths (cap 384
+    # at 2**24, 33 channels pulled, 34 pushed, tiles_step 2), two tiles
+    _case(lambda: fwd_multi(_wide_deep(2), 33), "fwd_multi-wd32-2tiles"),
+    _case(lambda: bwd_multi(_wide_deep(2), 34), "bwd_multi-wd32-2tiles"),
     # by hand before a chip call: full geometry, and the other variants
     _case(lambda: fwd(_criteo()), "fwd-criteo", slow=True),
     _case(lambda: bwd(_criteo()), "bwd-criteo", slow=True),
@@ -331,3 +341,56 @@ def test_fm_train_step_on_planes_compiles_for_v5e(v5e):
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= 2 * (1 + k) * 4 * nb
     assert mem.temp_size_in_bytes < 4 * nb
+
+
+def test_wide_deep_train_step_compiles_with_the_stated_tower_precision(v5e):
+    """The whole one-device train step of ``WideDeepStore`` at the widths of
+    ``criteo_wide_deep`` (32 values pooled into 1024-512-256), two tiles:
+    the split kernel pair with the tower between. The tower's precision is
+    stated in the program, and the v5e compiler has to keep it: every tower
+    matmul it leaves as a convolution takes bfloat16 operands, and the
+    one-column last layer, which it turns into float32 multiplies, has its
+    operands' rounding as ``reduce-precision`` (which the compiler may not
+    drop, as it dropped that layer's ``convert`` pairs: PERF.md, PR 34):
+    the activations, the weights and the incoming gradient, once each.
+    What ``criteo_wide_deep.replay_uniform`` steps."""
+    import re
+    from wormhole_tpu.data.crec import CRec2Info
+    from wormhole_tpu.learners.store import TableCheckpoint
+    from wormhole_tpu.models.wide_deep import WideDeepConfig, WideDeepStore
+    k, hidden, nb = 32, (1024, 512, 256), 2 * tilemm.TILE
+    store = WideDeepStore(WideDeepConfig(num_buckets=nb, dim=k,
+                                         hidden=hidden))
+    info = CRec2Info(nnz=39, block_rows=12 * tilemm.RSUB,
+                     total_rows=12 * tilemm.RSUB, nb=nb, ovf_cap=1024,
+                     subblocks=12, cap=128)
+    spec = info.spec
+    step = store._tile_step(info, "train")
+    assert store.step_kernel[0] == "split" and "spill" in store.step_kernel[1]
+    one_chip = SingleDeviceSharding(v5e.devices[0])
+
+    def on(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    mlp = jax.tree.map(lambda a: on(a.shape, a.dtype), store.mlp)
+    text = step.lower(
+        on((nb, 2 * (1 + k)), jnp.float32), mlp, mlp,
+        {"pw": on(spec.pairs_shape, jnp.uint32),
+         "labels": on((spec.block_rows,), jnp.uint8),
+         "ovf_b": on((1024,), jnp.uint32), "ovf_r": on((1024,), jnp.uint32)},
+        on((), jnp.int32), on((), jnp.float32),
+        on((TableCheckpoint.MACC_LEN,), jnp.float32)).compile().as_text()
+    assert text.count("tpu_custom_call") >= 2
+    tower = [line for line in text.splitlines()
+             if " convolution(" in line and "wd_tower" in line]
+    assert len(tower) >= 7, len(tower)       # the wide layers' matmuls
+    for line in tower:
+        operands = re.search(r" convolution\(([^)]*)\)", line).group(1)
+        names = [o.strip().split(" ")[-1] for o in operands.split(",")]
+        for name in names:
+            made = re.search(r"^\s*(?:ROOT )?" + re.escape(name)
+                             + r" = (\w+)\[", text, re.M)
+            assert made and made.group(1) == "bf16", (name, line[:120])
+    rounded = re.findall(r"reduce-precision\([^)]*\), exponent_bits=8, "
+                         r"mantissa_bits=7", text)
+    assert 3 <= len(rounded) <= 6, len(rounded)

@@ -38,6 +38,7 @@ from wormhole_tpu.ops.loss import create_loss
 from wormhole_tpu.ops.metrics import accuracy, auc
 from wormhole_tpu.ops.spmv import spmv_times
 from wormhole_tpu.parallel.mesh import MeshRuntime
+from wormhole_tpu.utils.timer import Timer
 
 
 @dataclass
@@ -78,7 +79,7 @@ def init_mlp(sizes: List[int], rng: np.random.Generator):
 # The deep-tower forward lives in ops/tilemm.py beside the fused wd step,
 # whose boundary phase runs the same tower over grid-layout chunks —
 # re-exported here for the split path and external users.
-from wormhole_tpu.ops.tilemm import mlp_forward  # noqa: E402,F401
+from wormhole_tpu.ops.tilemm import mlp_forward, tower_flops  # noqa: E402,F401
 
 
 class WideDeepStore(TableCheckpoint):
@@ -89,6 +90,12 @@ class WideDeepStore(TableCheckpoint):
         self.cfg = cfg
         self.rt = runtime
         self.objv_fn, _ = create_loss(cfg.loss)
+        # a learner that owns this store reads this timer as its own
+        # (AsyncSGD): the tower's work a tile step (tower_flops,
+        # dense_param_bytes: counts, not seconds) and, as every
+        # TableCheckpoint, any crossing of the table's form (table_cross;
+        # this table stays (nb, 2(1+k)) on every path, so none)
+        self.timer = Timer()
         k = cfg.dim
         rng = np.random.default_rng(cfg.seed)
         slots = np.zeros((cfg.num_buckets, 2 * (1 + k)), np.float32)
@@ -98,6 +105,10 @@ class WideDeepStore(TableCheckpoint):
         sizes = [k] + list(cfg.hidden) + [1]
         self.mlp, self.mlp_accum = init_mlp(sizes, rng)
         self.n_layers = len(sizes) - 1
+        # float32 parameters and accumulators, each read and written once
+        # by the dense update
+        self._dense_bytes = 2 * 2 * 4 * sum(
+            a * b + b for a, b in zip(sizes, sizes[1:]))
         self._step = self._build_step()
         self._eval = self._build_eval()
         self.t = 1
@@ -238,14 +249,61 @@ class WideDeepStore(TableCheckpoint):
             ovf_r = block["ovf_r"] if oc else None
             return block["pw"], labels, row_mask, ovf_b, ovf_r
 
-        def forward(s32, mlp, block):
-            pw, labels, row_mask, ovf_b, ovf_r = decode(block)
+        # The phases are jits of their own inside the step, named for
+        # what they do, so that the device trace's ops say which phase
+        # they belong to: wd_pull, wd_tower (forward under the scope
+        # wd_tower_forward, its vjp under wd_tower_backward), wd_push,
+        # wd_table_update, wd_dense_update. A nested jit and not a bare
+        # jax.named_scope (as the mesh step has, learners/store.py): the
+        # profiler's op metadata keeps the path of an op inside a nested
+        # jit and drops a bare scope's (jit(fwd) in the kept traces). XLA
+        # inlines them: the step is one program as before.
+        @jax.jit
+        def wd_pull(s32, pw, ovf_b, ovf_r):
             w, v = s32[:, 0], s32[:, 1:1 + k]
             wpull = jnp.concatenate([w[:, None], v], axis=1)
-            pulls = tilemm.forward_pulls(pw, wpull, spec, ovf_b, ovf_r)
+            return tilemm.forward_pulls(pw, wpull, spec, ovf_b, ovf_r)
+
+        @jax.jit
+        def wd_tower(m, x):
+            return mlp_forward(m, x, n_layers)
+
+        @jax.jit
+        def wd_push(pw, dual, g_pooled, row_mask, ovf_b, ovf_r):
+            dvals = jnp.concatenate(
+                [dual[:, None], g_pooled, row_mask[:, None]], axis=1)
+            return tilemm.backward_pushes(pw, dvals, spec, ovf_b, ovf_r)
+
+        @jax.jit
+        def wd_table_update(s32, push):
+            theta, cg = s32[:, :1 + k], s32[:, 1 + k:]
+            v = theta[:, 1:]
+            touched = push[:, 1 + k] > 0
+            g_v = push[:, 1:1 + k] + cfg.l2_v * v * touched[:, None]
+            grads = jnp.concatenate([push[:, :1], g_v], axis=1)
+            cg_new = jnp.where(touched[:, None],
+                               jnp.sqrt(cg * cg + grads * grads), cg)
+            eta = cfg.lr_alpha / (cfg.lr_beta + cg_new)
+            theta_new = jnp.where(touched[:, None],
+                                  theta - eta * grads, theta)
+            d0 = theta_new[:, 0] - theta[:, 0]
+            return (jnp.concatenate([theta_new, cg_new], axis=1),
+                    jnp.sum(d0 * d0))
+
+        @jax.jit
+        def wd_dense_update(mlp, accum, g_mlp):
+            accum = jax.tree.map(
+                lambda a, g: jnp.sqrt(a * a + g * g), accum, g_mlp)
+            return jax.tree.map(
+                lambda p, g, a: p - cfg.lr_alpha_dense
+                / (cfg.lr_beta + a) * g, mlp, g_mlp, accum), accum
+
+        def forward(s32, mlp, block):
+            pw, labels, row_mask, ovf_b, ovf_r = decode(block)
+            pulls = wd_pull(s32, pw, ovf_b, ovf_r)
             pooled = pulls[:, 1:]
-            deep_fn = lambda m, x: mlp_forward(m, x, n_layers)  # noqa: E731
-            deep, vjp = jax.vjp(deep_fn, mlp, pooled)
+            with jax.named_scope("wd_tower_forward"):
+                deep, vjp = jax.vjp(wd_tower, mlp, pooled)
             margin = pulls[:, 0] + deep
             return (pw, labels, row_mask, ovf_b, ovf_r, pooled, vjp,
                     margin)
@@ -255,30 +313,14 @@ class WideDeepStore(TableCheckpoint):
             # shared update/metric tail downstream of the push buffer
             # and MLP grads — structurally identical XLA in the fused
             # and split programs, so the update bits agree between them
-            theta, cg = s32[:, :1 + k], s32[:, 1 + k:]
-            v = theta[:, 1:]
             objv = objv_fn(margin, labels, row_mask)
-            touched = push[:, 1 + k] > 0
-            g_v = push[:, 1:1 + k] + cfg.l2_v * v * touched[:, None]
-            grads = jnp.concatenate([push[:, :1], g_v], axis=1)
-            cg_new = jnp.where(touched[:, None],
-                               jnp.sqrt(cg * cg + grads * grads), cg)
-            eta = cfg.lr_alpha / (cfg.lr_beta + cg_new)
-            theta_new = jnp.where(touched[:, None],
-                                  theta - eta * grads, theta)
-            new = jnp.concatenate([theta_new, cg_new], axis=1)
-            accum = jax.tree.map(
-                lambda a, g: jnp.sqrt(a * a + g * g), accum, g_mlp)
-            mlp_new = jax.tree.map(
-                lambda p, g, a: p - cfg.lr_alpha_dense
-                / (cfg.lr_beta + a) * g, mlp, g_mlp, accum)
+            new, d0_sq = wd_table_update(s32, push)
+            mlp_new, accum = wd_dense_update(mlp, accum, g_mlp)
             num_ex = jnp.sum(row_mask)
             acc = accuracy(labels, margin, row_mask)
             pos, neg = margin_hist(labels, margin, row_mask)
-            d0 = theta_new[:, 0] - theta[:, 0]
             packed = jnp.concatenate([
-                jnp.stack([objv, num_ex, acc, jnp.sum(d0 * d0)]),
-                pos, neg])
+                jnp.stack([objv, num_ex, acc, d0_sq]), pos, neg])
             # num_ex = completion ticket; the clock/macc outputs are
             # donated into the next step (see ShardedStore._tile_step)
             return (new.astype(slots.dtype), mlp_new, accum, t + 1,
@@ -295,6 +337,7 @@ class WideDeepStore(TableCheckpoint):
                 pw, labels, row_mask, _ovf_b, _ovf_r = decode(block)
                 w, v = s32[:, 0], s32[:, 1:1 + k]
                 wpull = jnp.concatenate([w[:, None], v], axis=1)
+                # pull, tower and push are one kernel here
                 margin, push, g_mlp = tilemm.fused_wd_step(
                     pw, wpull, labels, row_mask, mlp, spec, k,
                     tuple(cfg.hidden), cfg.loss)
@@ -307,11 +350,10 @@ class WideDeepStore(TableCheckpoint):
                 (pw, labels, row_mask, ovf_b, ovf_r, pooled, vjp,
                  margin) = forward(s32, mlp, block)
                 dual = dual_fn(margin, labels, row_mask)
-                g_mlp, g_pooled = vjp(dual)
-                dvals = jnp.concatenate(
-                    [dual[:, None], g_pooled, row_mask[:, None]], axis=1)
-                push = tilemm.backward_pushes(pw, dvals, spec,
-                                              ovf_b, ovf_r)
+                with jax.named_scope("wd_tower_backward"):
+                    g_mlp, g_pooled = vjp(dual)
+                push = wd_push(pw, dual, g_pooled, row_mask, ovf_b,
+                               ovf_r)
                 return finish(slots, s32, mlp, accum, push, g_mlp,
                               margin, labels, row_mask, t, macc)
         else:
@@ -526,6 +568,11 @@ class WideDeepStore(TableCheckpoint):
                             self._t_device(), self._tau_const(tau),
                             self._macc_buf())
         self._advance_t(t_new)
+        # counts a step, not seconds: the tower's FLOPs, forward and
+        # backward, and the dense update's bytes
+        self.timer.add("tower_flops", tower_flops(
+            info.spec.block_rows, self.cfg.dim, tuple(self.cfg.hidden)))
+        self.timer.add("dense_param_bytes", self._dense_bytes)
         return ticket
 
     def tile_eval_step(self, block: dict, info):
@@ -577,49 +624,43 @@ class WideDeepStore(TableCheckpoint):
                     for k, v in data.items() if k.startswith("mlp_")}
 
 
-def main(argv=None) -> int:
-    """CLI: ``python -m wormhole_tpu.models.wide_deep [conf]
-    train_data=<uri> hidden=64,32 [key=val ...]`` — the AsyncSGD driver
-    with a WideDeepStore plugged in; ingest flows through the shared
-    DeviceFeed pipeline.
+def build_app(argv):
+    """The app of ``python -m wormhole_tpu.models.wide_deep [conf]
+    train_data=<uri> dim=32 hidden=1024,512,256 [key=val ...]``: the
+    AsyncSGD driver with a WideDeepStore plugged in (what ``main`` runs,
+    and what anything that wants the same app builds).
 
     ``key=val`` routing mirrors the FM CLI: WideDeepConfig fields go to
     the model, the rest to the driver Config, with ``num_buckets`` /
-    ``loss`` / ``seed`` mirrored from the driver. ``hidden`` is parsed
-    here (comma-separated ints) because the generic coercer has no
-    Tuple handling."""
+    ``loss`` / ``seed`` mirrored from the driver."""
     import dataclasses as _dc
-    import sys
 
     from wormhole_tpu.learners.async_sgd import AsyncSGD
     from wormhole_tpu.utils.config import apply_kvs, load_config
 
-    args = list(sys.argv[1:] if argv is None else argv)
+    args = list(argv)
     conf = args.pop(0) if args and "=" not in args[0] else None
-    hidden = None
-    rest = []
-    for a in args:
-        key, _, val = a.partition("=")
-        if key.strip() == "hidden":
-            hidden = tuple(int(p) for p in
-                           val.replace(",", " ").split() if p)
-        else:
-            rest.append(a)
     shared = {"num_buckets", "loss", "seed", "tile_step_kernel",
               "tile_onehot_cache"}
     model_keys = {f.name for f in _dc.fields(WideDeepConfig)} - shared
-    model_kvs = [a for a in rest
+    model_kvs = [a for a in args
                  if a.partition("=")[0].strip() in model_keys]
-    cfg = load_config(conf, [a for a in rest if a not in model_kvs])
+    cfg = load_config(conf, [a for a in args if a not in model_kvs])
     mcfg = WideDeepConfig(num_buckets=cfg.num_buckets,
                           loss=cfg.loss.value, seed=cfg.seed,
                           tile_step_kernel=cfg.tile_step_kernel,
                           tile_onehot_cache=cfg.tile_onehot_cache)
     apply_kvs(mcfg, model_kvs)
-    if hidden is not None:
-        mcfg.hidden = hidden
     rt = MeshRuntime.create(cfg.mesh_shape)
-    AsyncSGD(cfg, rt, store=WideDeepStore(mcfg, rt)).run()
+    return AsyncSGD(cfg, rt, store=WideDeepStore(mcfg, rt))
+
+
+def main(argv=None) -> int:
+    """CLI: ``python -m wormhole_tpu.models.wide_deep [conf]
+    train_data=<uri> hidden=64,32 [key=val ...]`` (build_app); ingest
+    flows through the shared DeviceFeed pipeline."""
+    import sys
+    build_app(sys.argv[1:] if argv is None else argv).run()
     return 0
 
 

@@ -1834,6 +1834,69 @@ def _build_fm_step_update(spec: TileSpec, k: int, loss: str, update):
     return step
 
 
+# The deep tower's precision, stated and not the backend's default (one
+# bfloat16 pass on the TPU, float32 on the CPU): every matmul of the tower,
+# forward and backward, in XLA (tower_dot) and in the fused kernel's dense
+# phase (_tower_mm there too), rounds BOTH operands to TOWER_OPERANDS, once,
+# and accumulates the products in float32. Biases, ReLU, the dual and the
+# AdaGrad update stay float32.
+TOWER_OPERANDS = jnp.bfloat16
+
+
+def _tower_mm(a: jax.Array, b: jax.Array, contract,
+              held: bool = False) -> jax.Array:
+    """``a`` and ``b`` contracted over ``contract`` = (dims of a, dims
+    of b): operands rounded to TOWER_OPERANDS, float32 accumulation.
+
+    ``held``: for a product that is no matmul to XLA. A one-column
+    layer (the tower's last) is a multiply to the TPU compiler, its
+    operands' ``convert`` to bfloat16 and back is then a pair that it
+    drops (excess precision is allowed), and the layer runs in float32:
+    the first chip runs read the tower's gradient norms 0.15% off the
+    reference for it. There the rounding is a ``reduce_precision``,
+    which it may not drop, and the rounded values stay float32 (their
+    products are exact in it)."""
+    if held:
+        info = jnp.finfo(TOWER_OPERANDS)
+        a, b = (jax.lax.reduce_precision(x, info.nexp, info.nmant)
+                for x in (a, b))
+    else:
+        a, b = a.astype(TOWER_OPERANDS), b.astype(TOWER_OPERANDS)
+    return jax.lax.dot_general(a, b, (contract, ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+@jax.custom_vjp
+def tower_dot(h: jax.Array, w: jax.Array) -> jax.Array:
+    """``h @ w`` of the tower, (rows, a) @ (a, b), at the stated
+    precision. The backward's two matmuls (``g @ w.T``, ``h.T @ g``) are
+    written out so that they round their operands the same way, and not
+    however autodiff would transpose a mixed-precision product."""
+    return _tower_mm(h, w, ((1,), (0,)), held=w.shape[1] == 1)
+
+
+def _tower_dot_fwd(h, w):
+    return tower_dot(h, w), (h, w)
+
+
+def _tower_dot_bwd(res, g):
+    h, w = res
+    held = w.shape[1] == 1
+    return (_tower_mm(g, w, ((1,), (1,)), held),
+            _tower_mm(h, g, ((0,), (0,)), held))
+
+
+tower_dot.defvjp(_tower_dot_fwd, _tower_dot_bwd)
+
+
+def tower_flops(rows: int, dim: int, hidden: Tuple[int, ...]) -> int:
+    """FLOPs of the tower for ``rows`` rows, forward and backward: three
+    matmuls a layer (``h @ W``, ``g @ W.T``, ``h.T @ g``; the first
+    layer's input gradient is needed, the embeddings train)."""
+    sizes = [dim, *hidden, 1]
+    return 6 * rows * sum(a * b for a, b in zip(sizes, sizes[1:]))
+
+
 def mlp_forward(params: dict, x: jax.Array, n_layers: int) -> jax.Array:
     """Dense MLP forward on the pooled embeddings (wide&deep's deep
     tower; models/wide_deep.py re-exports this): the split step's, the
@@ -1842,7 +1905,7 @@ def mlp_forward(params: dict, x: jax.Array, n_layers: int) -> jax.Array:
     held to this function at float tolerance."""
     h = x
     for i in range(n_layers):
-        h = h @ params[f"W{i}"] + params[f"b{i}"]
+        h = tower_dot(h, params[f"W{i}"]) + params[f"b{i}"]
         if i + 1 < n_layers:
             h = jax.nn.relu(h)
     return h[:, 0]
@@ -1907,7 +1970,6 @@ def _make_wd_step_kernel(spec: TileSpec, ch_in: int, ch_out: int,
     NI = RH // R                      # chunks per subblock
     UN = 2                            # chunks per loop step: the bf16
     #                                   dual grid stores 16 sublanes
-    NT_DIMS = (((1,), (1,)), ((), ()))
 
     def kernel(*refs):
         pw_ref, wt_ref, lab_ref, msk_ref = refs[:4]
@@ -1925,8 +1987,12 @@ def _make_wd_step_kernel(spec: TileSpec, ch_in: int, ch_out: int,
         def _mlp():
             for gr in g_refs:
                 gr[...] = jnp.zeros_like(gr)
-            wk = [p_refs[3 * i][...] for i in range(n_layers)]
-            wkt = [p_refs[3 * i + 1][...] for i in range(n_layers)]
+            # rounded once, not a chunk at a time (_tower_mm's cast of
+            # a TOWER_OPERANDS value is no cast)
+            wk = [p_refs[3 * i][...].astype(TOWER_OPERANDS)
+                  for i in range(n_layers)]
+            wkt = [p_refs[3 * i + 1][...].astype(TOWER_OPERANDS)
+                   for i in range(n_layers)]
             bk = [p_refs[3 * i + 2][...] for i in range(n_layers)]
 
             def chunk(s, r0):
@@ -1939,8 +2005,7 @@ def _make_wd_step_kernel(spec: TileSpec, ch_in: int, ch_out: int,
                     axis=0)]                           # (k*R, RL)
                 pre = []
                 for i in range(n_layers):
-                    z = jnp.dot(wk[i], acts[-1],
-                                preferred_element_type=jnp.float32) + bk[i]
+                    z = _tower_mm(wk[i], acts[-1], ((1,), (0,))) + bk[i]
                     pre.append(z)
                     if i + 1 < n_layers:
                         acts.append(jnp.maximum(z, 0.0))
@@ -1949,13 +2014,11 @@ def _make_wd_step_kernel(spec: TileSpec, ch_in: int, ch_out: int,
                 dual = dual_fn(margin, lab, msk)
                 d = dual
                 for i in reversed(range(n_layers)):
-                    g_refs[2 * i][...] += jax.lax.dot_general(
-                        d, acts[i], NT_DIMS,
-                        preferred_element_type=jnp.float32)
+                    g_refs[2 * i][...] += _tower_mm(d, acts[i],
+                                                    ((1,), (1,)))
                     g_refs[2 * i + 1][...] += jnp.sum(d, axis=1,
                                                       keepdims=True)
-                    d = jnp.dot(wkt[i], d,
-                                preferred_element_type=jnp.float32)
+                    d = _tower_mm(wkt[i], d, ((1,), (0,)))
                     if i:
                         d = jnp.where(pre[i - 1] > 0.0, d, 0.0)
                 # [dual, g_pooled..., mask], channel-major on lanes

@@ -379,10 +379,11 @@ def _coerce(ftype: Any, raw: str) -> Any:
     """Coerce a raw string to the declared field type."""
     raw = raw.strip().strip("'\"")
     origin = typing.get_origin(ftype)
-    if origin in (list, List):
-        (inner,) = typing.get_args(ftype)
-        items = [p for p in raw.replace(",", " ").split() if p]
-        return [_coerce(inner, p) for p in items]
+    if origin in (list, List, tuple):   # List[x], Tuple[x, ...]
+        inner = typing.get_args(ftype)[0]
+        items = [_coerce(inner, p)
+                 for p in raw.replace(",", " ").split() if p]
+        return tuple(items) if origin is tuple else items
     if origin is typing.Union:  # Optional[...]
         inner = [a for a in typing.get_args(ftype) if a is not type(None)]
         return _coerce(inner[0], raw)

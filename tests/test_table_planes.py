@@ -4,6 +4,7 @@
 identity of the crossing, the checkpoint's bytes, the benchmark's probes,
 and a structural guard on what the in-place step does around its kernel."""
 
+import functools
 import os
 import sys
 import types
@@ -22,10 +23,13 @@ from wormhole_tpu.ops import tilemm
 from wormhole_tpu.ops.penalty import L1L2
 
 from test_tilemm_fused import (SPEC, SPECK2, make_block, make_info,
-                               make_spill_block)
+                               make_pairs, make_spill_block)
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 OC = 1536
+# step_kernel's second field for a wide&deep block with an overflow list
+WD_SPILL = ("wide&deep spill needs the pull channels in HBM for the COO "
+            "scatter between the phases")
 
 
 def _store(nb, kernel="fused", algo="ftrl", **cfg):
@@ -41,10 +45,24 @@ def _fm_store(nb, kernel="fused", dim=4, rt=None):
                             l2=0.05, seed=7, tile_step_kernel=kernel), rt)
 
 
+def _wd_store(nb, kernel="split", dim=4, rt=None, **cfg):
+    """Wide&deep at a rate three steps from a random table stay finite
+    at; ``split`` is what the published widths resolve on the chip (the
+    in-kernel tower wants more VMEM than there is)."""
+    from wormhole_tpu.models.wide_deep import WideDeepConfig, WideDeepStore
+    return WideDeepStore(WideDeepConfig(
+        num_buckets=nb, dim=dim, hidden=(16, 8), loss="logit", seed=7,
+        lr_alpha=0.01, tile_step_kernel=kernel, **cfg), rt)
+
+
 # the cases that hold for any store that keeps planes
-MAKERS = {"ftrl": _store, "fm": _fm_store}
-BOTH = pytest.mark.parametrize("make", [
-    pytest.param(_store, id="ftrl"), pytest.param(_fm_store, id="fm")])
+EVERY_STORE = pytest.mark.parametrize("make", [
+    pytest.param(_store, id="ftrl"), pytest.param(_fm_store, id="fm"),
+    pytest.param(_wd_store, id="wide_deep")])
+# the two whose table is [w, v, cg_w, cg_v] a bucket
+EMBEDDING_STORES = pytest.mark.parametrize("make", [
+    pytest.param(_fm_store, id="fm"),
+    pytest.param(_wd_store, id="wide_deep")])
 
 
 def _as_it_was(store):
@@ -54,8 +72,31 @@ def _as_it_was(store):
     return store
 
 
+def _assert_same_table(make, got, want):
+    """FTRL's and FM's planar steps are their stacked steps to the bit
+    (guarded products). Wide&deep's update is plain XLA on both sides, and
+    the compiler contracts a fusion over planes and one over slices of
+    (nb, slots) differently: a last bit in a few hundred values. Held two
+    decades under the cell's ``state_rel_rms`` limit (4e-3), value by
+    value."""
+    if make is _wd_store:
+        np.testing.assert_allclose(got, want, rtol=4e-5, atol=1e-7)
+    else:
+        np.testing.assert_array_equal(got, want)
+
+
 def _crossings(store) -> int:
     return store.timer.counts.get("table_cross", 0)
+
+
+def _back(make) -> int:
+    """What the way back to planes counts. FTRL's and FM's tile steps ask
+    ``_tile_table()``, which takes a stacked array across in a pass of its
+    own: 1. Wide&deep's next tile step takes the stacked array as it is
+    and gives planes back, the change of form inside its update pass: no
+    pass, no count (and ``tests/benchmark`` holds it to that: a stacked
+    table assigned, tile steps, no ``table_cross`` on the timer)."""
+    return 0 if make is _wd_store else 1
 
 
 def _blocks(rng, spec, n, spill):
@@ -174,6 +215,153 @@ def test_planar_fm_steps_match_the_stacked_split_oracle(spill, hosts,
     assert np.all(got[changed][:, 1 + k] > 0)
 
 
+def _wd_step_as_it_stood(cfg, n_layers, spec, table, mlp, accum, blk):
+    """One wide&deep tile step on the stacked (nb, 2(1+k)) table as it
+    stood before the planes (PR 34), from the stacked helpers: the pull
+    operand sliced and concatenated, ``forward_pulls`` and
+    ``backward_pushes`` with their transposes and the (nb, ch) overflow
+    scatter, ``where`` over (nb, 1+k) pieces, the table concatenated
+    again. -> (table, mlp, accum, margins)."""
+    from wormhole_tpu.ops.loss import create_loss
+    k = cfg.dim
+    _, dual_fn = create_loss(cfg.loss)
+    lab = blk["labels"]
+    row_mask = (lab != jnp.uint8(255)).astype(jnp.float32)
+    labels = jnp.minimum(lab, 1).astype(jnp.float32)
+    ovf = (blk.get("ovf_b"), blk.get("ovf_r"))
+    theta, cg = table[:, :1 + k], table[:, 1 + k:]
+    wpull = jnp.concatenate([theta[:, 0][:, None], theta[:, 1:]], axis=1)
+    pulls = tilemm.forward_pulls(blk["pw"], wpull, spec, *ovf)
+    deep, vjp = jax.vjp(lambda m, x: tilemm.mlp_forward(m, x, n_layers),
+                        mlp, pulls[:, 1:])
+    margin = pulls[:, 0] + deep
+    dual = dual_fn(margin, labels, row_mask)
+    g_mlp, g_pooled = vjp(dual)
+    push = tilemm.backward_pushes(
+        blk["pw"], jnp.concatenate([dual[:, None], g_pooled,
+                                    row_mask[:, None]], axis=1), spec, *ovf)
+    touched = push[:, 1 + k] > 0
+    g_v = push[:, 1:1 + k] + cfg.l2_v * theta[:, 1:] * touched[:, None]
+    grads = jnp.concatenate([push[:, :1], g_v], axis=1)
+    cg_new = jnp.where(touched[:, None],
+                       jnp.sqrt(cg * cg + grads * grads), cg)
+    theta_new = jnp.where(
+        touched[:, None],
+        theta - cfg.lr_alpha / (cfg.lr_beta + cg_new) * grads, theta)
+    accum = jax.tree.map(lambda a, g: jnp.sqrt(a * a + g * g), accum, g_mlp)
+    mlp = jax.tree.map(
+        lambda p, g, a: p - cfg.lr_alpha_dense / (cfg.lr_beta + a) * g,
+        mlp, g_mlp, accum)
+    return (jnp.concatenate([theta_new, cg_new], axis=1), mlp, accum,
+            margin)
+
+
+def _short_spill_blocks(rng, spec, n, oc):
+    """Blocks whose hot bucket passes its (subblock, tile) cap by a dozen
+    pairs: an overflow list short beside even this table's lane rows."""
+    out = []
+    for _ in range(n):
+        buckets, rows = make_pairs(rng, 3000, spec)
+        there = np.sum((buckets // tilemm.TILE == 1)
+                       & (rows // tilemm.RSUB == 0))
+        n_hot = spec.cap - there + 12
+        buckets = np.concatenate(
+            [buckets, np.full(n_hot, 7 * tilemm.TILE // 4, np.int64)])
+        rows = np.concatenate(
+            [rows, rng.integers(0, tilemm.RSUB, n_hot).astype(np.int64)])
+        pw, ovb, ovr = tilemm.encode_block(buckets, rows, spec)
+        assert 0 < len(ovb) <= oc
+        blk = {"pw": pw, "ovf_b": np.full(oc, 0xFFFFFFFF, np.uint32),
+               "ovf_r": np.zeros(oc, np.uint32),
+               "labels": rng.integers(0, 2, spec.block_rows).astype(np.uint8)}
+        blk["ovf_b"][:len(ovb)], blk["ovf_r"][:len(ovr)] = ovb, ovr
+        out.append(blk)
+    return out
+
+
+@pytest.mark.parametrize("oc", [
+    pytest.param(OC, id="spill"), pytest.param(32, id="short_list"),
+    pytest.param(0, id="no_list")])
+def test_planar_wide_deep_steps_match_the_stacked_steps(oc):
+    """Three wide&deep steps from a random table, blocks with and
+    without an overflow list. The planar store (33 theta planes rounded
+    into the pull kernel's operand in one op, the pushes left as the
+    kernel wrote them, the overflow pairs gathered from planes and
+    scattered into the tiled pushes, ONE pass over planes; its first step
+    takes the assigned stacked start as the held store does and gives
+    planes back) against (1) the same store held stacked, whose every step
+    goes through the (nb, ch) helpers at the kernels' edges, slices the
+    planes out of (nb, 2(1+k)) for the update and stacks them again, and
+    (2) the step as it stood on the stacked table, written here from the
+    stacked helpers. Margins, metric row, tower and accumulators of (1)
+    to the bit but for the table and the progress number
+    (``_assert_same_table``); (2) fuses the update otherwise, so it is
+    held by the cell's own measure, the relative rms of each leaf, at
+    1e-6: over three decades under the cell's limit of 4e-3 (it reads 3e-8
+    at most here). The planar table stays planes and never crosses."""
+    rng = np.random.default_rng(37)
+    k = 4
+    info = make_info(SPEC, ovf_cap=oc)
+    # a list of 32 is short beside the table's 256 lane rows and is ONE
+    # scatter of such rows, as in the cell; 1536 go a plane at a time
+    # (tilemm.spill_push_scatter_lanes)
+    blocks = (_short_spill_blocks(rng, SPEC, 3, oc) if oc == 32
+              else _blocks(rng, SPEC, 3, bool(oc)))
+    start = (rng.standard_normal((SPEC.nb, 2 * (1 + k))) * 0.1).astype(
+        np.float32)
+    start[:, 1 + k:] = np.abs(start[:, 1 + k:])
+    planar, held = _wd_store(SPEC.nb), _as_it_was(_wd_store(SPEC.nb))
+    for st in (planar, held):
+        st.slots = jnp.asarray(start)
+    stood = jax.jit(functools.partial(
+        _wd_step_as_it_stood, planar.cfg, planar.n_layers, info.spec))
+    ref = (jnp.asarray(start), planar.mlp, planar.mlp_accum)
+
+    def rel_rms(got, want):
+        got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+        return np.sqrt(np.sum((got - want) ** 2) / np.sum(want ** 2))
+
+    for blk in blocks:
+        dev = jax.device_put(blk)
+        *ref, margin = stood(*ref, dev)
+        np.testing.assert_allclose(
+            np.asarray(planar.tile_eval_step(dev, info)[5]),
+            np.asarray(margin), rtol=2e-5, atol=1e-6)
+        rows = []
+        for st in (planar, held):
+            st.tile_train_step(dev, info)
+            rows.append(st.fetch_metrics())
+        np.testing.assert_array_equal(np.delete(rows[0], 3),
+                                      np.delete(rows[1], 3))
+        assert rows[1][3] > 0
+        np.testing.assert_allclose(rows[0][3], rows[1][3], rtol=1e-5)
+        np.testing.assert_array_equal(
+            np.asarray(planar.tile_eval_step(dev, info)[5]),
+            np.asarray(held.tile_eval_step(dev, info)[5]))
+    assert planar.step_kernel[0] == "split"
+    assert isinstance(planar.slots, tbl.PlaneTable)
+    assert len(planar.slots.planes) == 2 * (1 + k)
+    assert not isinstance(held.slots, tbl.PlaneTable)
+    # the assigned start table became planes inside the first step
+    assert _crossings(planar) == 0 and _crossings(held) == 0
+    got = np.asarray(planar.slots)
+    _assert_same_table(_wd_store, got, np.asarray(held.slots))
+    for tree, other, stood_tree in ((planar.mlp, held.mlp, ref[1]),
+                                    (planar.mlp_accum, held.mlp_accum,
+                                     ref[2])):
+        for name, leaf in tree.items():
+            np.testing.assert_array_equal(np.asarray(leaf),
+                                          np.asarray(other[name]))
+            assert rel_rms(leaf, stood_tree[name]) < 1e-6, name
+    want = np.asarray(ref[0])
+    for name, cols in (("w", slice(0, 1)), ("v", slice(1, 1 + k)),
+                       ("cg", slice(1 + k, None))):
+        assert rel_rms(got[:, cols], want[:, cols]) < 1e-6, name
+    changed = np.any(got != start, axis=1)
+    assert 0 < changed.sum() < SPEC.nb        # touched buckets only
+    np.testing.assert_array_equal(changed, np.any(want != start, axis=1))
+
+
 def test_other_handles_step_on_planes():
     """A handle without an unstacked update goes through push() on the
     stacked planes inside the step; the touched-bucket mask holds."""
@@ -213,7 +401,7 @@ def test_a_stacked_table_keeps_its_overflow_lists():
     assert _crossings(store) == 0
 
 
-@BOTH
+@EVERY_STORE
 def test_an_empty_overflow_list_stays_on_the_host(make):
     """put_block leaves an overflow list with no pair behind, and the
     block then takes the step that has no spill to scatter (FTRL: the
@@ -226,20 +414,27 @@ def test_an_empty_overflow_list_stays_on_the_host(make):
              "ovf_r": np.zeros(OC, np.uint32)}
     (spilled,) = _blocks(rng, SPEC, 1, True)
     a, b = make(SPEC.nb), make(SPEC.nb)
-    no_spill = ("fused", IN_PLACE if make is _store else fm_model.IN_PLACE)
+    # wide&deep's two programs are both the split pair, resolved from the
+    # file's geometry (its blocks CAN spill): the one without a list skips
+    # the gather and the scatter
+    no_spill, with_spill = {
+        _store: (("fused", IN_PLACE), ("fused", "")),
+        _fm_store: (("fused", fm_model.IN_PLACE), ("fused", "")),
+        _wd_store: (("split", WD_SPILL), ("split", WD_SPILL))}[make]
     dev = a.put_block(empty)
     assert sorted(dev) == ["labels", "pw"]
     a.tile_train_step(dev, info)
     assert a.step_kernel[:2] == no_spill
     assert [key[2] for key in a._tile_cache] == [False]
     b.tile_train_step(jax.device_put(empty), info)       # the spill step
-    assert b.step_kernel[:2] == ("fused", "")
+    assert b.step_kernel[:2] == with_spill
     assert [key[2] for key in b._tile_cache] == [True]
     np.testing.assert_array_equal(np.asarray(a.slots), np.asarray(b.slots))
     dev = a.put_block(spilled)
     assert "ovf_b" in dev
     a.tile_train_step(dev, info)
-    assert a.step_kernel[:2] == ("fused", "")
+    assert a.step_kernel[:2] == with_spill
+    assert sorted(key[2] for key in a._tile_cache) == [False, True]
     assert _crossings(a) == 0 and _crossings(b) == 0
 
 
@@ -282,7 +477,7 @@ def test_a_plane_table_reads_like_the_stacked_array(form):
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
-@BOTH
+@EVERY_STORE
 def test_checkpoint_bytes_are_those_of_the_stacked_table(make, tmp_path):
     """A planar store's checkpoint file is byte for byte what the same
     state written as one (nb, slots) array gives (the format before the
@@ -298,8 +493,8 @@ def test_checkpoint_bytes_are_those_of_the_stacked_table(make, tmp_path):
     planar, stacked = tmp_path / "planar", tmp_path / "stacked"
     Checkpointer(str(planar)).save(2, store.state_pytree())
     Checkpointer(str(stacked)).save(
-        2, {"slots": jnp.asarray(np.asarray(store.slots)),
-            "t": np.int64(store.t)})
+        2, dict(store.state_pytree(),       # wide&deep: the tower as well
+                slots=jnp.asarray(np.asarray(store.slots))))
     name = "ckpt_v2.msgpack"
     assert (planar / name).read_bytes() == (stacked / name).read_bytes()
     assert _crossings(store) == 0         # stacked on the host, not here
@@ -313,12 +508,12 @@ def test_checkpoint_bytes_are_those_of_the_stacked_table(make, tmp_path):
                                   np.asarray(store.slots))
     store.tile_train_step(blocks[2], info)
     fresh.tile_train_step(blocks[2], info)
-    assert _crossings(fresh) == 1         # the restored array, taken across
+    assert _crossings(fresh) == _back(make)     # the restored array
     np.testing.assert_array_equal(np.asarray(fresh.slots),
                                   np.asarray(store.slots))
 
 
-@BOTH
+@EVERY_STORE
 def test_paths_that_want_the_array_cross_and_are_counted(make):
     """The sparse step asks for (nb, slots) and gets it, once; the next
     tile step takes the table back; both crossings are on the timer and
@@ -340,13 +535,14 @@ def test_paths_that_want_the_array_cross_and_are_counted(make):
         st.tile_train_step(blocks[0], info)
         st.train_step(batch)
         st.tile_train_step(blocks[1], info)
-    assert _crossings(planar) == 2 and _crossings(stacked) == 0
+    assert _crossings(planar) == 1 + _back(make)
+    assert _crossings(stacked) == 0
     assert planar.timer.totals["table_cross"] > 0
-    np.testing.assert_array_equal(np.asarray(planar.slots),
-                                  np.asarray(stacked.slots))
+    _assert_same_table(make, np.asarray(planar.slots),
+                       np.asarray(stacked.slots))
 
 
-@BOTH
+@EVERY_STORE
 def test_the_pager_crosses_once_and_is_counted(make):
     """bigmodel/paged.py moves rows of the hot table by index: it asks a
     planar store for the array (PagedStore._table), one counted crossing,
@@ -371,7 +567,8 @@ def test_the_pager_crosses_once_and_is_counted(make):
     assert _crossings(hot) == 1                  # already the array
     info = make_info(SPEC)
     hot.tile_train_step(jax.device_put(_blocks(rng, SPEC, 1, False)[0]), info)
-    assert isinstance(hot.slots, tbl.PlaneTable) and _crossings(hot) == 2
+    assert isinstance(hot.slots, tbl.PlaneTable)
+    assert _crossings(hot) == 1 + _back(make)
 
 
 # -- (c) the benchmark's probes, as they are ---------------------------------
@@ -414,19 +611,22 @@ def test_benchmark_state_probe_and_fence_cross_nothing(probed):
     assert _crossings(app.store) == 0
 
 
-def test_fm_benchmark_reads_and_writes_of_a_planar_table():
+@EMBEDDING_STORES
+def test_fm_benchmark_reads_and_writes_of_a_planar_table(make):
     """Everything benchmark/configs/criteo_fm/system.py does to
     ``store.slots``, on a planar FMStore: it reads ``.sharding``, replaces
     the table by a donated jit of ``slots.at[:, 1:1+k].set(v0)`` with that
     ``out_shardings`` and assigns the stacked result (ONE crossing, at the
     next tile step), then probes with ``astype``, ``s[:, 0]``,
     ``s[:, 1:1+k]``, ``s[:, 1+k]``, ``s[:, 2+k:]`` and ``slots[idx, :1+k]``
-    (no crossing)."""
+    (no crossing). ``criteo_wide_deep/system.py`` does the same to a
+    WideDeepStore with the same ``_v0`` and table probes, but for the
+    ``out_shardings``, which it leaves out."""
     if REPO not in sys.path:
         sys.path.insert(0, REPO)
     from benchmark.configs.criteo_fm import system as hooks
     k, nb, scale, seed = 4, SPEC.nb, 0.01, (1 << 31) + 12345
-    store = _fm_store(nb)
+    store = make(nb)
     assert isinstance(store.slots, tbl.PlaneTable)
     sharding = store.slots.sharding
     assert sharding == jax.sharding.SingleDeviceSharding(jax.devices()[0])
@@ -435,8 +635,9 @@ def test_fm_benchmark_reads_and_writes_of_a_planar_table():
         return slots.at[:, 1:1 + k].set(hooks._v0(nb, k, salt, scale))
 
     salt = jnp.uint32(hooks._salt(seed))
-    store.slots = jax.jit(seeded, donate_argnums=(0,),
-                          out_shardings=sharding)(store.slots, salt)
+    placed = {"out_shardings": sharding} if make is _fm_store else {}
+    store.slots = jax.jit(seeded, donate_argnums=(0,), **placed)(
+        store.slots, salt)
     assert store.slots.shape == (nb, 2 * (1 + k))
     assert not isinstance(store.slots, tbl.PlaneTable)
     v0 = np.asarray(hooks._v0(nb, k, salt, scale))
@@ -448,7 +649,7 @@ def test_fm_benchmark_reads_and_writes_of_a_planar_table():
     for blk in _blocks(rng, SPEC, 3, False):
         store.tile_train_step(jax.device_put(blk), info)
     assert isinstance(store.slots, tbl.PlaneTable)
-    assert _crossings(store) == 1          # the assigned array, once
+    assert _crossings(store) == _back(make)      # the assigned array, once
 
     app = types.SimpleNamespace(store=store)
     config = {"dim": k, "num_buckets": nb, "hyper": {"init_scale": scale}}
@@ -470,19 +671,21 @@ def test_fm_benchmark_reads_and_writes_of_a_planar_table():
     np.testing.assert_array_equal(rows["v"], full[buckets, 1:1 + k])
     jax.block_until_ready(store.slots)           # benchmark/system.py fence
     assert isinstance(store.slots, tbl.PlaneTable)
-    assert _crossings(store) == 1
+    assert _crossings(store) == _back(make)
 
 
-def test_fm_paths_that_want_the_array_cross_and_are_counted(tmp_path):
+@EMBEDDING_STORES
+def test_fm_paths_that_want_the_array_cross_and_are_counted(make, tmp_path):
     """serve_params, save_model/load_model and the mesh step ask a planar
-    FMStore for (nb, 2(1+k)) and get it, one counted crossing each time
-    the table is planes; the next tile step takes it back."""
+    FMStore (a planar WideDeepStore) for (nb, 2(1+k)) and get it, one
+    counted crossing each time the table is planes; the next tile step
+    takes it back."""
     from wormhole_tpu.parallel.mesh import MeshRuntime, make_mesh
     rng = np.random.default_rng(19)
     info = make_info(SPEC)
     blocks = [jax.device_put(b) for b in _blocks(rng, SPEC, 4, False)]
     rt = MeshRuntime(mesh=make_mesh("data:1", jax.devices()[:1]))
-    store = _fm_store(SPEC.nb, rt=rt)
+    store = make(SPEC.nb, rt=rt)
     k = store.cfg.dim
     assert isinstance(store.slots, tbl.PlaneTable)
 
@@ -495,29 +698,32 @@ def test_fm_paths_that_want_the_array_cross_and_are_counted(tmp_path):
     store.serve_params()
     assert _crossings(store) == 1                # already the array
 
+    back = _back(make)
     store.tile_train_step(blocks[1], info)                 # and back
-    assert isinstance(store.slots, tbl.PlaneTable) and _crossings(store) == 2
+    assert isinstance(store.slots, tbl.PlaneTable)
+    assert _crossings(store) == 1 + back
     store.save_model(str(tmp_path / "fm"), rank=0)         # the export
-    assert _crossings(store) == 3
+    assert _crossings(store) == 2 + back
     saved = np.load(tmp_path / "fm_0.npz")
     np.testing.assert_array_equal(saved["w"], np.asarray(store.slots)[:, 0])
     np.testing.assert_array_equal(saved["v"],
                                   np.asarray(store.slots)[:, 1:1 + k])
     store.tile_train_step(blocks[2], info)
-    assert _crossings(store) == 4
-    fresh = _fm_store(SPEC.nb)
+    assert _crossings(store) == 2 + 2 * back
+    fresh = make(SPEC.nb)
     fresh.load_model(str(tmp_path / "fm_0.npz"))           # the import
     assert _crossings(fresh) == 1
     np.testing.assert_array_equal(np.asarray(fresh.slots)[:, :1 + k],
                                   np.column_stack([saved["w"], saved["v"]]))
     fresh.tile_train_step(blocks[2], info)
-    assert isinstance(fresh.slots, tbl.PlaneTable) and _crossings(fresh) == 2
+    assert isinstance(fresh.slots, tbl.PlaneTable)
+    assert _crossings(fresh) == 1 + back
 
     group = {key: val[None] for key, val in blocks[3].items()}
     store.tile_train_step_mesh(group, info)                # the mesh step
-    assert _crossings(store) == 5
+    assert _crossings(store) == 3 + 2 * back
     assert store.slots.shape == (SPEC.nb, 2 * (1 + k))
-    twin = _as_it_was(_fm_store(SPEC.nb))
+    twin = _as_it_was(make(SPEC.nb))
     for blk in blocks:
         twin.tile_train_step(blk, info)
     np.testing.assert_allclose(np.asarray(store.slots),
@@ -527,11 +733,13 @@ def test_fm_paths_that_want_the_array_cross_and_are_counted(tmp_path):
 # -- (d) what the steps do around their kernels ------------------------------
 
 def _leaf_eqns(jaxpr):
-    """Equations of a jaxpr with calls opened, kernels left closed."""
+    """Equations of a jaxpr with calls opened, kernels (and scatters,
+    whose combiner is a jaxpr too) left closed."""
     for eqn in jaxpr.eqns:
         inner = [v for v in eqn.params.values()
                  if hasattr(v, "jaxpr") or hasattr(v, "eqns")]
-        if eqn.primitive.name == "pallas_call" or not inner:
+        if (eqn.primitive.name == "pallas_call"
+                or eqn.primitive.name.startswith("scatter") or not inner):
             yield eqn
         else:
             for sub in inner:
@@ -635,3 +843,115 @@ def test_fm_step_forms_nothing_table_sized_outside_the_kernel():
                "reshape", "scatter-add", "scatter_add"}
     names = {e.primitive.name for e in big}
     assert names <= allowed, names - allowed
+
+
+@pytest.mark.parametrize("oc", [pytest.param(16, id="lane_rows"),
+                                pytest.param(1536, id="plane_by_plane")])
+def test_plane_helpers_match_the_stacked_ones(oc):
+    """What the planar multi-channel step hands the kernels and does
+    around them, against the (nb, ch) forms: the bfloat16 operand of
+    ``plane_operand`` is the transposed, rounded (nb, ch) array of
+    ``_build_fwd_multi``; the overflow pulls gathered plane by plane are
+    ``spill_pull_rows``; the overflow pushes scattered into the tiled
+    pushes are ``spill_push_scatter`` (a short list as ONE scatter of
+    lane rows, a long one a plane at a time; sums of a bucket listed
+    more than twice may take another order)."""
+    rng = np.random.default_rng(41)
+    T, ch = SPEC.tiles, 6
+    nb, A, B = SPEC.nb, tilemm.A_HI, tilemm.B_LO
+    w = rng.standard_normal((nb, ch)).astype(np.float32)
+    push = rng.standard_normal((nb, ch)).astype(np.float32)
+    dual = jnp.asarray(rng.standard_normal((SPEC.block_rows, ch))
+                       .astype(np.float32))
+    ovf_b = np.full(oc, 0xFFFFFFFF, np.uint32)
+    n = oc * 3 // 4
+    ovf_b[:n] = rng.integers(0, nb, n)
+    ovf_b[:n:3] = ovf_b[0]                    # one bucket, many pairs
+    ovf_r = rng.integers(0, 4 * SPEC.block_rows, oc).astype(np.uint32)
+    ovf_b, ovf_r = jnp.asarray(ovf_b), jnp.asarray(ovf_r)
+
+    def tiled(x):          # (nb, ch) as the kernels lay it on the lanes
+        return (jnp.asarray(x).reshape(T, A, B, ch).transpose(0, 1, 3, 2)
+                .reshape(T, A, ch * B))
+
+    planes = tbl.split(jnp.asarray(w))
+    np.testing.assert_array_equal(
+        np.asarray(tilemm.plane_operand(planes).astype(jnp.float32)),
+        np.asarray(tiled(w).astype(jnp.bfloat16).astype(jnp.float32)))
+    np.testing.assert_array_equal(
+        np.asarray(tilemm.plane_spill_pull_rows(planes, ovf_b, ovf_r, SPEC)),
+        np.asarray(tilemm.spill_pull_rows(jnp.asarray(w), ovf_b, ovf_r,
+                                          SPEC)))
+    got = np.asarray(tbl.join(tilemm.spill_push_scatter_lanes(
+        tiled(push), dual, ovf_b, ovf_r, SPEC)))
+    want = np.asarray(tilemm.spill_push_scatter(
+        jnp.asarray(push), dual, ovf_b, ovf_r, SPEC))
+    assert np.any(want != push)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+    once = np.ones(nb, bool)
+    once[int(ovf_b[0])] = False
+    np.testing.assert_array_equal(got[once], want[once])
+    np.testing.assert_array_equal(
+        np.asarray(tbl.join(tilemm.push_planes(tiled(push)))), push)
+
+
+def test_wide_deep_step_forms_nothing_table_shaped_outside_the_kernels():
+    """The one-device wide&deep train step over channel planes, traced at
+    a table whose plane outgrows every block-sized array, with and
+    without an overflow list: TWO pallas_calls, the pull kernel taking the
+    bfloat16 (T, A_HI, (1+k)*B_LO) operand and the push kernel giving
+    float32 (T, A_HI, (k+2)*B_LO), and outside them no equation has a
+    result shaped (nb, anything): every result of nb elements or more is
+    a plane (or the same bytes flat), the operand or the tiled pushes,
+    made by a convert, the one concatenate, lane slices, the one
+    scatter-add and the elementwise AdaGrad pass. No transpose, pad or
+    stack. (What the v5e compiler makes of it is test_tpu_compile's to
+    say.)"""
+    from wormhole_tpu.data.crec import CRec2Info
+    k = 32
+    nb = 1024 * tilemm.TILE
+    info = CRec2Info(nnz=0, block_rows=2 * tilemm.RSUB,
+                     total_rows=2 * tilemm.RSUB, nb=nb, subblocks=2,
+                     cap=128, ovf_cap=1024)
+    spec = info.spec
+    store = _wd_store(SPEC.nb, dim=k)
+
+    def like(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    plane = like(tbl.plane_shape(nb), jnp.float32)
+    tiled = lambda ch: plane.shape[:2] + (ch * tilemm.B_LO,)
+    mlp = jax.tree.map(lambda a: like(a.shape, a.dtype), store.mlp)
+    for spill in (False, True):
+        step = store._tile_step(info, "train", spill)
+        assert store.step_kernel[0] == "split"
+        block = {"pw": like(spec.pairs_shape, jnp.uint32),
+                 "labels": like((spec.block_rows,), jnp.uint8)}
+        if spill:
+            block.update(ovf_b=like((1024,), jnp.uint32),
+                         ovf_r=like((1024,), jnp.uint32))
+        jaxpr = jax.make_jaxpr(step)(
+            tbl.PlaneTable([plane] * (2 * (1 + k))), mlp, mlp, block,
+            like((), jnp.int32), like((), jnp.float32),
+            like((TableCheckpoint.MACC_LEN,), jnp.float32))
+        eqns = list(_leaf_eqns(jaxpr.jaxpr))
+        pull, push = [e for e in eqns if e.primitive.name == "pallas_call"]
+        assert [(v.aval.shape, v.aval.dtype) for v in pull.invars
+                if v.aval.size >= nb] == [(tiled(1 + k), jnp.bfloat16)]
+        assert [(v.aval.shape, v.aval.dtype) for v in push.outvars] == [
+            (tiled(k + 2), jnp.float32)]
+        big = [e for e in eqns if e.primitive.name != "pallas_call"
+               and any(v.aval.size >= nb for v in e.outvars)]
+        shapes = {v.aval.shape for e in big for v in e.outvars
+                  if v.aval.size >= nb}
+        assert shapes <= {plane.shape, (nb,), tiled(1 + k), tiled(k + 2)}
+        allowed = {"add", "sub", "mul", "div", "sqrt", "gt", "select_n",
+                   "integer_pow", "square", "convert_element_type",
+                   "concatenate", "slice", "reshape"}
+        if spill:
+            allowed |= {"scatter-add", "scatter_add"}
+        names = {e.primitive.name for e in big}
+        assert names <= allowed, names - allowed
+        assert sum(e.primitive.name == "concatenate" for e in big) == 1
+        assert sum(e.primitive.name.startswith("scatter")
+                   for e in big) == int(spill)

@@ -27,7 +27,10 @@ ALLOWLIST = {
         "matmul path is the default, this is the oracle",
     "wormhole_tpu/ops/tilemm.py":
         "COO overflow-bucket spill: O(overflow) elements, not O(nnz); "
-        "the hot tile path is already a one-hot matmul",
+        "the hot tile path is already a one-hot matmul. On channel "
+        "planes: plane_spill_pull_rows (overflow pulls onto their rows) "
+        "and spill_push_scatter_lanes (ONE scatter of O(overflow) lane "
+        "rows into the tiled pushes, in place)",
     "wormhole_tpu/ops/histmm.py":
         "the scatter ORACLE kernels (_dense_scatter/_sparse_scatter) "
         "that the matmul kernels are parity-tested against",
@@ -48,7 +51,8 @@ ANNOTATED = {
     "wormhole_tpu/models/fm.py":
         "uniq-key push + tile overflow spill",
     "wormhole_tpu/models/wide_deep.py":
-        "uniq-key push + tile overflow spill",
+        "uniq-key push + the mesh step's overflow spill (the one-device "
+        "tile step's are ops/tilemm.py's helpers on planes)",
 }
 
 # the in-source audit marker required at each scatter site in ANNOTATED

@@ -80,12 +80,32 @@ def _table_sharding(num_buckets: int, runtime: Optional[MeshRuntime]):
     return NamedSharding(runtime.mesh, P(MODEL_AXIS, None))
 
 
-def shard_param_table(arr: jax.Array,
-                      runtime: Optional[MeshRuntime]) -> jax.Array:
-    """Place a built parameter table (FMStore / WideDeepStore, whose
-    tables come from the host)."""
-    sharding = _table_sharding(arr.shape[0], runtime)
-    return arr if sharding is None else jax.device_put(arr, sharding)
+def factor_table(v0: np.ndarray, runtime: Optional[MeshRuntime],
+                 planar: bool):
+    """The table ``[w, v_1..v_k, cg_w, cg_v_1..k]`` of an embedding store
+    (FMStore, WideDeepStore) from the host's float32 ``(nb, k)`` draw of
+    ``v``: ``w`` and the accumulators start at 0. ``planar``: one
+    (T, A_HI, B_LO) plane a channel (learners/table.py), the zeros made on
+    the device, a buffer each since the tile steps donate them, and the
+    ``v`` planes put one by one; else the stacked ``(nb, 2(1+k))`` array,
+    placed where the runtime wants it."""
+    nb, k = v0.shape
+    if not planar:
+        slots = np.zeros((nb, 2 * (1 + k)), np.float32)
+        slots[:, 1:1 + k] = v0
+        sharding = _table_sharding(nb, runtime)
+        slots = jnp.asarray(slots)
+        return slots if sharding is None else jax.device_put(slots,
+                                                             sharding)
+    shape = tbl.plane_shape(nb)
+
+    def zeros(n):
+        return [jnp.zeros(shape, jnp.float32) for _ in range(n)]
+
+    return tbl.PlaneTable(
+        zeros(1) + [jnp.asarray(col.reshape(shape))
+                    for col in np.ascontiguousarray(v0.T)]
+        + zeros(1 + k))
 
 
 def build_param_table(make, num_buckets: int,

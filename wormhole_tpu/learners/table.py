@@ -1,7 +1,7 @@
 """The parameter table's format on the device — the one module that owns it.
 
-A table-backed store holds ``slots`` values a bucket (FTRL: w, z, cg; FM:
-w, v_1..v_k and their AdaGrad accumulators). Two forms exist, and this
+A table-backed store holds ``slots`` values a bucket (FTRL: w, z, cg; FM and
+wide&deep: w, v_1..v_k and their AdaGrad accumulators). Two forms exist, and this
 module is the door between them:
 
   * stacked — one ``(nb, slots)`` array: what the sparse step, the v1 dense
@@ -13,15 +13,19 @@ module is the door between them:
     back — no slice, no stack, no transpose, no padding lane (the compiler
     lays ``f32[nb, 3]`` out four wide, ``f32[nb, 18]`` twenty-four).
 
-Which stores keep planes: ``ShardedStore`` (learners/store.py) and ``FMStore``
-(models/fm.py), each when it can see that it can — one device, a float32
-table, whole tiles (``TableCheckpoint.can_be_planar``). FTRL's in-place
-kernel updates its three planes itself, aliased onto its outputs, and so does
-FM's with its 2(1+k) (the bfloat16 operand [w, v, Σv²] is put together in
-VMEM from the w and v tiles); a block with a COO overflow list takes the
-kernel that writes the gradient (FM: a push plane a channel) and ONE
-elementwise pass over planes onto the donated state. The dense-tower store
-(models/wide_deep.py), a table on a mesh and a bfloat16 table stay stacked.
+Which stores keep planes: ``ShardedStore`` (learners/store.py), ``FMStore``
+(models/fm.py) and ``WideDeepStore`` (models/wide_deep.py), each when it can
+see that it can — one device, a float32 table, whole tiles
+(``TableCheckpoint.can_be_planar``). FTRL's in-place kernel updates its three
+planes itself, aliased onto its outputs, and so does FM's with its 2(1+k)
+(the bfloat16 operand [w, v, Σv²] is put together in VMEM from the w and v
+tiles); a block with a COO overflow list takes the kernel that writes the
+gradient (FM: a push plane a channel) and ONE elementwise pass over planes
+onto the donated state. The dense-tower store runs the split kernel pair
+with its tower between: the pull kernel's operand is one op over the w and v
+planes, the push kernel's (T, A_HI, ch*B_LO) output is read a lane block a
+channel, and the same ONE pass updates its 2(1+k) planes. A table on a mesh
+and a bfloat16 table stay stacked.
 
 Which paths cross: every one in the first list asks the store's
 ``_stacked()`` (the pager through ``PagedStore._table``) and the

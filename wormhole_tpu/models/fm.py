@@ -32,10 +32,10 @@ import numpy as np
 from wormhole_tpu.data.feed import SparseBatch
 from wormhole_tpu.learners import table as tbl
 from wormhole_tpu.learners.store import (TableCheckpoint,
+                                          factor_table,
                                           mesh_ovf_zeros,
                                           mesh_step_ici_bytes,
-                                          mesh_tile_geometry,
-                                          shard_param_table)
+                                          mesh_tile_geometry)
 from wormhole_tpu.ops.loss import create_loss
 from wormhole_tpu.ops.metrics import accuracy, auc
 from wormhole_tpu.ops.penalty import L1L2
@@ -148,20 +148,7 @@ class FMStore(TableCheckpoint):
         # layout, so it is built so and the step never re-forms it. Every
         # other path asks _stacked() for (nb, 2(1+k)) and gets it, counted.
         self._planar = self.can_be_planar(runtime, np.float32, nb)
-        if self._planar:
-            shape = tbl.plane_shape(nb)
-
-            def zeros(n):      # a buffer each: the steps donate them
-                return [jnp.zeros(shape, jnp.float32) for _ in range(n)]
-
-            self.slots = tbl.PlaneTable(
-                zeros(1) + [jnp.asarray(col.reshape(shape))
-                            for col in np.ascontiguousarray(v0.T)]
-                + zeros(1 + k))
-        else:
-            slots = np.zeros((nb, 2 * (1 + k)), np.float32)
-            slots[:, 1:1 + k] = v0
-            self.slots = shard_param_table(jnp.asarray(slots), runtime)
+        self.slots = factor_table(v0, runtime, self._planar)
         self._step = self._build_step()
         self._eval = self._build_eval()
         self.t = 1

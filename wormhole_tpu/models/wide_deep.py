@@ -11,7 +11,8 @@ margin(row) = Σᵢ wᵢxᵢ  +  MLP( Σᵢ xᵢ·vᵢ )
 Parameters:
 - sparse: one sharded ``(num_buckets, 1 + k + 1 + k)`` table
   ``[w, v, cg_w, cg_v]`` over the ``model`` mesh axis (same layout idea as
-  the FM store);
+  the FM store; on one device, where the tile kernels step it, kept as one
+  plane a channel in their layout: learners/table.py);
 - dense: MLP weights, replicated, updated with AdaGrad as well.
 
 Both parts train jointly in one jitted step via ``jax.grad`` through the
@@ -29,11 +30,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from wormhole_tpu.data.feed import SparseBatch
+from wormhole_tpu.learners import table as tbl
 from wormhole_tpu.learners.store import (TableCheckpoint,
+                                          factor_table,
                                           mesh_ovf_zeros,
                                           mesh_step_ici_bytes,
-                                          mesh_tile_geometry,
-                                          shard_param_table)
+                                          mesh_tile_geometry)
 from wormhole_tpu.ops.loss import create_loss
 from wormhole_tpu.ops.metrics import accuracy, auc
 from wormhole_tpu.ops.spmv import spmv_times
@@ -93,15 +95,22 @@ class WideDeepStore(TableCheckpoint):
         # a learner that owns this store reads this timer as its own
         # (AsyncSGD): the tower's work a tile step (tower_flops,
         # dense_param_bytes: counts, not seconds) and, as every
-        # TableCheckpoint, any crossing of the table's form (table_cross;
-        # this table stays (nb, 2(1+k)) on every path, so none)
+        # TableCheckpoint, any crossing of the table's form (table_cross)
         self.timer = Timer()
-        k = cfg.dim
+        k, nb = cfg.dim, cfg.num_buckets
         rng = np.random.default_rng(cfg.seed)
-        slots = np.zeros((cfg.num_buckets, 2 * (1 + k)), np.float32)
-        slots[:, 1:1 + k] = (cfg.init_scale
-                             * rng.standard_normal((cfg.num_buckets, k)))
-        self.slots = shard_param_table(jnp.asarray(slots), runtime)
+        # v must break symmetry; w and accumulators start at 0
+        v0 = (cfg.init_scale * rng.standard_normal((nb, k))).astype(
+            np.float32)
+        # One device and whole tiles: the tile steps take this table as
+        # one float32 (T, A_HI, B_LO) plane a channel (w, v_1..v_k, cg_w,
+        # cg_v_1..k; learners/table.py), the multi-channel kernels' own
+        # layout, so it is built so, plane by plane, and the step never
+        # re-forms it. Every other path asks _stacked() for (nb, 2(1+k))
+        # and gets it, counted.
+        self._planar = self.can_be_planar(runtime, np.float32, nb)
+        self.slots = factor_table(v0, runtime, self._planar)
+        del v0
         sizes = [k] + list(cfg.hidden) + [1]
         self.mlp, self.mlp_accum = init_mlp(sizes, rng)
         self.n_layers = len(sizes) - 1
@@ -179,7 +188,7 @@ class WideDeepStore(TableCheckpoint):
     # -- pull-only serving surface (serve/forward.py; see ShardedStore) -----
 
     def serve_params(self):
-        return {"slots": self.slots, "mlp": self.mlp}
+        return {"slots": self._stacked(), "mlp": self.mlp}
 
     def build_serve_margin(self):
         k = self.cfg.dim
@@ -215,9 +224,34 @@ class WideDeepStore(TableCheckpoint):
     # d pooled (R, k); the embedding grads are plain channel pushes
     # [dual, dpooled_1..k] plus a row-mask count channel for the exact
     # touched-bucket set.
+    #
+    # On one device the table is 2(1+k) float32 channel planes in the
+    # kernels' (T, A_HI, B_LO) layout (learners/table.py) and the split
+    # step never forms (nb, 1+k), (nb, 2+k) or (nb, 2(1+k)): the pull
+    # kernel's bfloat16 operand is ONE op over the w and v planes
+    # (tilemm.plane_operand), the push kernel's output stays as it wrote
+    # it (a channel is a lane block, the overflow pairs scattered into
+    # it in place, a lane row a pair), and ONE elementwise pass updates
+    # the donated planes.
+    # A list with no pair in it stays on the host (put_block), and its
+    # block takes the program without the gather and the scatter.
+    # A stacked (nb, 2(1+k)) array assigned to ``slots`` (a seeding hook,
+    # a restored checkpoint, what the sparse step leaves) is taken by the
+    # next tile step as it is, through the (nb, ch) helpers at the
+    # kernels' edges, and comes back from that step as planes: the change
+    # of form rides in the step's own update pass and is no pass of its
+    # own (so no ``table_cross``), at the price of one more program.
 
-    def _tile_step(self, info, kind: str):
-        key = (info, kind)
+    def _tile_step(self, info, kind: str, spill: bool = True):
+        """The jitted single-device tile step for a block geometry:
+        ``step(table, mlp, accum, block, t, tau, macc)`` (train) or
+        ``step(table, mlp, block)`` (eval). ``spill``: the block brings
+        a COO overflow list. Every variant updates the float32
+        (T, A_HI, B_LO) channel planes; a planar table IS those planes,
+        a stacked one is sliced into them inside the step. A store that
+        keeps planes gets planes back either way; one that does not, the
+        stacked array (ShardedStore._tile_step's contract)."""
+        key = (info, kind, spill)
         fn = getattr(self, "_tile_cache", {}).get(key)
         if fn is not None:
             self.step_kernel = self._tile_kernel[key]
@@ -226,9 +260,11 @@ class WideDeepStore(TableCheckpoint):
         from wormhole_tpu.ops.metrics import margin_hist
         cfg = self.cfg
         k = cfg.dim
+        oc = info.ovf_cap if spill else 0
         # the MLP runs in-kernel at the fused phase boundary when
-        # its row-blocked weights fit the VMEM budget; spill blocks and
-        # oversized hidden widths fall back split with a recorded reason
+        # its row-blocked weights fit the VMEM budget; a file whose blocks
+        # can spill (with a list or, this once, without) and oversized
+        # hidden widths fall back split with a recorded reason
         res = tilemm.resolve_step_kernel(
             getattr(cfg, "tile_step_kernel", "auto"), ovf_cap=info.ovf_cap,
             deep=True, spec=info.spec, dim=k, hidden=tuple(cfg.hidden),
@@ -239,7 +275,7 @@ class WideDeepStore(TableCheckpoint):
         objv_fn = self.objv_fn
         _, dual_fn = create_loss(cfg.loss)
         spec = info.spec
-        oc = info.ovf_cap
+        keeps_planes = self._planar
 
         def decode(block):
             lab_u8 = block["labels"]
@@ -258,37 +294,62 @@ class WideDeepStore(TableCheckpoint):
         # profiler's op metadata keeps the path of an op inside a nested
         # jit and drops a bare scope's (jit(fwd) in the kept traces). XLA
         # inlines them: the step is one program as before.
-        @jax.jit
-        def wd_pull(s32, pw, ovf_b, ovf_r):
-            w, v = s32[:, 0], s32[:, 1:1 + k]
-            wpull = jnp.concatenate([w[:, None], v], axis=1)
-            return tilemm.forward_pulls(pw, wpull, spec, ovf_b, ovf_r)
+        # At the kernels' edges a planar table takes the helpers over
+        # planes; a stacked one (``stacked``, static) the (nb, ch) helpers
+        # it had, which transpose the operand and the pushes
+        @partial(jax.jit, static_argnums=(0,))
+        def wd_pull(stacked, theta, pw, ovf_b, ovf_r):
+            if stacked:
+                return tilemm.forward_pulls(pw, tbl.join(theta), spec,
+                                            ovf_b, ovf_r)
+            # the operand rounded once from the w and v planes; the
+            # overflow pairs' values gathered from the planes, unrounded
+            pulls = tilemm.plane_pulls(pw, tilemm.plane_operand(theta),
+                                       spec)
+            if oc:
+                pulls = pulls + tilemm.plane_spill_pull_rows(
+                    theta, ovf_b, ovf_r, spec)
+            return pulls
 
         @jax.jit
         def wd_tower(m, x):
             return mlp_forward(m, x, n_layers)
 
-        @jax.jit
-        def wd_push(pw, dual, g_pooled, row_mask, ovf_b, ovf_r):
+        @partial(jax.jit, static_argnums=(0,))
+        def wd_push(stacked, pw, dual, g_pooled, row_mask, ovf_b, ovf_r):
             dvals = jnp.concatenate(
                 [dual[:, None], g_pooled, row_mask[:, None]], axis=1)
-            return tilemm.backward_pushes(pw, dvals, spec, ovf_b, ovf_r)
+            if stacked:
+                return tbl.split(tilemm.backward_pushes(
+                    pw, dvals, spec, ovf_b, ovf_r))
+            # the pushes stay as the kernel writes them,
+            # (T, A_HI, (k+2)*B_LO): a channel's plane is a lane slice
+            push = tilemm.tiled_pushes(pw, dvals, spec)
+            if oc:
+                return tilemm.spill_push_scatter_lanes(
+                    push, dvals, ovf_b, ovf_r, spec)
+            return tilemm.push_planes(push)
 
         @jax.jit
-        def wd_table_update(s32, push):
-            theta, cg = s32[:, :1 + k], s32[:, 1 + k:]
-            v = theta[:, 1:]
-            touched = push[:, 1 + k] > 0
-            g_v = push[:, 1:1 + k] + cfg.l2_v * v * touched[:, None]
-            grads = jnp.concatenate([push[:, :1], g_v], axis=1)
-            cg_new = jnp.where(touched[:, None],
-                               jnp.sqrt(cg * cg + grads * grads), cg)
-            eta = cfg.lr_alpha / (cfg.lr_beta + cg_new)
-            theta_new = jnp.where(touched[:, None],
-                                  theta - eta * grads, theta)
-            d0 = theta_new[:, 0] - theta[:, 0]
-            return (jnp.concatenate([theta_new, cg_new], axis=1),
-                    jnp.sum(d0 * d0))
+        def wd_table_update(planes, push):
+            # ONE elementwise pass, k+2 push and 2(1+k) state planes in,
+            # 2(1+k) out onto the donated state: AdaGrad (with weight
+            # decay on v) on the buckets the block touched (the count
+            # channel) and the progress number from the w plane
+            theta, cg = planes[:1 + k], planes[1 + k:]
+            touched = push[1 + k] > 0
+            grads = (push[0],) + tuple(
+                p + cfg.l2_v * v * touched
+                for p, v in zip(push[1:1 + k], theta[1:]))
+            cg_new = tuple(
+                jnp.where(touched, jnp.sqrt(a * a + g * g), a)
+                for a, g in zip(cg, grads))
+            theta_new = tuple(
+                jnp.where(touched,
+                          th - cfg.lr_alpha / (cfg.lr_beta + a) * g, th)
+                for th, a, g in zip(theta, cg_new, grads))
+            d0 = theta_new[0] - theta[0]
+            return theta_new + cg_new, jnp.sum(d0 * d0)
 
         @jax.jit
         def wd_dense_update(mlp, accum, g_mlp):
@@ -298,9 +359,13 @@ class WideDeepStore(TableCheckpoint):
                 lambda p, g, a: p - cfg.lr_alpha_dense
                 / (cfg.lr_beta + a) * g, mlp, g_mlp, accum), accum
 
-        def forward(s32, mlp, block):
+        def is_stacked(table) -> bool:
+            return not isinstance(table, tbl.PlaneTable)
+
+        def forward(table, mlp, block):
             pw, labels, row_mask, ovf_b, ovf_r = decode(block)
-            pulls = wd_pull(s32, pw, ovf_b, ovf_r)
+            pulls = wd_pull(is_stacked(table), tbl.planes_of(table)[:1 + k],
+                            pw, ovf_b, ovf_r)
             pooled = pulls[:, 1:]
             with jax.named_scope("wd_tower_forward"):
                 deep, vjp = jax.vjp(wd_tower, mlp, pooled)
@@ -308,13 +373,13 @@ class WideDeepStore(TableCheckpoint):
             return (pw, labels, row_mask, ovf_b, ovf_r, pooled, vjp,
                     margin)
 
-        def finish(slots, s32, mlp, accum, push, g_mlp, margin, labels,
+        def finish(table, planes, mlp, accum, push, g_mlp, margin, labels,
                    row_mask, t, macc):
-            # shared update/metric tail downstream of the push buffer
+            # shared update/metric tail downstream of the push planes
             # and MLP grads — structurally identical XLA in the fused
             # and split programs, so the update bits agree between them
             objv = objv_fn(margin, labels, row_mask)
-            new, d0_sq = wd_table_update(s32, push)
+            new, d0_sq = wd_table_update(planes, push)
             mlp_new, accum = wd_dense_update(mlp, accum, g_mlp)
             num_ex = jnp.sum(row_mask)
             acc = accuracy(labels, margin, row_mask)
@@ -323,45 +388,45 @@ class WideDeepStore(TableCheckpoint):
                 jnp.stack([objv, num_ex, acc, d0_sq]), pos, neg])
             # num_ex = completion ticket; the clock/macc outputs are
             # donated into the next step (see ShardedStore._tile_step)
-            return (new.astype(slots.dtype), mlp_new, accum, t + 1,
-                    macc + packed, num_ex)
+            new = (tbl.PlaneTable(new) if keeps_planes
+                   else tbl.table_like(new, table))
+            return new, mlp_new, accum, t + 1, macc + packed, num_ex
 
         if fused:
             # one grid: embedding pulls, in-kernel MLP forward/backward at
             # the phase boundary, dual, channel pushes and MLP param
             # grads in a single dispatch (resolve_step_kernel admits
-            # this only for spill-free blocks within the VMEM budget)
+            # this only for spill-free blocks within the VMEM budget).
+            # Its kernel takes (nb, 1+k) and gives (nb, k+2): formed from
+            # the planes and sliced back into them here
             @partial(jax.jit, donate_argnums=(0, 1, 2, 4, 6))
-            def step(slots, mlp, accum, block, t, tau, macc):
-                s32 = slots.astype(jnp.float32)
+            def step(table, mlp, accum, block, t, tau, macc):
+                planes = tbl.planes_of(table)
                 pw, labels, row_mask, _ovf_b, _ovf_r = decode(block)
-                w, v = s32[:, 0], s32[:, 1:1 + k]
-                wpull = jnp.concatenate([w[:, None], v], axis=1)
                 # pull, tower and push are one kernel here
                 margin, push, g_mlp = tilemm.fused_wd_step(
-                    pw, wpull, labels, row_mask, mlp, spec, k,
-                    tuple(cfg.hidden), cfg.loss)
-                return finish(slots, s32, mlp, accum, push, g_mlp,
-                              margin, labels, row_mask, t, macc)
+                    pw, tbl.join(planes[:1 + k]), labels, row_mask, mlp,
+                    spec, k, tuple(cfg.hidden), cfg.loss)
+                return finish(table, planes, mlp, accum, tbl.split(push),
+                              g_mlp, margin, labels, row_mask, t, macc)
         elif kind == "train":
             @partial(jax.jit, donate_argnums=(0, 1, 2, 4, 6))
-            def step(slots, mlp, accum, block, t, tau, macc):
-                s32 = slots.astype(jnp.float32)
+            def step(table, mlp, accum, block, t, tau, macc):
                 (pw, labels, row_mask, ovf_b, ovf_r, pooled, vjp,
-                 margin) = forward(s32, mlp, block)
+                 margin) = forward(table, mlp, block)
                 dual = dual_fn(margin, labels, row_mask)
                 with jax.named_scope("wd_tower_backward"):
                     g_mlp, g_pooled = vjp(dual)
-                push = wd_push(pw, dual, g_pooled, row_mask, ovf_b,
-                               ovf_r)
-                return finish(slots, s32, mlp, accum, push, g_mlp,
-                              margin, labels, row_mask, t, macc)
+                push = wd_push(is_stacked(table), pw, dual, g_pooled,
+                               row_mask, ovf_b, ovf_r)
+                return finish(table, tbl.planes_of(table), mlp, accum,
+                              push, g_mlp, margin, labels, row_mask, t,
+                              macc)
         else:
             @jax.jit
-            def step(slots, mlp, block):
-                s32 = slots.astype(jnp.float32)
+            def step(table, mlp, block):
                 (_, labels, row_mask, _, _, _, _,
-                 margin) = forward(s32, mlp, block)
+                 margin) = forward(table, mlp, block)
                 objv = objv_fn(margin, labels, row_mask)
                 num_ex = jnp.sum(row_mask)
                 acc = accuracy(labels, margin, row_mask)
@@ -554,7 +619,7 @@ class WideDeepStore(TableCheckpoint):
         """Fused crec2-block wide&deep step; metrics accumulate ON DEVICE
         (fetch_metrics, same harvest pipeline as ShardedStore). Returns
         the non-donated completion ticket, never the clock."""
-        step = self._tile_step(info, "train")
+        step = self._tile_step(info, "train", "ovf_b" in block)
         if self.step_kernel[0] == "fused":
             from wormhole_tpu.obs import trace
             with trace.span("tilemm:mlp_phase", cat="tile"):
@@ -576,19 +641,20 @@ class WideDeepStore(TableCheckpoint):
         return ticket
 
     def tile_eval_step(self, block: dict, info):
-        return self._tile_step(info, "eval")(self.slots, self.mlp, block)
+        return self._tile_step(info, "eval", "ovf_b" in block)(
+            self.slots, self.mlp, block)
 
     # -- ShardedStore surface ------------------------------------------------
 
     def train_step(self, batch: SparseBatch, tau: float = 0.0):
         self.slots, self.mlp, self.mlp_accum, t_new, metrics = self._step(
-            self.slots, self.mlp, self.mlp_accum, batch,
+            self._stacked(), self.mlp, self.mlp_accum, batch,
             self._t_device(), self._tau_const(tau))
         self._advance_t(t_new)
         return metrics
 
     def eval_step(self, batch: SparseBatch):
-        return self._eval(self.slots, self.mlp, batch)
+        return self._eval(self._stacked(), self.mlp, batch)
 
     def nnz_weight(self) -> int:
         return int(jnp.sum(self.slots[:, 0] != 0))
@@ -608,18 +674,18 @@ class WideDeepStore(TableCheckpoint):
         if rank is None:
             rank = jax.process_index()
         k = self.cfg.dim
-        arr = np.asarray(self.slots[:, :1 + k])
+        arr = np.asarray(self._stacked()[:, :1 + k])
         dense = {f"mlp_{k2}": np.asarray(v) for k2, v in self.mlp.items()}
         np.savez_compressed(f"{path}_{rank}.npz", w=arr[:, 0],
                             v=arr[:, 1:], **dense)
 
     def load_model(self, path: str, expect_key_fold: str = "") -> None:
         data = np.load(path)
-        slots = np.array(self.slots)
+        like = self._stacked()
+        slots = np.array(like)
         slots[:, 0] = data["w"]
         slots[:, 1:1 + self.cfg.dim] = data["v"]
-        self.slots = jax.device_put(jnp.asarray(slots),
-                                    self.slots.sharding)
+        self.slots = jax.device_put(jnp.asarray(slots), like.sharding)
         self.mlp = {k.replace("mlp_", ""): jnp.asarray(v)
                     for k, v in data.items() if k.startswith("mlp_")}
 

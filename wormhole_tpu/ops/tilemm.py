@@ -1050,6 +1050,72 @@ def spill_push_scatter_planes(push, dual_rows: jax.Array, ovf_b: jax.Array,
                  for c, p in enumerate(push))
 
 
+def plane_operand(planes) -> jax.Array:
+    """The multi-channel kernels' bfloat16 operand of float32
+    (T, A_HI, B_LO) planes that are the pull channels as they stand (a
+    table whose every pulled value is a stored one: wide&deep's w and
+    v): each rounded once, channel-major on the lanes. One concatenate
+    (jnp.concatenate nests them sixteen at a time)."""
+    return jax.lax.concatenate([p.astype(jnp.bfloat16) for p in planes],
+                               planes[0].ndim - 1)
+
+
+def plane_spill_pull_rows(planes, ovf_b: jax.Array, ovf_r: jax.Array,
+                          spec: TileSpec) -> jax.Array:
+    """spill_pull_rows from channel planes: the listed buckets' values
+    are gathered plane by plane (float32, unrounded, as the stacked
+    path pulls them) and summed onto their rows."""
+    valid = ovf_b != jnp.uint32(0xFFFFFFFF)
+    idx = jnp.where(valid, ovf_b, 0).astype(jnp.int32)
+    wv = jnp.where(valid[:, None],
+                   jnp.stack([p.reshape(-1)[idx] for p in planes], axis=1),
+                   0.0)
+    return jnp.zeros((spec.block_rows, wv.shape[1]), jnp.float32).at[
+        ovf_r.astype(jnp.int32) % spec.block_rows].add(wv)
+
+
+def tiled_pushes(pw: jax.Array, dual_rows: jax.Array,
+                 spec: TileSpec) -> jax.Array:
+    """backward_pushes as the kernel writes them: float32
+    (T, A_HI, ch*B_LO), channel ``c`` the lane block
+    ``[c*B_LO, (c+1)*B_LO)`` (push_planes)."""
+    return _build_bwd_multi(spec, dual_rows.shape[1], True)(pw, dual_rows)
+
+
+def push_planes(g: jax.Array) -> tuple:
+    """The (T, A_HI, B_LO) plane a channel of tiled pushes: lane slices,
+    which fuse into whatever reads them."""
+    return tuple(g[..., c * B_LO:(c + 1) * B_LO]
+                 for c in range(g.shape[-1] // B_LO))
+
+
+def spill_push_scatter_lanes(g: jax.Array, dual_rows: jax.Array,
+                             ovf_b: jax.Array, ovf_r: jax.Array,
+                             spec: TileSpec) -> tuple:
+    """spill_push_scatter into tiled pushes (tiled_pushes) -> their
+    planes. Bucket ``b``'s channels lie on ONE lane row of ``g``,
+    ``(b // TILE, (b % TILE) // B_LO)``, at lanes ``c*B_LO + b % B_LO``:
+    a list short beside the table is ONE scatter-add of such rows (a
+    pair's values spread over a row of zeros), in place on the kernel's
+    own output. (The v5e compiler flattens a scatter of single values
+    into the (8, 128)-tiled array, copying it out and back, 2.3 GB each
+    way at 2**24 x 34; a scatter a plane copies every plane out first.)
+    A long list, whose spread rows would outgrow the pushes themselves,
+    goes a plane at a time (spill_push_scatter_planes)."""
+    if 8 * ovf_b.shape[0] > g.shape[0] * g.shape[1]:
+        return spill_push_scatter_planes(push_planes(g), dual_rows, ovf_b,
+                                         ovf_r, spec)
+    valid = ovf_b != jnp.uint32(0xFFFFFFFF)
+    idx = jnp.where(valid, ovf_b, 0).astype(jnp.int32)
+    d = jnp.where(valid[:, None],
+                  dual_rows[ovf_r.astype(jnp.int32) % spec.block_rows],
+                  0.0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (d.shape[0], g.shape[-1]), 1)
+    rows = jnp.where(lane % B_LO == (idx % B_LO)[:, None],
+                     jnp.repeat(d, B_LO, axis=1), 0.0)
+    return push_planes(g.at[idx // TILE, (idx % TILE) // B_LO].add(rows))
+
+
 # ---------------------------------------------------------------------------
 # fused train-step kernels (tile_step_kernel=fused)
 # ---------------------------------------------------------------------------

@@ -1991,7 +1991,6 @@ def _mc_timed(app, path: str, mesh: bool) -> dict:
             "dispatch_skew_ms_max": round(gauges[1].value, 3),
             "feed_groups": int(gauges[2].value - c0[2]),
             "pad_blocks": int(gauges[3].value - c0[3]),
-            "spill_blocks": int(gauges[4].value - c0[4]),
         })
     wire = app.obs.registry.get("comm/bytes_wire")
     rec["comm_bytes_wire"] = int(wire.value) if wire else 0
@@ -2064,8 +2063,7 @@ def _bench_multichip_inline() -> dict:
                    "scaling_efficiency": round(
                        ring["ex_per_sec"] / max(rate0 * nd, 1e-9), 4)}
             for k in ("passes", "dispatch_skew_ms", "dispatch_skew_ms_max",
-                      "feed_groups", "pad_blocks", "spill_blocks",
-                      "comm_bytes_wire"):
+                      "feed_groups", "pad_blocks", "comm_bytes_wire"):
                 rec[k] = ring[k]
             out["shapes"][shape] = rec
     finally:

@@ -36,20 +36,3 @@ def tmp_libsvm(tmp_path, rng):
     path = tmp_path / "data.libsvm"
     path.write_text("\n".join(lines) + "\n")
     return str(path), labels, sp.csr_matrix(dense)
-
-
-# ``tests/benchmark/test_bm_catalog.py`` holds the replay cells as a constant
-# (``REPLAY_CELLS``) and wants ``train_ex_per_s`` to list exactly those. A PR
-# that adds a cell may not edit a file the benchmark has, and
-# ``tests/benchmark/conftest.py`` (PR 30, which joins the stream cells the
-# same way) is such a file now, so the replay cells such PRs add are named
-# here and joined as the module is collected. The next ``benchmark`` PR moves
-# them into ``test_bm_catalog.py`` and deletes this hook.
-ADDED_REPLAY_CELLS = {"criteo_wide_deep.replay_uniform"}           # PR 34
-
-
-def pytest_collection_modifyitems(items):
-    for module in {item.module for item in items
-                   if getattr(item, "module", None) is not None}:
-        if module.__name__ == "test_bm_catalog":
-            module.REPLAY_CELLS = set(module.REPLAY_CELLS) | ADDED_REPLAY_CELLS

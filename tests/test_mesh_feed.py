@@ -15,11 +15,10 @@ The contracts pinned here:
     PAD filler blocks pools exactly the same (margin, label) rows as
     the single-device path over the same file: PAD lanes (label 255)
     are invisible;
-  * spill accounting — an online-encoded block whose COO overflow
-    exceeds the cap rides the SAME ring as the groups (passthrough, no
-    group flush) to the audited scatter step: every row credited once,
-    and the mesh/spill_blocks + feed/tile_fallback_blocks counters
-    tick;
+  * overflow in a group — an online-encoded block whose pairs pass
+    the per-tile cap stays a member of its group with its overflow
+    list, the lists of one group widened to one width: every row
+    credited once, the crec2 path's table;
   * direct placement — every shard of a group assembled chip by chip
     holds the bytes of the same index of the stacked group, under the
     step's shape, dtype and sharding (tile and v1; full and padded;
@@ -145,45 +144,68 @@ def test_padded_tail_eval_pooled_matches_single_device(tmp_path, rng):
     assert np.isclose(prog1.objv, prog2.objv, rtol=1e-4)
 
 
-def test_online_spill_blocks_ride_the_ring(tmp_path, rng):
+def test_online_hot_block_rides_its_group(tmp_path, rng):
     """tile_online over a v1 stream on a data:2 mesh: a hot-bucket block
-    (overflow past the cap) falls back to the scatter step THROUGH the
-    ring as a passthrough spill — it must not flush the open group, the
-    spill counters tick, every row is credited once, and the pipelined
-    ring matches the workers=0 oracle bit for bit."""
-    from wormhole_tpu.obs.metrics import default_registry, mesh_feed_gauges
+    (32K pairs past the per-tile cap) stays a member of its group, its
+    pairs on its overflow list; the cold member's list is widened to the
+    hot one's width; every row is credited once; the table is the one
+    the same rows train from a crec2 file written with room for the
+    pairs; and the pipelined ring matches the workers=0 oracle bit for
+    bit."""
+    from wormhole_tpu.data import crec
     blocks = []
     lab = []
     for i in range(4):
         k, l = make_rows(rng, BR)
-        if i == 2:                          # the spill block: one hot bucket
+        if i == 2:                          # the hot block: one bucket
             k = np.full((BR, NNZ), np.uint32(42), np.uint32)
         blocks.append(k)
         lab.append(l)
     keys = np.concatenate(blocks)
     labels = np.concatenate(lab)
     n = len(labels)
-    path = tmp_path / "spill.crec"
+    path = tmp_path / "hot.crec"
     with CRecWriter(str(path), nnz=NNZ, block_rows=BR) as w:
         w.append(keys, labels)
-
-    reg = default_registry()
-    fallback = reg.counter("feed/tile_fallback_blocks")
+    info = crec.online_info(NNZ, BR, NB)
+    counts = [len(crec.encode_tile_pairs(k, NB, info.spec)[1])
+              for k in blocks]
+    n_ovf = counts[2]
+    assert n_ovf > 30 * crec.ONLINE_OVF_CAP > 30 * max(counts[:2])
 
     def train(workers):
-        gauges = mesh_feed_gauges(reg)
-        spills0, fb0 = gauges[4].value, fallback.value
         app = make_app(path, "data:2", fmt="crec", tile_online="on",
                        pipeline_workers=workers)
         prog = app.run()
         assert prog.num_ex == n, workers
-        assert gauges[4].value == spills0 + 1.0    # mesh/spill_blocks
-        assert fallback.value == fb0 + 1.0
+        assert app._online_room.room == crec.overflow_room(n_ovf)
+        assert app.timer.totals["online_overflow_pairs"] == sum(counts)
         return np.asarray(app.store.slots)
 
     w2 = train(2)
     w0 = train(0)
     assert np.array_equal(w2, w0)
+    c2 = tmp_path / "hot.crec2"
+    with CRec2Writer(str(c2), nnz=NNZ, nb=NB, subblocks=1, cap=info.cap,
+                     ovf_cap=crec.overflow_room(n_ovf)) as w:
+        w.append(keys, labels)
+    ref = make_app(c2, "data:2")
+    assert ref.run().num_ex == n
+    assert np.array_equal(np.asarray(ref.store.slots), w0)
+
+
+def test_widen_overflow_makes_a_group_one_width():
+    from wormhole_tpu.data.crec import widen_overflow
+    a = {"pw": 1, "ovf_b": np.array([7, 0xFFFFFFFF], np.uint32),
+         "ovf_r": np.array([3, 0], np.uint32)}
+    b = {"pw": 2, "ovf_b": np.array([5, 6, 8, 0xFFFFFFFF], np.uint32),
+         "ovf_r": np.array([1, 2, 4, 0], np.uint32)}
+    same = [a, dict(a)]
+    assert widen_overflow(same) is same
+    wa, wb = widen_overflow([a, b])
+    assert wb is b and wa["pw"] == 1 and len(a["ovf_b"]) == 2
+    assert wa["ovf_b"].tolist() == [7, 0xFFFFFFFF, 0xFFFFFFFF, 0xFFFFFFFF]
+    assert wa["ovf_r"].tolist() == [3, 0, 0, 0]
 
 
 def _group_feed(path, fmt, workers, want_labels=False):
@@ -233,10 +255,9 @@ def test_placed_group_equals_stacked_group(tmp_path, rng, fmt, member,
     feed, info, pads, shardings, is_tile = _group_feed(
         path, fmt, workers, want_labels=True)
     groups = list(feed)
-    assert [g[0] for g in groups] == ["group", "group"]
-    assert [g[3] for g in groups] == [2 * BR, 1000]
+    assert [g[2] for g in groups] == [2 * BR, 1000]
     k = 0 if member == "full" else 1
-    _tag, placed, lab, _rows = groups[k]
+    placed, lab, _rows = groups[k]
     want, want_lab = stack_mesh_group(blocks[2 * k:2 * k + 2], 2, info,
                                       pads, is_tile, want_labels=True)
     assert np.array_equal(lab, want_lab)
@@ -295,6 +316,6 @@ def test_pad_block_built_only_for_a_short_tail(tmp_path, rng, blocks):
     path = tmp_path / "p.crec2"
     write_file(path, keys, labels)
     feed = _group_feed(path, "crec2", 2)[0]
-    assert sum(g[3] for g in feed) == n
+    assert sum(g[2] for g in feed) == n
     assert ("_pads" in vars(feed)) == (blocks % 2 == 1)
     assert feed.skew_snapshot()["pad_blocks"] == blocks % 2

@@ -137,10 +137,10 @@ def test_mesh_rule_bounds_a_part_that_gates_on_no_step():
     assert "eval_wait" in app.timer.totals
     unbounded = MetricWindow(app, Progress(), VAL, None)
     for _ in range(4):
-        unbounded.add_spill(ROWS["sparse"](MARGINS), lab)
-    assert len(unbounded.spill) == 4 and unbounded.local.count == 0
+        unbounded.add_step(ROWS["sparse"](MARGINS), lab, "sparse")
+    assert len(unbounded.steps) == 4 and unbounded.local.count == 0
     unbounded.drain()
-    assert not unbounded.spill and unbounded.local.count == 4
+    assert not unbounded.steps and unbounded.local.count == 4
 
 
 class RecordingStore:
@@ -156,7 +156,6 @@ STEP_TABLE = {
                   False),
     "dense_mesh": ("dense_train_step_mesh", "dense_eval_step_mesh", "tile",
                    False),
-    "spill": ("train_step", "eval_step", "sparse", True),
 }
 
 
@@ -171,8 +170,7 @@ def test_crec_step_table(form, kind):
     name, args, kw = step("operand", 2.0)
     assert layout == want_layout
     assert name == (train_name if kind == TRAIN else eval_name)
-    geo = () if form == "spill" else \
-        (info,) if form.startswith("tile") else (64, 8)
+    geo = (info,) if form.startswith("tile") else (64, 8)
     assert args == ("operand",) + geo
     assert kw == ({"tau": 2.0} if kind == TRAIN and takes_tau else {})
 

@@ -316,22 +316,32 @@ def default_cap(nnz: int, nb: int) -> int:
     return max(128, int(-(-(mean + 3 * mean ** 0.5) // 128)) * 128)
 
 
-def encode_tile_block(keys: np.ndarray, nb: int, spec,
-                      ovf_cap: int) -> Tuple[np.ndarray, np.ndarray,
-                                             np.ndarray, int]:
-    """One keys grid -> crec2 block operands: fold the real keys of a
-    ``(block_rows, nnz)`` u32 grid (SENTINEL_KEY empties) to hashed
-    buckets and tile-group them. Returns ``(pw, ovf_b, ovf_r, n_ovf)``
-    with fixed-``ovf_cap`` overflow arrays (tilemm.encode_block_capped
-    contract). THE single encoder entry: the crec2 writer and the online
-    tile-encode feed both call it, which is what makes an online-encoded
+def encode_tile_pairs(keys: np.ndarray, nb: int,
+                      spec) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """One keys grid -> ``(pw, ovf_b, ovf_r)`` with the overflow list as
+    long as it is: fold the real keys of a ``(block_rows, nnz)`` u32 grid
+    (SENTINEL_KEY empties are no pair) to hashed buckets and tile-group
+    them. THE single encoder: the crec2 writer and the online tile-encode
+    feed both come through it, which is what makes an online-encoded
     block bit-identical to the same rows pre-converted to a crec2
     file."""
     from wormhole_tpu.data.hashing import fold_keys32
-    from wormhole_tpu.ops.tilemm import encode_block_capped
+    from wormhole_tpu.ops.tilemm import encode_block
     rr, cc = np.nonzero(keys != SENTINEL_KEY)
     buckets = fold_keys32(keys[rr, cc], nb)
-    return encode_block_capped(buckets, rr.astype(np.int64), spec, ovf_cap)
+    return encode_block(buckets, rr.astype(np.int64), spec)
+
+
+def encode_tile_block(keys: np.ndarray, nb: int, spec,
+                      ovf_cap: int) -> Tuple[np.ndarray, np.ndarray,
+                                             np.ndarray, int]:
+    """:func:`encode_tile_pairs` with the overflow list at a fixed width:
+    ``(pw, ovf_b, ovf_r, n_ovf)`` with ``ovf_cap``-long arrays
+    (tilemm.cap_overflow) and the true count, which the caller holds
+    against ``ovf_cap`` before trusting them."""
+    from wormhole_tpu.ops.tilemm import cap_overflow
+    pw, ovb, ovr = encode_tile_pairs(keys, nb, spec)
+    return (pw, *cap_overflow(ovb, ovr, ovf_cap), len(ovb))
 
 
 class CRec2Writer:
@@ -883,12 +893,54 @@ class TextCRecFeed(PackedFeed):
 # step without a pre-converted file (ISSUE 5)
 # ---------------------------------------------------------------------------
 
-# runtime overflow headroom per online-encoded block. Unlike the writer
-# (which can reject skew and ask for a bigger ovf_cap), the runtime path
-# falls back to the scatter step for a block whose overflow exceeds this
-# — so the value only trades a little device transfer width against
-# fallback frequency.
+# The least room an online block's COO overflow list is given: a list of
+# up to this many pairs keeps the width it has always had, so a stream
+# that hardly overflows compiles the one spill program it always did.
 ONLINE_OVF_CAP = 1024
+
+
+def overflow_room(n: int) -> int:
+    """The room given to a list of ``n`` pairs: ``n`` and an eighth more,
+    rounded up to a multiple of the power of two that lies between an
+    eighth and a quarter of ``n`` (so 1.125 to 1.375 times ``n``), and
+    never under ``ONLINE_OVF_CAP``. The coarse step makes the blocks of
+    one data set, whose counts differ by a percent, agree on ONE room:
+    a room is a shape, and a shape is a compile of the spill step."""
+    if n <= ONLINE_OVF_CAP:
+        return ONLINE_OVF_CAP
+    step = 1 << (int(n).bit_length() - 3)
+    return -(-(n + n // 8) // step) * step
+
+
+class OverflowRoom:
+    """The room in force for the overflow lists of online-encoded
+    blocks, chosen from what the encoder counted and from nothing else
+    (no option): it starts at ``ONLINE_OVF_CAP``, and a block whose list
+    passes it sets it to :func:`overflow_room` of that block's count. It
+    never shrinks, so a stream settles within its first blocks (a later
+    block grows it only by passing the hottest one by an eighth), and a
+    settled room compiles nothing. A block with NO overflow pair keeps
+    the least width whatever the room: its empty list stays on the host
+    (``TableCheckpoint.put_block``) and the block takes the step that
+    has no spill. One object outlives the feeds of a job (a pass makes a
+    new feed): the app holds it. Encode workers share it under a lock."""
+
+    def __init__(self):
+        import threading
+        self.room = ONLINE_OVF_CAP
+        self.grown = 0            # times a block passed the room
+        self._lock = threading.Lock()
+
+    def fit(self, n: int) -> int:
+        """The width for a list of ``n`` pairs, the room grown if need
+        be."""
+        if n == 0:
+            return ONLINE_OVF_CAP
+        with self._lock:
+            if n > self.room:
+                self.room = overflow_room(n)
+                self.grown += 1
+            return self.room
 
 
 def online_info(nnz: int, src_rows: int, nb: int,
@@ -896,10 +948,12 @@ def online_info(nnz: int, src_rows: int, nb: int,
     """Tile geometry for online-encoding a stream of ``src_rows``-row v1
     blocks into ``nb`` buckets: the subblock count rounds the source
     block up to a multiple of RSUB (extra rows ride as padding), cap is
-    the same mean+3o default the writer uses. Raises ValueError (via
-    ``.spec``) exactly where the tilemm limits would reject a writer
-    with the same geometry — callers probe admissibility by constructing
-    the spec."""
+    the same mean+3o default the writer uses. ``ovf_cap`` is the LEAST
+    width of a block's overflow list: the width in force is the feed's
+    (:class:`OverflowRoom`) and rides on the block's own arrays. Raises
+    ValueError (via ``.spec``) exactly where the tilemm limits would
+    reject a writer with the same geometry — callers probe admissibility
+    by constructing the spec."""
     from wormhole_tpu.ops.tilemm import RSUB
     subblocks = max(-(-src_rows // RSUB), 1)
     return CRec2Info(nnz=nnz, block_rows=subblocks * RSUB, total_rows=0,
@@ -910,7 +964,7 @@ def online_info(nnz: int, src_rows: int, nb: int,
 class TileOnlineFeed:
     """Online tile-encode stage: chain a v1-block source feed (PackedFeed
     over a crec file, or TextCRecFeed over text) into a DeviceFeed whose
-    prep workers run fold+tile-group (``encode_tile_block``) per block —
+    prep workers run fold+tile-group (``encode_tile_pairs``) per block —
     the CRec2Writer's expensive host work, relocated onto the PR 1
     parallel pad workers so it hides behind device compute. Yields the
     same ``(device_block_dict, host_labels, rows)`` triples the crec2
@@ -919,11 +973,13 @@ class TileOnlineFeed:
     pre-encoding move of Li et al.'s parameter server, done in the feed
     instead of a file format).
 
-    Cap-overflow fallback: a block whose COO overflow exceeds
-    ``info.ovf_cap`` (skew the writer would reject, but runtime data has
-    no writer) is instead localized into a whole-block SparseBatch and
-    yielded as-is — the consumer routes it through the audited scatter
-    step and counts it (``fallback_blocks``). Never an error.
+    Pairs past the per-tile cap ride on the block's COO overflow list,
+    as a crec2 file's do, at the width ``room`` has in force
+    (:class:`OverflowRoom`: sized to what the encoder counted, grown when
+    a block passes it). Every block stays a tile block: there is no
+    other step for a skewed one to fall to. ``overflow_pairs``,
+    ``overflow_slots`` (the widths of the lists that hold a pair) and
+    the room's ``grown`` are counted for the consumer's timer.
 
     ``inner`` must yield ``(dev, packed_v1, rows)`` with an identity
     device_put (its packed v1 bytes stay on host for the encode);
@@ -932,15 +988,19 @@ class TileOnlineFeed:
 
     def __init__(self, inner, info: CRec2Info, *, workers: int = 2,
                  depth: int = 2, device_put=None, cache: bool = False,
-                 name: str = "tile-encode"):
+                 name: str = "tile-encode", room=None):
         self.inner = inner
         self.info = info
+        self.room = room if room is not None else OverflowRoom()
         self.workers = workers
         self.depth = depth
         self.name = name
         self._device_put = device_put
         self.put_time = 0.0
-        self.fallback_blocks = 0
+        # transfer-thread counters (single writer)
+        self.overflow_pairs = 0
+        self.overflow_slots = 0
+        self._grown_before = self.room.grown
         self._cache: Optional[list] = [] if cache else None
         self._cache_full = False
         self._pipe = None
@@ -978,8 +1038,9 @@ class TileOnlineFeed:
                 self._cache = []
 
     def _encode(self, item, _ctx):
-        """Worker-side stage: v1 packed block -> crec2 typed dict, or a
-        SparseBatch when the block's overflow exceeds the cap."""
+        """Worker-side stage: v1 packed block -> crec2 typed dict, its
+        overflow list at the room's width."""
+        from wormhole_tpu.ops.tilemm import cap_overflow
         packed, rows = item
         info = self.info
         R, nnz = info.block_rows, info.nnz
@@ -996,17 +1057,10 @@ class TileOnlineFeed:
             kgrid[:src.block_rows] = keys
             lab = np.full(R, PAD_LABEL, np.uint8)
             lab[:src.block_rows] = labels
-        pw, ob, orow, n_ovf = encode_tile_block(kgrid, info.nb, info.spec,
-                                                info.ovf_cap)
-        if n_ovf > info.ovf_cap:
-            from wormhole_tpu.data.feed import bucket_block_batch
-            from wormhole_tpu.data.hashing import fold_keys32
-            valid = kgrid != SENTINEL_KEY
-            grid = np.zeros(kgrid.shape, np.int64)
-            grid[valid] = fold_keys32(kgrid[valid], info.nb)
-            return bucket_block_batch(grid, valid, lab), lab, rows
+        pw, ovb, ovr = encode_tile_pairs(kgrid, info.nb, info.spec)
+        ob, orow = cap_overflow(ovb, ovr, self.room.fit(len(ovb)))
         return ({"pw": pw, "labels": lab, "ovf_b": ob, "ovf_r": orow},
-                lab, rows)
+                lab, rows, len(ovb))
 
     def _src(self, packed) -> int:
         if self._src_rows is None:
@@ -1016,11 +1070,10 @@ class TileOnlineFeed:
     def _transfer(self, res):
         import time as _time
         import jax
-        payload, lab, rows = res
-        from wormhole_tpu.data.feed import SparseBatch
-        if isinstance(payload, SparseBatch):
-            # single transfer thread: plain increment is safe
-            self.fallback_blocks += 1
+        payload, lab, rows, n_ovf = res
+        if n_ovf:
+            self.overflow_pairs += n_ovf
+            self.overflow_slots += len(payload["ovf_b"])
         put = self._device_put or jax.device_put
         t0 = _time.perf_counter()
         dev = put(payload)
@@ -1064,7 +1117,16 @@ class TileOnlineFeed:
             "consume_stall": snap["consume_stall"],
             "batches": snap["batches"],
             "ring_max": snap["ring_max"],
+            # counts, not seconds: what this pass's blocks put on their
+            # overflow lists, the slots those lists were shipped at, and
+            # how often a block passed the room
+            "overflow_pairs": self.overflow_pairs,
+            "overflow_slots": self.overflow_slots,
+            "room_grown": self.room.grown - self._grown_before,
+            "room": self.room.room,
         }
+        self.overflow_pairs = self.overflow_slots = 0
+        self._grown_before = self.room.grown
         if timer is not None:
             n = max(out["batches"], 1)
             for k in ("parse", "put", "encode"):
@@ -1159,6 +1221,24 @@ def place_mesh_group(views: list, shardings):
     return jax.tree.map(place, shardings, *views)
 
 
+def widen_overflow(views: list) -> list:
+    """The tile blocks of one group with their overflow lists at ONE
+    width, the widest among them: a shorter list is continued with
+    unused slots (tilemm.cap_overflow), in a new dict; a group that
+    agrees already is returned as it is."""
+    from wormhole_tpu.ops.tilemm import cap_overflow
+    width = max(len(v["ovf_b"]) for v in views)
+    if all(len(v["ovf_b"]) == width for v in views):
+        return views
+    out = []
+    for v in views:
+        if len(v["ovf_b"]) != width:
+            ob, orow = cap_overflow(v["ovf_b"], v["ovf_r"], width)
+            v = dict(v, ovf_b=ob, ovf_r=orow)
+        out.append(v)
+    return out
+
+
 def mesh_group_labels(views: list, info, is_tile: bool) -> np.ndarray:
     """The label lanes of one whole group, concatenated in the global
     ``(D * R,)`` row order of the mesh eval step's margins, PAD rows
@@ -1185,16 +1265,14 @@ class MeshGroupFeed:
     (``want_labels``). The feed copies no byte itself:
     ``host_copy_bytes`` is the inner feed's.
 
-    Encode-overflow spill batches (online mode: the inner TileOnlineFeed
-    yields a SparseBatch for a block whose COO overflow exceeds the cap)
-    ride the SAME ring as ``("spill", batch_dev, labels_u8, rows)``
-    items — in stream position, without flushing the open group — so a
-    skewed block no longer stalls the group loop for a synchronous
-    scatter round trip.
+    The members of an ONLINE group may bring overflow lists of different
+    widths (a block with no overflow pair keeps the least width; the
+    room may have grown between two blocks): the stack workers widen the
+    shorter lists to the group's widest with unused slots
+    (:func:`widen_overflow`), since the group is one array a lane.
 
-    Yields ``("group", blocks_dev, labels_u8, rows)`` and
-    ``("spill", batch_dev, labels_u8, rows)``; ``labels_u8`` is None
-    unless ``want_labels``. ``workers=0`` runs every stage inline on
+    Yields ``(blocks_dev, labels_u8, rows)`` a group; ``labels_u8`` is
+    None unless ``want_labels``. ``workers=0`` runs every stage inline on
     the consumer thread — the bit-determinism oracle, same contract as
     DeviceFeed."""
 
@@ -1215,7 +1293,7 @@ class MeshGroupFeed:
         # dispatcher-thread counters (single writer; consumers read via
         # skew_snapshot after iteration)
         self.skew = {"groups": 0, "skew_sum": 0.0, "skew_max": 0.0,
-                     "pad_blocks": 0, "spill_blocks": 0}
+                     "pad_blocks": 0}
         self._pipe = None
 
     @property
@@ -1239,53 +1317,35 @@ class MeshGroupFeed:
 
     def _source(self):
         from wormhole_tpu.data.pipeline import group_blocks
-
-        def is_spill(item) -> bool:
-            # online inner feeds yield a SparseBatch (not a typed block
-            # dict) for cap-overflow blocks; v1/crec2 streams never spill
-            return self.online and not isinstance(item[0], dict)
-
         sk = self.skew
-        for tag, payload, skew_s in group_blocks(
-                self.inner, self.D, passthrough=is_spill):
-            if tag == "item":
-                dev, host, rows = payload
-                sk["spill_blocks"] += 1
-                yield ("spill", dev, np.asarray(host), rows)
-                continue
+        for group, skew_s in group_blocks(self.inner, self.D):
             sk["groups"] += 1
             sk["skew_sum"] += skew_s
             sk["skew_max"] = max(sk["skew_max"], skew_s)
-            sk["pad_blocks"] += self.D - len(payload)
-            yield ("group", [p[0] for p in payload],
-                   sum(p[2] for p in payload))
+            sk["pad_blocks"] += self.D - len(group)
+            yield [p[0] for p in group], sum(p[2] for p in group)
 
     def _assemble(self, item, _ctx):
         """Worker-side stage, all that is left of group assembly: a
         short tail takes the shared PAD block as its missing members,
-        and an eval pass its label lanes."""
-        if item[0] == "spill":
-            return item
-        _tag, views, rows = item
+        an online group's overflow lists one width, and an eval pass its
+        label lanes."""
+        views, rows = item
         if len(views) < self.D:
             views = views + [self._pads] * (self.D - len(views))
+        if self.online:
+            views = widen_overflow(views)
         labels = (mesh_group_labels(views, self.info, self.is_tile)
                   if self.want_labels else None)
-        return ("group", views, labels, rows)
+        return views, labels, rows
 
     def _transfer(self, item):
         import time as _time
-        import jax
         t0 = _time.perf_counter()
-        if item[0] == "spill":
-            _tag, batch, lab, rows = item
-            dev = jax.device_put(batch)
-            self.put_time += _time.perf_counter() - t0
-            return ("spill", dev, lab, rows)
-        _tag, views, labels, rows = item
+        views, labels, rows = item
         dev = place_mesh_group(views, self._shardings)
         self.put_time += _time.perf_counter() - t0
-        return ("group", dev, labels, rows)
+        return dev, labels, rows
 
     def __iter__(self):
         from wormhole_tpu.data.pipeline import DeviceFeed
@@ -1323,9 +1383,10 @@ class MeshGroupFeed:
             "batches": snap["batches"],
             "ring_max": snap["ring_max"],
         }
-        if "encode" in inner_snap:
-            out["encode"] = inner_snap["encode"]
-            out["encode_stall"] = inner_snap["encode_stall"]
+        for k in ("encode", "encode_stall", "overflow_pairs",
+                  "overflow_slots", "room_grown", "room"):
+            if k in inner_snap:
+                out[k] = inner_snap[k]
         if timer is not None:
             n = max(out["batches"], 1)
             for k in ("parse", "put", "stack"):

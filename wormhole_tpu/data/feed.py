@@ -125,37 +125,6 @@ def pad_to_batch(loc: Localized, minibatch_size: int,
     return out
 
 
-def bucket_block_batch(buckets: np.ndarray, valid: np.ndarray,
-                       labels_u8: np.ndarray,
-                       key_pad: int = 0) -> SparseBatch:
-    """Build the scatter-step SparseBatch for one folded crec block —
-    the online tile-encode overflow fallback (data/crec.TileOnlineFeed):
-    ``buckets`` is the (rows, nnz) global bucket grid, ``valid`` masks
-    real feature slots (binary features, so vals is the mask), and
-    ``labels_u8`` uses the crec convention (255 = padded row). The
-    whole block rides as ONE batch, sized to the block, so the scatter
-    step sees exactly the rows the tile step would have."""
-    from wormhole_tpu.data.localizer import localize_bucket_grid
-    uniq, cols = localize_bucket_grid(buckets, valid)
-    k = len(uniq)
-    kpad = key_pad or next_bucket(k, 64)
-    if k > kpad:
-        raise ValueError(
-            f"block has {k} unique buckets but key_pad={kpad}")
-    uniq_p = np.zeros(kpad, np.int32)
-    uniq_p[:k] = uniq.astype(np.int32)
-    key_mask = np.zeros(kpad, np.float32)
-    key_mask[:k] = 1.0
-    row_mask = (labels_u8 != 255).astype(np.float32)
-    out = SparseBatch(cols=cols.astype(np.int32),
-                      vals=valid.astype(np.float32),
-                      labels=np.minimum(labels_u8, 1).astype(np.float32),
-                      row_mask=row_mask,
-                      uniq_keys=uniq_p, key_mask=key_mask)
-    out.num_real = int(row_mask.sum())
-    return out
-
-
 def nnz_bucket(densest: int, cap: int = 4096) -> int:
     """The per-row padded-nnz bucketing policy: power-of-two, min 8,
     capped (denser rows are positionally truncated)."""

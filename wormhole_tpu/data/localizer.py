@@ -82,10 +82,9 @@ def localize_bucket_grid(buckets: np.ndarray,
     """Localize an already-folded fixed-nnz bucket grid: global bucket
     ids ``(rows, nnz)`` plus a validity mask → (sorted unique buckets,
     local-id grid with 0 on invalid slots). The class above localizes
-    ragged CSR RowBlocks before the fold; the online tile-encode spill
-    path (data/crec.TileOnlineFeed) arrives post-fold on the crec
-    fixed-width grid, so the unique/inverse pass maps the grid
-    directly — same sorted-unique contract as ``Localized.uniq_keys``."""
+    ragged CSR RowBlocks before the fold; the serving front end
+    (serve/frontend.py) arrives post-fold on the crec fixed-width grid,
+    so the unique/inverse pass maps the grid directly — same sorted-unique contract as ``Localized.uniq_keys``."""
     uniq, inv = np.unique(buckets[valid], return_inverse=True)
     cols = np.zeros(buckets.shape, np.int64)
     cols[valid] = inv

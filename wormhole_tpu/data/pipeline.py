@@ -63,34 +63,26 @@ _END = object()
 
 
 def group_blocks(source: Iterable[Any], size: int, *,
-                 passthrough: Optional[Callable[[Any], bool]] = None,
                  clock: Callable[[], float] = time.monotonic):
     """Group consecutive ``source`` items into runs of ``size``.
 
-    Yields ``("group", [items], skew_s)`` in stream order; the final
-    group may be short (the caller pads it). Items matching
-    ``passthrough`` bypass grouping as ``("item", x, 0.0)`` WITHOUT
-    flushing the open group — they are independent of it (the mesh feed
-    routes encode-overflow spill batches this way, so a spill never
-    forces a short group mid-stream). ``skew_s`` is the arrival-time
-    spread between the group's first and last member on this thread —
-    the per-group straggler signal the mesh dispatch telemetry reports
-    (a slow member shows up as the whole group's wait)."""
+    Yields ``([items], skew_s)`` in stream order; the final group may be
+    short (the caller pads it). ``skew_s`` is the arrival-time spread
+    between the group's first and last member on this thread — the
+    per-group straggler signal the mesh dispatch telemetry reports (a
+    slow member shows up as the whole group's wait)."""
     group: list = []
     t0 = 0.0
     for item in source:
-        if passthrough is not None and passthrough(item):
-            yield ("item", item, 0.0)
-            continue
         now = clock()
         if not group:
             t0 = now
         group.append(item)
         if len(group) == size:
-            yield ("group", group, now - t0)
+            yield group, now - t0
             group = []
     if group:
-        yield ("group", group, clock() - t0)
+        yield group, clock() - t0
 
 
 class _StageError:

@@ -128,6 +128,10 @@ class AsyncSGD:
         # ring stalls, batches delivered, deepest ring occupancy observed
         self.feed_stats = {"feed_stall": 0.0, "feed_batches": 0,
                            "ring_max": 0}
+        # the room of the online tile encoder's overflow lists: one for
+        # the job, since every pass makes a new feed (data/crec.py)
+        from wormhole_tpu.data.crec import OverflowRoom
+        self._online_room = OverflowRoom()
         # the one-device tile TRAIN passes' deferred metric accumulator
         # (learners/window.py): it survives parts; flush_metrics drains it
         self._crec_acc = MetricAccumulator()
@@ -398,6 +402,20 @@ class AsyncSGD:
             self.timer.add(pfx + "encode_stall", snap["encode_stall"], n)
             stall_c, _ = obs.metrics.encode_counters(self.obs.registry)
             stall_c.inc(snap["encode_stall"])
+            # counts, not seconds: the pairs the online encoder put on
+            # the blocks' overflow lists, the slots those lists crossed
+            # at (the room in force, summed over the blocks whose list
+            # holds a pair), and how often a block passed the room
+            self.timer.add(pfx + "online_overflow_pairs",
+                           snap["overflow_pairs"], n)
+            self.timer.add(pfx + "online_overflow_slots",
+                           snap["overflow_slots"], n)
+            self.timer.add(pfx + "online_room_grown", snap["room_grown"], n)
+            pairs_c, room_g, grown_c = obs.metrics.online_overflow_metrics(
+                self.obs.registry)
+            pairs_c.inc(snap["overflow_pairs"])
+            room_g.set(snap["room"])
+            grown_c.inc(snap["room_grown"])
         if "stack" in snap:
             # mesh group-assembly stage (data/crec.MeshGroupFeed):
             # stack_stall is the in-order transferrer waiting on the
@@ -499,7 +517,7 @@ class AsyncSGD:
                                     device_put=lambda x: x)
             return TileOnlineFeed(inner, tile_info, workers=workers,
                                   depth=depth, device_put=device_put,
-                                  cache=cache)
+                                  cache=cache, room=self._online_room)
         if fmt in ("crec", "crec2"):
             return PackedFeed(file, part, nparts, fmt=fmt, cache=cache,
                               device_put=device_put, workers=workers,
@@ -619,21 +637,16 @@ class AsyncSGD:
         inflight: deque = deque()
         # tile-train metrics accumulate ON DEVICE (store.fetch_metrics;
         # the app's accumulator survives across parts); eval/v1 metrics
-        # ride per-step vectors in the part's window, and so do
-        # overflow-fallback scatter steps (online blocks whose COO spill
-        # exceeded ovf_cap)
+        # ride per-step vectors in the part's window
         acc_metrics = tile and kind == TRAIN
         win = MetricWindow(self, local, kind, pooled,
                            acc=self._crec_acc if acc_metrics else None)
         step, layout = self._crec_step(kind, "tile" if tile else "dense",
                                        info)
-        spill_step, _ = self._crec_step(kind, "spill", info)
 
         def record(item) -> None:
-            m, labels, spilled = item
-            if spilled:
-                win.add_spill(m, labels)
-            elif not acc_metrics:
+            m, labels = item
+            if not acc_metrics:
                 win.add_step(m, labels, layout)
 
         def harvest(item) -> None:
@@ -674,18 +687,11 @@ class AsyncSGD:
                 while len(inflight) > max(max_delay - 1, 0):
                     harvest(inflight.popleft())
             with self.timer.scope(pfx + "dispatch"):
-                # online overflow fallback: the block arrived as a
-                # SparseBatch — audited scatter step, counted
-                spilled = tile and not isinstance(dev, dict)
-                if spilled:
-                    obs.metrics.encode_counters(self.obs.registry)[1].inc(1)
-                m = (spill_step if spilled else step)(
-                    dev, min(float(len(inflight)), tau_cap))
-                if acc_metrics and not spilled:
+                m = step(dev, min(float(len(inflight)), tau_cap))
+                if acc_metrics:
                     win.count_step()
                 inflight.append(
-                    (m, None if kind == TRAIN else _labels_of(host),
-                     spilled))
+                    (m, None if kind == TRAIN else _labels_of(host)))
         with self.timer.scope(pfx + "wait"):
             # no per-item block_until_ready here: the window's device
             # fetch synchronizes
@@ -714,9 +720,8 @@ class AsyncSGD:
         block (or, ``*_mesh``, one data-axis group) as ``step(operand,
         tau)``, with the name of the metric-row layout it returns
         (learners/window.fold_row). ``tile`` takes crec2-typed blocks,
-        ``dense`` packed crec v1 blocks, ``spill`` the SparseBatch of an
-        online block whose overflow passed the cap (the audited scatter
-        step, on a mesh too). Mesh steps and eval steps take no tau."""
+        ``dense`` packed crec v1 blocks. Mesh steps and eval steps take
+        no tau."""
         s, r, n = self.store, info.block_rows, info.nnz
         train, evl, layout = {
             "tile": (lambda x, tau: s.tile_train_step(x, info, tau=tau),
@@ -729,8 +734,6 @@ class AsyncSGD:
             "dense_mesh": (lambda x, tau: s.dense_train_step_mesh(x, r, n),
                            lambda x, tau: s.dense_eval_step_mesh(x, r, n),
                            "tile"),
-            "spill": (lambda b, tau: s.train_step(b, tau=tau),
-                      lambda b, tau: s.eval_step(b), "sparse"),
         }[form]
         return (train if kind == TRAIN else evl), layout
 
@@ -748,12 +751,9 @@ class AsyncSGD:
         encoder (same typed blocks as crec2).
 
         The feed is data/crec.MeshGroupFeed: groups come placed on the
-        (data, model) NamedSharding the step takes, and encode-overflow
-        spill batches ride the same ring in stream position, through
-        the audited scatter step (the replicated-table sparse path: the
-        on-device accumulator never sees such a block).
+        (data, model) NamedSharding the step takes.
 
-        Spill/eval metrics are folded from batched device fetches
+        Eval metrics are folded from batched device fetches
         (learners/window.py), and an eval pass pools one label lane a
         group (the blocks' lanes concatenated, 98 KB a block). The
         part's Timer takes three counts beside its seconds:
@@ -772,7 +772,6 @@ class AsyncSGD:
         win = MetricWindow(self, local, kind, pooled, bounded=True)
         step, layout = self._crec_step(
             kind, "tile_mesh" if is_tile else "dense_mesh", info)
-        spill_step, _ = self._crec_step(kind, "spill", info)
         tx = self.store.mesh_transport()
         steps_before, ici_before = tx.dispatches, tx.bytes_ici
         inner = self._make_feed(file, part, nparts, fmt,
@@ -784,14 +783,7 @@ class AsyncSGD:
             workers=self.cfg.pipeline_workers,
             depth=max(self.cfg.pipeline_ring, 1), online=online,
             want_labels=kind != TRAIN and pooled is not None)
-        for tag, payload, labels_u8, _rows in feed:
-            if tag == "spill":
-                obs.metrics.encode_counters(self.obs.registry)[1].inc(1)
-                with self.timer.scope(pfx + "dispatch"):
-                    with obs.trace.span("mesh:spill", cat="mesh"):
-                        m = spill_step(payload, 0.0)
-                win.add_spill(m, labels_u8)
-                continue
+        for payload, labels_u8, _rows in feed:
             with self.timer.scope(pfx + "dispatch"):
                 with obs.trace.span("mesh:dispatch", cat="mesh"):
                     m = step(payload, 0.0)
@@ -818,16 +810,15 @@ class AsyncSGD:
         """Fold a MeshGroupFeed's dispatcher-side counters into the obs
         registry (obs.metrics.mesh_feed_gauges): per-group arrival skew
         — the per-device straggler signal the multichip bench reports —
-        plus group/pad/spill block counts."""
+        plus group/pad block counts."""
         snap = feed.skew_snapshot()
-        g_skew, g_skew_max, c_groups, c_pads, c_spills = \
+        g_skew, g_skew_max, c_groups, c_pads = \
             obs.metrics.mesh_feed_gauges(self.obs.registry)
         if snap["groups"]:
             g_skew.set(1e3 * snap["skew_sum"] / snap["groups"])
         g_skew_max.max(1e3 * snap["skew_max"])
         c_groups.inc(snap["groups"])
         c_pads.inc(snap["pad_blocks"])
-        c_spills.inc(snap["spill_blocks"])
 
     @staticmethod
     def _real_rows(batch) -> np.ndarray:

@@ -595,7 +595,7 @@ class ShardedStore(TableCheckpoint):
                                    t.astype(jnp.float32), tau)
             delta = (new_rows - rows) * batch.key_mask[:, None]
             # scatter-fallback: uniq-key push, O(uniq) rows — the sparse
-            # step is the audited fallback for the online tile path
+            # step is the text and libsvm path's own
             slots = slots.at[batch.uniq_keys].add(
                 delta.astype(slots.dtype))
             num_ex = jnp.sum(batch.row_mask)
@@ -942,6 +942,27 @@ class ShardedStore(TableCheckpoint):
             # multi-device layouts, not just TPU)
             return new, t + 1, macc + packed, num_ex
 
+        # The phases XLA runs around the kernels are jits of their own,
+        # so that the device trace's ops say which phase they belong to
+        # (the profiler keeps the path of an op under a nested jit, not
+        # under a bare named scope): tile_ovf_gather (the overflow
+        # pairs' weights summed onto their rows), tile_ovf_scatter (the
+        # pairs' duals added into the gradient), tile_table_update (the
+        # one elementwise pass over the planes and the gradient).
+        @jax.jit
+        def tile_ovf_gather(w, ovf_b, ovf_r):
+            return tilemm.spill_margin_rows(w, ovf_b, ovf_r, spec)
+
+        @jax.jit
+        def tile_ovf_scatter(grad, dual, ovf_b, ovf_r):
+            return tilemm.spill_grad_scatter(grad, dual, ovf_b, ovf_r, spec)
+
+        @jax.jit
+        def tile_table_update(planes, grad, t, tau):
+            return masked_push_planes(
+                handle, planes, grad.reshape(planes[0].shape),
+                t.astype(jnp.float32), tau, exact_dense)
+
         if fused_update:
             @partial(jax.jit, donate_argnums=(0, 2, 4))
             def step(table, block, t, tau, macc):
@@ -961,36 +982,35 @@ class ShardedStore(TableCheckpoint):
                 pw, labels, row_mask, ovf_b, ovf_r = decode(block)
                 planes = planes_of(table)
                 w = handle.weights(tbl.PlaneTable(planes))
+                # the overflow pairs' margins, pre-aggregated onto
+                # their rows: ONE grid add on the split path, one extra
+                # operand of the fused kernel (summed into the
+                # phase-boundary dual), so the two stay bitwise-equal
+                sp = tile_ovf_gather(w, ovf_b, ovf_r) if oc else None
                 if not fused:
-                    margin = tilemm.forward_margins(pw, w, spec,
-                                                    ovf_b, ovf_r)
+                    margin = tilemm.forward_margins(pw, w, spec)
+                    if oc:
+                        margin = margin + sp
                     dual = dual_fn(margin, labels, row_mask)
                     if not exact_dense:
                         dual = _nudge_zero_dual(dual, labels, row_mask)
-                    grad = tilemm.backward_grad(pw, dual, spec,
-                                                ovf_b, ovf_r)
+                    grad = tilemm.backward_grad(pw, dual, spec)
                 else:
-                    # fused spill: the pre-aggregated spill margins ride
-                    # into the kernel as one extra operand (summed into
-                    # the phase-boundary dual); the spill pairs' grad
-                    # contributions scatter in XLA from the emitted
-                    # margins — the dual recompute is elementwise, so
-                    # the scattered duals are bitwise the kernel's own
-                    sp = (tilemm.spill_margin_rows(w, ovf_b, ovf_r, spec)
-                          if oc else None)
                     margin, grad = tilemm.fused_step_grad(
                         pw, w, labels, row_mask, spec, loss_name,
                         exact_dense, cache=cache, spill_margins=sp)
                     if oc:
+                        # the pairs' grad contributions scatter in XLA
+                        # from the emitted margins — the dual recompute
+                        # is elementwise, so the scattered duals are
+                        # bitwise the kernel's own
                         dual = dual_fn(margin, labels, row_mask)
                         if not exact_dense:
                             dual = _nudge_zero_dual(dual, labels,
                                                     row_mask)
-                        grad = tilemm.spill_grad_scatter(
-                            grad, dual, ovf_b, ovf_r, spec)
-                new, wdelta2 = masked_push_planes(
-                    handle, planes, grad.reshape(planes[0].shape),
-                    t.astype(jnp.float32), tau, exact_dense)
+                if oc:
+                    grad = tile_ovf_scatter(grad, dual, ovf_b, ovf_r)
+                new, wdelta2 = tile_table_update(planes, grad, t, tau)
                 return finish(table_of(new, table), wdelta2, margin,
                               labels, row_mask, t, macc)
         else:

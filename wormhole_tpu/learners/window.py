@@ -3,15 +3,13 @@
 A crec pass never fetches a step's metrics as it dispatches it: a
 per-step ``float(np.asarray(...))`` costs one blocking round trip and
 drains the async dispatch pipeline. What a pass defers rides one of
-three lists until a drain:
+two lists until a drain:
 
 - the on-device accumulator's async tickets (``MetricAccumulator``):
   tile and mesh TRAIN steps add their packed metric row into one device
   buffer (``store.fetch_metrics_async``), so the host only counts the
   steps and fetches one buffer a window;
-- per-step metric vectors (eval steps, the crec v1 dense steps);
-- spill steps (online blocks whose COO overflow passed the cap and ran
-  the audited scatter step on a ``SparseBatch``).
+- per-step metric vectors (eval steps, the crec v1 dense steps).
 
 This module is also the one place that knows the two metric-row layouts
 of the stores' steps by name (:func:`fold_row`) and that label 255 is a
@@ -36,8 +34,8 @@ def fold_row(local: Progress, row, layout: str, kind: str):
     """Fold one step's fetched metric row into ``local`` (one count).
 
     ``layout`` names what the step returned: ``"sparse"`` is
-    ``[objv, num_ex, auc, acc, wdelta2|margin]`` (the sparse step, which
-    spill blocks take, and the one-device crec v1 dense steps);
+    ``[objv, num_ex, auc, acc, wdelta2|margin]`` (the sparse step and
+    the one-device crec v1 dense steps);
     ``"tile"`` is ``[objv, num_ex, acc, pos, neg, wdelta2|margin]`` with
     the AUC in margin histograms (the tile steps and every mesh step).
     The last slot, where a step has one, is Σ(Δw)² on a TRAIN pass and
@@ -107,7 +105,6 @@ class MetricWindow:
         self.acc = acc
         self.bounded = bounded
         self.steps: list = []    # (metrics, labels_u8, layout) a step
-        self.spill: list = []    # (metrics, labels_u8): "sparse" rows
 
     def _wait_scope(self):
         return self.app.timer.scope(
@@ -127,12 +124,6 @@ class MetricWindow:
             with self._wait_scope():
                 self._fold_list(self.steps)
 
-    def add_spill(self, metrics, labels_u8) -> None:
-        self.spill.append((metrics, labels_u8, "sparse"))
-        if self.bounded and len(self.spill) >= self.app.CREC_DRAIN_CHUNK:
-            with self._wait_scope():
-                self._fold_list(self.spill)
-
     def _fold_list(self, steps: list) -> None:
         """Fold a deferred list with one batched fetch: per-leaf fetches
         cost one blocking round trip each, and each one drains the
@@ -150,9 +141,8 @@ class MetricWindow:
         steps.clear()
 
     def fold(self) -> None:
-        """Fold the two lists into ``local``, spill steps first; a TRAIN
-        pass shows its row after its per-step vectors."""
-        self._fold_list(self.spill)
+        """Fold the deferred list into ``local``; a TRAIN pass shows its
+        row after its per-step vectors."""
         if self.steps:
             self._fold_list(self.steps)
             if self.kind == TRAIN:

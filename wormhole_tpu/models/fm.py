@@ -191,7 +191,7 @@ class FMStore(TableCheckpoint):
                 [w_new[:, None], v_new, cg_new], axis=1)
             delta = (new_rows - rows) * batch.key_mask[:, None]
             # scatter-fallback: uniq-key push, O(uniq) rows — the sparse
-            # step is the audited fallback for the online tile path
+            # step is the text and libsvm path's own
             slots = slots.at[batch.uniq_keys].add(delta)
             num_ex = jnp.sum(batch.row_mask)
             a = auc(batch.labels, margin, batch.row_mask)

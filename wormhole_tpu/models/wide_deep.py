@@ -166,7 +166,7 @@ class WideDeepStore(TableCheckpoint):
             new_rows = jnp.concatenate([theta_new, cg_new], axis=1)
             delta = (new_rows - rows) * batch.key_mask[:, None]
             # scatter-fallback: uniq-key push, O(uniq) rows — the sparse
-            # step is the audited fallback for the online tile path
+            # step is the text and libsvm path's own
             slots = slots.at[batch.uniq_keys].add(delta)
 
             # dense AdaGrad

@@ -444,15 +444,37 @@ def encode_counters(reg: Optional[Registry] = None):
     (lint_knobs uniqueness contract), fetched per call so a cleared
     default registry never strands stale Counter objects: seconds the
     stream waited on the encode workers (beside the PR 1 feed stall
-    counters), and blocks whose COO overflow exceeded ``ovf_cap`` and
-    fell back to the audited scatter step."""
+    counters), and ``feed/tile_fallback_blocks``, which nothing counts
+    any more: the scatter step that online blocks past the overflow room
+    fell to is gone (the room is sized to the data), and the name stays
+    at 0 only because ``benchmark/configs/criteo_ftrl_text`` reads it
+    and states that it is 0; it goes with that file's next edit."""
     reg = reg if reg is not None else default_registry()
     return (reg.counter("feed/encode_stall",
                         help="seconds the stream waited on the online "
                              "tile-encode workers"),
             reg.counter("feed/tile_fallback_blocks",
-                        help="online-encoded blocks whose COO overflow "
-                             "fell back to the audited scatter step"))
+                        help="always 0: online-encoded blocks no longer "
+                             "leave the tile path"))
+
+
+def online_overflow_metrics(reg: Optional[Registry] = None):
+    """What the online tile encoder puts on the blocks' COO overflow
+    lists — single declaration site, fetched per call like
+    :func:`encode_counters`: the pairs past the per-tile cap, the room in
+    force for a list (data/crec.OverflowRoom) and how often a block
+    passed it (each time is one more compile of the spill step)."""
+    reg = reg if reg is not None else default_registry()
+    return (reg.counter("feed/online_overflow_pairs",
+                        help="pairs past the per-tile cap that online-"
+                             "encoded blocks carry on their overflow "
+                             "lists"),
+            reg.gauge("feed/online_overflow_room",
+                      help="slots of an online block's overflow list, "
+                           "sized to the counts seen", agg="max"),
+            reg.counter("feed/online_room_grown",
+                        help="times a block's overflow count passed the "
+                             "room and the room grew"))
 
 
 def mesh_feed_gauges(reg: Optional[Registry] = None):
@@ -474,7 +496,4 @@ def mesh_feed_gauges(reg: Optional[Registry] = None):
                              "the sharded mesh feed"),
             reg.counter("mesh/pad_blocks",
                         help="all-PAD filler blocks standing in for the "
-                             "missing members of short tail groups"),
-            reg.counter("mesh/spill_blocks",
-                        help="encode-overflow spill batches that rode "
-                             "the mesh feed ring to the scatter step"))
+                             "missing members of short tail groups"))

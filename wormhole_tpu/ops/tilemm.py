@@ -253,27 +253,19 @@ def encode_block(buckets: np.ndarray, rows: np.ndarray,
             np.concatenate(ovr) if ovr else np.zeros(0, np.uint32))
 
 
-def encode_block_capped(buckets: np.ndarray, rows: np.ndarray,
-                        spec: TileSpec, ovf_cap: int
-                        ) -> Tuple[np.ndarray, np.ndarray, np.ndarray, int]:
-    """``encode_block`` with the fixed-width overflow contract every
-    consumer wants: ``(pw, ovf_b, ovf_r, n_ovf)`` where the overflow
-    arrays are always exactly ``ovf_cap`` long — unused slots carry
-    0xFFFFFFFF buckets (the kernels' no-op sentinel) and row 0. Never
-    raises: ``n_ovf`` reports the TRUE overflow count, so a caller with
-    a writer can reject skew (``n_ovf > ovf_cap``, CRec2Writer) while a
-    runtime caller with no writer to reject it can fall back to another
-    step for the block (the online tile-encode feed). When the count
-    exceeds the cap the padded arrays hold the first ``ovf_cap``
-    entries — callers must check ``n_ovf`` before trusting them."""
-    pw, ovb, ovr = encode_block(buckets, rows, spec)
-    n_ovf = len(ovb)
+def cap_overflow(ovb: np.ndarray, ovr: np.ndarray,
+                 ovf_cap: int) -> Tuple[np.ndarray, np.ndarray]:
+    """An overflow list at the fixed width every consumer wants: exactly
+    ``ovf_cap`` long, unused slots carrying 0xFFFFFFFF buckets (the
+    kernels' no-op sentinel) and row 0. A list longer than ``ovf_cap``
+    is cut to its first ``ovf_cap`` entries: the caller compares the
+    true count first."""
     ob = np.full(max(ovf_cap, 0), 0xFFFFFFFF, np.uint32)
     orow = np.zeros(max(ovf_cap, 0), np.uint32)
-    keep = min(n_ovf, ovf_cap)
+    keep = min(len(ovb), ovf_cap)
     ob[:keep] = ovb[:keep]
     orow[:keep] = ovr[:keep]
-    return pw, ob, orow, n_ovf
+    return ob, orow
 
 
 # ---------------------------------------------------------------------------

@@ -148,6 +148,29 @@ def test_writer_rejects_skew_overflow(tmp_path, rng):
         write_file(tmp_path / "d.crec2", keys, labels, cap=128, ovf_cap=128)
 
 
+@pytest.mark.parametrize("cap", [40960, 128],
+                         ids=["no_overflow", "overflow"])
+def test_file_written_natively_is_byte_identical(tmp_path, rng, monkeypatch,
+                                                 cap):
+    """The writer's file does not say which encoder wrote it: the native
+    pass and the numpy encoder give the same bytes, the overflow lists
+    (two blocks, the second short) included."""
+    from wormhole_tpu.data import native
+    if native.get_tile_encoder() is None:
+        pytest.skip("native tile encoder not built")
+    n = 4 * tilemm.RSUB + 1234
+    keys, labels = make_rows(rng, n)
+    kw = dict(cap=cap, ovf_cap=1 << 18)
+    write_file(tmp_path / "native.crec2", keys, labels, **kw)
+    monkeypatch.setattr(native, "get_tile_encoder", lambda: None)
+    write_file(tmp_path / "numpy.crec2", keys, labels, **kw)
+    a = (tmp_path / "native.crec2").read_bytes()
+    assert a == (tmp_path / "numpy.crec2").read_bytes()
+    n_ovf = sum(int((np.asarray(v["ovf_b"]) != 0xFFFFFFFF).sum())
+                for v, _ in iter_packed2(str(tmp_path / "native.crec2")))
+    assert (n_ovf > 0) == (cap == 128)
+
+
 def test_crec2_mesh_training_converges(tmp_path, rng):
     """AsyncSGD over crec2 on a data:2,model:2 mesh (the shard_map tile
     step): learns the planted feature like the single-device path."""

@@ -93,16 +93,18 @@ def test_repo_metric_names_unique():
 
 def test_encode_metrics_single_declaration_site():
     """The online tile-encode stage metrics (feed/encode_stall,
-    feed/tile_fallback_blocks) are declared at exactly one site —
-    obs/metrics.encode_counters; consumers must fetch them through that
-    helper, never re-declare the literals."""
+    feed/tile_fallback_blocks, feed/encode_native_blocks) are declared at
+    exactly one site — obs/metrics.encode_counters and
+    encode_native_counter; consumers must fetch them through those
+    helpers, never re-declare the literals."""
     sys.path.insert(0, os.path.join(REPO, "scripts"))
     try:
         import lint_knobs
     finally:
         sys.path.pop(0)
     sites = lint_knobs.metric_sites(REPO)
-    for name in ("feed/encode_stall", "feed/tile_fallback_blocks"):
+    for name in ("feed/encode_stall", "feed/tile_fallback_blocks",
+                 "feed/encode_native_blocks"):
         assert name in sites, name
         assert len(sites[name]) == 1, (name, sites[name])
         assert sites[name][0].startswith("wormhole_tpu/obs/metrics.py")

@@ -519,12 +519,16 @@ def test_prometheus_help_type_for_every_family():
 def test_declared_repo_metrics_have_help():
     """The metric families the repo itself declares with help= render a
     non-trivial HELP line (not the name fallback)."""
-    from wormhole_tpu.obs.metrics import encode_counters
+    from wormhole_tpu.obs.metrics import (encode_counters,
+                                          encode_native_counter)
     r = Registry()
     encode_counters(r)
+    encode_native_counter(r)
     text = r.prometheus_text()
     assert "# HELP feed_encode_stall seconds the stream waited" in text
     assert "# TYPE feed_encode_stall counter" in text
+    assert "# HELP feed_encode_native_blocks online-encoded blocks" in text
+    assert "# TYPE feed_encode_native_blocks counter" in text
 
 
 # -- monitor incidents: dedup, recovery, relapse (PR-6) ----------------------

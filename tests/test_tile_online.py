@@ -157,6 +157,38 @@ def test_online_text_workers_deterministic(tmp_path, rng):
     assert np.array_equal(weights(apps[0]), weights(apps[1]))
 
 
+@pytest.mark.parametrize("live", ["native", "numpy"])
+def test_native_blocks_are_counted(tmp_path, rng, monkeypatch, live):
+    """A text pass under tile_online counts the blocks the native encoder
+    took, in the registry (``feed/encode_native_blocks``) and on the
+    Timer's line (``online_native_blocks``): every block of every pass
+    where the process could load it, none where it could not."""
+    from wormhole_tpu.data import native
+    from wormhole_tpu.obs.metrics import encode_native_counter
+    if live == "native" and native.get_tile_encoder() is None:
+        pytest.skip("native tile encoder not built")
+    if live == "numpy":
+        monkeypatch.setattr(native, "get_tile_encoder", lambda: None)
+    n = 2000
+    path = tmp_path / "t.criteo"
+    with open(path, "w") as f:
+        for i in range(n):
+            ints = "\t".join(str(rng.integers(0, 100)) for _ in range(13))
+            cats = "\t".join(f"{rng.integers(0, 1 << 32):x}"
+                             for _ in range(26))
+            f.write(f"{i % 2}\t{ints}\t{cats}\n")
+    app = make_app(path, "criteo", tile_online="on", pipeline_workers=2,
+                   max_data_pass=2, text_block_rows=512)
+    counter = encode_native_counter(app.obs.registry)
+    before = counter.value
+    app.run()
+    blocks = 2 * -(-n // 512)
+    assert app.timer.counts["encode"] == blocks
+    want = blocks if live == "native" else 0
+    assert app.timer.totals["online_native_blocks"] == want
+    assert counter.value - before == want
+
+
 def hot_rows(rng, n, share):
     """``share`` of the slots on one hot key, the rest uniform: at NB = 2
     tiles the hot key's tile passes the per-tile cap from a share of a

@@ -324,9 +324,15 @@ def encode_tile_pairs(keys: np.ndarray, nb: int,
     them. THE single encoder: the crec2 writer and the online tile-encode
     feed both come through it, which is what makes an online-encoded
     block bit-identical to the same rows pre-converted to a crec2
-    file."""
+    file. One native counting pass (native/tile_encode.cc) when the
+    process can load it, else the numpy below, which is its written
+    specification: the same bits, the list's order included."""
+    from wormhole_tpu.data import native
     from wormhole_tpu.data.hashing import fold_keys32
     from wormhole_tpu.ops.tilemm import encode_block
+    encode = native.get_tile_encoder()
+    if encode is not None:
+        return encode(keys, nb, spec)
     rr, cc = np.nonzero(keys != SENTINEL_KEY)
     buckets = fold_keys32(keys[rr, cc], nb)
     return encode_block(buckets, rr.astype(np.int64), spec)
@@ -978,8 +984,10 @@ class TileOnlineFeed:
     (:class:`OverflowRoom`: sized to what the encoder counted, grown when
     a block passes it). Every block stays a tile block: there is no
     other step for a skewed one to fall to. ``overflow_pairs``,
-    ``overflow_slots`` (the widths of the lists that hold a pair) and
-    the room's ``grown`` are counted for the consumer's timer.
+    ``overflow_slots`` (the widths of the lists that hold a pair), the
+    room's ``grown`` and ``native_blocks`` (blocks the native encoder
+    took: all of them or none, by what the process could load) are
+    counted for the consumer's timer.
 
     ``inner`` must yield ``(dev, packed_v1, rows)`` with an identity
     device_put (its packed v1 bytes stay on host for the encode);
@@ -1000,6 +1008,7 @@ class TileOnlineFeed:
         # transfer-thread counters (single writer)
         self.overflow_pairs = 0
         self.overflow_slots = 0
+        self.native_blocks = 0
         self._grown_before = self.room.grown
         self._cache: Optional[list] = [] if cache else None
         self._cache_full = False
@@ -1040,6 +1049,7 @@ class TileOnlineFeed:
     def _encode(self, item, _ctx):
         """Worker-side stage: v1 packed block -> crec2 typed dict, its
         overflow list at the room's width."""
+        from wormhole_tpu.data import native
         from wormhole_tpu.ops.tilemm import cap_overflow
         packed, rows = item
         info = self.info
@@ -1060,7 +1070,7 @@ class TileOnlineFeed:
         pw, ovb, ovr = encode_tile_pairs(kgrid, info.nb, info.spec)
         ob, orow = cap_overflow(ovb, ovr, self.room.fit(len(ovb)))
         return ({"pw": pw, "labels": lab, "ovf_b": ob, "ovf_r": orow},
-                lab, rows, len(ovb))
+                lab, rows, len(ovb), native.get_tile_encoder() is not None)
 
     def _src(self, packed) -> int:
         if self._src_rows is None:
@@ -1070,7 +1080,8 @@ class TileOnlineFeed:
     def _transfer(self, res):
         import time as _time
         import jax
-        payload, lab, rows, n_ovf = res
+        payload, lab, rows, n_ovf, native = res
+        self.native_blocks += native
         if n_ovf:
             self.overflow_pairs += n_ovf
             self.overflow_slots += len(payload["ovf_b"])
@@ -1118,14 +1129,16 @@ class TileOnlineFeed:
             "batches": snap["batches"],
             "ring_max": snap["ring_max"],
             # counts, not seconds: what this pass's blocks put on their
-            # overflow lists, the slots those lists were shipped at, and
-            # how often a block passed the room
+            # overflow lists, the slots those lists were shipped at, how
+            # often a block passed the room, and the blocks the native
+            # encoder took
+            "native_blocks": self.native_blocks,
             "overflow_pairs": self.overflow_pairs,
             "overflow_slots": self.overflow_slots,
             "room_grown": self.room.grown - self._grown_before,
             "room": self.room.room,
         }
-        self.overflow_pairs = self.overflow_slots = 0
+        self.overflow_pairs = self.overflow_slots = self.native_blocks = 0
         self._grown_before = self.room.grown
         if timer is not None:
             n = max(out["batches"], 1)
@@ -1383,8 +1396,8 @@ class MeshGroupFeed:
             "batches": snap["batches"],
             "ring_max": snap["ring_max"],
         }
-        for k in ("encode", "encode_stall", "overflow_pairs",
-                  "overflow_slots", "room_grown", "room"):
+        for k in ("encode", "encode_stall", "native_blocks",
+                  "overflow_pairs", "overflow_slots", "room_grown", "room"):
             if k in inner_snap:
                 out[k] = inner_snap[k]
         if timer is not None:

@@ -1,9 +1,11 @@
-"""ctypes binding to the native C++ chunk parsers (``native/`` at repo root).
+"""ctypes binding to the native C++ data plane (``native/`` at repo root):
+the chunk parsers and the tile encoder.
 
-The Python parsers in parsers.py are the reference implementations; the C++
-library is the hot path for streaming throughput (SURVEY.md §7 hard part (d):
-matching GB/s-scale parsing from hosts). ``get_parser`` returns None when the
-shared library is absent so everything degrades gracefully.
+The Python parsers in parsers.py and the numpy encoder in ops/tilemm.py are
+the reference implementations; the C++ library is the hot path for streaming
+throughput (SURVEY.md §7 hard part (d): matching GB/s-scale parsing from
+hosts). Every getter returns None when the shared library (or its symbol) is
+absent so everything degrades gracefully.
 """
 
 from __future__ import annotations
@@ -21,12 +23,13 @@ _TRIED = False
 _BUILD_ERROR = ""   # why the one-shot `make` failed, for build_error()
 
 _LIB_NAMES = ("libwormhole_data.so",)
+_NATIVE_DIR = os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__)))), "native")
 
 
 def _find_lib() -> Optional[str]:
-    here = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-    candidates = [os.path.join(here, "native", "build", n) for n in _LIB_NAMES]
-    candidates += [os.path.join(here, "native", n) for n in _LIB_NAMES]
+    candidates = [os.path.join(_NATIVE_DIR, "build", n) for n in _LIB_NAMES]
+    candidates += [os.path.join(_NATIVE_DIR, n) for n in _LIB_NAMES]
     env = os.environ.get("WORMHOLE_NATIVE_LIB")
     if env:
         candidates.insert(0, env)
@@ -36,17 +39,17 @@ def _find_lib() -> Optional[str]:
     return None
 
 
-def _try_build() -> Optional[str]:
+def _try_build(stale: bool = False) -> Optional[str]:
     """Best-effort one-shot `make` of the native library (a fresh checkout
     has no build/ — the hot path should not silently fall back to Python
     parsing on machines that have a toolchain). A file lock serializes
     concurrent builders (multi-process launches on a fresh checkout would
-    otherwise clobber each other's half-written .so)."""
+    otherwise clobber each other's half-written .so). ``stale``: the
+    library that is there lacks a symbol, so `make` runs even though one
+    is found (a no-op if a peer has rebuilt it meanwhile)."""
     import subprocess
     global _BUILD_ERROR
-    here = os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    ndir = os.path.join(here, "native")
+    ndir = _NATIVE_DIR
     if not os.path.exists(os.path.join(ndir, "Makefile")):
         return None
     try:
@@ -54,7 +57,7 @@ def _try_build() -> Optional[str]:
         with open(os.path.join(ndir, ".build.lock"), "w") as lock:
             fcntl.flock(lock, fcntl.LOCK_EX)   # waits for a peer's build
             found = _find_lib()
-            if found:                          # a peer built it first
+            if found and not stale:            # a peer built it first
                 return found
             subprocess.run(["make", "-C", ndir], capture_output=True,
                            text=True, timeout=120, check=True)
@@ -63,8 +66,8 @@ def _try_build() -> Optional[str]:
         _BUILD_ERROR = f"{e}: {(getattr(e, 'stderr', '') or '')[-2000:]}"
         from wormhole_tpu.utils.logging import get_logger
         get_logger("native").warning(
-            "native build FAILED (%s); the Python parsers are live",
-            _BUILD_ERROR)
+            "native build FAILED (%s); the Python parsers and the numpy "
+            "tile encoder are live", _BUILD_ERROR)
         return None
     return _find_lib()
 
@@ -81,6 +84,14 @@ def _load():
         return None
     try:
         lib = ctypes.CDLL(path)
+        if not hasattr(lib, "wh_tile_count"):
+            # found, but built before the tile encoder existed
+            # (native/build/ is git-ignored and outlives a checkout's
+            # update): without this the numpy encoder would run in
+            # silence. Unload, `make` once, load what it left.
+            import _ctypes
+            _ctypes.dlclose(lib._handle)
+            lib = ctypes.CDLL(_try_build(stale=True) or path)
     except OSError as e:
         _BUILD_ERROR = f"cannot load {path}: {e}"
         return None
@@ -105,8 +116,124 @@ def _load():
             ctypes.c_int32,
             ctypes.POINTER(ctypes.c_uint32),  # keys (rows*nnz)
             ctypes.POINTER(ctypes.c_uint8)]   # labels (rows)
+    if hasattr(lib, "wh_tile_count"):
+        # see native/tile_encode.cc for the ABI
+        u32p = ctypes.POINTER(ctypes.c_uint32)
+        i64p = ctypes.POINTER(ctypes.c_int64)
+        lib.wh_tile_count.restype = ctypes.c_int64
+        lib.wh_tile_count.argtypes = [
+            u32p, ctypes.c_int64, ctypes.c_int64,     # keys, rows, nnz
+            ctypes.c_uint32, ctypes.c_int64,          # nb, subblocks
+            ctypes.c_int64, ctypes.c_uint32,          # tiles, cap
+            u32p, u32p, i64p]                         # buckets, counts, offs
+        lib.wh_tile_place.restype = None
+        lib.wh_tile_place.argtypes = [
+            u32p, ctypes.c_int64, ctypes.c_int64,     # buckets, rows, nnz
+            ctypes.c_int64, ctypes.c_int64,           # subblocks, tiles
+            ctypes.c_uint32, i64p, u32p,              # cap, offs, counts
+            u32p, u32p, u32p]                         # pw, ovf_b, ovf_r
+    else:
+        from wormhole_tpu.utils.logging import get_logger
+        get_logger("native").warning(
+            "%s has no tile encoder and could not be rebuilt; the numpy "
+            "tile encoder is live", path)
     _LIB = lib
     return _LIB
+
+
+def _u32p(a: np.ndarray):
+    return a.ctypes.data_as(ctypes.POINTER(ctypes.c_uint32))
+
+
+class _SlabPool:
+    """Recycled memory for the tile encoder's pair words. A block's ``pw``
+    is 201 MB at the criteo geometry, past malloc's largest mmap
+    threshold, so ``np.empty`` maps it fresh and every page of it faults
+    on first touch: on the chip's host that alone is 260 of a block's 300
+    ms (PERF.md section 6, PR 40), six times the encoder's two passes.
+    ``empty`` hands out an array over an anonymous mapping this pool owns;
+    when the array and every view of it are gone (numpy keeps a view's
+    ``base`` at the array, because the mapping under it is no ndarray;
+    ``jax.device_put`` holds the array until its transfer is done) the
+    mapping comes back, pages in place, for the next block. At most
+    ``IDLE_BYTES`` wait idle; beyond that a mapping is let go. No lock:
+    the finalizer can run on any thread, also inside ``empty`` (a
+    collection), and list ``append``/``pop`` are atomic as they are."""
+
+    IDLE_BYTES = 1 << 30
+
+    def __init__(self) -> None:
+        self._idle: list = []     # mmap objects nobody views
+
+    def empty(self, shape, dtype) -> np.ndarray:
+        import mmap
+        import weakref
+        nbytes = int(np.prod(shape)) * np.dtype(dtype).itemsize
+        if not nbytes:
+            return np.empty(shape, dtype)
+        mm = None
+        while mm is None and self._idle:
+            try:
+                cand = self._idle.pop()
+            except IndexError:
+                break
+            if len(cand) == nbytes:   # another geometry's is let go
+                mm = cand
+        if mm is None:
+            mm = mmap.mmap(-1, nbytes,
+                           flags=mmap.MAP_PRIVATE | mmap.MAP_ANONYMOUS)
+        arr = np.ndarray(shape, dtype, buffer=mm)
+        weakref.finalize(arr, self._give_back, mm).atexit = False
+        return arr
+
+    def _give_back(self, mm) -> None:
+        if (len(self._idle) + 1) * len(mm) <= self.IDLE_BYTES:
+            self._idle.append(mm)
+
+
+_PW_POOL = _SlabPool()
+
+
+def _tile_encode(keys: np.ndarray, nb: int, spec):
+    """``(pw, ovf_b, ovf_r)`` of a ``(rows, nnz)`` u32 keys grid, the bits
+    of ``ops/tilemm.encode_block`` over its folded real keys, in two native
+    passes (native/tile_encode.cc): count, then place into arrays sized
+    here from the count. Scratch is this call's own, ``pw`` is recycled
+    memory no one else views (:class:`_SlabPool`), and ctypes releases
+    the GIL for both passes: concurrent callers share nothing."""
+    lib = _LIB
+    keys = np.ascontiguousarray(keys, np.uint32)
+    if keys.ndim != 2:
+        raise ValueError(f"keys must be (rows, nnz), got {keys.shape}")
+    if not 0 < nb < 1 << 32:
+        raise ValueError(f"nb={nb} does not fit the u32 bucket space")
+    # rows past the block are no pair (as in encode_block)
+    rows, nnz = min(keys.shape[0], spec.block_rows), keys.shape[1]
+    cells = spec.subblocks * spec.tiles
+    buckets = np.empty(rows * nnz, np.uint32)
+    counts = np.empty(cells, np.uint32)
+    offs = np.empty(cells, np.int64)
+    offs_p = offs.ctypes.data_as(ctypes.POINTER(ctypes.c_int64))
+    n_ovf = lib.wh_tile_count(_u32p(keys), rows, nnz, nb, spec.subblocks,
+                              spec.tiles, spec.cap, _u32p(buckets),
+                              _u32p(counts), offs_p)
+    pw = _PW_POOL.empty(spec.pairs_shape, np.uint32)
+    ovf_b = np.empty(n_ovf, np.uint32)
+    ovf_r = np.empty(n_ovf, np.uint32)
+    lib.wh_tile_place(_u32p(buckets), rows, nnz, spec.subblocks, spec.tiles,
+                      spec.cap, offs_p, _u32p(counts), _u32p(pw),
+                      _u32p(ovf_b), _u32p(ovf_r))
+    return pw, ovf_b, ovf_r
+
+
+def get_tile_encoder():
+    """The native tile encoder ``fn(keys, nb, spec) -> (pw, ovf_b,
+    ovf_r)``, or None when the library (or the symbol) is absent: the
+    numpy encoder is then live (data/crec.encode_tile_pairs)."""
+    lib = _load()
+    if lib is None or not hasattr(lib, "wh_tile_count"):
+        return None
+    return _tile_encode
 
 
 def get_crec_assembler(fmt: str, nnz: int):

@@ -405,7 +405,12 @@ class AsyncSGD:
             # counts, not seconds: the pairs the online encoder put on
             # the blocks' overflow lists, the slots those lists crossed
             # at (the room in force, summed over the blocks whose list
-            # holds a pair), and how often a block passed the room
+            # holds a pair), how often a block passed the room, and the
+            # blocks the native encoder took (0: the numpy one is live)
+            self.timer.add(pfx + "online_native_blocks",
+                           snap["native_blocks"], n)
+            obs.metrics.encode_native_counter(self.obs.registry).inc(
+                snap["native_blocks"])
             self.timer.add(pfx + "online_overflow_pairs",
                            snap["overflow_pairs"], n)
             self.timer.add(pfx + "online_overflow_slots",

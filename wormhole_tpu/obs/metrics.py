@@ -458,6 +458,18 @@ def encode_counters(reg: Optional[Registry] = None):
                              "leave the tile path"))
 
 
+def encode_native_counter(reg: Optional[Registry] = None):
+    """Blocks the online tile-encode stage put through the NATIVE encoder
+    (native/tile_encode.cc behind data/crec.encode_tile_pairs) — single
+    declaration site, fetched per call like :func:`encode_counters`.
+    Which encoder runs is decided by what the process can load, so a
+    stream whose count is 0 ran the numpy encoder for every block."""
+    reg = reg if reg is not None else default_registry()
+    return reg.counter("feed/encode_native_blocks",
+                       help="online-encoded blocks the native tile "
+                            "encoder took (0: the numpy encoder is live)")
+
+
 def online_overflow_metrics(reg: Optional[Registry] = None):
     """What the online tile encoder puts on the blocks' COO overflow
     lists — single declaration site, fetched per call like

@@ -670,6 +670,14 @@ def test_mp_trace_merge_without_jax_distributed(tmp_path):
         hub = obs.setup(Config(), rank=rank, registry=Registry())
         assert hub.active and trace.enabled(), "env fallbacks missing"
         hub.heartbeat_tick(step=0, num_ex=0)
+        # the two start the loop together: the ranks exchange nothing
+        # here, so without this the spawn skew between the children (a
+        # rank 0 that came up 200 ms late on a loaded host) comes off
+        # rank 1's lateness at every collective
+        open(os.path.join(READY_DIR, f"ready{rank}"), "w").close()
+        while not os.path.exists(os.path.join(READY_DIR,
+                                              f"ready{1 - rank}")):
+            time.sleep(0.002)
         for i in range(4):
             if rank == 1:
                 time.sleep(0.1)            # the planted straggler
@@ -677,7 +685,8 @@ def test_mp_trace_merge_without_jax_distributed(tmp_path):
                            site="test/step")
         hub.finalize(step=4, num_ex=400, wall_s=1.0)
         print(f"OK rank {rank}")
-    """, launcher_args=("--heartbeat-dir", str(hb_dir),
+    """.replace("READY_DIR", repr(str(tmp_path))),
+               launcher_args=("--heartbeat-dir", str(hb_dir),
                         "--trace-dir", str(trace_dir)), raw=True)
     assert r.returncode == 0, r.stdout + r.stderr
     assert r.stdout.count("OK rank") == 2
@@ -692,7 +701,7 @@ def test_mp_trace_merge_without_jax_distributed(tmp_path):
     w = report["worst"]
     assert w["rank"] == 1, report
     # cumulative sleeps: rank 1 trails by ~100*k ms at the k-th
-    # collective; spawn skew between the two children is far smaller
+    # collective (1,000 ms in all); the children leave a barrier together
     assert w["lateness_ms"] > 300, report
     # JSON object keys are strings on disk
     assert report["per_rank"]["1"]["last_in"] >= 3, report
@@ -732,6 +741,11 @@ def test_mp_socket_wire_trace_merge(tmp_path):
         assert hub.active and trace.enabled(), "env fallbacks missing"
         hub.heartbeat_tick(step=0, num_ex=0)
         stack = TransportStack(wire=SocketWire(rendezvous={str(rdv)!r}))
+        # both ranks leave this barrier together: without it the first
+        # collective's arrival order is the processes' start-up order,
+        # and a rank 0 that came up 100 ms late (a loaded host) took the
+        # first collective's lateness off rank 1
+        stack.sync("start")
         for i in range(4):
             if rank == 1:
                 time.sleep(0.1)            # the planted straggler
@@ -754,8 +768,10 @@ def test_mp_socket_wire_trace_merge(tmp_path):
     assert report["collectives_matched"] == 4
     w = report["worst"]
     assert w["rank"] == 1, report
-    # cumulative sleeps: rank 1 trails by ~100*k ms at the k-th
-    # collective (arrival skew survives the socket hop unchanged)
+    # an allreduce is a rendezvous, so rank 1 trails by its one sleep,
+    # ~100 ms, at EACH of the four collectives (arrival skew survives
+    # the socket hop unchanged): ~400 ms in all, never less than the
+    # sleeps, more on a loaded host
     assert w["lateness_ms"] > 300, report
     assert report["sites"]["test/step"]["max_skew_ms"] > 100, report
     assert "collective skew: w1" in r.stderr, r.stderr

@@ -49,11 +49,11 @@ def test_undeclared_span_caught(tmp_path):
 
 def test_prefix_patterns_and_rules_resolve(tmp_path):
     _write_tree(tmp_path, TABLE, {
-        "a.py": 'trace.complete(f"collective:allreduce_{op}", t0, d)\n'
+        "a.py": 'trace.span(f"collective:allreduce_{op}")\n'
                 'trace.span("collective:allreduce_sum")\n'
                 'with tm.scope("eval_dispatch"): pass\n'
-                'trace.complete("ring_stall", t0, d)\n'   # _stall rule
-                'trace.complete(pfx + "put", t0, d)\n'})  # prefixed literal
+                'trace.span("ring_stall")\n'              # _stall rule
+                'with tm.scope(pfx + "put"): pass\n'})     # prefixed literal
     r = _run("--root", str(tmp_path))
     assert r.returncode == 0, r.stderr
 

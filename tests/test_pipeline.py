@@ -232,6 +232,112 @@ def test_stats_drain_resets_and_feeds_timer():
     assert drained["batches"] == 0 and drained["prep"] == 0.0
 
 
+# -- stage spans (the ring's view of the feed's stages) ------------------------
+
+def _ring_spans(feed_factory):
+    """Every complete event the ring holds after one pass of the feed:
+    (name, tid, start_us, end_us)."""
+    from wormhole_tpu.obs import trace
+    trace.enable()
+    try:
+        out = _collect(feed_factory())
+        evs = [(e["name"], e["tid"], e["ts"], e["ts"] + e["dur"])
+               for e in trace.events() if e["ph"] == "X"]
+        assert all(e.get("cat") == "feed" for e in trace.events())
+    finally:
+        trace.disable()
+    return out, evs
+
+
+def test_stage_spans_names_and_threads_pipelined():
+    """The spans ``_acc`` composed, composed by ``_stage`` now:
+    ``<feed>:<stage>`` for work, ``<feed>:<stage>_stall`` for waits, the
+    prep label in place of ``prep``, each on the thread that did it."""
+    def fold(res):
+        return [] if res is None else [res]
+
+    got, evs = _ring_spans(lambda: DeviceFeed(
+        range(8), _jittered_prep, workers=2, transfer=_ident,
+        collate=fold, name="f", prep_label="encode"))
+    assert got == [i * 10 for i in range(8)]
+    names = {n for n, *_ in evs}
+    assert names == {"f:parse", "f:parse_stall", "f:encode",
+                     "f:encode_stall", "f:collate", "f:put", "f:put_stall",
+                     "f:consume_stall"}
+    tids = {}
+    for n, tid, *_ in evs:
+        tids.setdefault(n, set()).add(tid)
+    me = threading.get_ident()
+    assert tids["f:consume_stall"] == {me}
+    assert len(tids["f:parse"]) == 1 and tids["f:parse"] != {me}
+    assert tids["f:parse_stall"] == tids["f:parse"]
+    assert tids["f:put"] == tids["f:collate"] == tids["f:put_stall"]
+    assert len(tids["f:put"]) == 1
+    assert tids["f:encode"] == tids["f:encode_stall"]
+    assert not tids["f:encode"] & (tids["f:put"] | tids["f:parse"] | {me})
+    # one busy span an item a stage (parse has the end-of-stream probe;
+    # collate the flush of the tail)
+    count = lambda n: sum(1 for m, *_ in evs if m == n)   # noqa: E731
+    assert count("f:encode") == 8 and count("f:put") == 8
+    assert count("f:parse") == 9 and count("f:collate") == 9
+
+
+def test_stage_spans_do_not_overlap_on_a_thread_and_sum_to_the_stats():
+    feed = DeviceFeed(range(12), _jittered_prep, workers=2,
+                      transfer=_ident, name="f")
+    _got, evs = _ring_spans(lambda: feed)
+    by_tid = {}
+    for n, tid, s, e in evs:
+        by_tid.setdefault(tid, []).append((s, e, n))
+    for spans in by_tid.values():      # stages are sequential on a thread
+        spans.sort()
+        for (s0, e0, n0), (s1, _e1, n1) in zip(spans, spans[1:]):
+            assert e0 <= s1 + 1e-3, (n0, n1)
+    snap = feed.stats()
+    for stage, key in (("f:prep", "prep"), ("f:put", "put"),
+                       ("f:consume_stall", "consume_stall"),
+                       ("f:prep_stall", "prep_stall")):
+        total = sum(e - s for n, _t, s, e in evs if n == stage) / 1e6
+        assert snap[key] == pytest.approx(total, rel=0.05, abs=2e-3), stage
+    assert snap["collate"] == 0.0      # no collate, no stage, no span
+    assert not any(n == "f:collate" for n, *_ in evs)
+
+
+def test_stage_spans_serial_and_prepare_labels():
+    """workers=0: every stage's span on the consumer's thread; and
+    ``prepare``'s labels: a label with its own namespace IS the span."""
+    def fold(res):
+        return [] if res is None else [res]
+
+    _got, evs = _ring_spans(lambda: DeviceFeed(
+        range(4), workers=0, transfer=_ident, collate=fold, name="s"))
+    assert {n for n, *_ in evs} == {"s:parse", "s:prep", "s:collate",
+                                    "s:put"}
+    assert {tid for _n, tid, *_ in evs} == {threading.get_ident()}
+    from wormhole_tpu.obs import trace
+    feed = DeviceFeed((), lambda it, c: it + 1, workers=0,
+                      transfer=_ident, name="pager")
+    trace.enable()
+    try:
+        assert feed.prepare(1) == 2
+        assert feed.prepare(2, put_label="page:h2d") == 3
+        names = [e["name"] for e in trace.events()]
+    finally:
+        trace.disable()
+    assert names == ["pager:prep", "pager:put", "pager:prep", "page:h2d"]
+    assert feed.stats()["batches"] == 2
+
+
+def test_on_close_runs_under_a_close_span():
+    closed = []
+    _got, evs = _ring_spans(lambda: DeviceFeed(
+        range(3), workers=1, transfer=_ident, name="f",
+        on_close=lambda: closed.append(1)))
+    assert closed == [1]
+    assert [tid for n, tid, *_ in evs if n == "f:close"] == [
+        threading.get_ident()]
+
+
 # -- double buffering (acceptance: ≥2 batches device-resident) ---------------
 
 def test_ring_holds_two_device_batches_while_consumer_mid_step():

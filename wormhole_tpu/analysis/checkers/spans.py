@@ -23,13 +23,13 @@ from wormhole_tpu.analysis.engine import Checker, Engine, FileContext
 # which instrumentation only uses for the eval_ fold)
 _SCOPE_PAT = re.compile(
     r"\.scope\(\s*(?:\w+\s*\+\s*)?" + r"['\"]([^'\"]+)['\"]")
-# literal span/complete names
+# literal span names
 _SPAN_LIT_PAT = re.compile(
-    r"trace\.(?:span|complete)" + r"\(\s*['\"]([^'\"]+)['\"]")
-# f-string span/complete names with a literal prefix before the first
+    r"trace\.span" + r"\(\s*['\"]([^'\"]+)['\"]")
+# f-string span names with a literal prefix before the first
 # placeholder — the prefix must match a `prefix*` table pattern
 _SPAN_FPAT = re.compile(
-    r"trace\.(?:span|complete)" + r"\(\s*f['\"]([^'\"{}]+)\{")
+    r"trace\.span" + r"\(\s*f['\"]([^'\"{}]+)\{")
 
 _TABLE_NAME = "SPAN_TABLE"
 

@@ -35,11 +35,15 @@ from __future__ import annotations
 import functools
 import struct
 import threading
+import time
 import queue
+from contextlib import contextmanager
 from dataclasses import dataclass
 from typing import Iterator, Optional, Tuple
 
 import numpy as np
+
+from wormhole_tpu.obs import trace
 
 MAGIC = b"WCREC\x01\x00\x00"
 _HDR = struct.Struct("<8sIIQQ")  # magic, nnz, block_rows, total_rows, rsvd
@@ -582,6 +586,17 @@ class BlockSource:
                 pass
 
 
+@contextmanager
+def _timed_put(feed):
+    """A feed's own ``device_put``, its seconds added to
+    ``feed.put_time`` (one writer: the thread that transfers)."""
+    t0 = time.perf_counter()
+    try:
+        yield
+    finally:
+        feed.put_time += time.perf_counter() - t0
+
+
 class PackedFeed:
     """Prefetching device feed: a producer thread reads blocks and issues
     ``device_put`` so transfer overlaps the consumer's dispatch loop (the
@@ -665,7 +680,6 @@ class PackedFeed:
             self.host_copy_bytes += n
 
     def _stream_serial(self):
-        import time as _time
         import jax
         put = self._device_put or jax.device_put
         q: "queue.Queue" = queue.Queue(maxsize=self.depth)
@@ -687,9 +701,10 @@ class PackedFeed:
             try:
                 for packed, rows in self._iter_blocks(self.path, self.part,
                                                       self.nparts):
-                    t0 = _time.perf_counter()
-                    dev = put(packed)
-                    self.put_time += _time.perf_counter() - t0
+                    # the span the pipelined stream's put stage has
+                    with trace.span(f"{self.fmt}-feed:put", cat="feed"), \
+                            _timed_put(self):
+                        dev = put(packed)
                     self._account(packed)
                     if not _put_or_stop((dev, packed, rows)):
                         return
@@ -741,16 +756,15 @@ class PackedFeed:
         in-order transfer thread keeping ``depth`` device-resident blocks
         ahead of the consumer. Yields the same ``(dev, host, rows)``
         triples, in the same order, as the serial stream."""
-        import time as _time
         import jax
         from wormhole_tpu.data.pipeline import DeviceFeed
         put = self._device_put or jax.device_put
 
         def transfer(pr):
+            # inside the DeviceFeed's <fmt>-feed:put stage and its span
             packed, rows = pr
-            t0 = _time.perf_counter()
-            dev = put(packed)
-            self.put_time += _time.perf_counter() - t0
+            with _timed_put(self):
+                dev = put(packed)
             self._account(packed)
             return dev, packed, rows
 
@@ -892,6 +906,18 @@ class TextCRecFeed(PackedFeed):
             return asm(chunk)
 
         return source(), prep, fold, None
+
+    def drain_pipe_stats(self, timer, prefix: str = "") -> Optional[dict]:
+        """PackedFeed's snapshot with the reader named: ``text_read`` is
+        the dispatcher's busy seconds (``parse``: the split's chunking
+        and the ``bytes()`` copy)."""
+        snap = super().drain_pipe_stats(timer, prefix)
+        if snap is not None:
+            snap["text_read"] = snap["parse"]
+            if timer is not None:
+                timer.add(prefix + "text_read", snap["text_read"],
+                          max(snap["batches"], 1))
+        return snap
 
 
 # ---------------------------------------------------------------------------
@@ -1054,21 +1080,24 @@ class TileOnlineFeed:
         packed, rows = item
         info = self.info
         R, nnz = info.block_rows, info.nnz
-        src = CRecInfo(nnz=nnz, block_rows=self._src(packed),
-                       total_rows=0)
-        keys, labels = unpack_block(packed, src)
-        if src.block_rows == R:
-            kgrid = keys
-            lab = labels.copy()
-        else:
-            # source blocks shorter than the tile block: pad rows up —
-            # this is what makes ANY source block_rows admissible
-            kgrid = np.full((R, nnz), SENTINEL_KEY, np.uint32)
-            kgrid[:src.block_rows] = keys
-            lab = np.full(R, PAD_LABEL, np.uint8)
-            lab[:src.block_rows] = labels
-        pw, ovb, ovr = encode_tile_pairs(kgrid, info.nb, info.spec)
-        ob, orow = cap_overflow(ovb, ovr, self.room.fit(len(ovb)))
+        with trace.span("encode:unpack", cat="feed"):
+            src = CRecInfo(nnz=nnz, block_rows=self._src(packed),
+                           total_rows=0)
+            keys, labels = unpack_block(packed, src)
+            if src.block_rows == R:
+                kgrid = keys
+                lab = labels.copy()
+            else:
+                # source blocks shorter than the tile block: pad rows up —
+                # this is what makes ANY source block_rows admissible
+                kgrid = np.full((R, nnz), SENTINEL_KEY, np.uint32)
+                kgrid[:src.block_rows] = keys
+                lab = np.full(R, PAD_LABEL, np.uint8)
+                lab[:src.block_rows] = labels
+        with trace.span("encode:tile", cat="feed"):
+            pw, ovb, ovr = encode_tile_pairs(kgrid, info.nb, info.spec)
+        with trace.span("encode:list", cat="feed"):
+            ob, orow = cap_overflow(ovb, ovr, self.room.fit(len(ovb)))
         return ({"pw": pw, "labels": lab, "ovf_b": ob, "ovf_r": orow},
                 lab, rows, len(ovb), native.get_tile_encoder() is not None)
 
@@ -1078,7 +1107,7 @@ class TileOnlineFeed:
         return self._src_rows
 
     def _transfer(self, res):
-        import time as _time
+        # inside the DeviceFeed's <name>:put stage and its span
         import jax
         payload, lab, rows, n_ovf, native = res
         self.native_blocks += native
@@ -1086,9 +1115,8 @@ class TileOnlineFeed:
             self.overflow_pairs += n_ovf
             self.overflow_slots += len(payload["ovf_b"])
         put = self._device_put or jax.device_put
-        t0 = _time.perf_counter()
-        dev = put(payload)
-        self.put_time += _time.perf_counter() - t0
+        with _timed_put(self):
+            dev = put(payload)
         return dev, lab, rows
 
     def _pipelined(self):
@@ -1109,7 +1137,9 @@ class TileOnlineFeed:
         encode stage: ``prep`` stays the inner read/assembly work (the
         consumer's ``read`` timer line), ``encode``/``encode_stall`` are
         the outer pool's busy seconds and the in-order wait on it (the
-        time tile encoding actually delayed the stream)."""
+        time tile encoding actually delayed the stream). ``collate`` is
+        the inner feed's (this feed has none), and a text reader's
+        ``text_read`` passes through."""
         inner_snap = (self.inner.drain_pipe_stats(None)
                       if hasattr(self.inner, "drain_pipe_stats") else None)
         pipe, self._pipe = self._pipe, None
@@ -1125,6 +1155,7 @@ class TileOnlineFeed:
             "put_stall": inner_snap.get("put_stall", 0.0),
             "encode": snap["prep"],
             "encode_stall": snap["put_stall"],
+            "collate": inner_snap.get("collate", 0.0),
             "consume_stall": snap["consume_stall"],
             "batches": snap["batches"],
             "ring_max": snap["ring_max"],
@@ -1138,6 +1169,8 @@ class TileOnlineFeed:
             "room_grown": self.room.grown - self._grown_before,
             "room": self.room.room,
         }
+        if "text_read" in inner_snap:
+            out["text_read"] = inner_snap["text_read"]
         self.overflow_pairs = self.overflow_slots = self.native_blocks = 0
         self._grown_before = self.room.grown
         if timer is not None:
@@ -1353,11 +1386,10 @@ class MeshGroupFeed:
         return views, labels, rows
 
     def _transfer(self, item):
-        import time as _time
-        t0 = _time.perf_counter()
+        # inside the DeviceFeed's <name>:put stage and its span
         views, labels, rows = item
-        dev = place_mesh_group(views, self._shardings)
-        self.put_time += _time.perf_counter() - t0
+        with _timed_put(self):
+            dev = place_mesh_group(views, self._shardings)
         return dev, labels, rows
 
     def __iter__(self):
@@ -1396,8 +1428,9 @@ class MeshGroupFeed:
             "batches": snap["batches"],
             "ring_max": snap["ring_max"],
         }
-        for k in ("encode", "encode_stall", "native_blocks",
-                  "overflow_pairs", "overflow_slots", "room_grown", "room"):
+        for k in ("encode", "encode_stall", "collate", "text_read",
+                  "native_blocks", "overflow_pairs", "overflow_slots",
+                  "room_grown", "room"):
             if k in inner_snap:
                 out[k] = inner_snap[k]
         if timer is not None:

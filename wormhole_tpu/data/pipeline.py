@@ -45,7 +45,12 @@ Contracts preserved from the serial path:
 
 Per-stage busy/stall seconds and ring occupancy are accumulated under a
 lock and surfaced through ``stats()`` / ``drain_stats(timer, prefix)``
-so the bench can report where feed time goes.
+so the bench can report where feed time goes. Every accounted interval
+is also a trace span (``<feed>:<stage>``, ``<feed>:<stage>_stall``),
+opened and closed around the work on the thread that does it
+(``_stage``), so a profiler capture shows the dispatcher, the prep pool,
+the transfer thread and the consumer on their own lines beside the
+device's ops.
 """
 
 from __future__ import annotations
@@ -53,6 +58,7 @@ from __future__ import annotations
 import queue
 import threading
 import time
+from contextlib import contextmanager
 from typing import Any, Callable, Iterable, Optional
 
 from wormhole_tpu.obs import trace
@@ -60,6 +66,7 @@ from wormhole_tpu.obs import trace
 __all__ = ["DeviceFeed", "group_blocks"]
 
 _END = object()
+_EMPTY = object()       # a timed ring get that found nothing
 
 
 def group_blocks(source: Iterable[Any], size: int, *,
@@ -116,7 +123,9 @@ class DeviceFeed:
     collate: ``collate(result) -> iterable of payloads``; runs on the
              transfer thread sequentially in stream order (stateful
              re-blocking allowed). Called once more with ``None`` at
-             end of stream to flush a buffered tail.
+             end of stream to flush a buffered tail. A call's payloads
+             are taken whole before the first is transferred, so the
+             ``collate`` stage's seconds are the re-blocking's.
     transfer: ``transfer(payload) -> device item``; defaults to
              ``jax.device_put``.
     bytes_read: callable forwarded by :meth:`bytes_read` (accounting
@@ -156,40 +165,51 @@ class DeviceFeed:
         self.prep_label = prep_label
         self._lock = threading.Lock()
         # Stage accumulators are written from the dispatcher, prep-pool,
-        # and consumer threads; every read-modify-write goes through
-        # _acc() or an explicit `with self._lock` block.
-        self._busy = {"parse": 0.0, "prep": 0.0, "put": 0.0}  # guarded-by: _lock
+        # transfer and consumer threads; every read-modify-write goes
+        # through _stage() or an explicit `with self._lock` block.
+        self._busy = {"parse": 0.0, "prep": 0.0, "collate": 0.0,  # guarded-by: _lock
+                      "put": 0.0}
         self._stall = {"parse": 0.0, "prep": 0.0, "put": 0.0,  # guarded-by: _lock
                        "consume": 0.0}
         self._batches = 0  # guarded-by: _lock
         self._ring_max = 0  # guarded-by: _lock
+        # (stall?, key, label) -> span name, composed once a feed and not
+        # once an interval; racing writers store the same string
+        self._span_names: dict = {}
         self._threads: list = []
 
     # -- stats ---------------------------------------------------------------
 
-    def _acc(self, table: dict, key: str, dt: float,
-             label: Optional[str] = None) -> None:
-        with self._lock:
-            table[key] = table.get(key, 0.0) + dt
-        # every accounted interval doubles as a trace span on the thread
-        # that did the work, so Perfetto shows dispatcher / prep pool /
-        # transfer / consumer as separate tracks with stage overlap
-        if trace.enabled():
-            suffix = "_stall" if table is self._stall else ""
-            if label is None:
-                label = (self.prep_label
-                         if key == "prep" and self.prep_label else key)
+    @contextmanager
+    def _stage(self, table: dict, key: str, label: Optional[str] = None):
+        """One accounted interval of one stage: a trace span around the
+        body on the thread that does the work, its seconds added to
+        ``table[key]`` under the lock when it ends."""
+        stall = table is self._stall
+        name = self._span_names.get((stall, key, label))
+        if name is None:
+            name = label or (self.prep_label
+                             if key == "prep" and self.prep_label else key)
             # a label carrying its own namespace (e.g. "page:h2d") IS
             # the span name — it resolves through SPAN_TABLE directly
             # instead of the <feed>:<stage> rule
-            name = (label if ":" in label
-                    else f"{self.name}:{label}{suffix}")
-            trace.complete(name, time.monotonic() - dt, dt, cat="feed")
+            if ":" not in name:
+                name = f"{self.name}:{name}{'_stall' if stall else ''}"
+            self._span_names[(stall, key, label)] = name
+        t0 = time.monotonic()
+        try:
+            with trace.span(name, cat="feed"):
+                yield
+        finally:
+            dt = time.monotonic() - t0
+            with self._lock:
+                table[key] = table.get(key, 0.0) + dt
 
     def stats(self) -> dict:
         """Snapshot: per-stage busy/stall seconds (worker seconds sum
-        over the pool, so busy can exceed wall time), batches delivered,
-        and the deepest ring occupancy observed."""
+        over the pool, so busy can exceed wall time; ``collate`` stays
+        0 without a collate), batches delivered, and the deepest ring
+        occupancy observed."""
         with self._lock:
             out = {f"{k}": v for k, v in self._busy.items()}
             out.update({f"{k}_stall": v for k, v in self._stall.items()})
@@ -200,7 +220,8 @@ class DeviceFeed:
     def drain_stats(self, timer=None, prefix: str = "") -> dict:
         """Return the stats snapshot, reset the accumulators, and (when
         ``timer`` is given) merge the stage seconds into it as
-        ``{prefix}parse/pad/put`` + ``{prefix}*_stall`` entries."""
+        ``{prefix}parse/pad/put`` + ``{prefix}*_stall`` entries
+        (``{prefix}collate`` too where the feed has a collate)."""
         with self._lock:
             snap = {k: v for k, v in self._busy.items()}
             snap.update({f"{k}_stall": v for k, v in self._stall.items()})
@@ -217,6 +238,8 @@ class DeviceFeed:
             lbl = self.prep_label or "pad"
             timer.add(prefix + "parse", snap["parse"], n)
             timer.add(prefix + lbl, snap["prep"], n)
+            if self.collate:
+                timer.add(prefix + "collate", snap["collate"], n)
             timer.add(prefix + "put", snap["put"], n)
             timer.add(prefix + "feed_stall", snap["consume_stall"], n)
             timer.add(prefix + f"{lbl}_stall", snap["prep_stall"], n)
@@ -256,60 +279,66 @@ class DeviceFeed:
         its page-row H2D transfers here with ``put_label="page:h2d"``
         so paging reuses this one transfer path (stage accounting,
         spans, batch count) instead of growing a second one."""
-        mono = time.monotonic
         transfer = self._default_transfer()
-        t0 = mono()
-        res = self.prep(item, ctx) if self.prep else item
-        self._acc(self._busy, "prep", mono() - t0, label=prep_label)
-        t0 = mono()
-        out = transfer(res)
-        self._acc(self._busy, "put", mono() - t0, label=put_label)
+        with self._stage(self._busy, "prep", prep_label):
+            res = self.prep(item, ctx) if self.prep else item
+        with self._stage(self._busy, "put", put_label):
+            out = transfer(res)
         with self._lock:
             self._batches += 1
         return out
+
+    def _close(self) -> None:
+        """``on_close``, on the consumer's thread, under a
+        ``<feed>:close`` span (a mapped source unmaps here)."""
+        if self._on_close is not None:
+            with trace.span(f"{self.name}:close", cat="feed"):
+                self._on_close()
+
+    def _next_item(self, it):
+        """The ``parse`` stage: the source's next item and its
+        ``seq_ctx``, as ``(item, ctx)``; ``(_END, None)`` at the end."""
+        with self._stage(self._busy, "parse"):
+            try:
+                item = next(it)
+            except StopIteration:
+                return _END, None
+            return item, (self.seq_ctx(item) if self.seq_ctx else None)
+
+    def _collated(self, res, end: bool = False) -> Iterable[Any]:
+        """The ``collate`` stage: the payloads ``res`` folds into (at
+        the stream's ``end``, the flushed tail), taken whole inside the
+        stage."""
+        if not self.collate:
+            return () if end else (res,)
+        with self._stage(self._busy, "collate"):
+            return list(self.collate(None if end else res))
 
     def _iter_serial(self):
         """Inline fallback: every stage on the consumer thread, same
         order/exception semantics, no threads (``pipeline_workers=0``)."""
         transfer = self._default_transfer()
-        mono = time.monotonic
         try:
             it = iter(self.source)
             while True:
-                t0 = mono()
-                try:
-                    item = next(it)
-                except StopIteration:
-                    self._acc(self._busy, "parse", mono() - t0)
+                item, ctx = self._next_item(it)
+                end = item is _END      # then: the collate's tail
+                if not end:
+                    with self._stage(self._busy, "prep"):
+                        item = self.prep(item, ctx) if self.prep else item
+                for payload in self._collated(item, end):
+                    with self._stage(self._busy, "put"):
+                        out = transfer(payload)
+                    with self._lock:
+                        self._batches += 1
+                    yield out
+                if end:
                     break
-                ctx = self.seq_ctx(item) if self.seq_ctx else None
-                self._acc(self._busy, "parse", mono() - t0)
-                t0 = mono()
-                res = self.prep(item, ctx) if self.prep else item
-                self._acc(self._busy, "prep", mono() - t0)
-                payloads = self.collate(res) if self.collate else (res,)
-                for payload in payloads:
-                    t0 = mono()
-                    out = transfer(payload)
-                    self._acc(self._busy, "put", mono() - t0)
-                    with self._lock:
-                        self._batches += 1
-                    yield out
-            if self.collate:
-                for payload in self.collate(None):
-                    t0 = mono()
-                    out = transfer(payload)
-                    self._acc(self._busy, "put", mono() - t0)
-                    with self._lock:
-                        self._batches += 1
-                    yield out
         finally:
-            if self._on_close is not None:
-                self._on_close()
+            self._close()
 
     def _iter_pipelined(self):
         transfer = self._default_transfer()
-        mono = time.monotonic
         stop = threading.Event()
         work_q: "queue.Queue" = queue.Queue(maxsize=max(2 * self.workers, 2))
         ring: "queue.Queue" = queue.Queue(maxsize=self.ring_depth)
@@ -331,17 +360,11 @@ class DeviceFeed:
             try:
                 it = iter(self.source)
                 while not stop.is_set():
-                    t0 = mono()
-                    try:
-                        item = next(it)
-                    except StopIteration:
-                        self._acc(self._busy, "parse", mono() - t0)
+                    item, ctx = self._next_item(it)
+                    if item is _END:
                         break
-                    ctx = self.seq_ctx(item) if self.seq_ctx else None
-                    self._acc(self._busy, "parse", mono() - t0)
-                    t0 = mono()
-                    ok = put_or_stop(work_q, (seq, item, ctx))
-                    self._acc(self._stall, "parse", mono() - t0)
+                    with self._stage(self._stall, "parse"):
+                        ok = put_or_stop(work_q, (seq, item, ctx))
                     if not ok:
                         return
                     seq += 1
@@ -361,35 +384,31 @@ class DeviceFeed:
 
         def worker() -> None:
             while not stop.is_set():
-                t0 = mono()
-                try:
-                    task = work_q.get(timeout=0.2)
-                except queue.Empty:
-                    self._acc(self._stall, "prep", mono() - t0)
-                    continue
-                self._acc(self._stall, "prep", mono() - t0)
+                with self._stage(self._stall, "prep"):
+                    try:
+                        task = work_q.get(timeout=0.2)
+                    except queue.Empty:
+                        continue
                 if task is _END:
                     return
                 seq, item, ctx = task
-                t0 = mono()
-                try:
-                    res = self.prep(item, ctx) if self.prep else item
-                except BaseException as e:
-                    res = _StageError(e)
-                self._acc(self._busy, "prep", mono() - t0)
+                with self._stage(self._busy, "prep"):
+                    try:
+                        res = self.prep(item, ctx) if self.prep else item
+                    except BaseException as e:
+                        res = _StageError(e)
                 with cond:
                     done[seq] = res
                     cond.notify_all()
 
         def emit(payload) -> bool:
             """device_put + ring put; False when the consumer is gone."""
-            t0 = mono()
             try:
-                dev = transfer(payload)
+                with self._stage(self._busy, "put"):
+                    dev = transfer(payload)
             except BaseException as e:
                 put_or_stop(ring, _StageError(e))
                 return False
-            self._acc(self._busy, "put", mono() - t0)
             if not put_or_stop(ring, dev):
                 return False
             with self._lock:
@@ -405,43 +424,30 @@ class DeviceFeed:
         def transferrer() -> None:
             nxt = 0
             while not stop.is_set():
-                t0 = mono()
-                with cond:
+                with self._stage(self._stall, "put"), cond:
                     while nxt not in done and \
                             (total[0] is None or nxt < total[0]):
                         if stop.is_set():
                             return
                         cond.wait(timeout=0.2)
-                    if total[0] is not None and nxt >= total[0]:
-                        self._acc(self._stall, "put", mono() - t0)
-                        break
-                    res = done.pop(nxt)
-                self._acc(self._stall, "put", mono() - t0)
+                    end = total[0] is not None and nxt >= total[0]
+                    res = None if end else done.pop(nxt)
                 nxt += 1
                 if isinstance(res, _StageError):
                     put_or_stop(ring, res)
                     return
                 try:
-                    payloads = (self.collate(res) if self.collate
-                                else (res,))
+                    # at the end: the collate's tail
+                    payloads = self._collated(res, end)
                 except BaseException as e:
                     put_or_stop(ring, _StageError(e))
                     return
                 for payload in payloads:
                     if not emit(payload):
                         return
-            if stop.is_set():
-                return
-            if self.collate:
-                try:
-                    tail = list(self.collate(None))
-                except BaseException as e:
-                    put_or_stop(ring, _StageError(e))
+                if end:
+                    put_or_stop(ring, _END)
                     return
-                for payload in tail:
-                    if not emit(payload):
-                        return
-            put_or_stop(ring, _END)
 
         threads = [threading.Thread(target=dispatcher, daemon=True,
                                     name=f"{self.name}-dispatch")]
@@ -456,17 +462,17 @@ class DeviceFeed:
             t.start()
         try:
             while True:
-                t0 = mono()
-                try:
-                    item = ring.get(timeout=0.5)
-                except queue.Empty:
-                    self._acc(self._stall, "consume", mono() - t0)
+                with self._stage(self._stall, "consume"):
+                    try:
+                        item = ring.get(timeout=0.5)
+                    except queue.Empty:
+                        item = _EMPTY
+                if item is _EMPTY:
                     if not xfer.is_alive():
                         raise RuntimeError(
                             f"{self.name}: transfer thread died without "
                             "delivering end-of-stream")
                     continue
-                self._acc(self._stall, "consume", mono() - t0)
                 if item is _END:
                     break
                 if isinstance(item, _StageError):
@@ -476,5 +482,4 @@ class DeviceFeed:
                 yield item
         finally:
             stop.set()
-            if self._on_close is not None:
-                self._on_close()
+            self._close()

@@ -394,6 +394,11 @@ class AsyncSGD:
         self.timer.add(pfx + "feed_stall", snap["consume_stall"], n)
         self.timer.add(pfx + "read_stall", snap["prep_stall"], n)
         self.timer.add(pfx + "put_stall", snap["put_stall"], n)
+        # the text feed's serial stages (data/crec.TextCRecFeed): the
+        # reader's busy seconds and the re-blocking on its transfer thread
+        for k in ("text_read", "collate"):
+            if snap.get(k):
+                self.timer.add(pfx + k, snap[k], n)
         if "encode" in snap:
             # online tile-encode stage (data/crec.TileOnlineFeed):
             # encode_stall is the in-order transferrer waiting on the
@@ -572,7 +577,9 @@ class AsyncSGD:
         """Drain any deferred crec2 metrics; returns the tail Progress
         (callers merge it into their running totals)."""
         tail = Progress()
-        MetricWindow(self, tail, TRAIN, None, acc=self._crec_acc).drain()
+        with obs.trace.span("pass:flush", cat="pass"):
+            MetricWindow(self, tail, TRAIN, None,
+                         acc=self._crec_acc).drain()
         return tail
 
     def _process_crec(self, file: str, part: int, nparts: int,
@@ -644,10 +651,22 @@ class AsyncSGD:
         # the app's accumulator survives across parts); eval/v1 metrics
         # ride per-step vectors in the part's window
         acc_metrics = tile and kind == TRAIN
-        win = MetricWindow(self, local, kind, pooled,
-                           acc=self._crec_acc if acc_metrics else None)
-        step, layout = self._crec_step(kind, "tile" if tile else "dense",
-                                       info)
+        pfx = "" if kind == TRAIN else "eval_"
+        # pass:open is the head of a pass on this thread: the window,
+        # the step lookup, the feed. The feed's threads (and a mapped
+        # file's mapping) start with the loop's first `next`, under its
+        # first consume_stall
+        with obs.trace.span("pass:open", cat="pass"):
+            win = MetricWindow(self, local, kind, pooled,
+                               acc=self._crec_acc if acc_metrics else None)
+            step, layout = self._crec_step(
+                kind, "tile" if tile else "dense", info)
+            feed = self._feed(file, part, nparts, fmt,
+                              tile_info=info if online else None)
+            put_before, copied_before = feed.put_time, feed.host_copy_bytes
+            # snapshot BEFORE iterating: the feed flips _cache_full as
+            # its stream exhausts, which is mid-way through THIS part
+            replay = getattr(feed, "_cache_full", False)
 
         def record(item) -> None:
             m, labels = item
@@ -672,13 +691,6 @@ class AsyncSGD:
                 return host            # cached item: already labels-only
             return host[lab_off:lab_off + info.block_rows].copy()
 
-        pfx = "" if kind == TRAIN else "eval_"
-        feed = self._feed(file, part, nparts, fmt,
-                          tile_info=info if online else None)
-        put_before, copied_before = feed.put_time, feed.host_copy_bytes
-        # snapshot BEFORE iterating: the feed flips _cache_full as its
-        # stream exhausts, which is mid-way through THIS part
-        replay = getattr(feed, "_cache_full", False)
         if replay:
             # HBM-resident replay: single-device steps serialize on the
             # donated slots chain anyway, so the staleness window only
@@ -702,22 +714,28 @@ class AsyncSGD:
             # fetch synchronizes
             while inflight:
                 record(inflight.popleft())
-            if acc_metrics and replay:
-                # HBM-resident replay: leave the accumulator deferred —
-                # the end-of-part fetch is a round trip per part; the
-                # caller's flush_metrics()/disp_itv drains it — but bound
-                # it (pipelined, non-final) so dispatch can't run
-                # unboundedly ahead of the device
-                win.fold()
-                if self._crec_acc.count >= self.CREC_DRAIN_CHUNK:
-                    win.drain(final=False)
-            else:
-                win.drain()
-        self.timer.add(pfx + "put", feed.put_time - put_before)
-        # a count, not seconds: bytes the feed copied on the host
-        self.timer.add(pfx + "host_copy_bytes",
-                       feed.host_copy_bytes - copied_before)
-        self._merge_pipe_snap(feed.drain_pipe_stats(None), pfx, local)
+            with obs.trace.span("pass:drain", cat="pass"):
+                if acc_metrics and replay:
+                    # HBM-resident replay: leave the accumulator
+                    # deferred — the end-of-part fetch is a round trip
+                    # per part; the caller's flush_metrics()/disp_itv
+                    # drains it — but bound it (pipelined, non-final) so
+                    # dispatch can't run unboundedly ahead of the device
+                    win.fold()
+                    if self._crec_acc.count >= self.CREC_DRAIN_CHUNK:
+                        win.drain(final=False)
+                else:
+                    win.drain()
+        with obs.trace.span("pass:close", cat="pass"):
+            # the loop's last block goes here and not at the function's
+            # end: a mapped file's mapping lives as long as a view of it,
+            # and unmapping 1.4 GB takes tens of ms (PERF.md, PR 41)
+            dev = host = None  # noqa: F841
+            self.timer.add(pfx + "put", feed.put_time - put_before)
+            # a count, not seconds: bytes the feed copied on the host
+            self.timer.add(pfx + "host_copy_bytes",
+                           feed.host_copy_bytes - copied_before)
+            self._merge_pipe_snap(feed.drain_pipe_stats(None), pfx, local)
         return local
 
     def _crec_step(self, kind: str, form: str, info):
@@ -774,20 +792,21 @@ class AsyncSGD:
                 "process() is single-process only")
         is_tile = fmt == "crec2" or online
         pfx = "" if kind == TRAIN else "eval_"
-        win = MetricWindow(self, local, kind, pooled, bounded=True)
-        step, layout = self._crec_step(
-            kind, "tile_mesh" if is_tile else "dense_mesh", info)
-        tx = self.store.mesh_transport()
-        steps_before, ici_before = tx.dispatches, tx.bytes_ici
-        inner = self._make_feed(file, part, nparts, fmt,
-                                device_put=lambda x: x,
-                                tile_info=info if online else None)
-        feed = MeshGroupFeed(
-            inner, self.rt.data_axis_size,
-            mesh_group_shardings(self.rt, is_tile), info, is_tile,
-            workers=self.cfg.pipeline_workers,
-            depth=max(self.cfg.pipeline_ring, 1), online=online,
-            want_labels=kind != TRAIN and pooled is not None)
+        with obs.trace.span("pass:open", cat="pass"):
+            win = MetricWindow(self, local, kind, pooled, bounded=True)
+            step, layout = self._crec_step(
+                kind, "tile_mesh" if is_tile else "dense_mesh", info)
+            tx = self.store.mesh_transport()
+            steps_before, ici_before = tx.dispatches, tx.bytes_ici
+            inner = self._make_feed(file, part, nparts, fmt,
+                                    device_put=lambda x: x,
+                                    tile_info=info if online else None)
+            feed = MeshGroupFeed(
+                inner, self.rt.data_axis_size,
+                mesh_group_shardings(self.rt, is_tile), info, is_tile,
+                workers=self.cfg.pipeline_workers,
+                depth=max(self.cfg.pipeline_ring, 1), online=online,
+                want_labels=kind != TRAIN and pooled is not None)
         for payload, labels_u8, _rows in feed:
             with self.timer.scope(pfx + "dispatch"):
                 with obs.trace.span("mesh:dispatch", cat="mesh"):
@@ -797,18 +816,20 @@ class AsyncSGD:
             else:
                 win.add_step(m, labels_u8, layout)
         with self.timer.scope(pfx + "wait"):
-            win.drain()
-        self.timer.add(pfx + "put", feed.put_time)
-        self._merge_pipe_snap(feed.drain_pipe_stats(None), pfx, local)
-        # counts, not seconds: the mesh dispatches of this part, the
-        # ICI bytes one chip moved for them as the store's model books
-        # them (store.mesh_step_ici_bytes), and the bytes the feed
-        # copied on the host (0 from a mapped local file; a fall-back
-        # to readinto shows as a number)
-        self.timer.add(pfx + "mesh_steps", tx.dispatches - steps_before)
-        self.timer.add(pfx + "ici_bytes", tx.bytes_ici - ici_before)
-        self.timer.add(pfx + "host_copy_bytes", feed.host_copy_bytes)
-        self._export_group_feed_stats(feed)
+            with obs.trace.span("pass:drain", cat="pass"):
+                win.drain()
+        with obs.trace.span("pass:close", cat="pass"):
+            self.timer.add(pfx + "put", feed.put_time)
+            self._merge_pipe_snap(feed.drain_pipe_stats(None), pfx, local)
+            # counts, not seconds: the mesh dispatches of this part, the
+            # ICI bytes one chip moved for them as the store's model
+            # books them (store.mesh_step_ici_bytes), and the bytes the
+            # feed copied on the host (0 from a mapped local file; a
+            # fall-back to readinto shows as a number)
+            self.timer.add(pfx + "mesh_steps", tx.dispatches - steps_before)
+            self.timer.add(pfx + "ici_bytes", tx.bytes_ici - ici_before)
+            self.timer.add(pfx + "host_copy_bytes", feed.host_copy_bytes)
+            self._export_group_feed_stats(feed)
         return local
 
     def _export_group_feed_stats(self, feed) -> None:

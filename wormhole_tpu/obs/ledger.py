@@ -134,13 +134,34 @@ SPAN_TABLE: Dict[str, str] = {
     "page:h2d": "paging",
     "page:d2h": "paging",
     "page:evict": "paging",
+    # the crec pass loop's own phases on its thread
+    # (learners/async_sgd.py): pass:open is everything before the loop
+    # (window, step lookup, feed), pass:drain the end-of-part metric
+    # drain inside the `wait` scope that holds it, pass:close the feed's
+    # counters merged into the Timer, pass:flush flush_metrics. A device
+    # trace's idle gaps are named by these (benchmark/host_spans.py)
+    "pass:open": "other",
+    "pass:drain": "metrics_readback",
+    "pass:close": "other",
+    "pass:flush": "metrics_readback",
+    # the online tile encoder's three steps on a prep worker, inside
+    # its <feed>:encode stage (data/crec.TileOnlineFeed._encode)
+    "encode:unpack": "encode",
+    "encode:tile": "encode",
+    "encode:list": "encode",
+    # DeviceFeed's collate stage on the transfer thread (a text feed's
+    # re-blocking) and the feed's on_close on the consumer's thread (a
+    # mapped source unmaps there)
+    "collate": "host_prep",
+    "close": "other",
 }
 
 # DeviceFeed stage -> bucket, for dynamic ``<feed>:<stage>`` span names
 # (the feed name varies; the stage vocabulary is fixed in pipeline.py).
 _FEED_STAGES = {"parse": "host_prep", "prep": "host_prep",
                 "pad": "host_prep", "encode": "encode",
-                "stack": "host_prep", "put": "h2d_transfer"}
+                "stack": "host_prep", "collate": "host_prep",
+                "put": "h2d_transfer", "close": "other"}
 
 
 def span_bucket(name: str, cat: str = "") -> Optional[str]:
@@ -167,7 +188,7 @@ def _self_times(spans: List[Tuple[float, float, str]]):
     """Innermost-wins sweep over ``(start, end, name)`` intervals on one
     thread: returns (name -> self time, total covered time). Properly
     nested spans (context managers) partition exactly; a partial overlap
-    (a ``complete()`` with a back-dated start) is clamped to its
+    (a hand-made event with a back-dated start) is clamped to its
     enclosing span so no instant is charged twice."""
     out: Dict[str, float] = {}
     if not spans:

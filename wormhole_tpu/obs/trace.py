@@ -1,15 +1,29 @@
-"""Span tracing: a bounded, thread-aware trace recorder.
+"""Span tracing: one span primitive, two sinks.
 
 Dapper-style spans (Sigelman et al., 2010) over the hot paths this repo
-already times — DeviceFeed stages, device step dispatch/wait, collective
-boundaries, GBDT histogram kernels and chunk reads, checkpoint save/load
-— emitted as Chrome trace-event JSON that loads directly in Perfetto
-(ui.perfetto.dev) or chrome://tracing.
+already times — the pass loop's open/dispatch/wait/drain/close,
+DeviceFeed stages, collective boundaries, GBDT histogram kernels and
+chunk reads, checkpoint save/load. :func:`span` is the one primitive;
+a span is opened and closed where the work happens, on the thread that
+does it, and lands in:
 
-Design constraints, in order:
+* **the profiler** (``jax.profiler.TraceAnnotation``), always: with no
+  profiler session open that is the profiler's own no-op; inside one
+  (``jax.profiler.start_trace``, a TensorBoard capture, the benchmark's
+  ``--trace 1``) the span is in the same ``.xplane.pb`` as the device's
+  ops, on the same clock, on its thread's own line of ``/host:CPU``.
+  The class is bound lazily through ``sys.modules`` (as :func:`_rank`
+  finds jax), so ``obs`` imports without jax: no jax, no such sink;
+* **the ring**, behind :func:`enable`: a bounded recorder stamped with
+  ``time.monotonic()`` and flushed as Chrome trace-event JSON that
+  loads in Perfetto (ui.perfetto.dev) or chrome://tracing. Host only:
+  it shares no clock with a device trace (``obs/merge.py`` aligns the
+  ranks' files with each other on the wall clock).
 
-1. **Near-zero cost when off.** Tracing is off by default; every record
-   call starts with one module-global bool check and returns. The
+Design constraints of the ring, in order:
+
+1. **Near-zero cost when off.** The ring is off by default; every
+   record call starts with one module-global bool check and returns. The
    instrumented paths (``Timer.scope``, DeviceFeed stages) are
    per-*batch*, not per-row, so even enabled tracing is noise next to a
    device step.
@@ -23,25 +37,21 @@ Design constraints, in order:
 
 Events are stored as tuples and formatted only at :func:`flush`; the
 record path does no dict building, no JSON, no I/O.
-
-An optional XLA profile window (:func:`xla_profile`) hangs off the same
-API so a bench phase can capture a ``jax.profiler.trace`` alongside the
-host spans.
 """
 
 from __future__ import annotations
 
 import json
 import os
+import sys
 import threading
 import time
 from collections import deque
-from contextlib import contextmanager
-from typing import Iterator, Optional
+from typing import Optional
 
-__all__ = ["enable", "disable", "enabled", "configure", "complete",
-           "span", "instant", "counter", "events", "summary", "reset",
-           "dropped", "flush", "write_trace", "xla_profile"]
+__all__ = ["enable", "disable", "enabled", "configure", "span",
+           "counter", "events", "summary", "reset", "dropped", "flush",
+           "write_trace"]
 
 # module-global fast path: `if not _ENABLED: return` is the entire cost
 # of every record call while tracing is off
@@ -56,8 +66,23 @@ _TID_NAMES: dict = {}          # tid -> thread name (first event wins)
 
 # event tuples: (ph, name, cat, ts_us, dur_us, tid, arg)
 _PH_COMPLETE = "X"
-_PH_INSTANT = "i"
 _PH_COUNTER = "C"
+
+# jax.profiler.TraceAnnotation, once jax is there to take it from
+_ANNOTATION = None
+
+
+def _bind_annotation():
+    """The profiler's span class, from a jax that something else has
+    already imported (never imported here). None without one."""
+    global _ANNOTATION
+    j = sys.modules.get("jax")
+    if j is not None:
+        try:
+            _ANNOTATION = j.profiler.TraceAnnotation
+        except AttributeError:
+            pass        # a jax still importing: ask again next span
+    return _ANNOTATION
 
 
 def _rank() -> int:
@@ -66,7 +91,6 @@ def _rank() -> int:
     env, then 0. A jax that never ran ``distributed.initialize`` reports
     ``process_index() == 0`` in every launch_mp child, so its answer is
     only trusted when the jax world is actually larger than one."""
-    import sys
     j = sys.modules.get("jax")
     if j is not None:
         try:
@@ -131,41 +155,37 @@ def _record(ph: str, name: str, cat: str, ts: float, dur: float,
     _RING.append((ph, name, cat, (ts - _T0) * 1e6, dur * 1e6, tid, arg))
 
 
-def complete(name: str, t0: float, dur: float, cat: str = "",
-             args: Optional[dict] = None) -> None:
-    """Record a completed span: ``t0`` is the ``time.monotonic()`` start,
-    ``dur`` seconds. This is the hot-path entry point — callers that
-    already measured a duration (Timer.scope, DeviceFeed stages) hand it
-    over instead of paying a second context-manager frame. ``args``
-    (optional dict) lands as the event's Perfetto args panel."""
-    if not _ENABLED:
-        return
-    _record(_PH_COMPLETE, name, cat, t0, dur,
-            dict(args) if args else None)
-
-
-@contextmanager
-def span(name: str, cat: str = "",
-         args: Optional[dict] = None) -> Iterator[None]:
-    """``with trace.span("checkpoint:save"): ...`` — a no-op (single
-    bool check) while tracing is off. A mutable ``args`` dict may be
+class span:
+    """``with trace.span("checkpoint:save"): ...``: the one span
+    primitive. It always opens a profiler annotation of the same name
+    (see the module docstring) and, while the ring is on, records a
+    complete event when it closes. A mutable ``args`` dict may be
     filled *inside* the span (payload sizes known only after encoding);
-    it is snapshotted when the span closes."""
-    if not _ENABLED:
-        yield
-        return
-    t0 = time.monotonic()
-    try:
-        yield
-    finally:
-        _record(_PH_COMPLETE, name, cat, t0, time.monotonic() - t0,
-                dict(args) if args else None)
+    the ring snapshots it when the span closes. The profiler takes the
+    name alone."""
 
+    __slots__ = ("name", "cat", "args", "_t0", "_ann")
 
-def instant(name: str, cat: str = "") -> None:
-    if not _ENABLED:
-        return
-    _record(_PH_INSTANT, name, cat, time.monotonic(), 0.0)
+    def __init__(self, name: str, cat: str = "",
+                 args: Optional[dict] = None) -> None:
+        self.name, self.cat, self.args = name, cat, args
+
+    def __enter__(self) -> None:
+        ann = _ANNOTATION or _bind_annotation()
+        if ann is not None:
+            ann = ann(self.name)
+            ann.__enter__()
+        self._ann = ann
+        self._t0 = time.monotonic() if _ENABLED else None
+
+    def __exit__(self, *exc) -> None:
+        t0 = self._t0
+        if t0 is not None and _ENABLED:
+            _record(_PH_COMPLETE, self.name, self.cat, t0,
+                    time.monotonic() - t0,
+                    dict(self.args) if self.args else None)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
 
 
 def counter(name: str, value: float, cat: str = "") -> None:
@@ -187,8 +207,6 @@ def events() -> list:
             ev["dur"] = round(dur, 3)
             if arg:
                 ev["args"] = arg
-        elif ph == _PH_INSTANT:
-            ev["s"] = "t"
         elif ph == _PH_COUNTER:
             ev["args"] = {"value": arg}
         out.append(ev)
@@ -259,26 +277,3 @@ def flush(path: Optional[str] = None) -> Optional[str]:
     if not _ENABLED or not dst:
         return None
     return write_trace(dst, events())
-
-
-@contextmanager
-def xla_profile(logdir: str) -> Iterator[None]:
-    """Optional ``jax.profiler.trace`` window hanging off the same API:
-    a bench phase wraps itself in this to capture an XLA profile next to
-    the host spans. Degrades to a no-op when jax (or its profiler) is
-    unavailable or the profiler refuses to start."""
-    if not logdir:
-        yield
-        return
-    try:
-        import jax
-        ctx = jax.profiler.trace(logdir)
-    except Exception:
-        yield
-        return
-    try:
-        with ctx:
-            yield
-    except Exception:
-        # a profiler that fails to start/stop must never kill the run
-        yield

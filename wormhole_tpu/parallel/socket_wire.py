@@ -102,6 +102,8 @@ MAX_FRAME = 1 << 30
 # one sendall until the batch passes this many bytes
 _COALESCE_BYTES = 1 << 16
 _RECV_CHUNK = 1 << 16
+# what an orderly close gives a peer's send thread to empty its outbox
+_CLOSE_FLUSH_S = 2.0
 
 
 def free_port() -> int:
@@ -352,10 +354,20 @@ class _Peer:
                 w._cv.notify_all()
 
     def close(self) -> None:
+        """Orderly close: the frames already in the outbox go out first.
+        A rank that leaves its last collective a moment before its peer
+        has only ENQUEUED its half of it; shutting the socket down under
+        the send thread dropped that frame, and the peer then read the
+        close as a loss mid-collective (seen on a loaded host). So the
+        sentinel is queued behind the frames and the send thread is
+        given ``_CLOSE_FLUSH_S`` to reach it; a peer already marked dead
+        is not waited for."""
         try:
-            self.outbox.put_nowait(None)
+            self.outbox.put(None, timeout=_CLOSE_FLUSH_S)
         except queue.Full:
             pass
+        if self.rank not in self.wire._dead:
+            self._sender.join(_CLOSE_FLUSH_S)
         try:
             self.sock.shutdown(socket.SHUT_RDWR)
         except OSError:

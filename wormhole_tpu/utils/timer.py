@@ -24,14 +24,13 @@ class Timer:
     def scope(self, name: str) -> Iterator[None]:
         t0 = time.monotonic()
         try:
-            yield
+            # every timer scope is a trace span, opened and closed here
+            with trace.span(name, cat="timer"):
+                yield
         finally:
             dt = time.monotonic() - t0
             self.totals[name] = self.totals.get(name, 0.0) + dt
             self.counts[name] = self.counts.get(name, 0) + 1
-            # every timer scope doubles as a trace span; complete() is a
-            # single bool check while tracing is off
-            trace.complete(name, t0, dt, cat="timer")
 
     def add(self, name: str, seconds: float, calls: int = 1) -> None:
         """Merge externally-measured time (e.g. from a feed thread)."""

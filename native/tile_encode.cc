@@ -19,9 +19,22 @@
 // caller's, scratch included (buckets u32[rows*nnz], counts u32[cells],
 // offs i64[cells], cells = subblocks*tiles): nothing here is static or
 // thread-local, so concurrent callers share nothing.
+//
+// The overflow list's hot form (tilemm.py: hot_ranks / encode_hot are the
+// written specification, the same bits):
+//   int64 wh_hot_rank(ovf_b, ovf_r, n, subblocks, uniq, rank, cell_max)
+//                                                   -> distinct buckets
+//   int64 wh_hot_place(rank, ovf_r, n, subblocks, tiles, vtiles, cap,
+//                      counts, pw)                  -> pairs without room
+// Two calls because the room (tiles, vtiles) is the caller's to choose
+// from the count of distinct buckets and the fullest cell. wh_hot_rank's
+// hash table is the call's own (a vector); everything else is the
+// caller's.
 
+#include <algorithm>
 #include <cstdint>
 #include <cstring>
+#include <vector>
 
 namespace {
 
@@ -120,6 +133,98 @@ void wh_tile_place(const uint32_t* buckets, int64_t rows, int64_t nnz,
       }
     }
   }
+}
+
+// The list's distinct buckets in ascending order into uniq[0..d) (room
+// for n), every pair's index among them into rank[0..n), and into
+// *cell_max the most pairs any (subblock, hot tile) cell holds, a hot
+// tile being 16,384 consecutive ranks. Returns d. A list of a skewed
+// block names a few thousand buckets a million times, so the table is an
+// open-addressed one that starts small (it stays in cache) and doubles
+// when half full; ids are given in order of first sight and renumbered
+// once the distinct buckets are sorted.
+int64_t wh_hot_rank(const uint32_t* ovf_b, const uint32_t* ovf_r, int64_t n,
+                    int64_t subblocks, uint32_t* uniq, uint32_t* rank,
+                    int64_t* cell_max) {
+  size_t cap = 1u << 14;
+  std::vector<uint32_t> keys(cap, kSentinel), ids(cap);
+  int64_t d = 0;
+  for (int64_t i = 0; i < n; ++i) {
+    const uint32_t b = ovf_b[i];
+    size_t at = mix32(b) & (cap - 1);
+    while (keys[at] != kSentinel && keys[at] != b) at = (at + 1) & (cap - 1);
+    if (keys[at] == kSentinel) {
+      keys[at] = b;
+      ids[at] = static_cast<uint32_t>(d);
+      uniq[d++] = b;
+      if (static_cast<size_t>(d) * 2 > cap) {
+        cap *= 2;
+        keys.assign(cap, kSentinel);
+        ids.resize(cap);
+        for (int64_t j = 0; j < d; ++j) {
+          size_t to = mix32(uniq[j]) & (cap - 1);
+          while (keys[to] != kSentinel) to = (to + 1) & (cap - 1);
+          keys[to] = uniq[j];
+          ids[to] = static_cast<uint32_t>(j);
+        }
+        at = mix32(b) & (cap - 1);
+        while (keys[at] != b) at = (at + 1) & (cap - 1);
+      }
+    }
+    rank[i] = ids[at];
+  }
+  // first-sight id -> place in ascending order
+  std::vector<uint32_t> order(static_cast<size_t>(d)), place(
+      static_cast<size_t>(d));
+  for (int64_t j = 0; j < d; ++j) order[j] = static_cast<uint32_t>(j);
+  std::sort(order.begin(), order.end(),
+            [&](uint32_t a, uint32_t b) { return uniq[a] < uniq[b]; });
+  for (int64_t j = 0; j < d; ++j) place[order[j]] = static_cast<uint32_t>(j);
+  std::vector<uint32_t> sorted(static_cast<size_t>(d));
+  for (int64_t j = 0; j < d; ++j) sorted[j] = uniq[order[j]];
+  if (d) memcpy(uniq, sorted.data(), static_cast<size_t>(d) * sizeof(uint32_t));
+  const int64_t tiles = d ? (d + kTileMask) >> kTileShift : 1;
+  std::vector<int64_t> cells(static_cast<size_t>(subblocks * tiles), 0);
+  int64_t most = 0;
+  for (int64_t i = 0; i < n; ++i) {
+    const uint32_t r = place[rank[i]];
+    rank[i] = r;
+    const int64_t c = ++cells[(ovf_r[i] >> kRsubShift) * tiles +
+                              (r >> kTileShift)];
+    if (c > most) most = c;
+  }
+  *cell_max = most;
+  return d;
+}
+
+// Fill pw (tiles*vtiles, S, cap), flat, with PADWORD and place pair i,
+// the k-th of its (subblock s, hot tile h = rank >> 14) cell in list
+// order, at virtual tile h*vtiles + k/cap, subblock s, slot k%cap, as
+// tilemm.pack_fields(rank % TILE, row % RSUB). `counts` is scratch,
+// i64[subblocks*tiles]. The caller sized vtiles to the fullest cell
+// (wh_hot_rank's cell_max); a pair past it is dropped and counted, and
+// the count returned: anything but 0 is the caller's error.
+int64_t wh_hot_place(const uint32_t* rank, const uint32_t* ovf_r, int64_t n,
+                     int64_t subblocks, int64_t tiles, int64_t vtiles,
+                     uint32_t cap, int64_t* counts, uint32_t* pw) {
+  memset(counts, 0, static_cast<size_t>(subblocks * tiles) * sizeof(int64_t));
+  const int64_t words = tiles * vtiles * subblocks * cap;
+  for (int64_t i = 0; i < words; ++i) pw[i] = kPadWord;
+  int64_t lost = 0;
+  for (int64_t i = 0; i < n; ++i) {
+    const uint32_t r = ovf_r[i];
+    const int64_t sub = r >> kRsubShift;
+    const int64_t h = rank[i] >> kTileShift;
+    const int64_t k = counts[sub * tiles + h]++;
+    const int64_t vt = k / cap;
+    if (vt >= vtiles) {
+      ++lost;
+      continue;
+    }
+    pw[((h * vtiles + vt) * subblocks + sub) * cap + k % cap] =
+        (rank[i] & kTileMask) | ((r & kRsubMask) << 16);
+  }
+  return lost;
 }
 
 }  // extern "C"

@@ -117,6 +117,22 @@ def bwd_multi(spec, ch):
     return tilemm._build_bwd_multi(spec, ch), [_pw(spec), _rows(spec, ch)]
 
 
+def hot_gather(spec, vtiles):
+    """The overflow list through the hot tile, pull side: the gather of
+    one hot tile and the three-channel pull kernel over its hot form."""
+    hs = tilemm.hot_spec(vtiles, spec.subblocks)
+    return (lambda w, u, pw: tilemm.hot_margin_rows(w, u, pw, spec),
+            [((spec.nb,), jnp.float32), ((tilemm.TILE,), jnp.uint32),
+             (hs.pairs_shape, jnp.uint32)])
+
+
+def hot_scatter(spec, vtiles):
+    hs = tilemm.hot_spec(vtiles, spec.subblocks)
+    return (lambda g, d, u, pw: tilemm.hot_grad_scatter(g, d, u, pw, spec),
+            [((spec.nb,), jnp.float32), _rows(spec),
+             ((tilemm.TILE,), jnp.uint32), (hs.pairs_shape, jnp.uint32)])
+
+
 def fm_step(spec, k, spill=False):
     fn = tilemm._build_fm_step_fused(spec, k, "logit", spill)
     plane = ((spec.tiles, tilemm.A_HI, tilemm.B_LO), jnp.float32)
@@ -201,6 +217,11 @@ CASES = [
     # at 2**24, 33 channels pulled, 34 pushed, tiles_step 2), two tiles
     _case(lambda: fwd_multi(_wide_deep(2), 33), "fwd_multi-wd32-2tiles"),
     _case(lambda: bwd_multi(_wide_deep(2), 34), "bwd_multi-wd32-2tiles"),
+    # the skewed cells' overflow lists through the hot tile (PR 42): the
+    # kernels' per-step widths are the real ones (12 subblocks, 512 slots
+    # a cell, 3 channels, two tiles a step); 8 virtual tiles for 224-256
+    _case(lambda: hot_gather(_criteo(2), 8), "hot_gather-8vtiles"),
+    _case(lambda: hot_scatter(_criteo(2), 8), "hot_scatter-8vtiles"),
     # by hand before a chip call: full geometry, and the other variants
     _case(lambda: fwd(_criteo()), "fwd-criteo", slow=True),
     _case(lambda: bwd(_criteo()), "bwd-criteo", slow=True),

@@ -27,7 +27,9 @@ ALLOWLIST = {
         "matmul path is the default, this is the oracle",
     "wormhole_tpu/ops/tilemm.py":
         "COO overflow-bucket spill: O(overflow) elements, not O(nnz); "
-        "the hot tile path is already a one-hot matmul. On channel "
+        "the hot tile path is already a one-hot matmul. A long list of "
+        "few buckets comes through the MXU and leaves ONE scatter of "
+        "16,384 slots a hot tile (hot_grad_scatter). On channel "
         "planes: plane_spill_pull_rows (overflow pulls onto their rows) "
         "and spill_push_scatter_lanes (ONE scatter of O(overflow) lane "
         "rows into the tiled pushes, in place)",

@@ -613,9 +613,12 @@ class PackedFeed:
 
     def __init__(self, path: str, part: int = 0, nparts: int = 1,
                  depth: int = 3, device_put=None, fmt: str = "crec",
-                 cache: bool = False, workers: int = 0):
+                 cache: bool = False, workers: int = 0, hot=None):
         self.path, self.part, self.nparts = path, part, nparts
         self.fmt = fmt
+        # a HotRoom: crec2 blocks whose overflow list it takes also
+        # carry the list's hot form (the one-device train step's)
+        self.hot = hot if fmt == "crec2" else None
         self.depth = depth
         self.workers = workers
         self.read_time = 0.0
@@ -731,13 +734,26 @@ class PackedFeed:
         self._mapped = src.mapped
         return src
 
+    def _read(self, src: BlockSource, i: int):
+        """Block ``i`` as its reader hands it on: with the hot form of
+        its overflow list where this feed makes one (a new dict, the
+        file's views as they are beside it)."""
+        block, rows = src.read(i)
+        if self.hot is not None:
+            with trace.span("encode:hot", cat="feed"):
+                form = self.hot.form(block["ovf_b"], block["ovf_r"],
+                                     src.info.subblocks)
+            if form:
+                block = dict(block, **form)
+        return block, rows
+
     def _source_blocks(self, path: str, part: int, nparts: int):
         """The serial stream's blocks, in order, on the producer
         thread."""
         src = self._open_source()
         try:
             for i in src.part_range(part, nparts):
-                yield src.read(i)
+                yield self._read(src, i)
         finally:
             src.close()
 
@@ -749,7 +765,7 @@ class PackedFeed:
         source leaves the workers nothing to copy)."""
         src = self._open_source()
         return (iter(src.part_range(self.part, self.nparts)),
-                lambda i, _ctx: src.read(i), None, src.close)
+                lambda i, _ctx: self._read(src, i), None, src.close)
 
     def _stream_pipelined(self):
         """DeviceFeed-backed stream: parallel block reads/assembly, one
@@ -975,6 +991,130 @@ class OverflowRoom:
             return self.room
 
 
+# A list whose room is under this many slots keeps the COO helpers
+# whatever it names: two tiles' worth. The hot path's own floor is a
+# gather and a scatter of one hot tile (16,384 slots each) and a kernel
+# pair over eight virtual tiles, which a COO list pays for at about this
+# length (26 ns a gathered slot, PERF.md section 6, PR 42).
+HOT_MIN_ROOM = 32768
+# ... and one with fewer than this many pairs a distinct bucket: there is
+# little to share, the hot tiles would be a quarter of the list or more.
+HOT_MIN_SHARE = 4
+
+
+def hot_vtiles(cell_max: int) -> int:
+    """Virtual tiles a hot tile for cells of up to ``cell_max`` pairs:
+    :func:`overflow_room` of the count (an eighth more, coarsely
+    rounded, so that the blocks of one data set agree on one shape) in
+    cells of ``HOT_CAP`` slots, up to a multiple of eight (the kernels'
+    tiles a grid step)."""
+    from wormhole_tpu.ops.tilemm import HOT_CAP
+    return -(-overflow_room(cell_max) // (8 * HOT_CAP)) * 8
+
+
+class HotRoom:
+    """Which form the overflow list of a block takes on its way to the
+    one-device tile step, and the room of the hot form (ops/tilemm.py,
+    ``encode_hot``), chosen from what the feed's worker can see of the
+    list and from nothing else (no option):
+
+    * a list whose room (a static shape of the step program) is under
+      ``HOT_MIN_ROOM`` slots stays COO: ``"size"``;
+    * one with fewer than ``HOT_MIN_SHARE`` pairs a distinct bucket
+      stays COO: ``"distinct"``;
+    * every other list also gets the hot form, at a room of ``tiles``
+      hot tiles (the distinct buckets in whole tiles of 16,384) and
+      ``vtiles`` virtual tiles each (:func:`hot_vtiles` of the fullest
+      (subblock, hot tile) cell). Like :class:`OverflowRoom` the room
+      grows when a block passes it and never shrinks: a room is a shape,
+      and a shape is a compile of the spill step.
+
+    One object outlives the feeds of a job and is shared by their
+    workers under a lock; it counts what it chose (``drain``)."""
+
+    def __init__(self):
+        self.tiles = 1
+        self.vtiles = 8
+        self.slots = 0            # of ovf_pw, at the room in force
+        self._lock = threading.Lock()
+        self._counts = {"hot_blocks": 0, "coo_blocks": 0, "hot_buckets": 0}
+        self._said: set = set()
+
+    def _count(self, **add) -> None:
+        with self._lock:
+            for k, v in add.items():
+                self._counts[k] += v
+
+    def _stays_coo(self, why: str, detail: str) -> None:
+        self._count(coo_blocks=1)
+        with self._lock:
+            first = why not in self._said
+            self._said.add(why)
+        if first:
+            from wormhole_tpu.utils.logging import get_logger
+            get_logger("crec").info(
+                "an overflow list keeps the COO path (%s): %s", why, detail)
+
+    def fit(self, distinct: int, cell_max: int,
+            subblocks: int) -> Tuple[int, int]:
+        """The room ``(tiles, vtiles)`` for a list of ``distinct``
+        buckets whose fullest cell holds ``cell_max`` pairs, grown if
+        need be."""
+        from wormhole_tpu.ops.tilemm import HOT_CAP, TILE
+        with self._lock:
+            self.tiles = max(self.tiles, -(-distinct // TILE))
+            if cell_max > self.vtiles * HOT_CAP:
+                self.vtiles = hot_vtiles(cell_max)
+            self.slots = self.tiles * self.vtiles * subblocks * HOT_CAP
+            return self.tiles, self.vtiles
+
+    def form(self, ovf_b: np.ndarray, ovf_r: np.ndarray,
+             subblocks: int) -> Optional[dict]:
+        """``{"ovf_u", "ovf_pw"}`` for a block's overflow list as its
+        arrays stand (room-long, unused slots ``0xFFFFFFFF`` from the
+        first on), or None where the list is empty or stays COO. One
+        native pass where the process can load it
+        (native/tile_encode.cc), else the numpy specification: the same
+        bits."""
+        from wormhole_tpu.data import native
+        from wormhole_tpu.ops import tilemm
+        n = int(np.count_nonzero(ovf_b != tilemm.UNUSED))
+        if not n:
+            return None
+        room = len(ovf_b)
+        if room < HOT_MIN_ROOM:
+            return self._stays_coo(
+                "size", f"a room of {room} slots is under {HOT_MIN_ROOM}")
+        if (ovf_b[:n] == tilemm.UNUSED).any():
+            raise ValueError("an overflow list with a hole in it: unused "
+                             "slots must follow the pairs")
+        ovf_b, ovf_r = ovf_b[:n], ovf_r[:n]
+        if int(ovf_r.max()) >= subblocks * tilemm.RSUB:
+            raise ValueError(f"overflow row {int(ovf_r.max())} is outside "
+                             f"a block of {subblocks} subblocks")
+        ranks, place = native.get_hot_encoder() or (tilemm.hot_ranks,
+                                                    tilemm.encode_hot)
+        uniq, rank, cell_max = ranks(ovf_b, ovf_r, subblocks)
+        if len(uniq) * HOT_MIN_SHARE > n:
+            return self._stays_coo(
+                "distinct", f"{n} pairs name {len(uniq)} buckets, under "
+                f"{HOT_MIN_SHARE} pairs a bucket")
+        tiles, vtiles = self.fit(len(uniq), cell_max, subblocks)
+        ovf_u, ovf_pw = place(uniq, rank, ovf_r, subblocks, tiles, vtiles)
+        self._count(hot_blocks=1, hot_buckets=len(uniq))
+        return {"ovf_u": ovf_u, "ovf_pw": ovf_pw}
+
+    def drain(self) -> dict:
+        """What was chosen since the last call: blocks that took the hot
+        form, blocks with a list that stayed COO, distinct buckets listed
+        (summed over the hot blocks), and the slots of ``ovf_pw`` at the
+        room in force."""
+        with self._lock:
+            out = dict(self._counts, hot_room=self.slots)
+            self._counts = dict.fromkeys(self._counts, 0)
+        return out
+
+
 def online_info(nnz: int, src_rows: int, nb: int,
                 ovf_cap: int = ONLINE_OVF_CAP) -> CRec2Info:
     """Tile geometry for online-encoding a stream of ``src_rows``-row v1
@@ -1008,8 +1148,9 @@ class TileOnlineFeed:
     Pairs past the per-tile cap ride on the block's COO overflow list,
     as a crec2 file's do, at the width ``room`` has in force
     (:class:`OverflowRoom`: sized to what the encoder counted, grown when
-    a block passes it). Every block stays a tile block: there is no
-    other step for a skewed one to fall to. ``overflow_pairs``,
+    a block passes it), and where ``hot`` (:class:`HotRoom`) takes the
+    list, in its hot form beside it. Every block stays a tile block:
+    there is no other step for a skewed one to fall to. ``overflow_pairs``,
     ``overflow_slots`` (the widths of the lists that hold a pair), the
     room's ``grown`` and ``native_blocks`` (blocks the native encoder
     took: all of them or none, by what the process could load) are
@@ -1022,10 +1163,11 @@ class TileOnlineFeed:
 
     def __init__(self, inner, info: CRec2Info, *, workers: int = 2,
                  depth: int = 2, device_put=None, cache: bool = False,
-                 name: str = "tile-encode", room=None):
+                 name: str = "tile-encode", room=None, hot=None):
         self.inner = inner
         self.info = info
         self.room = room if room is not None else OverflowRoom()
+        self.hot = hot    # a HotRoom: lists it takes also ride hot
         self.workers = workers
         self.depth = depth
         self.name = name
@@ -1098,8 +1240,12 @@ class TileOnlineFeed:
             pw, ovb, ovr = encode_tile_pairs(kgrid, info.nb, info.spec)
         with trace.span("encode:list", cat="feed"):
             ob, orow = cap_overflow(ovb, ovr, self.room.fit(len(ovb)))
-        return ({"pw": pw, "labels": lab, "ovf_b": ob, "ovf_r": orow},
-                lab, rows, len(ovb), native.get_tile_encoder() is not None)
+        block = {"pw": pw, "labels": lab, "ovf_b": ob, "ovf_r": orow}
+        if self.hot is not None:
+            with trace.span("encode:hot", cat="feed"):
+                block.update(self.hot.form(ob, orow, info.subblocks) or {})
+        return (block, lab, rows, len(ovb),
+                native.get_tile_encoder() is not None)
 
     def _src(self, packed) -> int:
         if self._src_rows is None:

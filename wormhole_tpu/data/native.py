@@ -84,8 +84,9 @@ def _load():
         return None
     try:
         lib = ctypes.CDLL(path)
-        if not hasattr(lib, "wh_tile_count"):
-            # found, but built before the tile encoder existed
+        if not hasattr(lib, "wh_hot_rank"):
+            # found, but built before the tile encoder (or the overflow
+            # list's hot form) existed
             # (native/build/ is git-ignored and outlives a checkout's
             # update): without this the numpy encoder would run in
             # silence. Unload, `make` once, load what it left.
@@ -116,10 +117,10 @@ def _load():
             ctypes.c_int32,
             ctypes.POINTER(ctypes.c_uint32),  # keys (rows*nnz)
             ctypes.POINTER(ctypes.c_uint8)]   # labels (rows)
+    u32p = ctypes.POINTER(ctypes.c_uint32)
+    i64p = ctypes.POINTER(ctypes.c_int64)
     if hasattr(lib, "wh_tile_count"):
         # see native/tile_encode.cc for the ABI
-        u32p = ctypes.POINTER(ctypes.c_uint32)
-        i64p = ctypes.POINTER(ctypes.c_int64)
         lib.wh_tile_count.restype = ctypes.c_int64
         lib.wh_tile_count.argtypes = [
             u32p, ctypes.c_int64, ctypes.c_int64,     # keys, rows, nnz
@@ -132,11 +133,23 @@ def _load():
             ctypes.c_int64, ctypes.c_int64,           # subblocks, tiles
             ctypes.c_uint32, i64p, u32p,              # cap, offs, counts
             u32p, u32p, u32p]                         # pw, ovf_b, ovf_r
+    if hasattr(lib, "wh_hot_rank"):
+        lib.wh_hot_rank.restype = ctypes.c_int64
+        lib.wh_hot_rank.argtypes = [
+            u32p, u32p, ctypes.c_int64, ctypes.c_int64,  # ovf_b, ovf_r, n, S
+            u32p, u32p, i64p]                         # uniq, rank, cell_max
+        lib.wh_hot_place.restype = ctypes.c_int64
+        lib.wh_hot_place.argtypes = [
+            u32p, u32p, ctypes.c_int64, ctypes.c_int64,  # rank, ovf_r, n, S
+            ctypes.c_int64, ctypes.c_int64,           # tiles, vtiles
+            ctypes.c_uint32, i64p, u32p]              # cap, counts, pw
     else:
         from wormhole_tpu.utils.logging import get_logger
+        what = ("hot-form encoder" if hasattr(lib, "wh_tile_count")
+                else "tile encoder")
         get_logger("native").warning(
-            "%s has no tile encoder and could not be rebuilt; the numpy "
-            "tile encoder is live", path)
+            "%s has no %s and could not be rebuilt; the numpy %s is live",
+            path, what, what)
     _LIB = lib
     return _LIB
 
@@ -234,6 +247,57 @@ def get_tile_encoder():
     if lib is None or not hasattr(lib, "wh_tile_count"):
         return None
     return _tile_encode
+
+
+def _hot_ranks(ovf_b: np.ndarray, ovf_r: np.ndarray, subblocks: int):
+    """``ops/tilemm.hot_ranks`` in one native pass (wh_hot_rank)."""
+    ovf_b = np.ascontiguousarray(ovf_b, np.uint32)
+    ovf_r = np.ascontiguousarray(ovf_r, np.uint32)
+    n = len(ovf_b)
+    if len(ovf_r) != n:
+        raise ValueError(f"{n} buckets, {len(ovf_r)} rows")
+    uniq = np.empty(n, np.uint32)
+    rank = np.empty(n, np.uint32)
+    cell_max = ctypes.c_int64(0)
+    d = _LIB.wh_hot_rank(_u32p(ovf_b), _u32p(ovf_r), n, subblocks,
+                         _u32p(uniq), _u32p(rank), ctypes.byref(cell_max))
+    return uniq[:d].copy(), rank, int(cell_max.value)
+
+
+def _hot_place(uniq: np.ndarray, rank: np.ndarray, ovf_r: np.ndarray,
+               subblocks: int, tiles: int, vtiles: int):
+    """``ops/tilemm.encode_hot`` with the placement native
+    (wh_hot_place)."""
+    from wormhole_tpu.ops import tilemm
+    rank = np.ascontiguousarray(rank, np.uint32)
+    ovf_r = np.ascontiguousarray(ovf_r, np.uint32)
+    if len(uniq) > tiles * tilemm.TILE or len(rank) != len(ovf_r):
+        raise ValueError(f"{len(uniq)} buckets for {tiles} hot tiles, "
+                         f"{len(rank)} ranks for {len(ovf_r)} rows")
+    if len(rank) and (int(rank.max()) >= max(len(uniq), 1)
+                      or int(ovf_r.max()) >= subblocks * tilemm.RSUB):
+        raise ValueError("a rank or a row outside the hot form's room")
+    pw = np.empty(tilemm.hot_spec(tiles * vtiles, subblocks).pairs_shape,
+                  np.uint32)
+    counts = np.empty(subblocks * tiles, np.int64)
+    lost = _LIB.wh_hot_place(
+        _u32p(rank), _u32p(ovf_r), len(rank), subblocks, tiles, vtiles,
+        tilemm.HOT_CAP, counts.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),
+        _u32p(pw))
+    if lost:
+        raise ValueError(f"{lost} pairs past a room of {vtiles} x "
+                         f"{tilemm.HOT_CAP} a cell")
+    return tilemm.hot_buckets(uniq, tiles), pw
+
+
+def get_hot_encoder():
+    """The native ``(hot_ranks, encode_hot)`` pair of the overflow list's
+    hot form (ops/tilemm.py has the numpy pair, the specification), or
+    None when the library (or the symbols) is absent."""
+    lib = _load()
+    if lib is None or not hasattr(lib, "wh_hot_rank"):
+        return None
+    return _hot_ranks, _hot_place
 
 
 def get_crec_assembler(fmt: str, nnz: int):

@@ -130,8 +130,12 @@ class AsyncSGD:
                            "ring_max": 0}
         # the room of the online tile encoder's overflow lists: one for
         # the job, since every pass makes a new feed (data/crec.py)
-        from wormhole_tpu.data.crec import OverflowRoom
+        from wormhole_tpu.data.crec import HotRoom, OverflowRoom
         self._online_room = OverflowRoom()
+        # ... and, for a store whose one-device train step takes it, the
+        # rule and the room of the lists' hot form (data/crec.HotRoom)
+        self._hot_room = (HotRoom() if getattr(store, "hot_overflow", False)
+                          else None)
         # the one-device tile TRAIN passes' deferred metric accumulator
         # (learners/window.py): it survives parts; flush_metrics drains it
         self._crec_acc = MetricAccumulator()
@@ -510,7 +514,8 @@ class AsyncSGD:
         return mode == "on" or jax.default_backend() == "tpu"
 
     def _make_feed(self, file: str, part: int, nparts: int, fmt: str,
-                   device_put=None, cache: bool = False, tile_info=None):
+                   device_put=None, cache: bool = False, tile_info=None,
+                   hot=None):
         from wormhole_tpu.data.crec import (PackedFeed, TextCRecFeed,
                                             TileOnlineFeed)
         workers = self.cfg.pipeline_workers
@@ -527,11 +532,12 @@ class AsyncSGD:
                                     device_put=lambda x: x)
             return TileOnlineFeed(inner, tile_info, workers=workers,
                                   depth=depth, device_put=device_put,
-                                  cache=cache, room=self._online_room)
+                                  cache=cache, room=self._online_room,
+                                  hot=hot)
         if fmt in ("crec", "crec2"):
             return PackedFeed(file, part, nparts, fmt=fmt, cache=cache,
                               device_put=device_put, workers=workers,
-                              depth=depth)
+                              depth=depth, hot=hot)
         return TextCRecFeed(file, part, nparts, text_fmt=fmt,
                             nnz=self._text_nnz(),
                             block_rows=self.cfg.text_block_rows,
@@ -539,18 +545,20 @@ class AsyncSGD:
                             workers=workers, depth=depth)
 
     def _feed(self, file: str, part: int, nparts: int, fmt: str,
-              tile_info=None):
+              tile_info=None, hot=None):
         """Feed per (file, part), kept across data passes so cache_device
         replays HBM-resident blocks instead of re-streaming over the host
-        interconnect."""
+        interconnect. ``hot``: the HotRoom of a one-device train pass,
+        whose blocks carry their overflow lists' hot form."""
         if not self.cfg.cache_device:
             return self._make_feed(file, part, nparts, fmt,
-                                   tile_info=tile_info)
-        key = (file, part, nparts, fmt, tile_info is not None)
+                                   tile_info=tile_info, hot=hot)
+        key = (file, part, nparts, fmt, tile_info is not None,
+               hot is not None)
         feed = self._feeds.get(key) if hasattr(self, "_feeds") else None
         if feed is None:
             feed = self._make_feed(file, part, nparts, fmt, cache=True,
-                                   tile_info=tile_info)
+                                   tile_info=tile_info, hot=hot)
             if not hasattr(self, "_feeds"):
                 self._feeds = {}
             self._feeds[key] = feed
@@ -661,8 +669,11 @@ class AsyncSGD:
                                acc=self._crec_acc if acc_metrics else None)
             step, layout = self._crec_step(
                 kind, "tile" if tile else "dense", info)
+            # the train step takes a long list of few buckets through
+            # the hot tile; eval keeps the COO helpers
+            hot = self._hot_room if tile and kind == TRAIN else None
             feed = self._feed(file, part, nparts, fmt,
-                              tile_info=info if online else None)
+                              tile_info=info if online else None, hot=hot)
             put_before, copied_before = feed.put_time, feed.host_copy_bytes
             # snapshot BEFORE iterating: the feed flips _cache_full as
             # its stream exhausts, which is mid-way through THIS part
@@ -736,7 +747,22 @@ class AsyncSGD:
             self.timer.add(pfx + "host_copy_bytes",
                            feed.host_copy_bytes - copied_before)
             self._merge_pipe_snap(feed.drain_pipe_stats(None), pfx, local)
+            if hot is not None:
+                self._count_hot(hot.drain())
         return local
+
+    def _count_hot(self, chose: dict) -> None:
+        """What a train pass's blocks' overflow lists rode as
+        (data/crec.HotRoom.drain), into the timer and the registry:
+        counts, not seconds."""
+        for k in ("hot_blocks", "coo_blocks", "hot_buckets"):
+            self.timer.add("overflow_" + k, chose[k], 1)
+        hot_c, coo_c, buckets_c, room_g = obs.metrics.overflow_hot_metrics(
+            self.obs.registry)
+        hot_c.inc(chose["hot_blocks"])
+        coo_c.inc(chose["coo_blocks"])
+        buckets_c.inc(chose["hot_buckets"])
+        room_g.set(chose["hot_room"])
 
     def _crec_step(self, kind: str, form: str, info):
         """The step table of the crec passes: the store call for one

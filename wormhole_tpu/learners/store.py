@@ -156,6 +156,12 @@ def zero_grad_push_is_identity(handle: Handle) -> bool:
     return handle.penalty.lambda1 == 0.0 and handle.penalty.lambda2 == 0.0
 
 
+def _has_list(block: dict) -> bool:
+    """Does this tile block bring an overflow list, as COO pairs or in
+    its hot form (``put_block`` ships one of the two)?"""
+    return "ovf_b" in block or "ovf_pw" in block
+
+
 def _nudge_zero_dual(dual, labels, row_mask):
     """Replace exactly-zero duals of real rows with a signed 1e-30 so
     structural touch survives sigmoid saturation (see
@@ -402,10 +408,17 @@ class TableCheckpoint:
         ``device_put``). Where the table is planes, an overflow list
         with no pair in it stays behind: the block then takes the tile
         step that has no spill to scatter, which for FTRL and FM is
-        the in-place one. (A stacked table keeps its one step: its no-spill
+        the in-place one; a list that comes with its hot form
+        (``ovf_u``, ``ovf_pw``) crosses as that alone. (A stacked table
+        keeps its one step: its no-spill
         programs slice the planes out of ``(nb, slots)`` and compile for
         six to nine minutes at 2**28, PERF.md.)"""
-        if self._planar and isinstance(block, dict) and "ovf_b" in block:
+        if isinstance(block, dict) and "ovf_pw" in block:
+            # the list rides in its hot form (data/crec.HotRoom): the
+            # step reads that and nothing of the pairs themselves
+            block = {k: v for k, v in block.items()
+                     if k not in ("ovf_b", "ovf_r")}
+        elif self._planar and isinstance(block, dict) and "ovf_b" in block:
             ovf, unused = block["ovf_b"], np.uint32(0xFFFFFFFF)
             # writers fill the list from the front: one look settles
             # a list that has pairs, a scan only one that seems empty
@@ -526,6 +539,10 @@ class TableCheckpoint:
 
 class ShardedStore(TableCheckpoint):
     """Model state + the fused pull→forward→backward→push step."""
+
+    # this store's one-device tile steps take an overflow list in its hot
+    # form too (data/crec.HotRoom), so its app has the feeds make one
+    hot_overflow = True
 
     def __init__(self, cfg: StoreConfig, handle: Handle,
                  runtime: Optional[MeshRuntime] = None):
@@ -874,8 +891,9 @@ class ShardedStore(TableCheckpoint):
     def _tile_step(self, info, kind: str, spill: bool = True):
         """The jitted single-device tile step for a block geometry:
         ``step(table, block, t, tau, macc)`` (train) or ``step(table,
-        block)`` (eval). ``spill``: the block brings a COO overflow
-        list. Every variant computes on the float32 (T, A_HI, B_LO)
+        block)`` (eval). ``spill``: the block brings an overflow list,
+        as COO pairs or in its hot form (the jit has a program for
+        each). Every variant computes on the float32 (T, A_HI, B_LO)
         planes; a planar table IS those planes and is returned as such,
         a stacked one (bfloat16, on a mesh, a serving snapshot) is
         sliced into them and stacked again inside the step."""
@@ -921,9 +939,13 @@ class ShardedStore(TableCheckpoint):
             lab_u8 = block["labels"]
             row_mask = (lab_u8 != jnp.uint8(255)).astype(jnp.float32)
             labels = jnp.minimum(lab_u8, 1).astype(jnp.float32)
-            ovf_b = block["ovf_b"] if oc else None
-            ovf_r = block["ovf_r"] if oc else None
-            return block["pw"], labels, row_mask, ovf_b, ovf_r
+            # the overflow list in the form the block brings it: hot
+            # (ovf_u, ovf_pw: data/crec.HotRoom chose it) or COO. A form
+            # is a pytree structure, so each is a program of this jit
+            names = (("ovf_u", "ovf_pw") if "ovf_pw" in block
+                     else ("ovf_b", "ovf_r"))
+            lst = {k: block[k] for k in names} if oc else None
+            return block["pw"], labels, row_mask, lst
 
         def finish(new, wdelta2, margin, labels, row_mask, t, macc):
             # shared metric tail — identical ops downstream of the
@@ -948,14 +970,24 @@ class ShardedStore(TableCheckpoint):
         # under a bare named scope): tile_ovf_gather (the overflow
         # pairs' weights summed onto their rows), tile_ovf_scatter (the
         # pairs' duals added into the gradient), tile_table_update (the
-        # one elementwise pass over the planes and the gradient).
+        # one elementwise pass over the planes and the gradient). The
+        # first two take the list in either form: through the hot tile
+        # and the multi-channel kernel pair, or a slot a pair.
         @jax.jit
-        def tile_ovf_gather(w, ovf_b, ovf_r):
-            return tilemm.spill_margin_rows(w, ovf_b, ovf_r, spec)
+        def tile_ovf_gather(w, lst):
+            if "ovf_pw" in lst:
+                return tilemm.hot_margin_rows(w, lst["ovf_u"],
+                                              lst["ovf_pw"], spec)
+            return tilemm.spill_margin_rows(w, lst["ovf_b"], lst["ovf_r"],
+                                            spec)
 
         @jax.jit
-        def tile_ovf_scatter(grad, dual, ovf_b, ovf_r):
-            return tilemm.spill_grad_scatter(grad, dual, ovf_b, ovf_r, spec)
+        def tile_ovf_scatter(grad, dual, lst):
+            if "ovf_pw" in lst:
+                return tilemm.hot_grad_scatter(grad, dual, lst["ovf_u"],
+                                               lst["ovf_pw"], spec)
+            return tilemm.spill_grad_scatter(grad, dual, lst["ovf_b"],
+                                             lst["ovf_r"], spec)
 
         @jax.jit
         def tile_table_update(planes, grad, t, tau):
@@ -966,7 +998,7 @@ class ShardedStore(TableCheckpoint):
         if fused_update:
             @partial(jax.jit, donate_argnums=(0, 2, 4))
             def step(table, block, t, tau, macc):
-                pw, labels, row_mask, _ovf_b, _ovf_r = decode(block)
+                pw, labels, row_mask, _lst = decode(block)
                 margin, new, wdelta2 = tilemm.fused_step_update(
                     pw, planes_of(table), labels, row_mask, spec,
                     loss_name, handle, cache=cache)
@@ -979,14 +1011,14 @@ class ShardedStore(TableCheckpoint):
             # window instead of stacking per-step vectors
             @partial(jax.jit, donate_argnums=(0, 2, 4))
             def step(table, block, t, tau, macc):
-                pw, labels, row_mask, ovf_b, ovf_r = decode(block)
+                pw, labels, row_mask, lst = decode(block)
                 planes = planes_of(table)
                 w = handle.weights(tbl.PlaneTable(planes))
                 # the overflow pairs' margins, pre-aggregated onto
                 # their rows: ONE grid add on the split path, one extra
                 # operand of the fused kernel (summed into the
                 # phase-boundary dual), so the two stay bitwise-equal
-                sp = tile_ovf_gather(w, ovf_b, ovf_r) if oc else None
+                sp = tile_ovf_gather(w, lst) if oc else None
                 if not fused:
                     margin = tilemm.forward_margins(pw, w, spec)
                     if oc:
@@ -1009,17 +1041,18 @@ class ShardedStore(TableCheckpoint):
                             dual = _nudge_zero_dual(dual, labels,
                                                     row_mask)
                 if oc:
-                    grad = tile_ovf_scatter(grad, dual, ovf_b, ovf_r)
+                    grad = tile_ovf_scatter(grad, dual, lst)
                 new, wdelta2 = tile_table_update(planes, grad, t, tau)
                 return finish(table_of(new, table), wdelta2, margin,
                               labels, row_mask, t, macc)
         else:
             @jax.jit
             def step(table, block):
-                pw, labels, row_mask, ovf_b, ovf_r = decode(block)
+                pw, labels, row_mask, lst = decode(block)
                 w = handle.weights(tbl.PlaneTable(planes_of(table)))
-                margin = tilemm.forward_margins(pw, w, spec,
-                                                ovf_b, ovf_r)
+                margin = tilemm.forward_margins(pw, w, spec)
+                if oc:
+                    margin = margin + tile_ovf_gather(w, lst)
                 objv = objv_fn(margin, labels, row_mask)
                 num_ex = jnp.sum(row_mask)
                 acc = accuracy(labels, margin, row_mask)
@@ -1191,7 +1224,7 @@ class ShardedStore(TableCheckpoint):
         only so callers can gate the staleness window on real completion
         — the clock itself is donated into the next step, so it is NOT
         safe to block on."""
-        step = self._tile_step(info, "train", "ovf_b" in block)
+        step = self._tile_step(info, "train", _has_list(block))
         if self.step_kernel[0].startswith("fused"):
             from wormhole_tpu.obs import trace
             if self.step_kernel[2] == "onehot_cache=on":
@@ -1212,7 +1245,7 @@ class ShardedStore(TableCheckpoint):
         return ticket
 
     def tile_eval_step(self, block: dict, info):
-        return self._tile_step(info, "eval", "ovf_b" in block)(
+        return self._tile_step(info, "eval", _has_list(block))(
             self._tile_table(), block)
 
     # -- split pull/push pipeline (delay-tolerant DT2 path) -----------------

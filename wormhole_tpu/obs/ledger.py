@@ -144,11 +144,14 @@ SPAN_TABLE: Dict[str, str] = {
     "pass:drain": "metrics_readback",
     "pass:close": "other",
     "pass:flush": "metrics_readback",
-    # the online tile encoder's three steps on a prep worker, inside
-    # its <feed>:encode stage (data/crec.TileOnlineFeed._encode)
+    # the online tile encoder's steps on a prep worker, inside its
+    # <feed>:encode stage (data/crec.TileOnlineFeed._encode); encode:hot
+    # (the overflow list's hot form, data/crec.HotRoom.form) also inside
+    # a crec2 feed's prep stage (PackedFeed._read)
     "encode:unpack": "encode",
     "encode:tile": "encode",
     "encode:list": "encode",
+    "encode:hot": "encode",
     # DeviceFeed's collate stage on the transfer thread (a text feed's
     # re-blocking) and the feed's on_close on the consumer's thread (a
     # mapped source unmaps there)

@@ -489,6 +489,26 @@ def online_overflow_metrics(reg: Optional[Registry] = None):
                              "room and the room grew"))
 
 
+def overflow_hot_metrics(reg: Optional[Registry] = None):
+    """Which form the blocks' overflow lists took on their way to the
+    one-device train step (data/crec.HotRoom) — single declaration site,
+    fetched per call like :func:`encode_counters`."""
+    reg = reg if reg is not None else default_registry()
+    return (reg.counter("feed/overflow_hot_blocks",
+                        help="blocks whose overflow list rode in its hot "
+                             "form (distinct buckets + packed pair words)"),
+            reg.counter("feed/overflow_coo_blocks",
+                        help="blocks with an overflow list that kept the "
+                             "COO path (short, or its buckets mostly "
+                             "distinct; the reason is logged once)"),
+            reg.counter("feed/overflow_hot_buckets",
+                        help="distinct buckets the hot lists named, "
+                             "summed over their blocks"),
+            reg.gauge("feed/overflow_hot_room",
+                      help="slots of a hot list's pair words at the room "
+                           "in force", agg="max"))
+
+
 def mesh_feed_gauges(reg: Optional[Registry] = None):
     """The sharded mesh-feed (data/crec.MeshGroupFeed) telemetry —
     single declaration site (lint_knobs uniqueness contract), fetched

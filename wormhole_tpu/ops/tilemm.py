@@ -43,9 +43,19 @@ guards both directions (fwd: m row = 0 kills the value chain; bwd: the
 ohhiT column = 0 kills the contribution). No masks needed.
 
 Skewed data (a bucket hit by more than `cap` pairs of one subblock, e.g.
-a criteo missing-value token) overflows to a small (bucket, row) COO list
-handled by the classic scatter path — exact, and empty for hashed
-uniform-ish data.
+a criteo missing-value token) overflows to a (bucket, row) COO list —
+exact in float32, and empty for hashed uniform-ish data. A SHORT list,
+or one whose buckets are mostly distinct, is handled by the classic
+gather/scatter path (the COO spill helpers below). A long list of a
+skewed block names few buckets many times (1.3M pairs, 5,600 buckets at
+the criteo geometry), so the feed also ships it in a second form
+(``encode_hot``): the distinct buckets, and the pairs as packed words
+over their RANK in that list. The step then gathers the few thousand
+weights once into a hot tile and runs the pairs through the
+multi-channel kernels below over three bfloat16 channels whose sum is
+the float32 value (``split3``): the same one-hot matmuls, exact, with a
+16K-slot gather and scatter where the COO path has one a pair (the hot
+tile helpers). Which form a block takes is data/crec.HotRoom's rule.
 
 Off the TPU backend the kernels run in Pallas interpret mode, which is
 how the CPU tests drive them; the first such build says so on the log.
@@ -266,6 +276,92 @@ def cap_overflow(ovb: np.ndarray, ovr: np.ndarray,
     ob[:keep] = ovb[:keep]
     orow[:keep] = ovr[:keep]
     return ob, orow
+
+
+# -- the overflow list's hot form (host, numpy: the written specification) ---
+
+HOT_CAP = 512       # C': slots of a (subblock, virtual tile) cell
+HOT_CH = 3          # bfloat16 channels a float32 value splits into
+UNUSED = np.uint32(0xFFFFFFFF)   # an unused slot of ovf_b / ovf_u
+
+
+def hot_spec(tiles: int, subblocks: int) -> TileSpec:
+    """The TileSpec of a hot form of ``tiles`` virtual tiles: the block's
+    own subblocks and group, ``HOT_CAP`` slots a cell, two tiles a grid
+    step (some 12K slots of work a step at twelve subblocks, and a
+    quarter of the unrolled body that eight would lower and compile on
+    every start)."""
+    group = max(g for g in (4, 2, 1) if subblocks % g == 0)
+    return TileSpec(nb=tiles * TILE, subblocks=subblocks, cap=HOT_CAP,
+                    group=group, tiles_step=2 if tiles % 2 == 0 else 1)
+
+
+def hot_ranks(ovf_b: np.ndarray, ovf_r: np.ndarray, subblocks: int
+              ) -> Tuple[np.ndarray, np.ndarray, int]:
+    """``(uniq, rank, cell_max)`` of a list of valid pairs: its distinct
+    buckets in ascending order, each pair's index in them, and the most
+    pairs a (subblock, hot tile) cell holds, a hot tile being ``TILE``
+    consecutive ranks."""
+    uniq, rank = np.unique(ovf_b, return_inverse=True)
+    rank = rank.reshape(-1).astype(np.uint32)
+    tiles = max(-(-len(uniq) // TILE), 1)
+    cell = (ovf_r // RSUB).astype(np.int64) * tiles + (rank >> 14)
+    cells = np.bincount(cell, minlength=subblocks * tiles)
+    return uniq.astype(np.uint32), rank, int(cells.max(initial=0))
+
+
+def hot_buckets(uniq: np.ndarray, tiles: int) -> np.ndarray:
+    """``ovf_u``: the distinct buckets padded with ``UNUSED`` to ``tiles``
+    whole hot tiles."""
+    ovf_u = np.full(tiles * TILE, UNUSED, np.uint32)
+    ovf_u[:len(uniq)] = uniq
+    return ovf_u
+
+
+def encode_hot(uniq: np.ndarray, rank: np.ndarray, ovf_r: np.ndarray,
+               subblocks: int, tiles: int, vtiles: int
+               ) -> Tuple[np.ndarray, np.ndarray]:
+    """The hot form ``(ovf_u, ovf_pw)`` of an overflow list whose pairs
+    are ``(uniq[rank[i]], ovf_r[i])``, at a room of ``tiles`` hot tiles
+    and ``vtiles`` virtual tiles each (data/crec.HotRoom sizes both).
+
+    ``ovf_u`` is ``hot_buckets(uniq, tiles)``. ``ovf_pw`` is the kernels' pair-word array ``(tiles * vtiles, S//GS,
+    GS * HOT_CAP)`` over ``hot_spec``: a pair's bucket digits are its
+    rank in its hot tile (``rank % TILE``), its row digits the row's in
+    its subblock, and its place is dealt in list order: pair ``k`` of
+    the (subblock ``s``, hot tile ``h``) cell goes to virtual tile
+    ``h * vtiles + k // HOT_CAP``, slot ``k % HOT_CAP`` of subblock
+    ``s``. Virtual tiles ``h * vtiles ..`` all alias hot tile ``h``, so
+    the form is packed to the room, not to the table's tiles."""
+    spec = hot_spec(tiles * vtiles, subblocks)
+    pw = np.full((tiles * vtiles, subblocks, HOT_CAP), PADWORD, np.uint32)
+    if len(rank):
+        sub = (ovf_r // RSUB).astype(np.int64)
+        cell = sub * tiles + (rank >> 14)
+        order = np.argsort(cell, kind="stable")
+        starts = np.zeros(subblocks * tiles + 1, np.int64)
+        np.cumsum(np.bincount(cell, minlength=subblocks * tiles),
+                  out=starts[1:])
+        k = np.empty(len(rank), np.int64)
+        k[order] = np.arange(len(rank)) - starts[cell[order]]
+        if k.max() >= vtiles * HOT_CAP:
+            raise ValueError(f"a cell holds {k.max() + 1} pairs, the "
+                             f"room {vtiles} x {HOT_CAP}")
+        vt = (rank >> 14).astype(np.int64) * vtiles + k // HOT_CAP
+        pw[vt, sub, k % HOT_CAP] = pack_fields(rank & 16383, ovf_r % RSUB)
+    return hot_buckets(uniq, tiles), pw.reshape(spec.pairs_shape)
+
+
+def decode_hot(ovf_u: np.ndarray, ovf_pw: np.ndarray, subblocks: int
+               ) -> Tuple[np.ndarray, np.ndarray]:
+    """``(ovf_b, ovf_r)`` of a hot form's pairs, in the form's order."""
+    tiles = len(ovf_u) // TILE
+    vtiles = ovf_pw.shape[0] // tiles
+    pw = ovf_pw.reshape(tiles * vtiles, subblocks, -1)
+    b, r, pad = unpack_fields(pw)
+    vt, sub, _slot = np.nonzero(~pad)
+    rank = (vt // vtiles) * TILE + b[~pad]
+    return ovf_u[rank], (sub * RSUB + r[~pad]).astype(np.uint32)
 
 
 # ---------------------------------------------------------------------------
@@ -809,6 +905,11 @@ def _multi_spec(spec: TileSpec, ch: int) -> TileSpec:
     import dataclasses
     tb = max((t for t in (16, 8, 4, 2)
               if spec.tiles % t == 0 and t * (ch + 6) <= 128), default=1)
+    # a spec made for fewer tiles a step keeps them (make_spec never
+    # is: its own tiles_step is the largest divisor, so this changes
+    # none of its callers): the hot tile's pair, whose lowering time is
+    # set-up on every start, asks for two
+    tb = min(tb, spec.tiles_step)
     # fuse=1: the multi-channel kernels keep per-tile chains (their
     # channel batching already amortizes the per-chain fixed cost)
     return dataclasses.replace(spec, tiles_step=tb, fuse=1)
@@ -891,6 +992,12 @@ def _build_bwd_multi(spec: TileSpec, ch: int, tiled: bool = False):
 
 # -- COO spill helpers -------------------------------------------------------
 #
+# The list as (bucket, row) pairs, a gather and a scatter slot a pair:
+# what a short list takes, and one whose buckets are mostly distinct
+# (data/crec.HotRoom's rule), every list in eval, on a mesh, over a
+# stacked multi-channel table and in FM's and wide&deep's steps. A long
+# list of few buckets comes through the hot tile helpers further down.
+#
 # One shared aggregation for both step formulations: the spill pairs are
 # pre-aggregated into a zero row grid, and the kernel margins/pulls get
 # ONE elementwise add of that grid — in XLA on the split path, at the
@@ -946,6 +1053,77 @@ def spill_push_scatter(g: jax.Array, dual_rows: jax.Array,
                   dual_rows[ovf_r.astype(jnp.int32) % spec.block_rows],
                   0.0)
     return g.at[jnp.where(valid, ovf_b, 0).astype(jnp.int32)].add(d)
+
+
+# -- the hot tile helpers ----------------------------------------------------
+#
+# The same two sums as spill_margin_rows / spill_grad_scatter, from the
+# list's hot form (encode_hot): the distinct buckets' values are gathered
+# ONCE (16K slots a hot tile, where the COO helpers gather and scatter a
+# slot a pair), and the pairs run through the multi-channel kernel pair
+# above. The stated precision holds by construction: a float32 value is
+# split into three float32 parts that are each a bfloat16 value (split3),
+# so every cast to bfloat16 inside those kernels is the identity, every
+# one-hot matmul picks exactly one value, and the only sums are the row
+# histogram's and the bucket histogram's, in the MXU's float32
+# accumulator. Where a row (a bucket) has one listed pair the result is
+# the COO helpers' to the bit; where it has many, to the order of the
+# float32 additions.
+
+def split3(x: jax.Array) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """``(hi, mid, lo)`` float32 with ``(hi + mid) + lo == x`` to the bit
+    and each part a bfloat16 value: 8 + 8 + 8 of a float32's 24
+    significant bits, cut by masking (a cast that rounds would take the
+    largest normals to infinity, and XLA may drop a cast pair). Exact for
+    every finite ``x`` whose last bit is a normal float32 (``|x| >=
+    2**-103``, and 0, ``-0.0`` coming back as ``+0.0``); below that the
+    chip flushes the subnormal parts to zero and the sum is off by less
+    than ``2**-126``."""
+    top = jnp.uint32(0xFFFF0000)
+    u32, f32 = jnp.uint32, jnp.float32
+    bits = jax.lax.bitcast_convert_type
+    hi = bits(bits(x, u32) & top, f32)
+    r = x - hi
+    mid = bits(bits(r, u32) & top, f32)
+    return hi, mid, r - mid
+
+
+def _hot_dims(ovf_u: jax.Array, ovf_pw: jax.Array, spec: TileSpec):
+    """``(tiles, vtiles, hot spec, valid, index)`` of a hot form."""
+    tiles = ovf_u.shape[0] // TILE
+    valid = ovf_u != UNUSED
+    return (tiles, ovf_pw.shape[0] // tiles,
+            hot_spec(ovf_pw.shape[0], spec.subblocks), valid,
+            jnp.where(valid, ovf_u, 0).astype(jnp.int32))
+
+
+def hot_margin_rows(w: jax.Array, ovf_u: jax.Array, ovf_pw: jax.Array,
+                    spec: TileSpec) -> jax.Array:
+    """spill_margin_rows from the list's hot form: ``w[ovf_u]`` is the
+    hot tile, its three channels the kernel's operand, every virtual
+    tile a copy of its hot tile."""
+    tiles, vtiles, hs, valid, idx = _hot_dims(ovf_u, ovf_pw, spec)
+    wu = jnp.where(valid, w[idx], 0.0).reshape(tiles, A_HI, B_LO)
+    wt = jnp.repeat(jnp.concatenate(split3(wu), axis=-1)
+                    .astype(jnp.bfloat16), vtiles, axis=0)
+    p = _build_fwd_multi(hs, HOT_CH, True)(ovf_pw, wt)
+    return (p[:, 0] + p[:, 1]) + p[:, 2]
+
+
+def hot_grad_scatter(g: jax.Array, dual_rows: jax.Array, ovf_u: jax.Array,
+                     ovf_pw: jax.Array, spec: TileSpec) -> jax.Array:
+    """spill_grad_scatter from the list's hot form: the pairs' duals
+    summed a (virtual tile, channel) by the push kernel, those summed to
+    the hot tile, and the hot tile added at its buckets."""
+    tiles, vtiles, hs, valid, idx = _hot_dims(ovf_u, ovf_pw, spec)
+    push = _build_bwd_multi(hs, HOT_CH, True)(
+        ovf_pw, jnp.stack(split3(dual_rows), axis=1))
+    c = push.reshape(tiles, vtiles, A_HI, HOT_CH, B_LO)
+    gu = ((c[..., 0, :] + c[..., 1, :]) + c[..., 2, :]).sum(axis=1)
+    # scatter-fallback: ONE scatter of tiles * TILE slots (16,384 a hot
+    # tile) where the COO helper scatters a slot a pair; unused slots
+    # add 0 at bucket 0
+    return g.at[idx].add(jnp.where(valid, gu.reshape(-1), 0.0))
 
 
 def forward_pulls(pw: jax.Array, w: jax.Array, spec: TileSpec,

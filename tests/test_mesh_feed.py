@@ -319,3 +319,70 @@ def test_pad_block_built_only_for_a_short_tail(tmp_path, rng, blocks):
     assert sum(g[2] for g in feed) == n
     assert ("_pads" in vars(feed)) == (blocks % 2 == 1)
     assert feed.skew_snapshot()["pad_blocks"] == blocks % 2
+
+
+def test_text_groups_with_lists_ring_matches_inline(tmp_path, rng,
+                                                    monkeypatch):
+    """MeshGroupFeed over TileOnlineFeed over TextCRecFeed on a
+    data:2,model:2 mesh, from Criteo text with hot values (every block
+    brings an overflow list) and empty columns, two passes over five
+    groups: the pipelined ring (pipeline_workers=2) trains the table of
+    the inline oracle (workers=0), bit for bit; the encoder's recycled
+    slabs come round again while earlier groups' per-chip slices are
+    still in flight; the inner feeds' counters reach the part's Timer
+    from the mesh pass beside the mesh feed's own; and the stack workers'
+    widening is a span."""
+    from wormhole_tpu.data import crec, native
+    from wormhole_tpu.obs import trace
+    n, rows = 10 * BR, BR
+    path = tmp_path / "log.criteo"
+    hot = rng.random((n, 26)) < 0.5
+    with open(path, "w") as f:
+        for i in range(n):
+            ints = [str(rng.integers(0, 100)) for _ in range(13)]
+            ints[3] = "" if i % 3 == 0 else ints[3]      # an empty column
+            cats = ["7f" if hot[i, j] else f"{rng.integers(0, 1 << 32):x}"
+                    for j in range(26)]
+            f.write("\t".join([str(i % 2)] + ints + cats) + "\n")
+    seen = []
+    real = native._PW_POOL.empty
+
+    def recording(shape, dtype):
+        arr = real(shape, dtype)
+        seen.append(arr.ctypes.data)
+        return arr
+    monkeypatch.setattr(native._PW_POOL, "empty", recording)
+
+    def train(workers):
+        app = make_app(path, "data:2,model:2", fmt="criteo",
+                       tile_online="on", pipeline_workers=workers,
+                       text_block_rows=rows, max_data_pass=2)
+        assert app.run().num_ex == 2 * n, workers
+        return app
+
+    trace.configure(enabled=True)
+    try:
+        ring = train(2)
+        spans = {e["name"] for e in trace.events() if e["ph"] == "X"}
+    finally:
+        trace.configure(enabled=False)
+    inline = train(0)
+    assert np.array_equal(np.asarray(ring.store.slots),
+                          np.asarray(inline.store.slots))
+    assert "meshfeed:widen" in spans and "mesh:dispatch" in spans
+    if native.get_tile_encoder() is not None:
+        # twenty blocks a run came out of fewer mappings than blocks
+        assert len(seen) == 40 and len(set(seen)) < 20
+    t = ring.timer.totals
+    width = ring._online_room.room
+    assert width > crec.ONLINE_OVF_CAP          # every block has a list
+    for key in ("read", "encode", "text_read", "collate", "stack", "put"):
+        assert t.get(key, 0.0) > 0.0, key
+    assert "encode_stall" in t and t["mesh_steps"] == 10
+    assert t["online_overflow_pairs"] > 20 * crec.ONLINE_OVF_CAP
+    # ten groups of two lists each; the room settles within the first
+    # blocks, so all but the first groups cross at the settled width
+    assert t["mesh_overflow_slots"] <= 10 * 2 * width
+    assert t["mesh_overflow_slots"] >= 8 * 2 * width
+    assert t["mesh_widened_groups"] == inline.timer.totals[
+        "mesh_widened_groups"] <= 2

@@ -93,13 +93,63 @@ def test_pad_pairs_are_noops():
     assert np.all(np.asarray(tilemm.backward_grad(pw, dual, SPEC)) == 0)
 
 
+def _listed_block(rng, spec, listed, nb_local):
+    """One block's pairs for the mesh oracle test, split into what the
+    tile kernels take and what rides the COO overflow list. ``listed``:
+    "empty" (no list), "short" (a few dozen pairs) or "third" (a third of
+    the block's pairs). A list always holds pairs on both sides of the
+    MODEL shard boundary and on its first and last bucket: for each of
+    the four edge buckets one reserved row whose ONLY pair is that listed
+    pair (its margin is the bucket's weight, unrounded), and five more
+    listed pairs from rows of one label, so that the bucket's weight
+    leaves zero at the first step. Returns (pw, ovf_b, ovf_r, buckets,
+    rows, labels, edges) with ``edges`` the (bucket, reserved row)s."""
+    R = spec.block_rows
+    buckets, rows = make_pairs(rng, 3000, spec)
+    labels = (rng.random(R) < 0.4).astype(np.uint8)
+    if listed == "empty":
+        pw, ovb, _ = tilemm.encode_block(buckets, rows, spec)
+        assert not len(ovb)
+        return pw, None, None, buckets, rows, labels, []
+    edge_b = np.array([0, nb_local - 1, nb_local, spec.nb - 1], np.int64)
+    reserved = np.arange(4, dtype=np.int64) * 17 + 5
+    keep = ~np.isin(buckets, edge_b) & ~np.isin(rows, reserved)
+    buckets, rows = buckets[keep], rows[keep]
+    on_list = (rng.random(len(buckets)) < 1 / 3 if listed == "third"
+               else np.arange(len(buckets)) < 16)
+    lb, lr = [buckets[on_list]], [rows[on_list]]
+    lb.append(edge_b)
+    lr.append(reserved)
+    ones = np.setdiff1d(np.flatnonzero(labels == 1), reserved)
+    for b in edge_b:                     # five rows of one label a bucket
+        lb.append(np.full(5, b, np.int64))
+        lr.append(rng.choice(ones, size=5, replace=False).astype(np.int64))
+    lb, lr = np.concatenate(lb), np.concatenate(lr)
+    order = rng.permutation(len(lb))     # the edges anywhere in the list
+    lb, lr = lb[order], lr[order]
+    pw, ovb, _ = tilemm.encode_block(buckets[~on_list], rows[~on_list],
+                                     spec)
+    assert not len(ovb)
+    return (pw, lb, lr, np.concatenate([buckets[~on_list], lb]),
+            np.concatenate([rows[~on_list], lr]), labels,
+            list(zip(edge_b.tolist(), reserved.tolist())))
+
+
+@pytest.mark.parametrize("listed", ["empty", "short", "third"])
 @pytest.mark.parametrize("algo", ["ftrl", "adagrad_l1"])
-def test_mesh_tile_step_matches_oracle(algo):
+def test_mesh_tile_step_matches_oracle(algo, listed):
     """The shard_map tile step on a data:2,model:2 mesh computes the same
     margins/gradient/update as the exact scatter oracle: model shards own
     tile ranges, data shards own blocks, gradients sum across data.
     The adagrad_l1 case compiles and checks the masked (touched-bucket)
-    mesh branch: zero-psum'd-grad buckets must keep their exact slots."""
+    mesh branch: zero-psum'd-grad buckets must keep their exact slots.
+    With a COO overflow list (``short``, ``third``) every chip is handed
+    its DATA member's whole list and takes the pairs whose bucket its
+    MODEL shard owns: two steps, so that the listed pairs' weights are
+    gathered from a table that has left zero; after the first the
+    gradient is exact (every dual is +-0.5), and after it a row whose one
+    pair is a listed pair on the shard boundary reads that bucket's
+    weight to the last bit: taken by one shard, once, unrounded."""
     import jax
     import jax.numpy as jnp
     from wormhole_tpu.data.crec import CRec2Info
@@ -113,9 +163,12 @@ def test_mesh_tile_step_matches_oracle(algo):
     rng = np.random.default_rng(5)
     nb = 2 * tilemm.TILE            # one tile per model shard
     spec = tilemm.make_spec(nb, subblocks=2, cap=1280)
+    raw = [_listed_block(rng, spec, listed, nb // 2) for _ in range(2)]
+    width = 0 if listed == "empty" else 64 * -(-max(
+        len(b[1]) for b in raw) // 64)
     info = CRec2Info(nnz=8, block_rows=spec.block_rows,
                      total_rows=2 * spec.block_rows, nb=nb,
-                     subblocks=2, cap=spec.cap, ovf_cap=0)
+                     subblocks=2, cap=spec.cap, ovf_cap=width)
     rt = MeshRuntime.create()
     rt.mesh = make_mesh("data:2,model:2", jax.devices()[:4])
     if algo == "ftrl":
@@ -126,43 +179,64 @@ def test_mesh_tile_step_matches_oracle(algo):
     store = ShardedStore(StoreConfig(num_buckets=nb, loss="logit"),
                          handle, rt)
 
-    blocks = {"pw": [], "labels": []}
-    raw = []
-    for _ in range(2):
-        buckets, rows = make_pairs(rng, 3000, spec)
-        pw, ovb, _ = tilemm.encode_block(buckets, rows, spec)
-        assert not len(ovb)
-        labels = (rng.random(spec.block_rows) < 0.4).astype(np.uint8)
-        blocks["pw"].append(pw)
-        blocks["labels"].append(labels)
-        raw.append((buckets, rows, labels))
-    blocks = {k: np.stack(v) for k, v in blocks.items()}
+    blocks = {"pw": np.stack([b[0] for b in raw]),
+              "labels": np.stack([b[5] for b in raw])}
+    if width:
+        lists = [tilemm.cap_overflow(b[1].astype(np.uint32),
+                                     b[2].astype(np.uint32), width)
+                 for b in raw]
+        blocks["ovf_b"] = np.stack([ob for ob, _ in lists])
+        blocks["ovf_r"] = np.stack([orow for _, orow in lists])
 
-    slots0 = np.asarray(store.slots)
-    store.tile_train_step_mesh(blocks, info)
-    got = np.asarray(jax.device_get(store.slots))
+    want = np.asarray(store.slots)
+    mask = np.ones(spec.block_rows, np.float32)
+    for step in range(2 if width else 1):
+        slots0 = want
+        store.tile_train_step_mesh(blocks, info)
+        got = np.asarray(jax.device_get(store.slots))
 
-    # oracle: per-block margins/duals on pre-step weights; gradient sums
-    w0 = np.asarray(handle.weights(jnp.asarray(slots0)))
-    g_tot = np.zeros(nb, np.float64)
-    for buckets, rows, labels in raw:
-        mg = tilemm.forward_margins_ref(buckets, rows, w0, spec.block_rows)
-        mask = np.ones(spec.block_rows, np.float32)
-        dual = np.asarray(logit_dual(jnp.asarray(mg),
-                                     jnp.asarray(labels.astype(np.float32)),
-                                     jnp.asarray(mask)))
-        g_tot += tilemm.backward_grad_ref(buckets, rows, dual, nb)
-    want = np.asarray(handle.push(jnp.asarray(slots0),
-                                  jnp.asarray(g_tot.astype(np.float32)),
-                                  jnp.float32(1), jnp.float32(0)))
-    if algo != "ftrl":
-        want = np.where((g_tot != 0.0)[:, None], want, slots0)
-        # the masked branch really froze untouched buckets
-        untouched = g_tot == 0.0
-        assert untouched.any()
-        np.testing.assert_array_equal(got[untouched], slots0[untouched])
-    err = np.max(np.abs(got - want)) / (np.abs(want).max() + 1e-9)
-    assert err < 2e-2, err
+        # oracle: per-block margins/duals on pre-step weights; gradient
+        # sums
+        w0 = np.asarray(handle.weights(jnp.asarray(slots0)))
+        g_tot = np.zeros(nb, np.float64)
+        for _pw, _lb, _lr, buckets, rows, labels, _edges in raw:
+            mg = tilemm.forward_margins_ref(buckets, rows, w0,
+                                            spec.block_rows)
+            dual = np.asarray(logit_dual(
+                jnp.asarray(mg), jnp.asarray(labels.astype(np.float32)),
+                jnp.asarray(mask)))
+            g_tot += tilemm.backward_grad_ref(buckets, rows, dual, nb)
+        want = np.asarray(handle.push(jnp.asarray(slots0),
+                                      jnp.asarray(g_tot.astype(np.float32)),
+                                      jnp.float32(step + 1), jnp.float32(0)))
+        if algo != "ftrl":
+            want = np.where((g_tot != 0.0)[:, None], want, slots0)
+            # the masked branch really froze the buckets no pair names
+            # (a touched bucket's float32 gradient may cancel to zero in
+            # float64 and not on the chip, or the other way round)
+            untouched = np.bincount(np.concatenate([b[3] for b in raw]),
+                                    minlength=nb) == 0
+            assert untouched.any()
+            np.testing.assert_array_equal(got[untouched], slots0[untouched])
+        err = np.max(np.abs(got - want)) / (np.abs(want).max() + 1e-9)
+        assert err < 2e-2, (step, err)
+        if step == 0 and algo == "ftrl":
+            # from zero weights every dual is +-0.5: FTRL's z is the
+            # gradient, exact in float32 on both sides, listed pairs and
+            # all; a pair dropped or taken twice is off by 0.5
+            np.testing.assert_array_equal(got[:, 1],
+                                          g_tot.astype(np.float32))
+    if not width:
+        return
+    # the listed pairs at the shard boundary, through the eval step: the
+    # reserved rows' margins are their one bucket's weight, bit for bit
+    margin = np.asarray(store.tile_eval_step_mesh(blocks, info)[5])
+    w_now = np.asarray(handle.weights(jnp.asarray(got)))
+    for d, (_pw, _lb, _lr, _b, _r, _labels, edges) in enumerate(raw):
+        for bucket, row in edges:
+            assert w_now[bucket] != 0.0, bucket
+            assert margin[d * spec.block_rows + row] == w_now[bucket], (
+                d, bucket, row)
 
 
 def test_mesh_tile_step_large_nb_cap_floor():

@@ -308,6 +308,10 @@ def test_mesh_step_compiles_for_v5e_2x2(nb, v5e):
         on((TableCheckpoint.MACC_LEN,), jnp.float32, P())).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "all-reduce" in text
+    # the list's two phases are jits of their own: the compiler keeps
+    # their names on the ops it makes of them, which is what the device
+    # trace files an op under (overflow_ms_per_step.mesh reads them)
+    assert "jit(mesh_ovf_gather)" in text and "jit(mesh_ovf_scatter)" in text
 
 
 def test_fm_train_step_on_planes_compiles_for_v5e(v5e):

@@ -1486,6 +1486,11 @@ class MeshGroupFeed:
         # skew_snapshot after iteration)
         self.skew = {"groups": 0, "skew_sum": 0.0, "skew_max": 0.0,
                      "pad_blocks": 0}
+        # transfer-thread counters (single writer): the slots of the
+        # groups' stacked list lanes as they crossed (D x the width after
+        # widening), and the groups a member of which was widened
+        self.overflow_slots = 0
+        self.widened_groups = 0
         self._pipe = None
 
     @property
@@ -1525,15 +1530,21 @@ class MeshGroupFeed:
         views, rows = item
         if len(views) < self.D:
             views = views + [self._pads] * (self.D - len(views))
+        widened = False
         if self.online:
-            views = widen_overflow(views)
+            with trace.span("meshfeed:widen", cat="feed"):
+                wide = widen_overflow(views)
+            widened, views = wide is not views, wide
         labels = (mesh_group_labels(views, self.info, self.is_tile)
                   if self.want_labels else None)
-        return views, labels, rows
+        return views, labels, rows, widened
 
     def _transfer(self, item):
         # inside the DeviceFeed's <name>:put stage and its span
-        views, labels, rows = item
+        views, labels, rows, widened = item
+        if self.is_tile and "ovf_b" in views[0]:
+            self.overflow_slots += self.D * len(views[0]["ovf_b"])
+            self.widened_groups += widened
         with _timed_put(self):
             dev = place_mesh_group(views, self._shardings)
         return dev, labels, rows
@@ -1579,6 +1590,11 @@ class MeshGroupFeed:
                   "room_grown", "room"):
             if k in inner_snap:
                 out[k] = inner_snap[k]
+        # counts, not seconds: this feed's own (the inner feed's
+        # overflow_slots are the blocks' lists before widening)
+        out["mesh_overflow_slots"] = self.overflow_slots
+        out["mesh_widened_groups"] = self.widened_groups
+        self.overflow_slots = self.widened_groups = 0
         if timer is not None:
             n = max(out["batches"], 1)
             for k in ("parse", "put", "stack"):

@@ -437,6 +437,18 @@ class AsyncSGD:
             # bottleneck?" signal for the sharded mesh feed
             self.timer.add(pfx + "stack", snap["stack"], n)
             self.timer.add(pfx + "stack_stall", snap["stack_stall"], n)
+            # counts, not seconds: the slots of the groups' stacked list
+            # lanes as they crossed to the chips (every member at the
+            # group's widest), and the groups a member of which the stack
+            # workers widened (data/crec.widen_overflow)
+            self.timer.add(pfx + "mesh_overflow_slots",
+                           snap["mesh_overflow_slots"], n)
+            self.timer.add(pfx + "mesh_widened_groups",
+                           snap["mesh_widened_groups"], n)
+            slots_c, widened_c = obs.metrics.mesh_overflow_metrics(
+                self.obs.registry)
+            slots_c.inc(snap["mesh_overflow_slots"])
+            widened_c.inc(snap["mesh_widened_groups"])
         self.feed_stats["feed_stall"] += snap["consume_stall"]
         self.feed_stats["feed_batches"] += snap["batches"]
         self.feed_stats["ring_max"] = max(self.feed_stats["ring_max"],

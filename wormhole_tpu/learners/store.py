@@ -226,6 +226,35 @@ def shard_range_mask(ovb, off, nb_local):
     return valid, jnp.where(valid, bi - off, 0)
 
 
+# The mesh step's two list phases. Every chip is handed its DATA member's
+# whole list and takes the pairs whose bucket its MODEL shard owns
+# (shard_range_mask). Jits of their own, so that the device trace keeps
+# their names as an op's ``tf_op`` (the profiler keeps the path of an op
+# under a nested jit, not under a bare named scope), and module-level, so
+# that the linear mesh step has one definition of each.
+
+@partial(jax.jit, static_argnames=("nb_local",))
+def mesh_ovf_gather(mg, w, ovb, ovr, off, *, nb_local):
+    """The shard's partial margins with its share of the listed pairs:
+    ``w`` gathered unrounded at each owned pair's bucket and added onto
+    the pair's row (before the margins' psum over MODEL)."""
+    valid, idx = shard_range_mask(ovb, off, nb_local)
+    wv = jnp.where(valid, w[idx], 0.0)
+    # scatter-fallback: COO overflow spill, O(ovf_cap)
+    return mg.at[ovr.astype(jnp.int32)].add(wv)
+
+
+@partial(jax.jit, static_argnames=("nb_local",))
+def mesh_ovf_scatter(g, dual, ovb, ovr, off, *, nb_local):
+    """The shard's gradient with its share of the listed pairs: each
+    owned pair's dual gathered unrounded from its row and added into its
+    bucket (before the gradient's psum over DATA)."""
+    valid, idx = shard_range_mask(ovb, off, nb_local)
+    dv = jnp.where(valid, dual[ovr.astype(jnp.int32)], 0.0)
+    # scatter-fallback: COO overflow spill, O(ovf_cap)
+    return g.at[idx].add(dv)
+
+
 def mesh_metric_sums(objv, num_ex, acc, pos, neg):
     """DATA-axis metric reduction shared by every mesh step: returns
     (objv_g, tot_ex, acc_frac, pos_g, neg_g). acc is a per-shard
@@ -1104,9 +1133,14 @@ class ShardedStore(TableCheckpoint):
                                                               spec)
         oc, R = info.ovf_cap, info.block_rows
 
-        # The phases carry jax.named_scope names, so that the device
-        # trace's ops say which phase they belong to: mesh_forward,
-        # mesh_psum_margin, mesh_backward, mesh_psum_grad, mesh_push.
+        # Of the step's phases the device trace keeps the two that are
+        # jits of their own, as an op's ``tf_op``: mesh_ovf_gather and
+        # mesh_ovf_scatter (the listed pairs, above). The other five are
+        # bare jax.named_scopes (mesh_forward, mesh_psum_margin,
+        # mesh_backward, mesh_psum_grad, mesh_push), which name the HLO
+        # for a reader of the dump but which the profiler loses: the
+        # kernels are told by their custom call, the psums by their op
+        # kind, the table's passes only by their shapes.
         def mesh_step(slots_l, pw_l, lab_l, ovb_l, ovr_l, t, tau, macc):
             pw1 = pw_l[0].reshape(spec_local.pairs_shape)
             lab = lab_l[0]
@@ -1120,10 +1154,8 @@ class ShardedStore(TableCheckpoint):
                        if have_model else 0)
                 if oc:
                     ovb, ovr = ovb_l[0], ovr_l[0]
-                    valid, idx = shard_range_mask(ovb, off, nb_local)
-                    wv = jnp.where(valid, w[idx], 0.0)
-                    # scatter-fallback: COO overflow spill, O(ovf_cap)
-                    mg = mg.at[ovr.astype(jnp.int32)].add(wv)
+                    mg = mesh_ovf_gather(mg, w, ovb, ovr, off,
+                                         nb_local=nb_local)
             with jax.named_scope("mesh_psum_margin"):
                 margin = (jax.lax.psum(mg, MODEL_AXIS) if have_model
                           else mg)
@@ -1141,9 +1173,8 @@ class ShardedStore(TableCheckpoint):
                     dual = _nudge_zero_dual(dual, labels, row_mask)
                 g = tilemm.backward_grad(pw1, dual, spec_local)
                 if oc:
-                    dv = jnp.where(valid, dual[ovr.astype(jnp.int32)], 0.0)
-                    # scatter-fallback: COO overflow spill, O(ovf_cap)
-                    g = g.at[idx].add(dv)
+                    g = mesh_ovf_scatter(g, dual, ovb, ovr, off,
+                                         nb_local=nb_local)
             with jax.named_scope("mesh_psum_grad"):
                 g = jax.lax.psum(g, DATA_AXIS)
             with jax.named_scope("mesh_push"):

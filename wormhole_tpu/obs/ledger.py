@@ -76,6 +76,9 @@ SPAN_TABLE: Dict[str, str] = {
     "collective:mesh": "device_compute",
     "mesh:spill": "device_compute",
     "stack": "host_prep",
+    # an online group's overflow lists brought to one width, inside the
+    # mesh feed's <feed>:stack stage (data/crec.MeshGroupFeed._assemble)
+    "meshfeed:widen": "host_prep",
     # metrics ticket readback on the host
     "read": "metrics_readback",
     "collective:metrics_window": "metrics_readback",

@@ -509,6 +509,22 @@ def overflow_hot_metrics(reg: Optional[Registry] = None):
                            "in force", agg="max"))
 
 
+def mesh_overflow_metrics(reg: Optional[Registry] = None):
+    """What the sharded mesh feed ships of the blocks' overflow lists
+    (data/crec.MeshGroupFeed) — single declaration site, fetched per
+    call like :func:`encode_counters`: a group's lists cross as one lane
+    at the widest member's width, every chip handed its DATA member's
+    whole list."""
+    reg = reg if reg is not None else default_registry()
+    return (reg.counter("mesh/overflow_slots",
+                        help="slots of the groups' stacked overflow list "
+                             "lanes as they crossed to the chips, after "
+                             "widening"),
+            reg.counter("mesh/widened_groups",
+                        help="groups in which a member's overflow list "
+                             "was widened to the group's widest"))
+
+
 def mesh_feed_gauges(reg: Optional[Registry] = None):
     """The sharded mesh-feed (data/crec.MeshGroupFeed) telemetry —
     single declaration site (lint_knobs uniqueness contract), fetched

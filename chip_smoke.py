@@ -448,14 +448,26 @@ def four_chips(size: Size, need_tpu: bool, workdir: str) -> None:
         from wormhole_tpu.ops import tilemm
         check(not tilemm._interpret(),
               "the kernels are compiled, not interpreted")
-    slot_shards = [(s.device.id, s.data.shape)
-                   for s in app.store.slots.addressable_shards]
-    say(f"slots shards (device, shape): {slot_shards}")
+    # the linear store keeps its table on a mesh as one plane a slot,
+    # each split over MODEL on its tile axis (learners/table.py)
+    from wormhole_tpu.learners import table as tbl
+    table = app.store.slots
+    check(isinstance(table, tbl.PlaneTable),
+          "the mesh store's table is one plane a slot")
+    plane_shards = [[(s.device.id, s.data.shape)
+                     for s in p.addressable_shards] for p in table.planes]
+    say(f"plane 0 shards (device, shape): {plane_shards[0]}")
     say(f"fed block shards (device, shape): {fed}")
     nb_local = size.num_buckets // app.rt.model_axis_size
-    check(len({d for d, _ in slot_shards}) == mesh.size
-          and all(shape == (nb_local, 3) for _, shape in slot_shards),
-          f"every device holds a ({nb_local}, 3) shard of the slots")
+    shard = tbl.plane_shape(nb_local)
+    check(all(len({d for d, _ in shards}) == mesh.size
+              and all(shape == shard for _, shape in shards)
+              for shards in plane_shards),
+          f"every device holds a {shard} shard of each of the "
+          f"{len(plane_shards)} planes")
+    crossings = app.store.timer.counts.get("table_cross", 0)
+    check(crossings == 0, f"the table never changed form ({crossings} "
+          "table_cross)")
     check("pw" in fed and len({d for d, _ in fed["pw"]}) == mesh.size
           and all(shape[0] == 1 for _, shape in fed["pw"]),
           "a fed group is spread over every device, one block a data index")

@@ -955,3 +955,268 @@ def test_wide_deep_step_forms_nothing_table_shaped_outside_the_kernels():
         assert sum(e.primitive.name == "concatenate" for e in big) == 1
         assert sum(e.primitive.name.startswith("scatter")
                    for e in big) == int(spill)
+
+
+# -- (e) planes on a mesh: the linear store's server shards -------------------
+
+MESH = "data:2,model:2"
+
+
+def _mesh_store(nb=SPEC.nb, algo="ftrl", **cfg):
+    """The linear store on four host devices: two key-range shards of the
+    table, each held by the two chips of a DATA pair."""
+    from wormhole_tpu.parallel.mesh import MeshRuntime, make_mesh
+    rt = MeshRuntime(mesh=make_mesh(MESH, jax.devices()[:4]))
+    return ShardedStore(
+        StoreConfig(num_buckets=nb, loss="logit", **cfg),
+        create_handle(algo, L1L2(0.05, 0.1), LearnRate(0.1, 1.0)), rt)
+
+
+def _rows_over_model(store, full):
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    return jax.device_put(jnp.asarray(full),
+                          NamedSharding(store.rt.mesh, P("model", None)))
+
+
+def _mesh_as_it_was(store):
+    """The same mesh store on the (nb, slots) rows over MODEL it kept
+    before the planes: its mesh step slices a stacked shard."""
+    store._planar = False
+    store.slots = _rows_over_model(store, np.asarray(store.slots))
+    return store
+
+
+def _groups(rng, n, spill):
+    """``n`` groups of two blocks, stacked on the DATA axis as the mesh
+    step takes them."""
+    return [{k: np.stack([b[k] for b in pair]) for k in pair[0]}
+            for pair in (_blocks(rng, SPEC, 2, spill) for _ in range(n))]
+
+
+def _is_planes_over_model(table) -> bool:
+    return (isinstance(table, tbl.PlaneTable)
+            and all(tuple(p.sharding.spec) == ("model", None, None)
+                    for p in table.planes))
+
+
+@pytest.mark.parametrize("algo,spill", [
+    ("ftrl", False), ("ftrl", True), ("adagrad", True)])
+def test_planar_mesh_step_is_the_stacked_mesh_step_to_the_bit(algo, spill):
+    """Three groups through the mesh step on planes and through the same
+    step on stacked shards: the tables are equal bit for bit, metrics and
+    all, and neither store crosses. AdaGrad with L1 takes the masked push
+    (a bucket no pair names keeps its slots)."""
+    rng = np.random.default_rng(31)
+    info = make_info(SPEC, ovf_cap=OC if spill else 0)
+    groups = _groups(rng, 3, spill)
+    planar = _mesh_store(algo=algo)
+    assert planar._planar and _is_planes_over_model(planar.slots)
+    stacked = _mesh_as_it_was(_mesh_store(algo=algo))
+    for group in groups:
+        for st in (planar, stacked):
+            st.tile_train_step_mesh(group, info)
+        assert _is_planes_over_model(planar.slots)
+        assert not isinstance(stacked.slots, tbl.PlaneTable)
+    got, want = np.asarray(planar.slots), np.asarray(stacked.slots)
+    assert np.abs(want[:, 0]).sum() > 0
+    np.testing.assert_array_equal(got, want)
+    np.testing.assert_array_equal(planar.fetch_metrics(),
+                                  stacked.fetch_metrics())
+    np.testing.assert_array_equal(
+        np.asarray(planar.tile_eval_step_mesh(groups[0], info)[5]),
+        np.asarray(stacked.tile_eval_step_mesh(groups[0], info)[5]))
+    assert _crossings(planar) == 0 and _crossings(stacked) == 0
+
+
+@pytest.mark.parametrize("make", [
+    pytest.param(_fm_store, id="fm"), pytest.param(_wd_store, id="wide_deep")])
+def test_embedding_stores_stay_stacked_on_a_mesh(make):
+    """FM's and wide&deep's mesh steps slice a stacked shard: on a mesh
+    they keep (nb, slots) rows over MODEL and never cross; the linear
+    store, whose mesh step takes planes, says so of itself."""
+    from wormhole_tpu.parallel.mesh import MeshRuntime, make_mesh
+    rt = MeshRuntime(mesh=make_mesh(MESH, jax.devices()[:4]))
+    store = make(SPEC.nb, rt=rt)
+    assert not type(store).mesh_step_takes_planes
+    assert ShardedStore.mesh_step_takes_planes
+    assert not store._planar
+    assert not isinstance(store.slots, tbl.PlaneTable)
+    assert tuple(store.slots.sharding.spec) == ("model", None)
+    assert not type(store).can_be_planar(rt, np.float32, SPEC.nb)
+    assert ShardedStore.can_be_planar(rt, np.float32, SPEC.nb)
+    assert not ShardedStore.can_be_planar(rt, jnp.bfloat16, SPEC.nb)
+    assert not ShardedStore.can_be_planar(rt, np.float32, 3 * tilemm.TILE)
+    # one device: every store's answer is what it was
+    assert type(store).can_be_planar(None, np.float32, SPEC.nb)
+
+
+@pytest.fixture(scope="module")
+def mesh_probed():
+    """A mesh store after three groups with lists, its twin on the stacked
+    table those planes came from, and both four-chip cells' hooks,
+    imported as they are."""
+    if REPO not in sys.path:
+        sys.path.insert(0, REPO)
+    from benchmark.configs.criteo_ftrl_clicklog_ps4 import system as clicklog
+    from benchmark.configs.criteo_ftrl_ps4 import system as ps4
+    rng = np.random.default_rng(37)
+    info = make_info(SPEC, ovf_cap=OC)
+    store = _mesh_store()
+    for group in _groups(rng, 3, True):
+        store.tile_train_step_mesh(group, info)
+    assert _is_planes_over_model(store.slots)
+    twin = _mesh_as_it_was(_mesh_store())
+    twin.slots = _rows_over_model(twin, np.asarray(store.slots))
+    return (types.SimpleNamespace(store=store),
+            types.SimpleNamespace(store=twin), {"ps4": ps4,
+                                                "clicklog": clicklog})
+
+
+@pytest.mark.parametrize("cell", ["ps4", "clicklog"])
+def test_four_chip_probes_read_sharded_planes_as_the_stacked_table(
+        mesh_probed, cell):
+    """``w_squares``, ``cg_squares`` and ``w_rows`` of both four-chip
+    ``system.py`` files (``shard_map`` with ``P(MODEL, None)`` over
+    ``store.slots``, ``slots[:, col]``, ``slots[idx, 0]``,
+    ``slots.shape[0]``) give the same numbers from the sharded PlaneTable
+    as from the stacked table it came from, and cross nothing."""
+    app, twin, hooks = mesh_probed
+    hooks = hooks[cell]
+    full = np.asarray(app.store.slots, np.float64)
+    for probe, col in (("grad_norms", 2), ("change_norms", 0)):
+        got = getattr(hooks, probe)(app, {}, 0)["w"]
+        want = getattr(hooks, probe)(twin, {}, 0)["w"]
+        assert got > 0
+        np.testing.assert_allclose(got, want, rtol=1e-6)
+        np.testing.assert_allclose(got, np.linalg.norm(full[:, col]),
+                                   rtol=1e-6)
+    # both sides of the shard boundary and its edges
+    buckets = np.concatenate([
+        [0, SPEC.nb // 2 - 1, SPEC.nb // 2, SPEC.nb - 1],
+        np.random.default_rng(0).integers(0, SPEC.nb, 4096)])
+    got = hooks.state(app, {}, 0, buckets)["w"]
+    np.testing.assert_array_equal(got, hooks.state(twin, {}, 0, buckets)["w"])
+    np.testing.assert_array_equal(got, full[buckets, 0])
+    assert np.abs(got).sum() > 0
+    jax.block_until_ready(app.store.slots)       # benchmark/system.py fence
+    assert _is_planes_over_model(app.store.slots)
+    assert _crossings(app.store) == 0
+
+
+def test_a_sharded_plane_table_names_its_shards_in_rows(mesh_probed):
+    """``addressable_shards`` of planes over a mesh reads as the stacked
+    table's: a device, its ROWS of (nb, slots), and those rows' values
+    (``tests/benchmark`` and ``save_model`` put the shards end to end)."""
+    app, twin, _hooks = mesh_probed
+
+    def shards(table):
+        return {s.device.id: ((s.index[0].start or 0, s.index[0].stop),
+                              np.asarray(s.data))
+                for s in table.addressable_shards}
+
+    got, want = shards(app.store.slots), shards(twin.store.slots)
+    assert sorted(got) == sorted(want) and len(got) == 4
+    for dev, (rows, values) in want.items():
+        assert got[dev][0] == rows
+        np.testing.assert_array_equal(got[dev][1], values)
+    assert app.store.slots.is_fully_addressable
+
+
+def test_a_mesh_table_zeroed_in_place_stays_planes():
+    """``criteo_ftrl_clicklog_ps4/seeds.py`` starts every seed from
+    ``jit(lambda s: s * 0, donate_argnums=0)(store.slots)``: planes in,
+    planes out, where they were."""
+    rng = np.random.default_rng(41)
+    store = _mesh_store()
+    store.tile_train_step_mesh(_groups(rng, 1, False)[0], make_info(SPEC))
+    assert np.abs(np.asarray(store.slots)).sum() > 0
+    store.slots = jax.jit(lambda s: s * 0, donate_argnums=0)(store.slots)
+    assert _is_planes_over_model(store.slots)
+    assert not np.asarray(store.slots).any()
+    store.tile_train_step_mesh(_groups(rng, 1, False)[0], make_info(SPEC))
+    assert _crossings(store) == 0
+
+
+def test_a_mesh_pass_crosses_nothing_and_a_sparse_batch_once(tmp_path, rng):
+    """A whole pass of the learner over a crec2 file on the 2 x 2 mesh
+    (MeshGroupFeed, the mesh step, the pass end's ``nnz_weight``) counts
+    no ``table_cross`` and leaves planes; a sparse batch after it asks
+    for (nb, val_len) and gets it, rows over MODEL, one counted crossing;
+    the next mesh pass takes the table back, one more."""
+    from test_mesh_feed import BR, NB, make_app, make_rows, write_file
+    from wormhole_tpu.data.feed import SparseBatch
+    n = 4 * BR
+    keys, labels = make_rows(rng, n)
+    path = tmp_path / "c.crec2"
+    write_file(path, keys, labels)
+    app = make_app(path, MESH)
+    store = app.store
+    assert store._planar and _is_planes_over_model(store.slots)
+    assert app.run().num_ex == n
+    assert app.timer.totals["mesh_steps"] == 2
+    assert _is_planes_over_model(store.slots)
+    assert _crossings(store) == 0
+
+    batch = SparseBatch(
+        cols=jnp.asarray(rng.integers(0, 64, (32, 4)), jnp.int32),
+        vals=jnp.ones((32, 4), jnp.float32),
+        labels=jnp.asarray(rng.integers(0, 2, 32), jnp.float32),
+        row_mask=jnp.ones(32, jnp.float32),
+        uniq_keys=jnp.asarray(np.arange(0, 64, dtype=np.int32) * 97),
+        key_mask=jnp.ones(64, jnp.float32))
+    before = np.asarray(store.slots)
+    store.train_step(batch)
+    assert _crossings(store) == 1
+    assert store.slots.shape == (NB, 3)
+    assert tuple(store.slots.sharding.spec) == ("model", None)
+    touched = np.asarray(batch.uniq_keys)
+    rest = np.setdiff1d(np.arange(NB), touched)
+    np.testing.assert_array_equal(np.asarray(store.slots)[rest],
+                                  before[rest])
+    app.process(str(path), 0, 1)
+    assert _is_planes_over_model(store.slots)
+    assert _crossings(store) == 2
+
+
+def test_mesh_checkpoint_round_trip_keeps_the_planes(tmp_path):
+    """A mesh store's checkpoint: ``state_pytree`` hands the writer the
+    (nb, val_len) global array a stacked mesh store hands it, rows over
+    MODEL, stacked shard by shard on the chips (a counted pass; the store
+    keeps its planes and the host sees nothing before the writer asks);
+    the file is byte for byte the stacked store's; loaded into a fresh
+    mesh store it becomes planes again, a column to each plane's shards,
+    with no crossing, and both go on stepping to the same table."""
+    from wormhole_tpu.parallel.checkpoint import Checkpointer
+    rng = np.random.default_rng(43)
+    info = make_info(SPEC, ovf_cap=OC)
+    groups = _groups(rng, 3, True)
+    store = _mesh_store()
+    for group in groups[:2]:
+        store.tile_train_step_mesh(group, info)
+    state = store.state_pytree()
+    assert isinstance(state["slots"], jax.Array)
+    assert state["slots"].shape == (SPEC.nb, 3)
+    assert tuple(state["slots"].sharding.spec) == ("model", None)
+    assert _is_planes_over_model(store.slots) and _crossings(store) == 1
+    planar, stacked = tmp_path / "planar", tmp_path / "stacked"
+    Checkpointer(str(planar)).save(2, state)
+    Checkpointer(str(stacked)).save(
+        2, dict(state, slots=_rows_over_model(store,
+                                              np.asarray(store.slots))))
+    name = "ckpt_v2.msgpack"
+    assert (planar / name).read_bytes() == (stacked / name).read_bytes()
+
+    fresh = _mesh_store()
+    ver, loaded = Checkpointer(str(planar)).load(fresh.state_pytree())
+    assert ver == 2
+    fresh.restore_pytree(loaded)
+    assert fresh.t == store.t
+    assert _is_planes_over_model(fresh.slots)
+    np.testing.assert_array_equal(np.asarray(fresh.slots),
+                                  np.asarray(store.slots))
+    crossed = _crossings(fresh)              # the template it was asked for
+    for st in (store, fresh):
+        st.tile_train_step_mesh(groups[2], info)
+    assert _crossings(fresh) == crossed and _crossings(store) == 1
+    np.testing.assert_array_equal(np.asarray(fresh.slots),
+                                  np.asarray(store.slots))

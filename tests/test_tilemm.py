@@ -9,6 +9,7 @@ masked pairs, and the overflow spill path.
 import numpy as np
 import pytest
 
+from wormhole_tpu.learners import table as tbl
 from wormhole_tpu.ops import tilemm
 
 SPEC = tilemm.TileSpec(nb=2 * tilemm.TILE, subblocks=2, cap=1280,
@@ -135,12 +136,20 @@ def _listed_block(rng, spec, listed, nb_local):
             list(zip(edge_b.tolist(), reserved.tolist())))
 
 
-@pytest.mark.parametrize("listed", ["empty", "short", "third"])
-@pytest.mark.parametrize("algo", ["ftrl", "adagrad_l1"])
-def test_mesh_tile_step_matches_oracle(algo, listed):
+@pytest.mark.parametrize("algo,listed,dtype", [
+    *[pytest.param(algo, listed, "float32", id=f"{algo}-{listed}")
+      for listed in ("empty", "short", "third")
+      for algo in ("ftrl", "adagrad_l1")],
+    # a bfloat16 table stays (nb, val_len): the same step slices the
+    # shard's planes out of it and stacks them again
+    pytest.param("ftrl", "short", "bfloat16", id="ftrl-short-bf16-stacked"),
+])
+def test_mesh_tile_step_matches_oracle(algo, listed, dtype):
     """The shard_map tile step on a data:2,model:2 mesh computes the same
     margins/gradient/update as the exact scatter oracle: model shards own
-    tile ranges, data shards own blocks, gradients sum across data.
+    tile ranges, data shards own blocks, gradients sum across data. A
+    float32 table is one plane a slot there, each split over MODEL on its
+    tile axis, before the step and after it, and no step crosses.
     The adagrad_l1 case compiles and checks the masked (touched-bucket)
     mesh branch: zero-psum'd-grad buckets must keep their exact slots.
     With a COO overflow list (``short``, ``third``) every chip is handed
@@ -176,8 +185,10 @@ def test_mesh_tile_step_matches_oracle(algo, listed):
     else:
         handle = AdaGradHandle(penalty=L1L2(0.1, 0.01),
                                lr=LearnRate(0.5, 1.0))
-    store = ShardedStore(StoreConfig(num_buckets=nb, loss="logit"),
-                         handle, rt)
+    store = ShardedStore(StoreConfig(num_buckets=nb, loss="logit",
+                                     param_dtype=dtype), handle, rt)
+    planar = dtype == "float32"
+    assert isinstance(store.slots, tbl.PlaneTable) == planar
 
     blocks = {"pw": np.stack([b[0] for b in raw]),
               "labels": np.stack([b[5] for b in raw])}
@@ -188,12 +199,14 @@ def test_mesh_tile_step_matches_oracle(algo, listed):
         blocks["ovf_b"] = np.stack([ob for ob, _ in lists])
         blocks["ovf_r"] = np.stack([orow for _, orow in lists])
 
-    want = np.asarray(store.slots)
+    want = np.asarray(store.slots).astype(np.float32)
     mask = np.ones(spec.block_rows, np.float32)
     for step in range(2 if width else 1):
         slots0 = want
         store.tile_train_step_mesh(blocks, info)
-        got = np.asarray(jax.device_get(store.slots))
+        assert isinstance(store.slots, tbl.PlaneTable) == planar
+        assert store.slots.sharding.spec[0] == "model"
+        got = np.asarray(jax.device_get(store.slots)).astype(np.float32)
 
         # oracle: per-block margins/duals on pre-step weights; gradient
         # sums
@@ -220,6 +233,8 @@ def test_mesh_tile_step_matches_oracle(algo, listed):
             np.testing.assert_array_equal(got[untouched], slots0[untouched])
         err = np.max(np.abs(got - want)) / (np.abs(want).max() + 1e-9)
         assert err < 2e-2, (step, err)
+        if not planar:
+            want = got              # the table's own rounding is no error
         if step == 0 and algo == "ftrl":
             # from zero weights every dual is +-0.5: FTRL's z is the
             # gradient, exact in float32 on both sides, listed pairs and
@@ -231,12 +246,50 @@ def test_mesh_tile_step_matches_oracle(algo, listed):
     # the listed pairs at the shard boundary, through the eval step: the
     # reserved rows' margins are their one bucket's weight, bit for bit
     margin = np.asarray(store.tile_eval_step_mesh(blocks, info)[5])
+    assert store.timer.counts.get("table_cross", 0) == 0
     w_now = np.asarray(handle.weights(jnp.asarray(got)))
     for d, (_pw, _lb, _lr, _b, _r, _labels, edges) in enumerate(raw):
         for bucket, row in edges:
             assert w_now[bucket] != 0.0, bucket
             assert margin[d * spec.block_rows + row] == w_now[bucket], (
                 d, bucket, row)
+
+
+def test_a_mesh_table_without_whole_tiles_a_shard_stays_stacked():
+    """Three tiles over ``model:2`` split into rows but not into whole
+    tiles: the store keeps ``(nb, val_len)`` rows over MODEL, the mesh
+    tile step refuses the geometry as it always did, and the v1 dense
+    mesh step runs on the stacked shards with no crossing."""
+    import jax
+    from wormhole_tpu.data.crec import CRec2Info
+    from wormhole_tpu.learners.handles import FTRLHandle
+    from wormhole_tpu.learners.store import ShardedStore, StoreConfig
+    from wormhole_tpu.parallel.mesh import MeshRuntime, make_mesh
+
+    nb = 3 * tilemm.TILE
+    rt = MeshRuntime(mesh=make_mesh("data:2,model:2", jax.devices()[:4]))
+    store = ShardedStore(StoreConfig(num_buckets=nb, loss="logit"),
+                         FTRLHandle(), rt)
+    assert not store._planar
+    assert store.slots.shape == (nb, 3)
+    assert tuple(store.slots.sharding.spec) == ("model", None)
+    spec = tilemm.make_spec(nb, subblocks=1, cap=128)
+    info = CRec2Info(nnz=1, block_rows=spec.block_rows,
+                     total_rows=2 * spec.block_rows, nb=nb, subblocks=1,
+                     cap=spec.cap, ovf_cap=0)
+    with pytest.raises(ValueError, match="not shardable over model axis"):
+        store.tile_train_step_mesh({}, info)
+    rows, nnz = 64, 3
+    rng = np.random.default_rng(3)
+    packed = np.concatenate([
+        rng.integers(0, 1 << 32, (2, rows * nnz), dtype=np.uint32)
+        .view(np.uint8).reshape(2, -1),
+        rng.integers(0, 2, (2, rows), dtype=np.uint8)], axis=1)
+    store.dense_train_step_mesh(packed, rows, nnz)
+    assert store.slots.shape == (nb, 3)
+    assert tuple(store.slots.sharding.spec) == ("model", None)
+    assert np.abs(np.asarray(store.slots)[:, 1]).sum() > 0   # z moved
+    assert store.timer.counts.get("table_cross", 0) == 0
 
 
 def test_mesh_tile_step_large_nb_cap_floor():

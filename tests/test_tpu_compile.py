@@ -263,6 +263,41 @@ def test_compiles_for_v5e(build, pallas, v5e):
     assert compiled.memory_analysis().generated_code_size_in_bytes > 0
 
 
+def _table_sized_fusions(text: str, nb_local: int) -> list:
+    """(results, operands) of every fusion of the entry computation that
+    makes or reads an f32 array of one table column's size and shape
+    (flat, a plane, or (nb_local, slots)) outside the two list jits:
+    how many such arrays it writes and how many it reads."""
+    import re
+    from wormhole_tpu.learners import table as tbl
+    column = {"f32[%d]" % nb_local,
+              "f32[%d,%d,%d]" % tbl.plane_shape(nb_local)}
+    column |= {"f32[%d,%d]" % (nb_local, k) for k in (1, 3)}
+    entry = text[text.index("ENTRY"):]
+    made, fusions = {}, []
+    for line in entry.splitlines()[1:]:
+        m = re.match(r"\s*(?:ROOT )?(%[\w.\-]+) = (.+?) ([\w\-]+)\((.*)",
+                     line)
+        if not m:
+            continue
+        name, result, op, rest = m.groups()
+        made[name] = result
+        if op == "fusion" and not re.search(
+                r"jit\(mesh_ovf_(gather|scatter)\)", line):
+            fusions.append((result, rest.split("), kind=")[0]))
+
+    def count(shapes: str) -> int:
+        return sum(s in column for s in re.findall(r"f32\[[\d,]*\]", shapes))
+
+    out = []
+    for result, operands in fusions:
+        reads = sum(count(made.get(o.strip(), ""))
+                    for o in operands.split(",") if o.strip() in made)
+        if count(result) or reads:
+            out.append((count(result), reads))
+    return out
+
+
 @pytest.mark.parametrize("nb", [
     pytest.param(4 * tilemm.TILE, id="2tiles-a-shard"),
     pytest.param(NB, id="criteo", marks=pytest.mark.slow)])
@@ -270,9 +305,19 @@ def test_mesh_step_compiles_for_v5e_2x2(nb, v5e):
     """The whole ``data:2,model:2`` train step of the flagship store —
     shard_map, the split fwd/bwd kernels on each model shard, the psums
     — for the four described chips, with the NamedShardings the mesh
-    feed places its groups on. What ``chip_smoke.py --chips 4`` runs."""
+    feed places its groups on and the table as the store keeps it on a
+    mesh: one plane a slot, each split over MODEL on its tile axis. What
+    ``chip_smoke.py --chips 4`` runs.
+
+    Around the kernels and the list's two jits the compiler leaves ONE
+    table-sized fusion: the push, which reads the shard's three planes
+    and the summed gradient and writes the three planes. Nothing is
+    shaped like a stacked shard or a column sliced out of one (the
+    stacked step had three such fusions: the slice of w, the push, the
+    concatenate: PERF.md, PR 45)."""
     from jax.sharding import NamedSharding, PartitionSpec as P
     from wormhole_tpu.data.crec import CRec2Info
+    from wormhole_tpu.learners import table as tbl
     from wormhole_tpu.learners.store import (ShardedStore, StoreConfig,
                                              TableCheckpoint,
                                              mesh_step_specs)
@@ -284,6 +329,7 @@ def test_mesh_step_compiles_for_v5e_2x2(nb, v5e):
     store = ShardedStore(
         StoreConfig(num_buckets=nb), _ftrl(),
         MeshRuntime(mesh=make_mesh(shape, jax.devices()[:4])))
+    assert isinstance(store.slots, tbl.PlaneTable)
     store.rt = MeshRuntime(mesh=make_mesh(shape, v5e.devices))
     spec = tilemm.make_spec(nb, **CRITEO)
     oc = 1024                                   # CRec2Writer's default
@@ -292,7 +338,7 @@ def test_mesh_step_compiles_for_v5e_2x2(nb, v5e):
                      ovf_cap=oc, **CRITEO)
     step = store._tile_step_mesh(info, "train")
     mesh = store.rt.mesh
-    Pm, Pblk, _ = mesh_step_specs(True)
+    Pm, Pblk, _ = mesh_step_specs(True, planes=True)
     lane = P("data", None)
 
     def on(shape, dtype, spec):
@@ -300,7 +346,7 @@ def test_mesh_step_compiles_for_v5e_2x2(nb, v5e):
                                     sharding=NamedSharding(mesh, spec))
 
     compiled = step.lower(
-        on((nb, 3), jnp.float32, Pm),
+        tbl.PlaneTable([on(tbl.plane_shape(nb), jnp.float32, Pm)] * 3),
         on((2, *spec.pairs_shape), jnp.uint32, Pblk),
         on((2, spec.block_rows), jnp.uint8, lane),
         on((2, oc), jnp.uint32, lane), on((2, oc), jnp.uint32, lane),
@@ -312,6 +358,13 @@ def test_mesh_step_compiles_for_v5e_2x2(nb, v5e):
     # their names on the ops it makes of them, which is what the device
     # trace files an op under (overflow_ms_per_step.mesh reads them)
     assert "jit(mesh_ovf_gather)" in text and "jit(mesh_ovf_scatter)" in text
+    nb_local = nb // 2
+    for gone in ("f32[%d,3]" % nb_local, "f32[%d,1]" % nb_local):
+        assert gone not in text, gone
+    # the push: three planes out; three planes and the gradient in
+    assert _table_sized_fusions(text, nb_local) == [(3, 4)]
+    # the planes are donated onto the new planes
+    assert compiled.memory_analysis().alias_size_in_bytes >= 3 * 4 * nb_local
 
 
 def test_fm_train_step_on_planes_compiles_for_v5e(v5e):

@@ -3,8 +3,9 @@
 The reference shards the model by key range over server processes and moves
 weights/gradients over ZeroMQ (``ps-lite`` ZPush/ZPull, async_sgd.h:84-117).
 Here the model is ONE ``(num_buckets, val_len)`` device table sharded over
-the ``model`` mesh axis (on one device, where the tile kernels step it, kept
-as one plane a slot in their layout: learners/table.py); a minibatch's
+the ``model`` mesh axis (where the tile kernels step it, on one device or as
+the linear mesh step's server shards, kept as one plane a slot in their
+layout: learners/table.py); a minibatch's
 "pull" is a gather of its unique bucket rows, the "push" a scatter-add of
 per-key update deltas — both inside the same jitted train step, so XLA turns
 the key exchange into ICI collectives instead of RPC. Keys are hashed into
@@ -47,8 +48,16 @@ def put_like(template: jax.Array, full: np.ndarray) -> jax.Array:
     """Place a full host-side array like ``template`` — including when the
     template is sharded ACROSS processes (model axis spanning hosts), where
     a plain device_put is illegal: each process contributes its local rows
-    via make_array_from_process_local_data."""
+    via make_array_from_process_local_data. Planes over a mesh
+    (learners/table.py) get a column each, to the plane's own shards:
+    ``full`` as (nb, val_len) comes to no chip."""
     full = np.asarray(full)
+    if (isinstance(template, tbl.PlaneTable)
+            and isinstance(template.sharding, NamedSharding)):
+        shape = tbl.plane_shape(full.shape[0])
+        return tbl.PlaneTable(
+            put_like(plane, np.ascontiguousarray(full[:, k]).reshape(shape))
+            for k, plane in enumerate(template.planes))
     if getattr(template, "is_fully_addressable", True):
         sharding = getattr(template, "sharding", None)
         if not isinstance(sharding, NamedSharding):
@@ -66,10 +75,12 @@ def put_like(template: jax.Array, full: np.ndarray) -> jax.Array:
     return jax.make_array_from_process_local_data(template.sharding, local)
 
 
-def _table_sharding(num_buckets: int, runtime: Optional[MeshRuntime]):
+def _table_sharding(num_buckets: int, runtime: Optional[MeshRuntime],
+                    planes: bool = False):
     """Where a (num_buckets, val_len) parameter table lives: rows over the
     ``model`` mesh axis (validating divisibility), or None for the default
-    device."""
+    device. ``planes``: where each of its (T, A_HI, B_LO) planes lives,
+    the tile axis over ``model``: the same key range a shard."""
     if runtime is None or MODEL_AXIS not in runtime.mesh.axis_names \
             or runtime.model_axis_size <= 1:
         return None
@@ -77,7 +88,7 @@ def _table_sharding(num_buckets: int, runtime: Optional[MeshRuntime]):
         raise ValueError(
             f"num_buckets {num_buckets} not divisible by model axis "
             f"{runtime.model_axis_size}")
-    return NamedSharding(runtime.mesh, P(MODEL_AXIS, None))
+    return NamedSharding(runtime.mesh, mesh_table_spec(True, planes))
 
 
 def factor_table(v0: np.ndarray, runtime: Optional[MeshRuntime],
@@ -108,14 +119,23 @@ def factor_table(v0: np.ndarray, runtime: Optional[MeshRuntime],
         + zeros(1 + k))
 
 
+def _one_device(runtime: Optional[MeshRuntime]) -> bool:
+    return runtime is None or runtime.mesh.size == 1
+
+
 def build_param_table(make, num_buckets: int,
-                      runtime: Optional[MeshRuntime]) -> jax.Array:
+                      runtime: Optional[MeshRuntime], planes: bool = False):
     """``make()`` -> the (num_buckets, val_len) table, built where it is
     to live: with a model axis every chip writes its own shard and nothing
     else. (Built on the default device and placed afterwards, the whole
     table is on one chip first: at 2**29 buckets 8.6 GB beside that chip's
-    own 4.3 GB shard, which stays its ``peak_bytes_in_use`` for good.)"""
-    sharding = _table_sharding(num_buckets, runtime)
+    own 4.3 GB shard, which stays its ``peak_bytes_in_use`` for good.)
+    ``planes``: as a :class:`~wormhole_tpu.learners.table.PlaneTable`,
+    and never as (num_buckets, val_len)."""
+    sharding = _table_sharding(num_buckets, runtime, planes)
+    if planes:
+        return jax.jit(lambda: tbl.PlaneTable(tbl.split(make())),
+                       out_shardings=sharding)()
     if sharding is None:
         return make()
     return jax.jit(make, out_shardings=sharding)()
@@ -279,15 +299,24 @@ def mesh_macc_row(objv_g, tot_ex, acc_frac, wdelta2, pos_g, neg_g):
         jnp.stack([objv_g, tot_ex, acc_frac, wdelta2]), pos_g, neg_g])
 
 
-def mesh_step_specs(have_model):
+def mesh_table_spec(have_model, planes: bool = False):
+    """The spec of the table in a mesh step: its rows over MODEL or, for
+    a table kept as ``planes``, each plane's tile axis, which is the same
+    key range a shard (a plane is the column's bytes as they lie)."""
+    first = MODEL_AXIS if have_model else None
+    return P(first, None, None) if planes else P(first, None)
+
+
+def mesh_step_specs(have_model, planes: bool = False):
     """(Pm, Pblk, data_specs) shared by every stacked-group tile mesh
-    step (linear/FM/wide&deep): the slots-table spec, the (D,T,SG,N)
+    step (linear/FM/wide&deep): the slots-table spec (with ``planes``
+    the spec of each plane of a PlaneTable), the (D,T,SG,N)
     packed-word spec, and the full (slots, pw, labels, ovf_b, ovf_r)
     in_specs prefix. One declaration keeps the three step builders and
     :func:`mesh_group_shardings` (the feed's pre-placement layout) from
     drifting apart."""
     from wormhole_tpu.parallel.mesh import DATA_AXIS
-    Pm = P(MODEL_AXIS, None) if have_model else P(None, None)
+    Pm = mesh_table_spec(have_model, planes)
     Pblk = (P(DATA_AXIS, MODEL_AXIS, None, None) if have_model
             else P(DATA_AXIS, None, None, None))
     data_specs = (Pm, Pblk, P(DATA_AXIS, None),
@@ -381,20 +410,36 @@ class TableCheckpoint:
     forms (learners/table.py). Stores with extra state (wide&deep's MLP)
     extend the pytree."""
 
-    # A store whose single-device tile steps take the table as planes
-    # sets this from what it can see of itself: one device, a float32
-    # table, whole tiles. Crossings are counted in ``self.timer``, which
-    # such a store makes (a learner that owns it reads it as its own).
+    # A store whose tile steps take the table as planes sets this from
+    # what it can see of itself (can_be_planar): a float32 table of whole
+    # tiles, on one device, or on a mesh where its mesh tile step
+    # computes on planes too. Crossings are counted in ``self.timer``,
+    # which such a store makes (a learner that owns it reads it as its
+    # own).
     _planar = False
 
-    @staticmethod
-    def can_be_planar(runtime: Optional[MeshRuntime], dtype,
+    # Does this store's MESH tile step compute on the planes of its
+    # shard? The linear store's does; FM's and wide&deep's slice a
+    # stacked shard, so on a mesh they keep (nb, slots) and do not cross
+    # every step.
+    mesh_step_takes_planes = False
+
+    @classmethod
+    def can_be_planar(cls, runtime: Optional[MeshRuntime], dtype,
                       num_buckets: int) -> bool:
-        return ((runtime is None or runtime.mesh.size == 1)
-                and jnp.dtype(dtype) == jnp.float32
-                and num_buckets % tbl.TILE == 0)
+        if jnp.dtype(dtype) != jnp.float32:
+            return False
+        if _one_device(runtime):
+            return num_buckets % tbl.TILE == 0
+        # whole tiles a MODEL shard: what mesh_tile_geometry demands
+        return (cls.mesh_step_takes_planes
+                and num_buckets % (tbl.TILE * runtime.model_axis_size) == 0)
 
     # -- the table and its two forms (learners/table.py) --------------------
+
+    @property
+    def _on_one_device(self) -> bool:
+        return _one_device(getattr(self, "rt", None))
 
     @property
     def slots(self):
@@ -409,27 +454,36 @@ class TableCheckpoint:
     def slots(self, table) -> None:
         self._table = table
 
-    def _cross(self, convert) -> None:
-        """Change the table's form: a pass over the whole table, counted
-        (calls and seconds) under ``table_cross`` in the timer and, as
-        every timer scope, in the trace. A run whose window shows none
-        never rebuilt the table."""
+    def _crossed(self, planes: bool):
+        """The table in its other form, planes or ``(nb, val_len)``: a
+        pass over the whole table, counted (calls and seconds) under
+        ``table_cross`` in the timer and, as every timer scope, in the
+        trace. A run whose window shows none never rebuilt the table. On
+        a mesh every chip crosses its own shard."""
+        convert = tbl.crossing(planes, _table_sharding(
+            self._table.shape[0], getattr(self, "rt", None), planes))
         with self.timer.scope("table_cross"):
-            self._table = jax.block_until_ready(convert(self._table))
+            return jax.block_until_ready(convert(self._table))
 
     def _stacked(self) -> jax.Array:
         """The table as one ``(nb, val_len)`` array, for every path but
-        the single-device tile steps; it stays so until one of those
+        the tile steps that take planes; it stays so until one of those
         runs."""
         if isinstance(self._table, tbl.PlaneTable):
-            self._cross(tbl.to_stacked)
+            self._table = self._crossed(planes=False)
+        return self._table
+
+    def _planes(self):
+        """The table as planes, for the tile steps that take them."""
+        if not isinstance(self._table, tbl.PlaneTable):
+            self._table = self._crossed(planes=True)
         return self._table
 
     def _tile_table(self):
         """The table as the single-device tile steps take it: planes
-        where this store keeps them (``_planar``)."""
-        if self._planar and not isinstance(self._table, tbl.PlaneTable):
-            self._cross(tbl.to_planes)
+        where a store on one device keeps them (``_planar``)."""
+        if self._planar and self._on_one_device:
+            return self._planes()
         return self._table
 
     def put_block(self, block):
@@ -441,13 +495,17 @@ class TableCheckpoint:
         (``ovf_u``, ``ovf_pw``) crosses as that alone. (A stacked table
         keeps its one step: its no-spill
         programs slice the planes out of ``(nb, slots)`` and compile for
-        six to nine minutes at 2**28, PERF.md.)"""
+        six to nine minutes at 2**28, PERF.md. So does a table on a
+        mesh, planes or not: the mesh step takes its list operands
+        whatever they hold, and its groups come through
+        ``crec.place_mesh_group``, not through here.)"""
         if isinstance(block, dict) and "ovf_pw" in block:
             # the list rides in its hot form (data/crec.HotRoom): the
             # step reads that and nothing of the pairs themselves
             block = {k: v for k, v in block.items()
                      if k not in ("ovf_b", "ovf_r")}
-        elif self._planar and isinstance(block, dict) and "ovf_b" in block:
+        elif (self._planar and self._on_one_device
+              and isinstance(block, dict) and "ovf_b" in block):
             ovf, unused = block["ovf_b"], np.uint32(0xFFFFFFFF)
             # writers fill the list from the front: one look settles
             # a list that has pairs, a scan only one that seems empty
@@ -459,15 +517,25 @@ class TableCheckpoint:
     def state_pytree(self):
         slots = self._table
         if isinstance(slots, tbl.PlaneTable):
-            # the checkpoint holds (nb, val_len): planes are stacked on
-            # the host, where the bytes go anyway, not on the device
-            slots = np.asarray(slots)
+            # the checkpoint holds (nb, val_len). One device: planes are
+            # stacked on the host, where the bytes go anyway, not on the
+            # device. Planes over a mesh: the global array a stacked mesh
+            # store hands over, every chip stacking its own shard beside
+            # its planes (counted; the store keeps its planes), so that
+            # nothing comes to the host before a writer asks for it,
+            # shard by shard where the table spans processes
+            # (ShardCheckpointer).
+            slots = (np.asarray(slots) if self._on_one_device
+                     else self._crossed(planes=False))
         return {"slots": slots, "t": np.int64(self.t)}
 
     def restore_pytree(self, state) -> None:
         slots = state["slots"]
         if isinstance(slots, jax.Array) and not slots.is_fully_addressable:
-            self.slots = slots       # already a global array (ShardCkpt)
+            # already a global array (ShardCkpt), (nb, val_len) as
+            # state_pytree gave it: the next mesh tile step of a store
+            # that keeps planes takes it across, counted
+            self.slots = slots
         else:
             self.slots = put_like(self.slots, np.asarray(slots))
         self.t = int(state["t"])
@@ -498,15 +566,21 @@ class TableCheckpoint:
             return x
         return jax.device_put(x, rt.replicated())
 
-    def _mesh_table(self):
-        """The table as a mesh step takes it. Without a model axis the
+    def _mesh_table(self, tile: bool = False):
+        """The table as a mesh step takes it: for the ``tile`` step of a
+        store that keeps planes and whose mesh step computes on them, the
+        planes; for every other (the v1 dense step, FM's and
+        wide&deep's), ``(nb, val_len)``. Without a model axis the
         table starts out uncommitted on the default device (the
         single-device steps want it there) while a mesh step returns it
         replicated over the mesh: the same double compile as in
         _step_operand, so commit it at its first mesh step."""
-        slots = self._stacked()
-        if not isinstance(getattr(slots, "sharding", None), NamedSharding):
-            self.slots = jax.device_put(slots, self.rt.replicated())
+        if tile and self._planar and self.mesh_step_takes_planes:
+            table = self._planes()
+        else:
+            table = self._stacked()
+        if not isinstance(getattr(table, "sharding", None), NamedSharding):
+            self.slots = jax.device_put(table, self.rt.replicated())
         return self.slots
 
     def _macc_buf(self):
@@ -569,6 +643,10 @@ class TableCheckpoint:
 class ShardedStore(TableCheckpoint):
     """Model state + the fused pull→forward→backward→push step."""
 
+    # _tile_step_mesh hands plane 0 of the shard to the forward kernel
+    # and pushes in one pass over the shard's planes
+    mesh_step_takes_planes = True
+
     # this store's one-device tile steps take an overflow list in its hot
     # form too (data/crec.HotRoom), so its app has the feeds make one
     hot_overflow = True
@@ -587,19 +665,19 @@ class ShardedStore(TableCheckpoint):
         # crossings of the table's format (scope "table_cross"); a learner
         # that owns this store reads it as its own timer
         self.timer = Timer()
-        # One device, a float32 table, whole tiles: the tile steps can take
-        # this table, so it is built in THEIR form (one plane a slot,
-        # learners/table.py) and never as (nb, val_len), which the compiler
-        # lays out four wide. Every other path asks _stacked() for the
-        # (nb, val_len) array and gets it, counted; a table on a mesh or in
-        # bfloat16 stays stacked and the tile steps slice it as they go.
+        # A float32 table of whole tiles, on one device or in whole tiles a
+        # MODEL shard on a mesh: the tile steps, this store's mesh step
+        # among them, can take this table, so it is built in THEIR form (one
+        # plane a slot, learners/table.py; on a mesh each chip its own
+        # shard of each plane) and never as (nb, val_len), which the
+        # compiler lays out four wide. Every other path asks _stacked() for
+        # the (nb, val_len) array and gets it, counted; a table in bfloat16
+        # or without whole tiles a shard stays stacked and the tile steps
+        # slice it as they go.
         self._planar = self.can_be_planar(runtime, self.dtype, nb)
-        if self._planar:
-            self._table = jax.jit(
-                lambda: tbl.PlaneTable(tbl.split(handle.init(nb))))()
-        else:
-            self._table = build_param_table(
-                lambda: handle.init(nb).astype(self.dtype), nb, runtime)
+        self._table = build_param_table(
+            lambda: handle.init(nb).astype(self.dtype), nb, runtime,
+            planes=self._planar)
         self._step = self._build_step()
         self._eval = self._build_eval()
         self.t = 1  # global update counter (SGD eta schedule)
@@ -924,7 +1002,7 @@ class ShardedStore(TableCheckpoint):
         as COO pairs or in its hot form (the jit has a program for
         each). Every variant computes on the float32 (T, A_HI, B_LO)
         planes; a planar table IS those planes and is returned as such,
-        a stacked one (bfloat16, on a mesh, a serving snapshot) is
+        a stacked one (bfloat16, a serving snapshot) is
         sliced into them and stacked again inside the step."""
         key = (info, kind, spill)
         fn = getattr(self, "_tile_cache", {}).get(key)
@@ -1140,15 +1218,19 @@ class ShardedStore(TableCheckpoint):
         # mesh_backward, mesh_psum_grad, mesh_push), which name the HLO
         # for a reader of the dump but which the profiler loses: the
         # kernels are told by their custom call, the psums by their op
-        # kind, the table's passes only by their shapes.
-        def mesh_step(slots_l, pw_l, lab_l, ovb_l, ovr_l, t, tau, macc):
+        # kind, the table's pass only by its shapes. The shard comes as
+        # the store keeps it: its planes (``_planar``: plane 0 IS w, and
+        # the push is one elementwise pass over the planes and the summed
+        # gradient), or a stacked (nb_local, val_len) shard (bfloat16),
+        # sliced into planes here and stacked again after the push.
+        def mesh_step(table_l, pw_l, lab_l, ovb_l, ovr_l, t, tau, macc):
             pw1 = pw_l[0].reshape(spec_local.pairs_shape)
             lab = lab_l[0]
             row_mask = (lab != jnp.uint8(255)).astype(jnp.float32)
             labels = jnp.minimum(lab, 1).astype(jnp.float32)
-            s32 = slots_l.astype(jnp.float32)
+            planes = tbl.planes_of(table_l)
             with jax.named_scope("mesh_forward"):
-                w = handle.weights(s32)
+                w = handle.weights(tbl.PlaneTable(planes))
                 mg = tilemm.forward_margins(pw1, w, spec_local)
                 off = (jax.lax.axis_index(MODEL_AXIS) * nb_local
                        if have_model else 0)
@@ -1178,17 +1260,16 @@ class ShardedStore(TableCheckpoint):
             with jax.named_scope("mesh_psum_grad"):
                 g = jax.lax.psum(g, DATA_AXIS)
             with jax.named_scope("mesh_push"):
-                new = masked_push(handle, s32, g, t.astype(jnp.float32),
-                                  tau, exact_dense)
-                d0 = new[:, 0] - s32[:, 0]
-                wdelta2 = jnp.sum(d0 * d0)
+                new, wdelta2 = masked_push_planes(
+                    handle, planes, g.reshape(planes[0].shape),
+                    t.astype(jnp.float32), tau, exact_dense)
                 if have_model:
                     wdelta2 = jax.lax.psum(wdelta2, MODEL_AXIS)
             packed = mesh_macc_row(objv_g, tot_ex, acc_frac, wdelta2,
                                    pos_g, neg_g)
-            return new.astype(slots_l.dtype), t + 1, macc + packed
+            return tbl.table_like(new, table_l), t + 1, macc + packed
 
-        Pm, _Pblk, data_specs = mesh_step_specs(have_model)
+        Pm, _Pblk, data_specs = mesh_step_specs(have_model, self._planar)
         if kind == "train":
             in_specs = data_specs + (P(), P(), P())
             out_specs = (Pm, P(), P())
@@ -1228,7 +1309,8 @@ class ShardedStore(TableCheckpoint):
         z = mesh_ovf_zeros(D, oc)
         nb_local = mesh_tile_geometry(self.rt, info.spec)[0]
         self.slots, t_new, self._macc = self.mesh_transport().dispatch(
-            step, self._mesh_table(), blocks["pw"], blocks["labels"],
+            step, self._mesh_table(tile=True), blocks["pw"],
+            blocks["labels"],
             blocks.get("ovf_b", z), blocks.get("ovf_r", z),
             self._t_device(), self._tau_const(tau), self._macc_buf(),
             ici_bytes=mesh_step_ici_bytes(
@@ -1243,7 +1325,7 @@ class ShardedStore(TableCheckpoint):
         z = mesh_ovf_zeros(D, oc)
         return self.mesh_transport().dispatch(
             self._tile_step_mesh(info, "eval"),
-            self._mesh_table(), blocks["pw"], blocks["labels"],
+            self._mesh_table(tile=True), blocks["pw"], blocks["labels"],
             blocks.get("ovf_b", z), blocks.get("ovf_r", z),
             ici_bytes=mesh_step_ici_bytes(
                 self.rt, margin_elems=info.block_rows, train=False))

@@ -5,8 +5,8 @@ wide&deep: w, v_1..v_k and their AdaGrad accumulators). Two forms exist, and thi
 module is the door between them:
 
   * stacked — one ``(nb, slots)`` array: what the sparse step, the v1 dense
-    step, the mesh steps, serving, the pager, ``save_model``/``load_model``
-    and the checkpoint read;
+    steps, FM's and wide&deep's mesh steps, serving, the pager,
+    ``save_model``/``load_model`` and the checkpoint read;
   * planar — one float32 ``(T, A_HI, B_LO)`` plane a slot (:class:`PlaneTable`):
     the tile kernels' own layout (ops/tilemm.py), so a tile step hands the
     planes to ``pallas_call`` as they are and gets the next step's state
@@ -15,8 +15,12 @@ module is the door between them:
 
 Which stores keep planes: ``ShardedStore`` (learners/store.py), ``FMStore``
 (models/fm.py) and ``WideDeepStore`` (models/wide_deep.py), each when it can
-see that it can — one device, a float32 table, whole tiles
-(``TableCheckpoint.can_be_planar``). FTRL's in-place kernel updates its three
+see that it can — a float32 table of whole tiles, on one device or, where
+the store's own mesh tile step computes on planes (the linear store's does),
+on a mesh with whole tiles a MODEL shard (``TableCheckpoint.can_be_planar``).
+On a mesh a plane is split over MODEL on its tile axis, which is the key
+range the stacked table's rows are split into, and repeated over DATA.
+FTRL's in-place kernel updates its three
 planes itself, aliased onto its outputs, and so does FM's with its 2(1+k)
 (the bfloat16 operand [w, v, Σv²] is put together in VMEM from the w and v
 tiles); a block with a COO overflow list takes the kernel that writes the
@@ -24,22 +28,32 @@ gradient (FM: a push plane a channel) and ONE elementwise pass over planes
 onto the donated state. The dense-tower store runs the split kernel pair
 with its tower between: the pull kernel's operand is one op over the w and v
 planes, the push kernel's (T, A_HI, ch*B_LO) output is read a lane block a
-channel, and the same ONE pass updates its 2(1+k) planes. A table on a mesh
-and a bfloat16 table stay stacked.
+channel, and the same ONE pass updates its 2(1+k) planes. The linear mesh
+step hands plane 0 to the forward kernel of each shard as it stands and
+updates the shard's planes in that same ONE pass, after the gradient's psum.
+A bfloat16 table, a table without whole tiles a shard, and FM's and
+wide&deep's tables on a mesh stay stacked.
 
 Which paths cross: every one in the first list asks the store's
-``_stacked()`` (the pager through ``PagedStore._table``) and the
-single-device tile steps ask ``_tile_table()`` (``TableCheckpoint``, shared
-by the stores); anything that writes through
+``_stacked()`` (the pager through ``PagedStore._table``), the
+single-device tile steps ask ``_tile_table()`` and the mesh tile steps
+``_mesh_table()`` (``TableCheckpoint``, shared by the stores); anything
+that writes through
 ``PlaneTable.at`` gets the stacked form as well. A (T, A_HI, B_LO) plane and
 the flat ``(nb,)`` column are the same bytes: reshapes between them are
 free. Crossing between the FORMS is a pass over the whole table; the store
-that crosses counts it (``TableCheckpoint._cross``, timer scope
-``table_cross``). The checkpoint does not cross: planes are stacked on the
-host, where the bytes go anyway.
+that crosses counts it (``TableCheckpoint._crossed``, timer scope
+``table_cross``). The checkpoint of a one-device store does not cross:
+planes are stacked on the host, where the bytes go anyway. A mesh store's
+planes are stacked shard by shard on the chips for the writer (counted; the
+store keeps its planes), and a restored table is put plane by plane
+(``store.put_like``).
 """
 
 from __future__ import annotations
+
+import collections
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -94,13 +108,29 @@ def to_planes(stacked: jax.Array) -> "PlaneTable":
     return PlaneTable(split(stacked))
 
 
+# what a device holds of a PlaneTable, as a stacked table's shard says it
+TableShard = collections.namedtuple("TableShard", "device index data")
+
+
+@functools.lru_cache(maxsize=None)
+def crossing(planes: bool, sharding=None):
+    """The jitted crossing to planes (``planes``) or to ``(nb, slots)``.
+    On a mesh the form it makes is placed by ``sharding``, each chip
+    writing its own shard; with None it is the one-device crossing."""
+    fn = to_planes if planes else to_stacked
+    if sharding is None:
+        return fn
+    return jax.jit(fn.__wrapped__, out_shardings=sharding)
+
+
 @jax.tree_util.register_pytree_node_class
 class PlaneTable:
     """A table as one plane a slot, a pytree of those planes. It answers
     the reads a ``(nb, slots)`` array gets from the code around the stores
-    (``shape``, ``dtype``, ``astype``, ``np.asarray``, the indexings in use)
-    without building that array; anything that writes through ``.at`` gets
-    the stacked form."""
+    (``shape``, ``dtype``, ``astype``, ``np.asarray``, ``* scalar``, the
+    indexings in use, a mesh's ``addressable_shards``) without building
+    that array; anything that writes through ``.at`` gets the stacked
+    form."""
 
     def __init__(self, planes):
         self.planes = tuple(planes)
@@ -124,8 +154,33 @@ class PlaneTable:
 
     @property
     def sharding(self):
-        """Where the table lives: every plane is on the one device."""
+        """Where a plane lives, which is where every plane lives: the one
+        device, or on a mesh the tile axis split over MODEL (a key range
+        a shard, as the stacked table's rows are split)."""
         return self.planes[0].sharding
+
+    @property
+    def is_fully_addressable(self) -> bool:
+        return self.planes[0].is_fully_addressable
+
+    @property
+    def addressable_shards(self) -> list:
+        """What each local device holds, as the stacked table's shards
+        would say it: ``index`` in ROWS of ``(nb, slots)`` (a plane's
+        tile range is a key range), ``data`` the PlaneTable of that
+        device's part of every plane, on that device."""
+        held = [{s.device: s for s in p.addressable_shards}
+                for p in self.planes]
+        tiles = self.planes[0].shape[0]
+        out = []
+        for device, first in held[0].items():
+            cut = first.index[0]
+            rows = slice((cut.start or 0) * TILE,
+                         (tiles if cut.stop is None else cut.stop) * TILE)
+            out.append(TableShard(
+                device, (rows, slice(None)),
+                PlaneTable(h[device].data for h in held)))
+        return out
 
     def astype(self, dtype) -> "PlaneTable":
         if jnp.dtype(dtype) == self.dtype:
@@ -135,6 +190,9 @@ class PlaneTable:
     @property
     def at(self):
         return join(self.planes).at
+
+    def __mul__(self, other) -> "PlaneTable":
+        return PlaneTable(p * other for p in self.planes)
 
     def __array__(self, dtype=None, copy=None):
         # stacked on the host: no (nb, slots) copy on the device

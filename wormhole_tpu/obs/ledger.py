@@ -110,7 +110,7 @@ SPAN_TABLE: Dict[str, str] = {
     "tilemm:fused_cached": "device_compute",
     "tilemm:mlp_phase": "device_compute",
     # the parameter table changing form, planes <-> (nb, slots)
-    # (learners/table.py, ShardedStore._cross): a pass over the whole
+    # (learners/table.py, TableCheckpoint._crossed): a pass over the whole
     # table on the device; a training pass should show none
     "table_cross": "device_compute",
     # online serving (serve/): the pull-only forward is device work;

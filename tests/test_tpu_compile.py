@@ -367,6 +367,36 @@ def test_mesh_step_compiles_for_v5e_2x2(nb, v5e):
     assert compiled.memory_analysis().alias_size_in_bytes >= 3 * 4 * nb_local
 
 
+def _compile_fm_train_step(v5e, step, spec, nb: int, k: int, room: int = 0):
+    """An ``FMStore`` tile train step compiled for one described chip on
+    a planar table of ``nb`` buckets; ``room``: the slots of the block's
+    COO overflow list (0: the block brings none). Returns (compiled, the
+    plane's shape struct)."""
+    from wormhole_tpu.learners import table as tbl
+    from wormhole_tpu.learners.store import TableCheckpoint
+    one_chip = SingleDeviceSharding(v5e.devices[0])
+
+    def on(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    plane = on(tbl.plane_shape(nb), jnp.float32)
+    block = {"pw": on(spec.pairs_shape, jnp.uint32),
+             "labels": on((spec.block_rows,), jnp.uint8)}
+    if room:
+        # the list as FMStore.put_block ships it: with its distinct
+        # buckets (two tiles hold a click-log list's 25,000) and each
+        # slot's index in them
+        block.update(ovf_b=on((room,), jnp.uint32),
+                     ovf_r=on((room,), jnp.uint32),
+                     ovf_u=on((2 * tilemm.TILE,), jnp.uint32),
+                     ovf_k=on((room,), jnp.uint32))
+    compiled = step.lower(
+        tbl.PlaneTable([plane] * (2 * (1 + k))), block,
+        on((), jnp.int32), on((), jnp.float32),
+        on((TableCheckpoint.MACC_LEN,), jnp.float32)).compile()
+    return compiled, plane
+
+
 def test_fm_train_step_on_planes_compiles_for_v5e(v5e):
     """The whole one-device FM train step of a planar ``FMStore`` at the
     widths of ``criteo_fm`` (cap 256), two tiles a grid step: the fused
@@ -377,8 +407,6 @@ def test_fm_train_step_on_planes_compiles_for_v5e(v5e):
     ``criteo_fm.replay_uniform`` steps."""
     import re
     from wormhole_tpu.data.crec import CRec2Info
-    from wormhole_tpu.learners import table as tbl
-    from wormhole_tpu.learners.store import TableCheckpoint
     from wormhole_tpu.models.fm import IN_PLACE, FMConfig, FMStore
     # two tiles a grid step (tiles_step divides the tile count) keep the
     # unrolled kernel short; 1018 tiles keep a plane out of VMEM, as at
@@ -392,18 +420,7 @@ def test_fm_train_step_on_planes_compiles_for_v5e(v5e):
     spec = info.spec
     step = store._tile_step(info, "train", False)
     assert store.step_kernel[:2] == ("fused", IN_PLACE)
-    one_chip = SingleDeviceSharding(v5e.devices[0])
-
-    def on(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
-    plane = on(tbl.plane_shape(nb), jnp.float32)
-    compiled = step.lower(
-        tbl.PlaneTable([plane] * (2 * (1 + k))),
-        {"pw": on(spec.pairs_shape, jnp.uint32),
-         "labels": on((spec.block_rows,), jnp.uint8)},
-        on((), jnp.int32), on((), jnp.float32),
-        on((TableCheckpoint.MACC_LEN,), jnp.float32)).compile()
+    compiled, plane = _compile_fm_train_step(v5e, step, spec, nb, k)
     text = compiled.as_text()
     assert text.count("tpu_custom_call") >= 1
     plane_txt = "f32[%d,%d,%d]" % plane.shape
@@ -420,6 +437,53 @@ def test_fm_train_step_on_planes_compiles_for_v5e(v5e):
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= 2 * (1 + k) * 4 * nb
     assert mem.temp_size_in_bytes < 4 * nb
+
+
+def test_fm_spill_train_step_compiles_for_v5e_at_the_click_log_cells_size(v5e):
+    """The one-device FM train step of a planar ``FMStore`` for a block
+    that brings a COO overflow list, at the size of
+    ``criteo_fm_clicklog.replay_fields``: 2**26 buckets (cap 128, sixteen
+    tiles a grid step), a list room of 1,638,400 slots. The v5e compiler
+    accepts it inside the chip's memory, the three XLA phases keep their
+    names in the optimized HLO (each is a jit of its own, so the device
+    trace can tell them apart), and nothing in it, operand or temporary,
+    is the table stacked as ``(nb, 18)``. About a minute."""
+    import json
+    import re
+    from wormhole_tpu.data.crec import CRec2Info, default_cap
+    from wormhole_tpu.models.fm import IN_PLACE, FMConfig, FMStore
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "criteo_fm_clicklog", "config.json")) as f:
+        config = json.load(f)
+    with open(os.path.join(root, "benchmark", "traffic",
+                           "replay_fields.json")) as f:
+        room = int(json.load(f)["ovf_cap"])
+    k, nb = int(config["dim"]), int(config["num_buckets"])
+    assert (k, nb, room) == (8, 1 << 26, 1638400)
+    store = FMStore(FMConfig(num_buckets=2 * tilemm.TILE, dim=k,
+                             tile_step_kernel="fused"))
+    info = CRec2Info(nnz=39, block_rows=12 * tilemm.RSUB,
+                     total_rows=12 * tilemm.RSUB, nb=nb, ovf_cap=room,
+                     subblocks=12, cap=default_cap(39, nb))
+    assert info.cap == config["tile"]["cap"]
+    spec = info.spec
+    step = store._tile_step(info, "train", True)
+    assert store.step_kernel[0] == "fused"
+    assert store.step_kernel[1] != IN_PLACE
+    compiled, _plane = _compile_fm_train_step(v5e, step, spec, nb, k, room)
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 1
+    for phase in ("fm_ovf_pull", "fm_ovf_scatter", "fm_table_update"):
+        assert re.search(r"jit\(%s\)" % phase, text), phase
+    # the table is planes throughout: no array of nb rows by some columns
+    assert not re.findall(r"f32\[%d,\d+\]" % nb, text)
+    # the 18 planes are donated onto the 18 results, and the program (its
+    # arguments and its temporaries: the ten push planes among them) fits
+    # the chip beside nothing else with 7 GB to spare
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * (1 + k) * 4 * nb
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 9e9
 
 
 def test_wide_deep_train_step_compiles_with_the_stated_tower_precision(v5e):

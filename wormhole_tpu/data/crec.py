@@ -1431,6 +1431,47 @@ def widen_overflow(views: list) -> list:
     return out
 
 
+def spread_overflow(ovf_b: np.ndarray, ovf_r: np.ndarray
+                    ) -> Tuple[np.ndarray, np.ndarray, np.ndarray,
+                               np.ndarray]:
+    """``(ovf_b, ovf_r, uniq, ovf_k)`` of one overflow list, for a store
+    whose spill step walks the list a slot at a time
+    (models/fm.FMStore.put_block): the list with its slots in another
+    order, pairs and unused slots alike (new arrays; the same pairs, each
+    once), its distinct buckets in ascending order (the unused slots'
+    ``0xFFFFFFFF`` is none of them), and each slot's index in those (0 for
+    an unused slot).
+
+    The order: every bucket's slots evenly spaced over the list, the
+    ``j``-th of a bucket's ``c`` slots near ``(j + phase) / c`` of the way
+    through, the phase the bucket's own. The encoder lays a list out cell
+    by cell, and a cell's pairs past the cap are mostly its hottest bucket
+    over and over, so a step that gathers a value a slot in that order
+    asks for one address hundreds of times in a row. The distinct buckets:
+    a click-log block's list names 25,000 of them in 1.5M pairs, a dozen
+    of them 1-3% of the list each, and a gather a slot from a table plane
+    follows which addresses those are and where the plane lies (chip, nine
+    plane gathers of 1,638,400 slots: PERF.md section 6, PR 47); read once
+    a bucket, the planes are asked for 25,000 values.
+    Sums over a list do not depend on its order but for the order of
+    float32 additions. Two sorts of the list, 0.2 s for 1.6M slots."""
+    from wormhole_tpu.ops.tilemm import UNUSED
+    n = len(ovf_b)
+    by_bucket = np.argsort(ovf_b, kind="stable")
+    sb = ovf_b[by_bucket]
+    start = np.flatnonzero(np.concatenate(([True], sb[1:] != sb[:-1])))
+    count = np.diff(np.concatenate((start, [n])))
+    run = np.repeat(np.arange(len(start)), count)
+    phase = (np.arange(len(start)) * 0.6180339887498949) % 1.0
+    at = (np.arange(n) - start[run] + phase[run]) / count[run]
+    order = np.argsort(at, kind="stable")
+    uniq = sb[start]
+    ovf_k = np.where(sb != UNUSED, run, 0).astype(np.uint32)[order]
+    order = by_bucket[order]
+    return (np.take(ovf_b, order), np.take(ovf_r, order),
+            uniq[uniq != UNUSED], ovf_k)
+
+
 def mesh_group_labels(views: list, info, is_tile: bool) -> np.ndarray:
     """The label lanes of one whole group, concatenated in the global
     ``(D * R,)`` row order of the mesh eval step's margins, PAD rows

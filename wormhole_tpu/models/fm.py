@@ -22,6 +22,7 @@ scattered back. Same bounded-staleness driver as the linear learner
 
 from __future__ import annotations
 
+import weakref
 from dataclasses import dataclass
 from functools import partial
 from typing import Optional
@@ -142,6 +143,11 @@ class FMStore(TableCheckpoint):
         # crossings of the table's format (scope "table_cross"), as
         # ShardedStore counts them
         self.timer = Timer()
+        # pairs on the overflow list of each block now on the device, by
+        # the id of the list's device array (put_block, _count_step)
+        self._listed = {}
+        # the widest ovf_u a list has crossed with, in tiles (put_block)
+        self._distinct_tiles = 1
         # One device and whole tiles: the tile steps take this table as
         # one float32 (T, A_HI, B_LO) plane a channel (w, v_1..v_k, cg_w,
         # cg_v_1..k; learners/table.py), the multi-channel kernel's own
@@ -297,6 +303,13 @@ class FMStore(TableCheckpoint):
             ovf_r = block["ovf_r"] if oc else None
             return block["pw"], labels, row_mask, ovf_b, ovf_r
 
+        def distinct(block):
+            # the list's distinct buckets and each slot's index in them,
+            # where put_block made them (a block put by other hands
+            # brings none, and its pull reads the planes a slot at a time)
+            return ((block["ovf_u"], block["ovf_k"])
+                    if oc and "ovf_u" in block else None)
+
         def forward(planes, block):
             # the split kernel pair's forward half: the operand is ONE
             # XLA op over the w and v planes (fm_operand, which the fused
@@ -307,8 +320,8 @@ class FMStore(TableCheckpoint):
             pulls = tilemm.plane_pulls(
                 pw, tilemm.fm_operand(theta[0], theta[1:], one), spec)
             if oc:
-                pulls = pulls + tilemm.fm_spill_pull_rows(
-                    theta, ovf_b, ovf_r, spec, one)
+                pulls = pulls + fm_ovf_pull(theta, ovf_b, ovf_r, one,
+                                            distinct(block))
             s = pulls[:, 1:1 + k]
             # same guarded channel-by-channel sum the fused kernel runs
             # at its phase boundary — keeps split/fused margins bitwise
@@ -317,7 +330,26 @@ class FMStore(TableCheckpoint):
                 one)
             return pw, labels, row_mask, ovf_b, ovf_r, s, margin
 
-        def update(table, planes, push, margin, labels, row_mask, t, macc):
+        # The phases XLA runs around the kernel are jits of their own, so
+        # that the device trace's ops say which phase they belong to (the
+        # profiler keeps the path of an op under a nested jit, not under
+        # a bare named scope; ShardedStore._tile_step does the same):
+        # fm_ovf_pull (the listed buckets' w and v gathered plane by plane,
+        # the pairs' pull channels formed unrounded and summed onto their
+        # rows), fm_ovf_scatter (the pairs' dual channels added into the
+        # push planes), fm_table_update (the one elementwise pass).
+        @jax.jit
+        def fm_ovf_pull(theta, ovf_b, ovf_r, one, distinct):
+            return tilemm.fm_spill_pull_rows(theta, ovf_b, ovf_r, spec, one,
+                                             distinct)
+
+        @jax.jit
+        def fm_ovf_scatter(push, dvals, ovf_b, ovf_r):
+            return tilemm.spill_push_scatter_planes(push, dvals, ovf_b,
+                                                    ovf_r, spec)
+
+        @jax.jit
+        def fm_table_update(planes, push):
             # everything downstream of the push planes: ONE elementwise
             # pass, 10 push and 18 state planes in, 18 out onto the
             # donated state — AdaGrad on the touched buckets (the count
@@ -329,9 +361,12 @@ class FMStore(TableCheckpoint):
             theta_new, cg_new = adagrad(planes[:1 + k], planes[1 + k:],
                                         push, opaque_one(push[-1]))
             d0 = theta_new[0] - planes[0]
-            return finish(tbl.table_like(theta_new + cg_new, table),
-                          jnp.sum(d0 * d0), margin, labels, row_mask, t,
-                          macc)
+            return theta_new + cg_new, jnp.sum(d0 * d0)
+
+        def update(table, planes, push, margin, labels, row_mask, t, macc):
+            new, wdelta2 = fm_table_update(tuple(planes), tuple(push))
+            return finish(tbl.table_like(new, table), wdelta2, margin,
+                          labels, row_mask, t, macc)
 
         def finish(new, wdelta2, margin, labels, row_mask, t, macc):
             # the metric tail: identical ops downstream of the margins in
@@ -375,13 +410,12 @@ class FMStore(TableCheckpoint):
                 pw, labels, row_mask, ovf_b, ovf_r = decode(block)
                 theta = planes[:1 + k]
                 if oc:
-                    sp = tilemm.fm_spill_pull_rows(
-                        theta, ovf_b, ovf_r, spec, opaque_one(row_mask))
+                    sp = fm_ovf_pull(theta, ovf_b, ovf_r,
+                                     opaque_one(row_mask), distinct(block))
                     margin, push, dv = tilemm.fused_fm_step(
                         pw, theta, labels, row_mask, spec, k, cfg.loss,
                         spill_pulls=sp)
-                    push = tilemm.spill_push_scatter_planes(
-                        push, dv, ovf_b, ovf_r, spec)
+                    push = fm_ovf_scatter(push, dv, ovf_b, ovf_r)
                 else:
                     margin, push = tilemm.fused_fm_step(
                         pw, theta, labels, row_mask, spec, k, cfg.loss)
@@ -399,8 +433,7 @@ class FMStore(TableCheckpoint):
                      row_mask[:, None]], axis=1)
                 push = tilemm.plane_pushes(pw, dvals, spec)
                 if oc:
-                    push = tilemm.spill_push_scatter_planes(
-                        push, dvals, ovf_b, ovf_r, spec)
+                    push = fm_ovf_scatter(push, dvals, ovf_b, ovf_r)
                 return update(table, planes, push, margin, labels,
                               row_mask, t, macc)
         else:
@@ -581,11 +614,60 @@ class FMStore(TableCheckpoint):
                 self.rt, margin_elems=info.block_rows * ch,
                 train=False))
 
+    def put_block(self, block):
+        """TableCheckpoint.put_block of a block whose overflow list, if it
+        has pairs, crosses as data/crec.spread_overflow makes it: its
+        slots spread, and with them ``ovf_u`` (its distinct buckets in
+        whole tiles, tilemm.hot_buckets; the tiles never fewer than an
+        earlier list's, a shape being a compile of the spill step) and
+        ``ovf_k`` (each slot's index in them), so that the spill step
+        reads a plane once a listed bucket and not once a pair; and the
+        count of the pairs on the list that crossed: taken here, where
+        the list is host memory, and kept for as long as the device copy
+        lives (a resident block is put once and stepped every pass)."""
+        pairs = 0
+        if isinstance(block, dict) and "ovf_b" in block:
+            pairs = int(np.count_nonzero(
+                block["ovf_b"] != np.uint32(0xFFFFFFFF)))
+        if pairs:
+            from wormhole_tpu.data.crec import spread_overflow
+            from wormhole_tpu.ops import tilemm
+            ovf_b, ovf_r, uniq, ovf_k = spread_overflow(block["ovf_b"],
+                                                        block["ovf_r"])
+            self._distinct_tiles = max(self._distinct_tiles,
+                                       -(-len(uniq) // tilemm.TILE))
+            block = dict(block, ovf_b=ovf_b, ovf_r=ovf_r, ovf_k=ovf_k,
+                         ovf_u=tilemm.hot_buckets(uniq,
+                                                  self._distinct_tiles))
+        dev = super().put_block(block)
+        if pairs:
+            key = id(dev["ovf_b"])
+            self._listed[key] = pairs
+            weakref.finalize(dev["ovf_b"], self._listed.pop, key, None)
+        return dev
+
+    def _count_step(self, block: dict) -> None:
+        """Which variant a train block took and the pairs its list holds,
+        into the timer and the registry: counts, not seconds."""
+        from wormhole_tpu.obs import metrics
+        spill_c, in_place_c, pairs_c = metrics.fm_step_metrics()
+        if "ovf_b" not in block:
+            if self.step_kernel[1] == IN_PLACE:
+                self.timer.add("fm_in_place_blocks", 1)
+                in_place_c.inc()
+            return
+        pairs = self._listed.get(id(block["ovf_b"]), 0)
+        self.timer.add("fm_spill_blocks", 1)
+        self.timer.add("fm_listed_pairs", pairs)
+        spill_c.inc()
+        pairs_c.inc(pairs)
+
     def tile_train_step(self, block: dict, info, tau: float = 0.0):
         """Fused crec2-block FM step; metrics accumulate ON DEVICE
         (fetch_metrics, same harvest pipeline as ShardedStore). Returns
         the non-donated completion ticket, never the clock."""
         step = self._tile_step(info, "train", "ovf_b" in block)
+        self._count_step(block)
         if self.step_kernel[0] == "fused":
             from wormhole_tpu.obs import trace
             with trace.span("tilemm:fused_multi", cat="tile"):

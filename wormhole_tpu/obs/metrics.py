@@ -525,6 +525,26 @@ def mesh_overflow_metrics(reg: Optional[Registry] = None):
                              "was widened to the group's widest"))
 
 
+def fm_step_metrics(reg: Optional[Registry] = None):
+    """Which variant of the one-device FM tile train step each block took
+    (models/fm.FMStore.tile_train_step) — single declaration site,
+    fetched per call like :func:`encode_counters`. A block that brings an
+    overflow list takes the spill step (the gradient-writing kernel, the
+    COO pull and scatter, one update pass in XLA), one without a list the
+    in-place kernel; a resident click-log shard whose in-place count
+    moves is stepping blocks that lost their lists."""
+    reg = reg if reg is not None else default_registry()
+    return (reg.counter("step/fm_spill_blocks",
+                        help="train blocks that took FM's spill step "
+                             "(the block brought an overflow list)"),
+            reg.counter("step/fm_in_place_blocks",
+                        help="train blocks that took FM's in-place "
+                             "kernel (no overflow list)"),
+            reg.counter("step/fm_listed_pairs",
+                        help="pairs on the overflow lists of the blocks "
+                             "that took FM's spill step"))
+
+
 def mesh_feed_gauges(reg: Optional[Registry] = None):
     """The sharded mesh-feed (data/crec.MeshGroupFeed) telemetry —
     single declaration site (lint_knobs uniqueness contract), fetched

@@ -375,3 +375,47 @@ def test_fm_spill_step_reads_a_listed_bucket_once_and_gives_the_same_bits(
     np.testing.assert_array_equal(
         np.asarray(a.tile_eval_step(dev, info)[5]),
         np.asarray(b.tile_eval_step(plain, info)[5]))
+
+
+def test_fm_put_block_ships_one_form_and_counts_the_pairs_in_both(rng):
+    """A list that ``HotRoom`` took crosses as its hot form alone, one it
+    left COO as the spread list alone; the pairs are counted from the COO
+    arrays on the host either way, keyed on whichever list array crossed,
+    and each form steps through its own program of the one spill step to
+    the same table but for the order of float32 sums."""
+    import gc
+    from wormhole_tpu.data.crec import HOT_MIN_ROOM, HotRoom, default_cap
+    n = tilemm.RSUB
+    keys, labels = _make_rows(rng, n)
+    spec = tilemm.make_spec(NB, 1, default_cap(NNZ, NB))
+    info = _Info(spec, HOT_MIN_ROOM)
+    block = {k: np.array(v) for k, v in
+             _tile_block(keys, labels, spec, oc=HOT_MIN_ROOM).items()}
+    block["ovf_b"][:300] = 5 * np.repeat(np.arange(3, dtype=np.uint32), 100)
+    block["ovf_r"][:300] = np.arange(300, dtype=np.uint32)
+    form = HotRoom().form(block["ovf_b"], block["ovf_r"], 1)
+    assert set(form) == {"ovf_u", "ovf_pw"}
+    cfg = FMConfig(num_buckets=NB, dim=4, seed=3)
+    a, b = FMStore(cfg), FMStore(cfg)
+    assert a.hot_overflow
+    hot = a.put_block(dict(block, **form))
+    coo = b.put_block(block)
+    assert set(hot) == {"pw", "labels", "ovf_u", "ovf_pw"}
+    assert set(coo) == {"pw", "labels", "ovf_b", "ovf_r", "ovf_u", "ovf_k"}
+    assert a._listed == {id(hot["ovf_pw"]): 300}
+    assert b._listed == {id(coo["ovf_b"]): 300}
+    for store, dev in ((a, hot), (b, coo)):
+        for _ in range(2):
+            store.tile_train_step(dev, info)
+        assert store.step_kernel[1] != "in place"
+        assert store.timer.totals["fm_spill_blocks"] == 2
+        assert store.timer.totals["fm_listed_pairs"] == 600
+        assert "fm_in_place_blocks" not in store.timer.totals
+    np.testing.assert_allclose(np.asarray(a.slots), np.asarray(b.slots),
+                               rtol=2e-5, atol=1e-7)
+    np.testing.assert_allclose(
+        np.asarray(a.tile_eval_step(hot, info)[5]),
+        np.asarray(b.tile_eval_step(coo, info)[5]), rtol=1e-5, atol=1e-6)
+    del hot, coo, dev
+    gc.collect()
+    assert a._listed == b._listed == {}

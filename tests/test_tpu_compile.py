@@ -117,20 +117,41 @@ def bwd_multi(spec, ch):
     return tilemm._build_bwd_multi(spec, ch), [_pw(spec), _rows(spec, ch)]
 
 
+def _hot_form(spec, tiles, vtiles):
+    """``(ovf_u, ovf_pw)`` of a hot form of ``tiles`` hot tiles."""
+    hs = tilemm.hot_spec(tiles * vtiles, spec.subblocks)
+    return [((tiles * tilemm.TILE,), jnp.uint32),
+            (hs.pairs_shape, jnp.uint32)]
+
+
 def hot_gather(spec, vtiles):
     """The overflow list through the hot tile, pull side: the gather of
     one hot tile and the three-channel pull kernel over its hot form."""
-    hs = tilemm.hot_spec(vtiles, spec.subblocks)
     return (lambda w, u, pw: tilemm.hot_margin_rows(w, u, pw, spec),
-            [((spec.nb,), jnp.float32), ((tilemm.TILE,), jnp.uint32),
-             (hs.pairs_shape, jnp.uint32)])
+            [((spec.nb,), jnp.float32), *_hot_form(spec, 1, vtiles)])
 
 
 def hot_scatter(spec, vtiles):
-    hs = tilemm.hot_spec(vtiles, spec.subblocks)
     return (lambda g, d, u, pw: tilemm.hot_grad_scatter(g, d, u, pw, spec),
             [((spec.nb,), jnp.float32), _rows(spec),
-             ((tilemm.TILE,), jnp.uint32), (hs.pairs_shape, jnp.uint32)])
+             *_hot_form(spec, 1, vtiles)])
+
+
+def fm_hot_pull(spec, k, vtiles):
+    """FM's list through the hot tile, pull side: 1 + k plane gathers of
+    one hot tile and the pull kernel over 3(k + 2) parts."""
+    from wormhole_tpu.ops.loss import opaque_one
+    plane = ((spec.tiles, tilemm.A_HI, tilemm.B_LO), jnp.float32)
+    return (lambda planes, u, pw: tilemm.fm_hot_pull_rows(
+        planes, u, pw, spec, opaque_one(planes[0])),
+        [[plane] * (1 + k), *_hot_form(spec, 1, vtiles)])
+
+
+def fm_hot_push(spec, k, vtiles):
+    plane = ((spec.tiles, tilemm.A_HI, tilemm.B_LO), jnp.float32)
+    return (lambda push, d, u, pw: tilemm.hot_push_scatter_planes(
+        push, d, u, pw, spec),
+        [[plane] * (k + 2), _rows(spec, k + 2), *_hot_form(spec, 1, vtiles)])
 
 
 def fm_step(spec, k, spill=False):
@@ -222,6 +243,10 @@ CASES = [
     # a cell, 3 channels, two tiles a step); 8 virtual tiles for 224-256
     _case(lambda: hot_gather(_criteo(2), 8), "hot_gather-8vtiles"),
     _case(lambda: hot_scatter(_criteo(2), 8), "hot_scatter-8vtiles"),
+    # FM's list through the same pair (PR 48): ten float32 channels as
+    # thirty bfloat16 parts, two tiles a step, 12 subblocks of 512 slots
+    _case(lambda: fm_hot_pull(_criteo(2), 8, 8), "fm_hot_pull-k8-8vtiles"),
+    _case(lambda: fm_hot_push(_criteo(2), 8, 8), "fm_hot_push-k8-8vtiles"),
     # by hand before a chip call: full geometry, and the other variants
     _case(lambda: fwd(_criteo()), "fwd-criteo", slow=True),
     _case(lambda: bwd(_criteo()), "bwd-criteo", slow=True),
@@ -367,11 +392,13 @@ def test_mesh_step_compiles_for_v5e_2x2(nb, v5e):
     assert compiled.memory_analysis().alias_size_in_bytes >= 3 * 4 * nb_local
 
 
-def _compile_fm_train_step(v5e, step, spec, nb: int, k: int, room: int = 0):
+def _compile_fm_train_step(v5e, step, spec, nb: int, k: int, room: int = 0,
+                           hot: tuple = ()):
     """An ``FMStore`` tile train step compiled for one described chip on
     a planar table of ``nb`` buckets; ``room``: the slots of the block's
-    COO overflow list (0: the block brings none). Returns (compiled, the
-    plane's shape struct)."""
+    COO overflow list (0: the block brings none); ``hot``: the ``(tiles,
+    vtiles)`` of the list's hot form, which then crosses in place of the
+    COO arrays. Returns (compiled, the plane's shape struct)."""
     from wormhole_tpu.learners import table as tbl
     from wormhole_tpu.learners.store import TableCheckpoint
     one_chip = SingleDeviceSharding(v5e.devices[0])
@@ -382,7 +409,11 @@ def _compile_fm_train_step(v5e, step, spec, nb: int, k: int, room: int = 0):
     plane = on(tbl.plane_shape(nb), jnp.float32)
     block = {"pw": on(spec.pairs_shape, jnp.uint32),
              "labels": on((spec.block_rows,), jnp.uint8)}
-    if room:
+    if hot:
+        # the list as HotRoom made it and FMStore.put_block ships it
+        u, pw = _hot_form(spec, *hot)
+        block.update(ovf_u=on(*u), ovf_pw=on(*pw))
+    elif room:
         # the list as FMStore.put_block ships it: with its distinct
         # buckets (two tiles hold a click-log list's 25,000) and each
         # slot's index in them
@@ -439,15 +470,22 @@ def test_fm_train_step_on_planes_compiles_for_v5e(v5e):
     assert mem.temp_size_in_bytes < 4 * nb
 
 
-def test_fm_spill_train_step_compiles_for_v5e_at_the_click_log_cells_size(v5e):
+@pytest.mark.parametrize("form", ["coo", "hot"])
+def test_fm_spill_train_step_compiles_for_v5e_at_the_click_log_cells_size(
+        v5e, form):
     """The one-device FM train step of a planar ``FMStore`` for a block
-    that brings a COO overflow list, at the size of
+    that brings an overflow list, at the size of
     ``criteo_fm_clicklog.replay_fields``: 2**26 buckets (cap 128, sixteen
-    tiles a grid step), a list room of 1,638,400 slots. The v5e compiler
+    tiles a grid step), a list room of 1,638,400 slots; ``coo``: a slot a
+    pair, as ``put_block`` ships a list that ``HotRoom`` leaves; ``hot``:
+    the same list as ``HotRoom`` makes it there (two hot tiles of 192
+    virtual tiles each, ten channels as thirty parts). The v5e compiler
     accepts it inside the chip's memory, the three XLA phases keep their
     names in the optimized HLO (each is a jit of its own, so the device
     trace can tell them apart), and nothing in it, operand or temporary,
-    is the table stacked as ``(nb, 18)``. About a minute."""
+    is the table stacked as ``(nb, 18)``; the hot program gathers and
+    scatters two hot tiles' slots a plane and nothing as long as the
+    list's room. A minute, and two for the hot one."""
     import json
     import re
     from wormhole_tpu.data.crec import CRec2Info, default_cap
@@ -471,19 +509,38 @@ def test_fm_spill_train_step_compiles_for_v5e_at_the_click_log_cells_size(v5e):
     step = store._tile_step(info, "train", True)
     assert store.step_kernel[0] == "fused"
     assert store.step_kernel[1] != IN_PLACE
-    compiled, _plane = _compile_fm_train_step(v5e, step, spec, nb, k, room)
+    compiled, _plane = _compile_fm_train_step(
+        v5e, step, spec, nb, k, room, (2, 192) if form == "hot" else ())
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") >= 1
+    assert text.count("tpu_custom_call") >= (3 if form == "hot" else 1)
     for phase in ("fm_ovf_pull", "fm_ovf_scatter", "fm_table_update"):
         assert re.search(r"jit\(%s\)" % phase, text), phase
+    # every gather and scatter of the program sits under one of the two
+    # list jits, 1 + k plane gathers and k + 2 plane scatter-adds among
+    # them; the hot form's are two hot tiles long (32,768 slots), and
+    # nothing in its program, operand or temporary, is as long as the
+    # list's room
+    for op, phase, n in (("gather", "fm_ovf_pull", 1 + k),
+                         ("scatter", "fm_ovf_scatter", k + 2)):
+        lines = [ln for ln in text.splitlines()
+                 if re.search(r" = \S+ %s\(" % op, ln)]
+        assert all("jit(fm_ovf_" in ln for ln in lines), op
+        assert sum("jit(%s)" % phase in ln for ln in lines) >= n, op
+    gathered = set(re.findall(r" = (f32\[\d+\])\S* gather\(", text))
+    if form == "hot":
+        assert str(room) not in text
+        assert gathered == {"f32[%d]" % (2 * tilemm.TILE)}, gathered
+    else:
+        assert "f32[%d]" % room in gathered
     # the table is planes throughout: no array of nb rows by some columns
     assert not re.findall(r"f32\[%d,\d+\]" % nb, text)
     # the 18 planes are donated onto the 18 results, and the program (its
-    # arguments and its temporaries: the ten push planes among them) fits
-    # the chip beside nothing else with 7 GB to spare
+    # arguments and its temporaries: the ten push planes among them, and
+    # the hot pair's operand and output, 0.4 and 0.75 GB) fits the chip
+    # beside nothing else with 6.5 GB to spare
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= 2 * (1 + k) * 4 * nb
-    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 9e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 9.5e9
 
 
 def test_wide_deep_train_step_compiles_with_the_stated_tower_precision(v5e):

@@ -114,6 +114,17 @@ class FMAdaGrad:
 # step_kernel's second field when the fused tile step is the in-place one
 IN_PLACE = "in place: the AdaGrad update runs inside the kernel"
 
+# the arrays of a tile block that are its overflow list: hot (ovf_u,
+# ovf_pw), or COO (ovf_b, ovf_r, and ovf_u, ovf_k where put_block made them)
+LIST_ARRAYS = ("ovf_b", "ovf_r", "ovf_u", "ovf_k", "ovf_pw")
+
+
+def _list_array(block: dict):
+    """The array a block's overflow list is known by, in whichever form
+    it crossed (the hot form's pair words, else the COO buckets); None
+    where the block brings no list."""
+    return block.get("ovf_pw", block.get("ovf_b"))
+
 
 def fm_margin(theta: jax.Array, batch: SparseBatch) -> jax.Array:
     """theta (kpad, 1+k): col 0 = w, cols 1: = v. Returns (mb,) margins."""
@@ -131,6 +142,10 @@ class FMStore(TableCheckpoint):
     """Sharded FM parameters + fused train/eval steps (ShardedStore
     surface, pluggable into the AsyncSGD driver)."""
 
+    # the one-device spill train step takes an overflow list in its hot
+    # form too (data/crec.HotRoom), so the app has the feeds make one
+    hot_overflow = True
+
     def __init__(self, cfg: FMConfig, runtime: Optional[MeshRuntime] = None):
         self.cfg = cfg
         self.rt = runtime
@@ -144,9 +159,11 @@ class FMStore(TableCheckpoint):
         # ShardedStore counts them
         self.timer = Timer()
         # pairs on the overflow list of each block now on the device, by
-        # the id of the list's device array (put_block, _count_step)
+        # the id of the list's device array (_list_array; put_block,
+        # _count_step)
         self._listed = {}
-        # the widest ovf_u a list has crossed with, in tiles (put_block)
+        # the widest ovf_u a COO list has crossed with, in tiles
+        # (put_block; a hot form's room is data/crec.HotRoom's)
         self._distinct_tiles = 1
         # One device and whole tiles: the tile steps take this table as
         # one float32 (T, A_HI, B_LO) plane a channel (w, v_1..v_k, cg_w,
@@ -258,16 +275,24 @@ class FMStore(TableCheckpoint):
     # float32 from the unrounded v, then rounded), and applies AdaGrad
     # (FMAdaGrad) to the touched buckets of each tile from the tile's
     # push accumulator, all planes aliased onto its outputs. A block
-    # with an overflow list needs the pushes in HBM for the COO scatter:
-    # its kernel writes a push plane a channel, and ONE elementwise pass
-    # over planes updates the donated state. A list with no pair in it
-    # stays on the host (put_block), so its block takes the first step.
+    # with an overflow list needs the pushes in HBM for the list's own
+    # pushes: its kernel writes a push plane a channel, and ONE
+    # elementwise pass over planes updates the donated state. The list
+    # comes in one of two forms (a pytree structure each, so a program
+    # each of the one jit): hot, where data/crec.HotRoom takes a train
+    # block's list (a long list of few buckets: its distinct buckets'
+    # values gathered once, the pairs through the multi-channel kernel
+    # pair over a hot tile, every float32 channel as three bfloat16
+    # parts), or COO, a gather and a scatter-add a slot (a short list,
+    # one of mostly distinct buckets, every eval block's). A list with
+    # no pair in it stays on the host (put_block), so its block takes
+    # the first step.
 
     def _tile_step(self, info, kind: str, spill: bool = True):
         """The jitted single-device tile step for a block geometry:
         ``step(table, block, t, tau, macc)`` (train) or ``step(table,
-        block)`` (eval). ``spill``: the block brings a COO overflow
-        list. Every variant computes on the float32 (T, A_HI, B_LO)
+        block)`` (eval). ``spill``: the block brings an overflow list,
+        hot or COO. Every variant computes on the float32 (T, A_HI, B_LO)
         channel planes; a planar table IS those planes and is returned
         as such, a stacked one is sliced into them and stacked again
         inside the step (ShardedStore._tile_step's contract)."""
@@ -299,36 +324,32 @@ class FMStore(TableCheckpoint):
             lab_u8 = block["labels"]
             row_mask = (lab_u8 != jnp.uint8(255)).astype(jnp.float32)
             labels = jnp.minimum(lab_u8, 1).astype(jnp.float32)
-            ovf_b = block["ovf_b"] if oc else None
-            ovf_r = block["ovf_r"] if oc else None
-            return block["pw"], labels, row_mask, ovf_b, ovf_r
-
-        def distinct(block):
-            # the list's distinct buckets and each slot's index in them,
-            # where put_block made them (a block put by other hands
-            # brings none, and its pull reads the planes a slot at a time)
-            return ((block["ovf_u"], block["ovf_k"])
-                    if oc and "ovf_u" in block else None)
+            # the overflow list in the form the block brings it: hot
+            # (ovf_u, ovf_pw: data/crec.HotRoom chose it), or COO, with
+            # its distinct buckets and each slot's index in them (ovf_u,
+            # ovf_k) where put_block made them
+            lst = ({n: block[n] for n in LIST_ARRAYS if n in block}
+                   if oc else None)
+            return block["pw"], labels, row_mask, lst
 
         def forward(planes, block):
             # the split kernel pair's forward half: the operand is ONE
             # XLA op over the w and v planes (fm_operand, which the fused
             # step runs tile by tile in VMEM)
-            pw, labels, row_mask, ovf_b, ovf_r = decode(block)
+            pw, labels, row_mask, lst = decode(block)
             one = opaque_one(row_mask)
             theta = planes[:1 + k]
             pulls = tilemm.plane_pulls(
                 pw, tilemm.fm_operand(theta[0], theta[1:], one), spec)
             if oc:
-                pulls = pulls + fm_ovf_pull(theta, ovf_b, ovf_r, one,
-                                            distinct(block))
+                pulls = pulls + fm_ovf_pull(theta, lst, one)
             s = pulls[:, 1:1 + k]
             # same guarded channel-by-channel sum the fused kernel runs
             # at its phase boundary — keeps split/fused margins bitwise
             margin = tilemm.fm_margin_math(
                 pulls[:, 0], [s[:, j] for j in range(k)], pulls[:, 1 + k],
                 one)
-            return pw, labels, row_mask, ovf_b, ovf_r, s, margin
+            return pw, labels, row_mask, lst, s, margin
 
         # The phases XLA runs around the kernel are jits of their own, so
         # that the device trace's ops say which phase they belong to (the
@@ -337,16 +358,28 @@ class FMStore(TableCheckpoint):
         # fm_ovf_pull (the listed buckets' w and v gathered plane by plane,
         # the pairs' pull channels formed unrounded and summed onto their
         # rows), fm_ovf_scatter (the pairs' dual channels added into the
-        # push planes), fm_table_update (the one elementwise pass).
+        # push planes), fm_table_update (the one elementwise pass). The
+        # first two take the list in either form: through the hot tile
+        # and the multi-channel kernel pair at 3(k + 2) parts (the planes
+        # read and the push planes added to once a distinct bucket), or
+        # a slot a pair (a block put by other hands brings no ovf_u, and
+        # its pull reads the planes a slot at a time).
         @jax.jit
-        def fm_ovf_pull(theta, ovf_b, ovf_r, one, distinct):
-            return tilemm.fm_spill_pull_rows(theta, ovf_b, ovf_r, spec, one,
-                                             distinct)
+        def fm_ovf_pull(theta, lst, one):
+            if "ovf_pw" in lst:
+                return tilemm.fm_hot_pull_rows(theta, lst["ovf_u"],
+                                               lst["ovf_pw"], spec, one)
+            return tilemm.fm_spill_pull_rows(
+                theta, lst["ovf_b"], lst["ovf_r"], spec, one,
+                (lst["ovf_u"], lst["ovf_k"]) if "ovf_k" in lst else None)
 
         @jax.jit
-        def fm_ovf_scatter(push, dvals, ovf_b, ovf_r):
-            return tilemm.spill_push_scatter_planes(push, dvals, ovf_b,
-                                                    ovf_r, spec)
+        def fm_ovf_scatter(push, dvals, lst):
+            if "ovf_pw" in lst:
+                return tilemm.hot_push_scatter_planes(
+                    push, dvals, lst["ovf_u"], lst["ovf_pw"], spec)
+            return tilemm.spill_push_scatter_planes(
+                push, dvals, lst["ovf_b"], lst["ovf_r"], spec)
 
         @jax.jit
         def fm_table_update(planes, push):
@@ -391,7 +424,7 @@ class FMStore(TableCheckpoint):
             # the update as an XLA pass, PERF.md section 6, PR 33)
             @partial(jax.jit, donate_argnums=(0, 2, 4))
             def step(table, block, t, tau, macc):
-                pw, labels, row_mask, _ovf_b, _ovf_r = decode(block)
+                pw, labels, row_mask, _lst = decode(block)
                 margin, new, wdelta2 = tilemm.fused_fm_step_update(
                     pw, tbl.planes_of(table), labels, row_mask, spec, k,
                     cfg.loss, adagrad)
@@ -402,20 +435,19 @@ class FMStore(TableCheckpoint):
             # a channel, for the one update pass in XLA. With an overflow
             # list the pre-aggregated spill pulls ride in as an extra grid
             # operand (summed into the boundary pulls) and the kernel
-            # emits the (rows, ch) dual channels, so the spill pairs'
-            # pushes scatter in XLA first
+            # emits the (rows, ch) dual channels, so the listed pairs'
+            # pushes are added in XLA first
             @partial(jax.jit, donate_argnums=(0, 2, 4))
             def step(table, block, t, tau, macc):
                 planes = tbl.planes_of(table)
-                pw, labels, row_mask, ovf_b, ovf_r = decode(block)
+                pw, labels, row_mask, lst = decode(block)
                 theta = planes[:1 + k]
                 if oc:
-                    sp = fm_ovf_pull(theta, ovf_b, ovf_r,
-                                     opaque_one(row_mask), distinct(block))
+                    sp = fm_ovf_pull(theta, lst, opaque_one(row_mask))
                     margin, push, dv = tilemm.fused_fm_step(
                         pw, theta, labels, row_mask, spec, k, cfg.loss,
                         spill_pulls=sp)
-                    push = fm_ovf_scatter(push, dv, ovf_b, ovf_r)
+                    push = fm_ovf_scatter(push, dv, lst)
                 else:
                     margin, push = tilemm.fused_fm_step(
                         pw, theta, labels, row_mask, spec, k, cfg.loss)
@@ -425,21 +457,21 @@ class FMStore(TableCheckpoint):
             @partial(jax.jit, donate_argnums=(0, 2, 4))
             def step(table, block, t, tau, macc):
                 planes = tbl.planes_of(table)
-                (pw, labels, row_mask, ovf_b, ovf_r, s,
-                 margin) = forward(planes, block)
+                pw, labels, row_mask, lst, s, margin = forward(planes,
+                                                               block)
                 dual = dual_fn(margin, labels, row_mask)
                 dvals = jnp.concatenate(
                     [dual[:, None], dual[:, None] * s,
                      row_mask[:, None]], axis=1)
                 push = tilemm.plane_pushes(pw, dvals, spec)
                 if oc:
-                    push = fm_ovf_scatter(push, dvals, ovf_b, ovf_r)
+                    push = fm_ovf_scatter(push, dvals, lst)
                 return update(table, planes, push, margin, labels,
                               row_mask, t, macc)
         else:
             @jax.jit
             def step(table, block):
-                (_, labels, row_mask, _, _, _,
+                (_, labels, row_mask, _, _,
                  margin) = forward(tbl.planes_of(table), block)
                 objv = objv_fn(margin, labels, row_mask)
                 num_ex = jnp.sum(row_mask)
@@ -616,20 +648,24 @@ class FMStore(TableCheckpoint):
 
     def put_block(self, block):
         """TableCheckpoint.put_block of a block whose overflow list, if it
-        has pairs, crosses as data/crec.spread_overflow makes it: its
-        slots spread, and with them ``ovf_u`` (its distinct buckets in
-        whole tiles, tilemm.hot_buckets; the tiles never fewer than an
-        earlier list's, a shape being a compile of the spill step) and
-        ``ovf_k`` (each slot's index in them), so that the spill step
-        reads a plane once a listed bucket and not once a pair; and the
-        count of the pairs on the list that crossed: taken here, where
-        the list is host memory, and kept for as long as the device copy
-        lives (a resident block is put once and stepped every pass)."""
+        has pairs, crosses in ONE of two forms. Hot, where the feed's
+        data/crec.HotRoom took the list (a train block's long list of few
+        buckets: ``ovf_u``, ``ovf_pw``; the COO arrays stay behind). Else
+        COO as data/crec.spread_overflow makes it: its slots spread, and
+        with them ``ovf_u`` (its distinct buckets in whole tiles,
+        tilemm.hot_buckets; the tiles never fewer than an earlier list's,
+        a shape being a compile of the spill step) and ``ovf_k`` (each
+        slot's index in them), so that the spill step reads a plane once a
+        listed bucket and not once a pair. Either way the pairs on the
+        list are counted here, from ``ovf_b`` while the list is host
+        memory, and the count is kept for as long as the device copy of
+        the list lives (a resident block is put once and stepped every
+        pass)."""
         pairs = 0
         if isinstance(block, dict) and "ovf_b" in block:
             pairs = int(np.count_nonzero(
                 block["ovf_b"] != np.uint32(0xFFFFFFFF)))
-        if pairs:
+        if pairs and "ovf_pw" not in block:
             from wormhole_tpu.data.crec import spread_overflow
             from wormhole_tpu.ops import tilemm
             ovf_b, ovf_r, uniq, ovf_k = spread_overflow(block["ovf_b"],
@@ -641,9 +677,9 @@ class FMStore(TableCheckpoint):
                                                   self._distinct_tiles))
         dev = super().put_block(block)
         if pairs:
-            key = id(dev["ovf_b"])
-            self._listed[key] = pairs
-            weakref.finalize(dev["ovf_b"], self._listed.pop, key, None)
+            lst = _list_array(dev)
+            self._listed[id(lst)] = pairs
+            weakref.finalize(lst, self._listed.pop, id(lst), None)
         return dev
 
     def _count_step(self, block: dict) -> None:
@@ -651,12 +687,13 @@ class FMStore(TableCheckpoint):
         into the timer and the registry: counts, not seconds."""
         from wormhole_tpu.obs import metrics
         spill_c, in_place_c, pairs_c = metrics.fm_step_metrics()
-        if "ovf_b" not in block:
+        lst = _list_array(block)
+        if lst is None:
             if self.step_kernel[1] == IN_PLACE:
                 self.timer.add("fm_in_place_blocks", 1)
                 in_place_c.inc()
             return
-        pairs = self._listed.get(id(block["ovf_b"]), 0)
+        pairs = self._listed.get(id(lst), 0)
         self.timer.add("fm_spill_blocks", 1)
         self.timer.add("fm_listed_pairs", pairs)
         spill_c.inc()
@@ -666,7 +703,8 @@ class FMStore(TableCheckpoint):
         """Fused crec2-block FM step; metrics accumulate ON DEVICE
         (fetch_metrics, same harvest pipeline as ShardedStore). Returns
         the non-donated completion ticket, never the clock."""
-        step = self._tile_step(info, "train", "ovf_b" in block)
+        step = self._tile_step(info, "train",
+                               _list_array(block) is not None)
         self._count_step(block)
         if self.step_kernel[0] == "fused":
             from wormhole_tpu.obs import trace
@@ -682,8 +720,8 @@ class FMStore(TableCheckpoint):
         return ticket
 
     def tile_eval_step(self, block: dict, info):
-        return self._tile_step(info, "eval", "ovf_b" in block)(
-            self._tile_table(), block)
+        step = self._tile_step(info, "eval", _list_array(block) is not None)
+        return step(self._tile_table(), block)
 
     # -- ShardedStore surface ------------------------------------------------
 

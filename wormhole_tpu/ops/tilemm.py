@@ -55,7 +55,11 @@ weights once into a hot tile and runs the pairs through the
 multi-channel kernels below over three bfloat16 channels whose sum is
 the float32 value (``split3``): the same one-hot matmuls, exact, with a
 16K-slot gather and scatter where the COO path has one a pair (the hot
-tile helpers). Which form a block takes is data/crec.HotRoom's rule.
+tile helpers). FMStore's spill step takes the same form at k + 2 float32
+channels, 3(k + 2) parts through one call of each kernel (``_hot_pull``
+and ``_hot_push`` are parameterised by the channels; FTRL's helpers are
+their one-channel case). Which form a block takes is data/crec.HotRoom's
+rule.
 
 Off the TPU backend the kernels run in Pallas interpret mode, which is
 how the CPU tests drive them; the first such build says so on the log.
@@ -290,7 +294,10 @@ def hot_spec(tiles: int, subblocks: int) -> TileSpec:
     own subblocks and group, ``HOT_CAP`` slots a cell, two tiles a grid
     step (some 12K slots of work a step at twelve subblocks, and a
     quarter of the unrolled body that eight would lower and compile on
-    every start)."""
+    every start). FM's thirty parts keep the two: one tile a step would
+    compile the pair cold in 20 + 17 s where two take 46 + 51, beside the
+    step kernel's own 37 and not after it, and cost 1.5 ms a call, 3 ms
+    of every step (PERF.md section 6, PR 48)."""
     group = max(g for g in (4, 2, 1) if subblocks % g == 0)
     return TileSpec(nb=tiles * TILE, subblocks=subblocks, cap=HOT_CAP,
                     group=group, tiles_step=2 if tiles % 2 == 0 else 1)
@@ -995,8 +1002,10 @@ def _build_bwd_multi(spec: TileSpec, ch: int, tiled: bool = False):
 # The list as (bucket, row) pairs, a gather and a scatter slot a pair:
 # what a short list takes, and one whose buckets are mostly distinct
 # (data/crec.HotRoom's rule), every list in eval, on a mesh, over a
-# stacked multi-channel table and in FM's and wide&deep's steps. A long
-# list of few buckets comes through the hot tile helpers further down.
+# stacked multi-channel table and in wide&deep's step. A long list of
+# few buckets comes through the hot tile helpers further down (FTRL's
+# one channel) and fm_hot_pull_rows / hot_push_scatter_planes (FM's
+# k + 2).
 #
 # One shared aggregation for both step formulations: the spill pairs are
 # pre-aggregated into a zero row grid, and the kernel margins/pulls get
@@ -1061,7 +1070,9 @@ def spill_push_scatter(g: jax.Array, dual_rows: jax.Array,
 # list's hot form (encode_hot): the distinct buckets' values are gathered
 # ONCE (16K slots a hot tile, where the COO helpers gather and scatter a
 # slot a pair), and the pairs run through the multi-channel kernel pair
-# above. The stated precision holds by construction: a float32 value is
+# above: _hot_pull (float32 hot tiles in, row sums out) and _hot_push
+# (float32 dual rows in, hot tiles out), for any number of channels. The
+# stated precision holds by construction: a float32 value is
 # split into three float32 parts that are each a bfloat16 value (split3),
 # so every cast to bfloat16 inside those kernels is the identity, every
 # one-hot matmul picks exactly one value, and the only sums are the row
@@ -1097,29 +1108,51 @@ def _hot_dims(ovf_u: jax.Array, ovf_pw: jax.Array, spec: TileSpec):
             jnp.where(valid, ovf_u, 0).astype(jnp.int32))
 
 
+def _hot_pull(values, ovf_pw: jax.Array, vtiles: int,
+              hs: TileSpec) -> jax.Array:
+    """``(block_rows, c)`` row sums of the hot form's pairs over ``c``
+    float32 channels, each a ``(tiles, A_HI, B_LO)`` hot tile: the
+    ``3c`` parts ride part-major on the lanes (every channel's hi, then
+    every mid, then every lo), every virtual tile a copy of its hot
+    tile, and a channel's three sums add as ``(hi + mid) + lo``."""
+    c = len(values)
+    parts = zip(*(split3(v) for v in values))
+    wt = jnp.repeat(jax.lax.concatenate(
+        [x.astype(jnp.bfloat16) for part in parts for x in part], 2),
+        vtiles, axis=0)
+    p = _build_fwd_multi(hs, HOT_CH * c, True)(ovf_pw, wt)
+    p = p.reshape(-1, HOT_CH, c)
+    return (p[:, 0] + p[:, 1]) + p[:, 2]
+
+
+def _hot_push(dual_rows: jax.Array, ovf_pw: jax.Array, tiles: int,
+              vtiles: int, hs: TileSpec) -> jax.Array:
+    """``(tiles, A_HI, c, B_LO)`` hot tiles of the pairs' ``(block_rows,
+    c)`` float32 duals: the push kernel sums each of the ``3c`` parts a
+    virtual tile, a channel's three add as ``(hi + mid) + lo``, and a
+    hot tile's virtual tiles are summed."""
+    c = dual_rows.shape[1]
+    push = _build_bwd_multi(hs, HOT_CH * c, True)(
+        ovf_pw, jnp.concatenate(split3(dual_rows), axis=1))
+    p = push.reshape(tiles, vtiles, A_HI, HOT_CH, c, B_LO)
+    return ((p[:, :, :, 0] + p[:, :, :, 1]) + p[:, :, :, 2]).sum(axis=1)
+
+
 def hot_margin_rows(w: jax.Array, ovf_u: jax.Array, ovf_pw: jax.Array,
                     spec: TileSpec) -> jax.Array:
     """spill_margin_rows from the list's hot form: ``w[ovf_u]`` is the
-    hot tile, its three channels the kernel's operand, every virtual
-    tile a copy of its hot tile."""
+    hot tile, its three parts the kernel's operand."""
     tiles, vtiles, hs, valid, idx = _hot_dims(ovf_u, ovf_pw, spec)
     wu = jnp.where(valid, w[idx], 0.0).reshape(tiles, A_HI, B_LO)
-    wt = jnp.repeat(jnp.concatenate(split3(wu), axis=-1)
-                    .astype(jnp.bfloat16), vtiles, axis=0)
-    p = _build_fwd_multi(hs, HOT_CH, True)(ovf_pw, wt)
-    return (p[:, 0] + p[:, 1]) + p[:, 2]
+    return _hot_pull([wu], ovf_pw, vtiles, hs)[:, 0]
 
 
 def hot_grad_scatter(g: jax.Array, dual_rows: jax.Array, ovf_u: jax.Array,
                      ovf_pw: jax.Array, spec: TileSpec) -> jax.Array:
     """spill_grad_scatter from the list's hot form: the pairs' duals
-    summed a (virtual tile, channel) by the push kernel, those summed to
-    the hot tile, and the hot tile added at its buckets."""
+    summed to the hot tile, and the hot tile added at its buckets."""
     tiles, vtiles, hs, valid, idx = _hot_dims(ovf_u, ovf_pw, spec)
-    push = _build_bwd_multi(hs, HOT_CH, True)(
-        ovf_pw, jnp.stack(split3(dual_rows), axis=1))
-    c = push.reshape(tiles, vtiles, A_HI, HOT_CH, B_LO)
-    gu = ((c[..., 0, :] + c[..., 1, :]) + c[..., 2, :]).sum(axis=1)
+    gu = _hot_push(dual_rows[:, None], ovf_pw, tiles, vtiles, hs)
     # scatter-fallback: ONE scatter of tiles * TILE slots (16,384 a hot
     # tile) where the COO helper scatters a slot a pair; unused slots
     # add 0 at bucket 0
@@ -1230,6 +1263,36 @@ def spill_push_scatter_planes(push, dual_rows: jax.Array, ovf_b: jax.Array,
                   0.0)
     return tuple(p.reshape(-1).at[idx].add(d[:, c]).reshape(p.shape)
                  for c, p in enumerate(push))
+
+
+def fm_hot_pull_rows(planes, ovf_u: jax.Array, ovf_pw: jax.Array,
+                     spec: TileSpec, one) -> jax.Array:
+    """fm_spill_pull_rows from the list's hot form: the w and v planes
+    are read once a distinct bucket (``tiles * TILE`` slots a plane),
+    the pull channels formed from those in float32 (Σv² from the
+    unrounded factors, as the COO helper forms it a pair), and every
+    channel runs through the hot tile as three parts: the float32
+    values to the bit, a row's pairs summed in the MXU's accumulator."""
+    tiles, vtiles, hs, valid, idx = _hot_dims(ovf_u, ovf_pw, spec)
+    got = [jnp.where(valid, p.reshape(-1)[idx], 0.0)
+           .reshape(tiles, A_HI, B_LO) for p in planes]
+    return _hot_pull(fm_pull_channels(got[0], got[1:], one), ovf_pw,
+                     vtiles, hs)
+
+
+def hot_push_scatter_planes(push, dual_rows: jax.Array, ovf_u: jax.Array,
+                            ovf_pw: jax.Array, spec: TileSpec) -> tuple:
+    """spill_push_scatter_planes from the list's hot form: a bucket's
+    duals are summed a channel in the hot tile, and each channel's hot
+    tile is added at its buckets into its push plane."""
+    tiles, vtiles, hs, valid, idx = _hot_dims(ovf_u, ovf_pw, spec)
+    gu = _hot_push(dual_rows, ovf_pw, tiles, vtiles, hs)
+    # scatter-fallback: a scatter of tiles * TILE slots a channel where
+    # the COO helper scatters a slot a pair; unused slots add 0 at
+    # bucket 0
+    return tuple(p.reshape(-1).at[idx].add(
+        jnp.where(valid, gu[:, :, c].reshape(-1), 0.0)).reshape(p.shape)
+        for c, p in enumerate(push))
 
 
 def plane_operand(planes) -> jax.Array:

@@ -30,6 +30,12 @@
 // from the count of distinct buckets and the fullest cell. wh_hot_rank's
 // hash table is the call's own (a vector); everything else is the
 // caller's.
+//
+// A list cut by the key range that owns each bucket, for a table sharded
+// over a mesh's MODEL axis (data/crec.py: cut_overflow is the written
+// specification), each part then a list of its own to the two above:
+//   int64 wh_hot_cut(ovf_b, ovf_r, n, parts, nb_local, out_b, out_r,
+//                    counts)                        -> valid pairs, or -1
 
 #include <algorithm>
 #include <cstdint>
@@ -195,6 +201,46 @@ int64_t wh_hot_rank(const uint32_t* ovf_b, const uint32_t* ovf_r, int64_t n,
   }
   *cell_max = most;
   return d;
+}
+
+// A stable partition of the list's valid pairs (bucket != the sentinel)
+// by owner: part m holds the pairs whose bucket lies in [m*nb_local,
+// (m+1)*nb_local), in list order, the bucket made local to the range. The
+// parts lie end to end in out_b / out_r (room for n), counts[m] pairs
+// each. Returns the valid pairs, or -1 where a bucket lies past the last
+// range (nothing is written then).
+int64_t wh_hot_cut(const uint32_t* ovf_b, const uint32_t* ovf_r, int64_t n,
+                   int64_t parts, uint32_t nb_local, uint32_t* out_b,
+                   uint32_t* out_r, int64_t* counts) {
+  // the owner by the ranges' ends, summed without a branch: which range
+  // a pair falls in is a coin's toss, and a mesh has a few ranges
+  std::vector<uint64_t> end(static_cast<size_t>(parts));
+  for (int64_t m = 0; m < parts; ++m)
+    end[m] = static_cast<uint64_t>(m + 1) * nb_local;
+  const auto owner = [&](uint32_t b) {
+    int64_t m = 0;
+    for (int64_t k = 0; k < parts; ++k) m += b >= end[k];
+    return m;
+  };
+  for (int64_t m = 0; m < parts; ++m) counts[m] = 0;
+  for (int64_t i = 0; i < n; ++i) {
+    const uint32_t b = ovf_b[i];
+    if (b == kSentinel) continue;
+    const int64_t m = owner(b);
+    if (m >= parts) return -1;
+    ++counts[m];
+  }
+  std::vector<int64_t> at(static_cast<size_t>(parts), 0);
+  for (int64_t m = 1; m < parts; ++m) at[m] = at[m - 1] + counts[m - 1];
+  for (int64_t i = 0; i < n; ++i) {
+    const uint32_t b = ovf_b[i];
+    if (b == kSentinel) continue;
+    const int64_t m = owner(b);
+    const int64_t j = at[m]++;
+    out_b[j] = b - static_cast<uint32_t>(m) * nb_local;
+    out_r[j] = ovf_r[i];
+  }
+  return at[parts - 1];
 }
 
 // Fill pw (tiles*vtiles, S, cap), flat, with PADWORD and place pair i,

@@ -331,7 +331,7 @@ def test_text_groups_with_lists_ring_matches_inline(tmp_path, rng,
     slabs come round again while earlier groups' per-chip slices are
     still in flight; the inner feeds' counters reach the part's Timer
     from the mesh pass beside the mesh feed's own; and the stack workers'
-    widening is a span."""
+    making of the hot form a shard is a span."""
     from wormhole_tpu.data import crec, native
     from wormhole_tpu.obs import trace
     n, rows = 10 * BR, BR
@@ -369,7 +369,9 @@ def test_text_groups_with_lists_ring_matches_inline(tmp_path, rng,
     inline = train(0)
     assert np.array_equal(np.asarray(ring.store.slots),
                           np.asarray(inline.store.slots))
-    assert "meshfeed:widen" in spans and "mesh:dispatch" in spans
+    # every group goes hot, and a hot group's COO lanes are not widened
+    assert {"meshfeed:hot", "mesh:dispatch"} <= spans
+    assert "meshfeed:widen" not in spans
     if native.get_tile_encoder() is not None:
         # twenty blocks a run came out of fewer mappings than blocks
         assert len(seen) == 40 and len(set(seen)) < 20
@@ -380,9 +382,10 @@ def test_text_groups_with_lists_ring_matches_inline(tmp_path, rng,
         assert t.get(key, 0.0) > 0.0, key
     assert "encode_stall" in t and t["mesh_steps"] == 10
     assert t["online_overflow_pairs"] > 20 * crec.ONLINE_OVF_CAP
-    # ten groups of two lists each; the room settles within the first
-    # blocks, so all but the first groups cross at the settled width
-    assert t["mesh_overflow_slots"] <= 10 * 2 * width
-    assert t["mesh_overflow_slots"] >= 8 * 2 * width
+    # ten groups of two lists each, and every one goes hot (ISSUE 49:
+    # half a block's pairs name one bucket): what crosses is a hot form
+    # a chip, and no COO lane at all
+    assert t["overflow_hot_blocks"] == 20 and t["overflow_coo_blocks"] == 0
+    assert ring._hot_room.slots > 0 and t["mesh_overflow_slots"] == 0
     assert t["mesh_widened_groups"] == inline.timer.totals[
-        "mesh_widened_groups"] <= 2
+        "mesh_widened_groups"] == 0

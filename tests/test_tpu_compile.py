@@ -22,6 +22,7 @@ is written to the cache but cannot be read back without a chip.
 """
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
 
@@ -323,10 +324,11 @@ def _table_sized_fusions(text: str, nb_local: int) -> list:
     return out
 
 
+@pytest.mark.parametrize("form", ["coo", "hot"])
 @pytest.mark.parametrize("nb", [
     pytest.param(4 * tilemm.TILE, id="2tiles-a-shard"),
     pytest.param(NB, id="criteo", marks=pytest.mark.slow)])
-def test_mesh_step_compiles_for_v5e_2x2(nb, v5e):
+def test_mesh_step_compiles_for_v5e_2x2(nb, form, v5e):
     """The whole ``data:2,model:2`` train step of the flagship store —
     shard_map, the split fwd/bwd kernels on each model shard, the psums
     — for the four described chips, with the NamedShardings the mesh
@@ -339,7 +341,12 @@ def test_mesh_step_compiles_for_v5e_2x2(nb, v5e):
     and the summed gradient and writes the three planes. Nothing is
     shaped like a stacked shard or a column sliced out of one (the
     stacked step had three such fusions: the slice of w, the push, the
-    concatenate: PERF.md, PR 45)."""
+    concatenate: PERF.md, PR 45).
+
+    ``hot``: the group's lists crossed in their hot form a shard (ISSUE
+    49), a chip's own hot tile and 104 virtual tiles of rank words (the
+    click log's half lists), and the list's two jits hold the hot kernel
+    pair: two more Mosaic calls, filed under the jits' names."""
     from jax.sharding import NamedSharding, PartitionSpec as P
     from wormhole_tpu.data.crec import CRec2Info
     from wormhole_tpu.learners import table as tbl
@@ -361,9 +368,10 @@ def test_mesh_step_compiles_for_v5e_2x2(nb, v5e):
     info = CRec2Info(nnz=39, block_rows=spec.block_rows,
                      total_rows=2 * spec.block_rows, nb=nb,
                      ovf_cap=oc, **CRITEO)
-    step = store._tile_step_mesh(info, "train")
+    hot = form == "hot"
+    step = store._tile_step_mesh(info, "train", hot)
     mesh = store.rt.mesh
-    Pm, Pblk, _ = mesh_step_specs(True, planes=True)
+    Pm, Pblk, specs = mesh_step_specs(True, planes=True, hot=hot)
     lane = P("data", None)
 
     def on(shape, dtype, spec):
@@ -374,11 +382,18 @@ def test_mesh_step_compiles_for_v5e_2x2(nb, v5e):
         tbl.PlaneTable([on(tbl.plane_shape(nb), jnp.float32, Pm)] * 3),
         on((2, *spec.pairs_shape), jnp.uint32, Pblk),
         on((2, spec.block_rows), jnp.uint8, lane),
-        on((2, oc), jnp.uint32, lane), on((2, oc), jnp.uint32, lane),
+        *(on((2, 2, *shape), jnp.uint32, sp) for (shape, _), sp in zip(
+            _hot_form(spec, 1, 104), specs[3:])) if hot else
+        (on((2, oc), jnp.uint32, lane), on((2, oc), jnp.uint32, lane)),
         on((), jnp.int32, P()), on((), jnp.float32, P()),
         on((TableCheckpoint.MACC_LEN,), jnp.float32, P())).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "all-reduce" in text
+    kernels = [line for line in text.splitlines()
+               if "custom-call(" in line and "tpu_custom_call" in line]
+    in_lists = [line for line in kernels
+                if re.search(r"jit\(mesh_ovf_(gather|scatter)\)", line)]
+    assert (len(kernels), len(in_lists)) == ((4, 2) if hot else (2, 0))
     # the list's two phases are jits of their own: the compiler keeps
     # their names on the ops it makes of them, which is what the device
     # trace files an op under (overflow_ms_per_step.mesh reads them)

@@ -1012,9 +1012,38 @@ def hot_vtiles(cell_max: int) -> int:
     return -(-overflow_room(cell_max) // (8 * HOT_CAP)) * 8
 
 
+def cut_overflow(ovf_b: np.ndarray, ovf_r: np.ndarray, parts: int,
+                 nb_local: int) -> list:
+    """One overflow list cut by owner for a table in ``parts`` key ranges
+    of ``nb_local`` buckets (a MODEL shard each, learners/store
+    ``mesh_tile_geometry``): ``[(buckets, rows)] * parts``, part ``m``
+    the list's pairs whose bucket lies in ``[m * nb_local, (m + 1) *
+    nb_local)`` in the list's order, the bucket made local to the range.
+    A stable partition: every pair is in exactly one part and unused
+    slots in none."""
+    from wormhole_tpu.ops.tilemm import UNUSED
+    valid = ovf_b != UNUSED
+    b, r = ovf_b[valid], ovf_r[valid]
+    owner = b // np.uint32(nb_local)
+    if len(owner) and int(owner.max()) >= parts:
+        raise ValueError(f"overflow bucket {int(b.max())} is outside "
+                         f"{parts} ranges of {nb_local} buckets")
+    return [(b[owner == m] - np.uint32(m * nb_local), r[owner == m])
+            for m in range(parts)]
+
+
+def _hot_encoder() -> tuple:
+    """``(ranks, place)`` of the hot form: native where the process can
+    load it (native/tile_encode.cc), else the numpy specification
+    (ops/tilemm.py ``hot_ranks`` / ``encode_hot``): the same bits."""
+    from wormhole_tpu.data import native
+    from wormhole_tpu.ops import tilemm
+    return native.get_hot_encoder() or (tilemm.hot_ranks, tilemm.encode_hot)
+
+
 class HotRoom:
     """Which form the overflow list of a block takes on its way to the
-    one-device tile step, and the room of the hot form (ops/tilemm.py,
+    tile train step, and the room of the hot form (ops/tilemm.py,
     ``encode_hot``), chosen from what the feed's worker can see of the
     list and from nothing else (no option):
 
@@ -1028,6 +1057,10 @@ class HotRoom:
       (subblock, hot tile) cell). Like :class:`OverflowRoom` the room
       grows when a block passes it and never shrinks: a room is a shape,
       and a shape is a compile of the spill step.
+
+    On a mesh the same rule is asked a group at a time, of each member's
+    list cut by the MODEL shard that owns the bucket (``form_shards``:
+    a hot form A SHARD, one room for the group's step program).
 
     One object outlives the feeds of a job and is shared by their
     workers under a lock; it counts what it chose (``drain``)."""
@@ -1045,8 +1078,8 @@ class HotRoom:
             for k, v in add.items():
                 self._counts[k] += v
 
-    def _stays_coo(self, why: str, detail: str) -> None:
-        self._count(coo_blocks=1)
+    def _stays_coo(self, why: str, detail: str, blocks: int = 1) -> None:
+        self._count(coo_blocks=blocks)
         with self._lock:
             first = why not in self._said
             self._said.add(why)
@@ -1068,6 +1101,27 @@ class HotRoom:
             self.slots = self.tiles * self.vtiles * subblocks * HOT_CAP
             return self.tiles, self.vtiles
 
+    def _take(self, b: np.ndarray, r: np.ndarray, room: int,
+              subblocks: int, blocks: int = 1) -> Optional[tuple]:
+        """The rule above asked of one list's pairs ``(b, r)`` (no
+        unused slot among them) at a room of ``room`` slots: the list
+        ranked, ``(uniq, rank, cell_max)`` (``tilemm.hot_ranks``), where
+        it takes the hot form; else None, ``blocks`` counted COO."""
+        from wormhole_tpu.ops import tilemm
+        if room < HOT_MIN_ROOM:
+            return self._stays_coo(
+                "size", f"a room of {room} slots is under {HOT_MIN_ROOM}",
+                blocks)
+        if int(r.max()) >= subblocks * tilemm.RSUB:
+            raise ValueError(f"overflow row {int(r.max())} is outside "
+                             f"a block of {subblocks} subblocks")
+        uniq, rank, cell_max = _hot_encoder()[0](b, r, subblocks)
+        if len(uniq) * HOT_MIN_SHARE > len(b):
+            return self._stays_coo(
+                "distinct", f"{len(b)} pairs name {len(uniq)} buckets, "
+                f"under {HOT_MIN_SHARE} pairs a bucket", blocks)
+        return uniq, rank, cell_max
+
     def form(self, ovf_b: np.ndarray, ovf_r: np.ndarray,
              subblocks: int) -> Optional[dict]:
         """``{"ovf_u", "ovf_pw"}`` for a block's overflow list as its
@@ -1076,33 +1130,67 @@ class HotRoom:
         native pass where the process can load it
         (native/tile_encode.cc), else the numpy specification: the same
         bits."""
-        from wormhole_tpu.data import native
         from wormhole_tpu.ops import tilemm
         n = int(np.count_nonzero(ovf_b != tilemm.UNUSED))
         if not n:
             return None
-        room = len(ovf_b)
-        if room < HOT_MIN_ROOM:
-            return self._stays_coo(
-                "size", f"a room of {room} slots is under {HOT_MIN_ROOM}")
         if (ovf_b[:n] == tilemm.UNUSED).any():
             raise ValueError("an overflow list with a hole in it: unused "
                              "slots must follow the pairs")
-        ovf_b, ovf_r = ovf_b[:n], ovf_r[:n]
-        if int(ovf_r.max()) >= subblocks * tilemm.RSUB:
-            raise ValueError(f"overflow row {int(ovf_r.max())} is outside "
-                             f"a block of {subblocks} subblocks")
-        ranks, place = native.get_hot_encoder() or (tilemm.hot_ranks,
-                                                    tilemm.encode_hot)
-        uniq, rank, cell_max = ranks(ovf_b, ovf_r, subblocks)
-        if len(uniq) * HOT_MIN_SHARE > n:
-            return self._stays_coo(
-                "distinct", f"{n} pairs name {len(uniq)} buckets, under "
-                f"{HOT_MIN_SHARE} pairs a bucket")
+        took = self._take(ovf_b[:n], ovf_r[:n], len(ovf_b), subblocks)
+        if took is None:
+            return None
+        uniq, rank, cell_max = took
         tiles, vtiles = self.fit(len(uniq), cell_max, subblocks)
-        ovf_u, ovf_pw = place(uniq, rank, ovf_r, subblocks, tiles, vtiles)
+        ovf_u, ovf_pw = _hot_encoder()[1](uniq, rank, ovf_r[:n], subblocks,
+                                          tiles, vtiles)
         self._count(hot_blocks=1, hot_buckets=len(uniq))
         return {"ovf_u": ovf_u, "ovf_pw": ovf_pw}
+
+    def form_shards(self, lists: list, parts: int, nb_local: int,
+                    subblocks: int) -> Optional[list]:
+        """The hot form A SHARD of one mesh group's overflow lists, a
+        ``{"ovf_u": (parts, tiles * TILE), "ovf_pw": (parts, ...)}`` a
+        member, or None where the group keeps its COO lanes. ``lists``
+        holds a member's ``(ovf_b, ovf_r)`` as :meth:`form` takes them.
+
+        Every list is cut by owner (:func:`cut_overflow`; one native
+        pass where the process can load it) and every part that holds a
+        pair is a list of its own to the rule (:meth:`_take`, at the
+        room :func:`overflow_room` gives its count): one part that
+        stays COO keeps the group COO, and a group with no listed pair
+        is None uncounted, as an empty list is. The room is a static
+        shape of the group's ONE step program, so every part is ranked
+        first, the room fitted once at the largest, and every part
+        placed at it by :meth:`form`'s own encoder; a part without a
+        pair is all padding. Counted a member that brought a list, as
+        :meth:`form` counts a block."""
+        from wormhole_tpu.data import native
+        from wormhole_tpu.ops.tilemm import UNUSED
+        if not any(len(ovf_b) and ovf_b[0] != UNUSED for ovf_b, _r in lists):
+            return None      # unused slots follow the pairs: no pair here
+        cut = native.get_hot_cutter() or cut_overflow
+        every = [part for ovf_b, ovf_r in lists
+                 for part in cut(ovf_b, ovf_r, parts, nb_local)]
+        listed = sum(any(len(b) for b, _r in every[i:i + parts])
+                     for i in range(0, len(every), parts))
+        ranked = []
+        for b, r in every:
+            took = (self._take(b, r, overflow_room(len(b)), subblocks,
+                               blocks=listed) if len(b) else (b, b, 0))
+            if took is None:
+                return None
+            ranked.append(took)
+        tiles, vtiles = self.fit(max(len(u) for u, _k, _c in ranked),
+                                 max(c for _u, _k, c in ranked), subblocks)
+        place = _hot_encoder()[1]
+        us, pws = zip(*(place(uniq, rank, r, subblocks, tiles, vtiles)
+                        for (uniq, rank, _c), (_b, r) in zip(ranked, every)))
+        self._count(hot_blocks=listed,
+                    hot_buckets=sum(len(u) for u, _k, _c in ranked))
+        return [{"ovf_u": np.stack(us[i:i + parts]),
+                 "ovf_pw": np.stack(pws[i:i + parts])}
+                for i in range(0, len(every), parts)]
 
     def drain(self) -> dict:
         """What was chosen since the last call: blocks that took the hot
@@ -1504,6 +1592,18 @@ class MeshGroupFeed:
     shorter lists to the group's widest with unused slots
     (:func:`widen_overflow`), since the group is one array a lane.
 
+    With ``hot`` (a :class:`HotRoom`: a train pass hands the job's) the
+    stack workers also ask it for the group's lists in their hot form A
+    SHARD (``HotRoom.form_shards``: each member's list cut by owner into
+    ``hot_parts`` key ranges, each part ranked and placed at one room).
+    Where the rule takes the group, what crosses is ``{pw, labels,
+    ovf_u, ovf_pw}`` under ``hot_shardings``: chip ``(d, m)`` receives
+    member ``d``'s part ``m`` alone, its distinct listed buckets in whole
+    hot tiles and its pairs as rank words, and the COO lanes (two
+    room-long lanes a member, to both chips of its MODEL pair) stay on
+    the host. Any other group (a part that stays COO, no listed pair, no
+    ``hot``) crosses as it always did.
+
     Yields ``(blocks_dev, labels_u8, rows)`` a group; ``labels_u8`` is
     None unless ``want_labels``. ``workers=0`` runs every stage inline on
     the consumer thread — the bit-determinism oracle, same contract as
@@ -1511,13 +1611,17 @@ class MeshGroupFeed:
 
     def __init__(self, inner, D: int, shardings, info, is_tile: bool, *,
                  workers: int = 2, depth: int = 2, online: bool = False,
-                 want_labels: bool = False, name: str = "meshfeed"):
+                 want_labels: bool = False, name: str = "meshfeed",
+                 hot=None, hot_shardings=None, hot_parts: int = 1):
         self.inner = inner
         self.D = D
         self.info = info
         self.is_tile = is_tile
         self.online = online
         self.want_labels = want_labels
+        self.hot = hot if is_tile else None
+        self.hot_parts = hot_parts
+        self._hot_shardings = hot_shardings
         self.workers = workers
         self.depth = depth
         self.name = name
@@ -1528,8 +1632,9 @@ class MeshGroupFeed:
         self.skew = {"groups": 0, "skew_sum": 0.0, "skew_max": 0.0,
                      "pad_blocks": 0}
         # transfer-thread counters (single writer): the slots of the
-        # groups' stacked list lanes as they crossed (D x the width after
-        # widening), and the groups a member of which was widened
+        # groups' stacked COO list lanes as they crossed (D x the width
+        # after widening; a hot group has none: its room is HotRoom's
+        # gauge), and the groups a member of which was widened
         self.overflow_slots = 0
         self.widened_groups = 0
         self._pipe = None
@@ -1566,13 +1671,17 @@ class MeshGroupFeed:
     def _assemble(self, item, _ctx):
         """Worker-side stage, all that is left of group assembly: a
         short tail takes the shared PAD block as its missing members,
-        an online group's overflow lists one width, and an eval pass its
-        label lanes."""
+        a train group's lists their hot form a shard where ``hot`` takes
+        them, any other online group's lists one width, and an eval
+        pass its label lanes."""
         views, rows = item
         if len(views) < self.D:
             views = views + [self._pads] * (self.D - len(views))
+        if self.hot is not None and "ovf_b" in views[0]:
+            with trace.span("meshfeed:hot", cat="feed"):
+                views = self._hot_views(views)
         widened = False
-        if self.online:
+        if self.online and "ovf_pw" not in views[0]:
             with trace.span("meshfeed:widen", cat="feed"):
                 wide = widen_overflow(views)
             widened, views = wide is not views, wide
@@ -1580,14 +1689,27 @@ class MeshGroupFeed:
                   if self.want_labels else None)
         return views, labels, rows, widened
 
+    def _hot_views(self, views: list) -> list:
+        """The group with its lists in their hot form a shard in the COO
+        lanes' place, where the room's rule takes it; else as it is."""
+        forms = self.hot.form_shards(
+            [(v["ovf_b"], v["ovf_r"]) for v in views], self.hot_parts,
+            self.info.nb // self.hot_parts, self.info.subblocks)
+        if forms is None:
+            return views
+        return [{"pw": v["pw"], "labels": v["labels"], **form}
+                for v, form in zip(views, forms)]
+
     def _transfer(self, item):
         # inside the DeviceFeed's <name>:put stage and its span
         views, labels, rows, widened = item
+        hot = self.is_tile and "ovf_pw" in views[0]
         if self.is_tile and "ovf_b" in views[0]:
             self.overflow_slots += self.D * len(views[0]["ovf_b"])
             self.widened_groups += widened
         with _timed_put(self):
-            dev = place_mesh_group(views, self._shardings)
+            dev = place_mesh_group(
+                views, self._hot_shardings if hot else self._shardings)
         return dev, labels, rows
 
     def __iter__(self):

@@ -84,9 +84,9 @@ def _load():
         return None
     try:
         lib = ctypes.CDLL(path)
-        if not hasattr(lib, "wh_hot_rank"):
+        if not hasattr(lib, "wh_hot_cut"):
             # found, but built before the tile encoder (or the overflow
-            # list's hot form) existed
+            # list's hot form, or its cut by key range) existed
             # (native/build/ is git-ignored and outlives a checkout's
             # update): without this the numpy encoder would run in
             # silence. Unload, `make` once, load what it left.
@@ -143,6 +143,12 @@ def _load():
             u32p, u32p, ctypes.c_int64, ctypes.c_int64,  # rank, ovf_r, n, S
             ctypes.c_int64, ctypes.c_int64,           # tiles, vtiles
             ctypes.c_uint32, i64p, u32p]              # cap, counts, pw
+        if hasattr(lib, "wh_hot_cut"):
+            lib.wh_hot_cut.restype = ctypes.c_int64
+            lib.wh_hot_cut.argtypes = [
+                u32p, u32p, ctypes.c_int64,           # ovf_b, ovf_r, n
+                ctypes.c_int64, ctypes.c_uint32,      # parts, nb_local
+                u32p, u32p, i64p]                     # out_b, out_r, counts
     else:
         from wormhole_tpu.utils.logging import get_logger
         what = ("hot-form encoder" if hasattr(lib, "wh_tile_count")
@@ -169,11 +175,16 @@ class _SlabPool:
     ``base`` at the array, because the mapping under it is no ndarray;
     ``jax.device_put`` holds the array until its transfer is done) the
     mapping comes back, pages in place, for the next block. At most
-    ``IDLE_BYTES`` wait idle; beyond that a mapping is let go. No lock:
+    ``IDLE_BYTES`` wait idle; beyond that a mapping is let go: room for
+    every slab a pass has alive at once, since a pass's end hands them
+    all back together (the four-chip group feed has seven to nine alive;
+    at 1 GB the pool kept five and every pass mapped two to five afresh,
+    a sixth to a third of its blocks at 240 ms each: PERF.md section 6,
+    PR 49). No lock:
     the finalizer can run on any thread, also inside ``empty`` (a
     collection), and list ``append``/``pop`` are atomic as they are."""
 
-    IDLE_BYTES = 1 << 30
+    IDLE_BYTES = 4 << 30
 
     def __init__(self) -> None:
         self._idle: list = []     # mmap objects nobody views
@@ -298,6 +309,39 @@ def get_hot_encoder():
     if lib is None or not hasattr(lib, "wh_hot_rank"):
         return None
     return _hot_ranks, _hot_place
+
+
+def _hot_cut(ovf_b: np.ndarray, ovf_r: np.ndarray, parts: int,
+             nb_local: int) -> list:
+    """``data/crec.cut_overflow`` in one native pass (wh_hot_cut): the
+    parts are views of two arrays made here."""
+    ovf_b = np.ascontiguousarray(ovf_b, np.uint32)
+    ovf_r = np.ascontiguousarray(ovf_r, np.uint32)
+    n = len(ovf_b)
+    if len(ovf_r) != n:
+        raise ValueError(f"{n} buckets, {len(ovf_r)} rows")
+    out_b = np.empty(n, np.uint32)
+    out_r = np.empty(n, np.uint32)
+    counts = np.zeros(parts, np.int64)
+    valid = _LIB.wh_hot_cut(
+        _u32p(ovf_b), _u32p(ovf_r), n, parts, nb_local, _u32p(out_b),
+        _u32p(out_r), counts.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)))
+    if valid < 0:
+        raise ValueError(f"an overflow bucket outside {parts} ranges of "
+                         f"{nb_local} buckets")
+    ends = np.cumsum(counts).tolist()
+    return [(out_b[end - c:end], out_r[end - c:end])
+            for c, end in zip(counts.tolist(), ends)]
+
+
+def get_hot_cutter():
+    """The native ``cut_overflow`` (data/crec.py has the numpy one, the
+    specification), or None when the library (or the symbol) is
+    absent."""
+    lib = _load()
+    if lib is None or not hasattr(lib, "wh_hot_cut"):
+        return None
+    return _hot_cut
 
 
 def get_crec_assembler(fmt: str, nnz: int):

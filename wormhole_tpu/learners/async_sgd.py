@@ -132,8 +132,9 @@ class AsyncSGD:
         # the job, since every pass makes a new feed (data/crec.py)
         from wormhole_tpu.data.crec import HotRoom, OverflowRoom
         self._online_room = OverflowRoom()
-        # ... and, for a store whose one-device train step takes it, the
-        # rule and the room of the lists' hot form (data/crec.HotRoom)
+        # ... and, for a store whose train step takes it (on one device,
+        # or a shard on a mesh), the rule and the room of the lists' hot
+        # form (data/crec.HotRoom)
         self._hot_room = (HotRoom() if getattr(store, "hot_overflow", False)
                           else None)
         # the one-device tile TRAIN passes' deferred metric accumulator
@@ -812,7 +813,11 @@ class AsyncSGD:
         encoder (same typed blocks as crec2).
 
         The feed is data/crec.MeshGroupFeed: groups come placed on the
-        (data, model) NamedSharding the step takes.
+        (data, model) NamedSharding the step takes. A train pass over a
+        store whose mesh step takes it (``mesh_hot_overflow``) hands the
+        feed the job's HotRoom: a group whose lists pass its rule
+        crosses as a hot form a MODEL shard, and what the room chose is
+        counted as on one device (``_count_hot``).
 
         Eval metrics are folded from batched device fetches
         (learners/window.py), and an eval pass pools one label lane a
@@ -839,12 +844,20 @@ class AsyncSGD:
             inner = self._make_feed(file, part, nparts, fmt,
                                     device_put=lambda x: x,
                                     tile_info=info if online else None)
+            # as on one device, the train step alone takes a long list
+            # of few buckets through the hot tile: here a hot form a
+            # MODEL shard, made by the group feed's stack workers
+            hot = (self._hot_room if is_tile and kind == TRAIN and getattr(
+                self.store, "mesh_hot_overflow", False) else None)
             feed = MeshGroupFeed(
                 inner, self.rt.data_axis_size,
                 mesh_group_shardings(self.rt, is_tile), info, is_tile,
                 workers=self.cfg.pipeline_workers,
                 depth=max(self.cfg.pipeline_ring, 1), online=online,
-                want_labels=kind != TRAIN and pooled is not None)
+                want_labels=kind != TRAIN and pooled is not None,
+                hot=hot, hot_parts=self.rt.model_axis_size,
+                hot_shardings=(mesh_group_shardings(self.rt, True, hot=True)
+                               if hot is not None else None))
         for payload, labels_u8, _rows in feed:
             with self.timer.scope(pfx + "dispatch"):
                 with obs.trace.span("mesh:dispatch", cat="mesh"):
@@ -868,6 +881,8 @@ class AsyncSGD:
             self.timer.add(pfx + "ici_bytes", tx.bytes_ici - ici_before)
             self.timer.add(pfx + "host_copy_bytes", feed.host_copy_bytes)
             self._export_group_feed_stats(feed)
+            if hot is not None:
+                self._count_hot(hot.drain())
         return local
 
     def _export_group_feed_stats(self, feed) -> None:

@@ -246,29 +246,43 @@ def shard_range_mask(ovb, off, nb_local):
     return valid, jnp.where(valid, bi - off, 0)
 
 
-# The mesh step's two list phases. Every chip is handed its DATA member's
-# whole list and takes the pairs whose bucket its MODEL shard owns
-# (shard_range_mask). Jits of their own, so that the device trace keeps
-# their names as an op's ``tf_op`` (the profiler keeps the path of an op
-# under a nested jit, not under a bare named scope), and module-level, so
-# that the linear mesh step has one definition of each.
+# The mesh step's two list phases, over a list in either of the two forms
+# it crosses in (data/crec.MeshGroupFeed). COO: every chip is handed its
+# DATA member's whole list and takes the pairs whose bucket its MODEL
+# shard owns (shard_range_mask), a slot a pair. Hot (``hot``, the shard's
+# TileSpec, says so): the host cut the member's list by owner, so the chip
+# is handed its OWN shard's hot form alone, ``ovb`` its distinct listed
+# buckets (local ids, whole hot tiles) and ``ovr`` the pairs as rank
+# words, and runs it through the hot kernel pair as the one-device step
+# does (tilemm.hot_margin_rows / hot_grad_scatter): nothing to mask.
+# Jits of their own, so that the device trace keeps their names as an
+# op's ``tf_op`` (the profiler keeps the path of an op under a nested
+# jit, not under a bare named scope; the hot pair's two kernels are filed
+# under them too), and module-level, so that the linear mesh step has one
+# definition of each.
 
-@partial(jax.jit, static_argnames=("nb_local",))
-def mesh_ovf_gather(mg, w, ovb, ovr, off, *, nb_local):
+@partial(jax.jit, static_argnames=("nb_local", "hot"))
+def mesh_ovf_gather(mg, w, ovb, ovr, off, *, nb_local, hot=None):
     """The shard's partial margins with its share of the listed pairs:
     ``w`` gathered unrounded at each owned pair's bucket and added onto
     the pair's row (before the margins' psum over MODEL)."""
+    if hot is not None:
+        from wormhole_tpu.ops import tilemm
+        return mg + tilemm.hot_margin_rows(w, ovb, ovr, hot)
     valid, idx = shard_range_mask(ovb, off, nb_local)
     wv = jnp.where(valid, w[idx], 0.0)
     # scatter-fallback: COO overflow spill, O(ovf_cap)
     return mg.at[ovr.astype(jnp.int32)].add(wv)
 
 
-@partial(jax.jit, static_argnames=("nb_local",))
-def mesh_ovf_scatter(g, dual, ovb, ovr, off, *, nb_local):
+@partial(jax.jit, static_argnames=("nb_local", "hot"))
+def mesh_ovf_scatter(g, dual, ovb, ovr, off, *, nb_local, hot=None):
     """The shard's gradient with its share of the listed pairs: each
     owned pair's dual gathered unrounded from its row and added into its
     bucket (before the gradient's psum over DATA)."""
+    if hot is not None:
+        from wormhole_tpu.ops import tilemm
+        return tilemm.hot_grad_scatter(g, dual, ovb, ovr, hot)
     valid, idx = shard_range_mask(ovb, off, nb_local)
     dv = jnp.where(valid, dual[ovr.astype(jnp.int32)], 0.0)
     # scatter-fallback: COO overflow spill, O(ovf_cap)
@@ -307,20 +321,25 @@ def mesh_table_spec(have_model, planes: bool = False):
     return P(first, None, None) if planes else P(first, None)
 
 
-def mesh_step_specs(have_model, planes: bool = False):
+def mesh_step_specs(have_model, planes: bool = False, hot: bool = False):
     """(Pm, Pblk, data_specs) shared by every stacked-group tile mesh
     step (linear/FM/wide&deep): the slots-table spec (with ``planes``
     the spec of each plane of a PlaneTable), the (D,T,SG,N)
     packed-word spec, and the full (slots, pw, labels, ovf_b, ovf_r)
-    in_specs prefix. One declaration keeps the three step builders and
-    :func:`mesh_group_shardings` (the feed's pre-placement layout) from
-    drifting apart."""
+    in_specs prefix; with ``hot`` the last two are the lanes of a group
+    whose lists crossed in their hot form A SHARD, (D, M, tiles * TILE)
+    ``ovf_u`` and (D, M, vtiles', SG, N') ``ovf_pw``, split over DATA
+    and MODEL: a chip holds its own shard's form only. One declaration
+    keeps the three step builders and :func:`mesh_group_shardings` (the
+    feed's pre-placement layout) from drifting apart."""
     from wormhole_tpu.parallel.mesh import DATA_AXIS
     Pm = mesh_table_spec(have_model, planes)
+    model = MODEL_AXIS if have_model else None
     Pblk = (P(DATA_AXIS, MODEL_AXIS, None, None) if have_model
             else P(DATA_AXIS, None, None, None))
-    data_specs = (Pm, Pblk, P(DATA_AXIS, None),
-                  P(DATA_AXIS, None), P(DATA_AXIS, None))
+    lists = ((P(DATA_AXIS, model, None), P(DATA_AXIS, model, None, None, None))
+             if hot else (P(DATA_AXIS, None), P(DATA_AXIS, None)))
+    data_specs = (Pm, Pblk, P(DATA_AXIS, None)) + lists
     return Pm, Pblk, data_specs
 
 
@@ -348,23 +367,27 @@ def mesh_step_ici_bytes(rt: "MeshRuntime", *, margin_elems: int,
     return n
 
 
-def mesh_group_shardings(rt: MeshRuntime, is_tile: bool):
+def mesh_group_shardings(rt: MeshRuntime, is_tile: bool, hot: bool = False):
     """NamedSharding pytree for ONE D-group, matching the mesh steps'
     in_specs exactly — the layout the sharded feed
     (data/crec.MeshGroupFeed) assembles a group on, so a pre-placed
     group enters shard_map with zero re-layout copies. Tile groups are
-    the {pw, labels, ovf_b, ovf_r} dict; v1 groups the (D, block_bytes)
-    u8 array. Chip ``(d, m)`` holds ``pw[d, m*T/M:(m+1)*T/M]`` and row
-    ``d`` of every lane, each a contiguous slice of ONE block, which is
-    what lets the feed send the block's own bytes with no stacked copy
-    (``crec.place_mesh_group``)."""
+    the {pw, labels, ovf_b, ovf_r} dict, or with ``hot`` the {pw,
+    labels, ovf_u, ovf_pw} dict of a group whose lists crossed in their
+    hot form a shard; v1 groups the (D, block_bytes) u8 array. Chip
+    ``(d, m)`` holds ``pw[d, m*T/M:(m+1)*T/M]``, row ``d`` of every COO
+    lane and ``[d, m]`` of every hot lane, each a contiguous slice of
+    ONE block's arrays, which is what lets the feed send the block's own
+    bytes with no stacked copy (``crec.place_mesh_group``)."""
     from wormhole_tpu.parallel.mesh import DATA_AXIS
     lane = rt.sharding(DATA_AXIS, None)
     if not is_tile:
         return lane
-    _Pm, Pblk, _ = mesh_step_specs(rt.have_model)
+    _Pm, Pblk, specs = mesh_step_specs(rt.have_model, hot=hot)
+    lists = ("ovf_u", "ovf_pw") if hot else ("ovf_b", "ovf_r")
     return {"pw": NamedSharding(rt.mesh, Pblk), "labels": lane,
-            "ovf_b": lane, "ovf_r": lane}
+            **{k: NamedSharding(rt.mesh, spec)
+               for k, spec in zip(lists, specs[3:])}}
 
 
 def mesh_ovf_zeros(D: int, oc: int) -> np.ndarray:
@@ -423,6 +446,11 @@ class TableCheckpoint:
     # stacked shard, so on a mesh they keep (nb, slots) and do not cross
     # every step.
     mesh_step_takes_planes = False
+
+    # Does this store's MESH tile step take a group's overflow lists in
+    # their hot form a shard (data/crec.MeshGroupFeed)? The linear
+    # store's does; FM's and wide&deep's read the COO lanes.
+    mesh_hot_overflow = False
 
     @classmethod
     def can_be_planar(cls, runtime: Optional[MeshRuntime], dtype,
@@ -650,6 +678,8 @@ class ShardedStore(TableCheckpoint):
     # this store's one-device tile steps take an overflow list in its hot
     # form too (data/crec.HotRoom), so its app has the feeds make one
     hot_overflow = True
+    # ... and so does its mesh tile step, a hot form a MODEL shard
+    mesh_hot_overflow = True
 
     def __init__(self, cfg: StoreConfig, handle: Handle,
                  runtime: Optional[MeshRuntime] = None):
@@ -1195,8 +1225,13 @@ class ShardedStore(TableCheckpoint):
     # Partial margins psum over model; gradients psum over data; the handle
     # applies shard-locally. Inputs arrive stacked on a leading data axis.
 
-    def _tile_step_mesh(self, info, kind: str):
-        key = (info, kind, "mesh")
+    def _tile_step_mesh(self, info, kind: str, hot: bool = False):
+        """The mesh step program. ``hot``: the group's lists crossed in
+        their hot form a shard (data/crec.MeshGroupFeed chose it), so
+        the two list lanes are ``ovf_u`` / ``ovf_pw`` and the two list
+        phases run the hot kernel pair; a program of its own, the COO
+        one is as it was."""
+        key = (info, kind, "mesh") + (("hot",) if hot else ())
         fn = getattr(self, "_tile_cache", {}).get(key)
         if fn is not None:
             return fn
@@ -1209,7 +1244,12 @@ class ShardedStore(TableCheckpoint):
         spec = info.spec
         nb_local, spec_local, have_model = mesh_tile_geometry(self.rt,
                                                               spec)
-        oc, R = info.ovf_cap, info.block_rows
+        oc = info.ovf_cap
+        # the list phases' static argument: a hot form reads the shard's
+        # spec, the COO list the shard's key range alone
+        form = {"nb_local": nb_local}
+        if hot:
+            form["hot"] = spec_local
 
         # Of the step's phases the device trace keeps the two that are
         # jits of their own, as an op's ``tf_op``: mesh_ovf_gather and
@@ -1235,9 +1275,10 @@ class ShardedStore(TableCheckpoint):
                 off = (jax.lax.axis_index(MODEL_AXIS) * nb_local
                        if have_model else 0)
                 if oc:
-                    ovb, ovr = ovb_l[0], ovr_l[0]
-                    mg = mesh_ovf_gather(mg, w, ovb, ovr, off,
-                                         nb_local=nb_local)
+                    # a COO lane is a DATA member's, a hot lane a chip's
+                    ovb, ovr = ((ovb_l[0, 0], ovr_l[0, 0]) if hot
+                                else (ovb_l[0], ovr_l[0]))
+                    mg = mesh_ovf_gather(mg, w, ovb, ovr, off, **form)
             with jax.named_scope("mesh_psum_margin"):
                 margin = (jax.lax.psum(mg, MODEL_AXIS) if have_model
                           else mg)
@@ -1255,8 +1296,7 @@ class ShardedStore(TableCheckpoint):
                     dual = _nudge_zero_dual(dual, labels, row_mask)
                 g = tilemm.backward_grad(pw1, dual, spec_local)
                 if oc:
-                    g = mesh_ovf_scatter(g, dual, ovb, ovr, off,
-                                         nb_local=nb_local)
+                    g = mesh_ovf_scatter(g, dual, ovb, ovr, off, **form)
             with jax.named_scope("mesh_psum_grad"):
                 g = jax.lax.psum(g, DATA_AXIS)
             with jax.named_scope("mesh_push"):
@@ -1269,7 +1309,8 @@ class ShardedStore(TableCheckpoint):
                                    pos_g, neg_g)
             return tbl.table_like(new, table_l), t + 1, macc + packed
 
-        Pm, _Pblk, data_specs = mesh_step_specs(have_model, self._planar)
+        Pm, _Pblk, data_specs = mesh_step_specs(have_model, self._planar,
+                                                hot)
         if kind == "train":
             in_specs = data_specs + (P(), P(), P())
             out_specs = (Pm, P(), P())
@@ -1300,18 +1341,21 @@ class ShardedStore(TableCheckpoint):
     def tile_train_step_mesh(self, blocks: dict, info, tau: float = 0.0):
         """Mesh tile step over ``data_axis_size`` blocks stacked on a
         leading axis: blocks = {pw (D,T,SG,N), labels (D,R),
-        ovf_b (D,O), ovf_r (D,O)}. Metrics accumulate on device
-        (fetch_metrics), cross-shard sums included; returns the step
-        clock scalar."""
+        ovf_b (D,O), ovf_r (D,O)}, or with the lists in their hot form
+        a shard {.., ovf_u (D,M,U), ovf_pw (D,M,V,SG,N)} in the COO
+        lanes' place. Metrics accumulate on device (fetch_metrics),
+        cross-shard sums included; returns the step clock scalar."""
         oc = info.ovf_cap
         D = self.rt.data_axis_size
-        step = self._tile_step_mesh(info, "train")
+        hot = "ovf_pw" in blocks
+        step = self._tile_step_mesh(info, "train", hot)
         z = mesh_ovf_zeros(D, oc)
         nb_local = mesh_tile_geometry(self.rt, info.spec)[0]
+        lists = ((blocks["ovf_u"], blocks["ovf_pw"]) if hot
+                 else (blocks.get("ovf_b", z), blocks.get("ovf_r", z)))
         self.slots, t_new, self._macc = self.mesh_transport().dispatch(
             step, self._mesh_table(tile=True), blocks["pw"],
-            blocks["labels"],
-            blocks.get("ovf_b", z), blocks.get("ovf_r", z),
+            blocks["labels"], *lists,
             self._t_device(), self._tau_const(tau), self._macc_buf(),
             ici_bytes=mesh_step_ici_bytes(
                 self.rt, margin_elems=info.block_rows,

@@ -79,6 +79,9 @@ SPAN_TABLE: Dict[str, str] = {
     # an online group's overflow lists brought to one width, inside the
     # mesh feed's <feed>:stack stage (data/crec.MeshGroupFeed._assemble)
     "meshfeed:widen": "host_prep",
+    # ... and a train group's lists cut by owner into their hot form a
+    # MODEL shard (HotRoom.form_shards), in the same stage
+    "meshfeed:hot": "host_prep",
     # metrics ticket readback on the host
     "read": "metrics_readback",
     "collective:metrics_window": "metrics_readback",

@@ -277,41 +277,8 @@ def test_wide_deep_crec2_mesh_training_converges(tmp_path, rng):
     assert prog.acc / max(prog.count, 1) > 0.7
 
 
-def test_spread_overflow_is_the_same_pairs_with_each_buckets_slots_evenly_spaced():
-    """The list as ``FMStore.put_block`` ships it: every slot once (pairs
-    and unused slots alike), whatever the width, the same order every
-    time, a bucket's slots as far from each other as its share of the
-    list allows (a cell's run of one hot bucket broken up), and with it
-    the distinct buckets and each slot's index in them."""
-    from wormhole_tpu.data.crec import spread_overflow
-    for width in (1, 1024, 1536, 1638400, 16384):
-        ovb = np.full(width, 0xFFFFFFFF, np.uint32)
-        n = width * 3 // 4
-        ovb[:n] = 7 * np.repeat(np.arange(8, dtype=np.uint32),
-                                -(-n // 8))[:n]
-        ovr = np.arange(width, dtype=np.uint32)
-        sb, sr, uniq, ovk = spread_overflow(ovb, ovr)
-        assert sb.dtype == ovb.dtype and sr.dtype == ovr.dtype
-        assert sorted(zip(sb.tolist(), sr.tolist())) == sorted(
-            zip(ovb.tolist(), ovr.tolist()))
-        again = spread_overflow(ovb, ovr)
-        for got, same in zip((sb, sr, uniq, ovk), again):
-            np.testing.assert_array_equal(got, same)
-        pair = sb != 0xFFFFFFFF
-        assert uniq.dtype == ovk.dtype == np.uint32
-        np.testing.assert_array_equal(uniq, np.unique(ovb[:n]))
-        np.testing.assert_array_equal(uniq[ovk[pair]], sb[pair])
-        assert not ovk[~pair].any()
-        if width >= 16384:
-            # a run was n/8 long; now a bucket that holds a share p of
-            # the list comes back every 1/p slots, never sooner than half
-            for b in np.unique(sb):
-                at = np.flatnonzero(sb == b)
-                assert np.diff(at).min() >= width // len(at) // 2
-
-
-def test_fm_put_block_spreads_the_list_counts_it_and_drops_an_empty_one(rng):
-    from wormhole_tpu.data.crec import default_cap, spread_overflow
+def test_fm_put_block_ships_a_coo_list_as_it_is_counts_it_and_drops_an_empty_one(rng):  # noqa: E501
+    from wormhole_tpu.data.crec import default_cap
     n = tilemm.RSUB
     keys, labels = _make_rows(rng, n)
     spec = tilemm.make_spec(NB, 1, default_cap(NNZ, NB))
@@ -319,20 +286,16 @@ def test_fm_put_block_spreads_the_list_counts_it_and_drops_an_empty_one(rng):
              _tile_block(keys, labels, spec, oc=2048).items()}
     store = FMStore(FMConfig(num_buckets=NB, dim=4, seed=3))
     dev = store.put_block(block)                    # no pair: stays behind
-    assert not {"ovf_b", "ovf_r", "ovf_u", "ovf_k"} & set(dev)
-    # a list with pairs crosses spread, with its distinct buckets in whole
-    # tiles and each slot's index in them, and its pairs are counted
+    assert set(dev) == {"pw", "labels"}
+    # a list with pairs crosses as the encoder laid it out, a slot a pair
+    # (the linear store's COO form: no array beside the two), and its
+    # pairs are counted
     block["ovf_b"][:300] = 5 * np.repeat(np.arange(3, dtype=np.uint32), 100)
     block["ovf_r"][:300] = np.arange(300, dtype=np.uint32)
     dev = store.put_block(block)
-    want = spread_overflow(block["ovf_b"], block["ovf_r"])
-    np.testing.assert_array_equal(np.asarray(dev["ovf_b"]), want[0])
-    np.testing.assert_array_equal(np.asarray(dev["ovf_r"]), want[1])
-    np.testing.assert_array_equal(np.asarray(dev["ovf_k"]), want[3])
-    ovf_u = np.asarray(dev["ovf_u"])
-    assert ovf_u.shape == (tilemm.TILE,)
-    np.testing.assert_array_equal(ovf_u[:3], [0, 5, 10])
-    assert (ovf_u[3:] == 0xFFFFFFFF).all()
+    assert set(dev) == {"pw", "labels", "ovf_b", "ovf_r"}
+    np.testing.assert_array_equal(np.asarray(dev["ovf_b"]), block["ovf_b"])
+    np.testing.assert_array_equal(np.asarray(dev["ovf_r"]), block["ovf_r"])
     assert store._listed == {id(dev["ovf_b"]): 300}
     store.tile_train_step(dev, _Info(spec, 2048))
     assert store.timer.totals["fm_spill_blocks"] == 1
@@ -343,43 +306,9 @@ def test_fm_put_block_spreads_the_list_counts_it_and_drops_an_empty_one(rng):
     assert store._listed == {}                      # gone with the device copy
 
 
-@pytest.mark.parametrize("kernel", ["fused", "split"])
-def test_fm_spill_step_reads_a_listed_bucket_once_and_gives_the_same_bits(
-        rng, kernel):
-    """A list that crossed through ``put_block`` brings its distinct
-    buckets and each slot's index in them, and the spill step then reads a
-    plane once a bucket; a block put by other hands brings the list alone
-    and the step reads a plane once a pair. The same values, so the same
-    table and the same margins to the bit, train and eval."""
-    from wormhole_tpu.data.crec import default_cap, spread_overflow
-    n = tilemm.RSUB
-    keys, labels = _make_rows(rng, n)
-    spec = tilemm.make_spec(NB, 1, default_cap(NNZ, NB))
-    info = _Info(spec, 2048)
-    block = {k: np.array(v) for k, v in
-             _tile_block(keys, labels, spec, oc=2048).items()}
-    # a few hundred listed pairs over a handful of hot buckets
-    block["ovf_b"][:600] = 11 * rng.integers(0, 9, 600).astype(np.uint32)
-    block["ovf_r"][:600] = rng.integers(0, n, 600).astype(np.uint32)
-    cfg = FMConfig(num_buckets=NB, dim=4, seed=3, tile_step_kernel=kernel)
-    a, b = FMStore(cfg), FMStore(cfg)
-    dev = a.put_block(block)
-    assert {"ovf_u", "ovf_k"} <= set(dev)
-    ovf_b, ovf_r = spread_overflow(block["ovf_b"], block["ovf_r"])[:2]
-    plain = jax.device_put(dict(block, ovf_b=ovf_b, ovf_r=ovf_r))
-    for _ in range(2):
-        a.tile_train_step(dev, info)
-        b.tile_train_step(plain, info)
-    assert a.step_kernel[0] == b.step_kernel[0] == kernel
-    np.testing.assert_array_equal(np.asarray(a.slots), np.asarray(b.slots))
-    np.testing.assert_array_equal(
-        np.asarray(a.tile_eval_step(dev, info)[5]),
-        np.asarray(b.tile_eval_step(plain, info)[5]))
-
-
 def test_fm_put_block_ships_one_form_and_counts_the_pairs_in_both(rng):
     """A list that ``HotRoom`` took crosses as its hot form alone, one it
-    left COO as the spread list alone; the pairs are counted from the COO
+    left COO as its two COO arrays alone; the pairs are counted from the COO
     arrays on the host either way, keyed on whichever list array crossed,
     and each form steps through its own program of the one spill step to
     the same table but for the order of float32 sums."""
@@ -401,7 +330,7 @@ def test_fm_put_block_ships_one_form_and_counts_the_pairs_in_both(rng):
     hot = a.put_block(dict(block, **form))
     coo = b.put_block(block)
     assert set(hot) == {"pw", "labels", "ovf_u", "ovf_pw"}
-    assert set(coo) == {"pw", "labels", "ovf_b", "ovf_r", "ovf_u", "ovf_k"}
+    assert set(coo) == {"pw", "labels", "ovf_b", "ovf_r"}
     assert a._listed == {id(hot["ovf_pw"]): 300}
     assert b._listed == {id(coo["ovf_b"]): 300}
     for store, dev in ((a, hot), (b, coo)):

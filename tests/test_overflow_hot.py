@@ -651,8 +651,8 @@ def test_each_outcome_reaches_its_fm_program_and_counter(tmp_path,
         assert t["overflow_hot_buckets"] == sum(len(np.unique(b))
                                                 for b in lists)
     else:
-        assert all(set(b) == {"pw", "labels", "ovf_b", "ovf_r", "ovf_u",
-                              "ovf_k"} for b in shipped)
+        assert all(set(b) == {"pw", "labels", "ovf_b", "ovf_r"}
+                   for b in shipped)
         assert set(traced) == {"fm_spill_pull_rows",
                                "spill_push_scatter_planes"}
         assert t["overflow_hot_blocks"] == 0 and t["overflow_coo_blocks"] == 2
@@ -669,8 +669,7 @@ def test_fm_eval_pass_keeps_the_coo_list(tmp_path, monkeypatch):
                                 app=_fm_app, helpers=FM_HELPERS,
                                 val_data=str(tmp_path / "fmev.crec"))
     assert "ovf_pw" in shipped[0] and "ovf_b" not in shipped[0]
-    assert {"ovf_b", "ovf_k"} <= set(shipped[-1])
-    assert "ovf_pw" not in shipped[-1]
+    assert set(shipped[-1]) == {"pw", "labels", "ovf_b", "ovf_r"}
     assert traced.count("fm_spill_pull_rows") == 1    # the eval program
     assert traced.count("fm_hot_pull_rows") == 1
     assert app.timer.totals["overflow_hot_blocks"] == 1

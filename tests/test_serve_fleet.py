@@ -61,11 +61,16 @@ def _owned_forwards(store, n):
 
 
 def _wait_versions(fleet, ver, timeout=15.0):
+    """Until every replica AND the publisher report ``ver``: a replica has
+    the version once it decodes the frame, the publisher (its base, its
+    frame counts) only when the broadcast that landed it returns."""
     deadline = time.monotonic() + timeout
-    while (any(v < ver for v in fleet.versions())
+    while ((any(v < ver for v in fleet.versions())
+            or fleet.publisher.version < ver)
            and time.monotonic() < deadline):
         time.sleep(0.01)
     assert fleet.versions() == [ver] * fleet.n, fleet.versions()
+    assert fleet.publisher.version == ver
 
 
 def _leaves_equal(a, b):

@@ -1,0 +1,126 @@
+"""``WideDeepStore``'s whole one-device tile train steps compiled for a
+DESCRIBED TPU v5e, without a chip (see ``test_tpu_compile.py``)."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.sharding import SingleDeviceSharding
+
+from wormhole_tpu.ops import tilemm
+
+from tpu_compile_helpers import compiled_not_interpreted, v5e  # noqa: F401
+
+
+def test_wide_deep_train_step_compiles_with_the_stated_tower_precision(v5e):
+    """The whole one-device train step of ``WideDeepStore`` at the widths of
+    ``criteo_wide_deep`` (32 values pooled into 1024-512-256), two tiles:
+    the split kernel pair with the tower between. The tower's precision is
+    stated in the program, and the v5e compiler has to keep it: every tower
+    matmul it leaves as a convolution takes bfloat16 operands, and the
+    one-column last layer, which it turns into float32 multiplies, has its
+    operands' rounding as ``reduce-precision`` (which the compiler may not
+    drop, as it dropped that layer's ``convert`` pairs: PERF.md, PR 34):
+    the activations, the weights and the incoming gradient, once each.
+    What ``criteo_wide_deep.replay_uniform`` steps."""
+    import re
+    from wormhole_tpu.data.crec import CRec2Info
+    from wormhole_tpu.learners.store import TableCheckpoint
+    from wormhole_tpu.models.wide_deep import WideDeepConfig, WideDeepStore
+    k, hidden, nb = 32, (1024, 512, 256), 2 * tilemm.TILE
+    store = WideDeepStore(WideDeepConfig(num_buckets=nb, dim=k,
+                                         hidden=hidden))
+    info = CRec2Info(nnz=39, block_rows=12 * tilemm.RSUB,
+                     total_rows=12 * tilemm.RSUB, nb=nb, ovf_cap=1024,
+                     subblocks=12, cap=128)
+    spec = info.spec
+    step = store._tile_step(info, "train")
+    assert store.step_kernel[0] == "split" and "spill" in store.step_kernel[1]
+    one_chip = SingleDeviceSharding(v5e.devices[0])
+
+    def on(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    mlp = jax.tree.map(lambda a: on(a.shape, a.dtype), store.mlp)
+    text = step.lower(
+        on((nb, 2 * (1 + k)), jnp.float32), mlp, mlp,
+        {"pw": on(spec.pairs_shape, jnp.uint32),
+         "labels": on((spec.block_rows,), jnp.uint8),
+         "ovf_b": on((1024,), jnp.uint32), "ovf_r": on((1024,), jnp.uint32)},
+        on((), jnp.int32), on((), jnp.float32),
+        on((TableCheckpoint.MACC_LEN,), jnp.float32)).compile().as_text()
+    assert text.count("tpu_custom_call") >= 2
+    tower = [line for line in text.splitlines()
+             if " convolution(" in line and "wd_tower" in line]
+    assert len(tower) >= 7, len(tower)       # the wide layers' matmuls
+    for line in tower:
+        operands = re.search(r" convolution\(([^)]*)\)", line).group(1)
+        names = [o.strip().split(" ")[-1] for o in operands.split(",")]
+        for name in names:
+            made = re.search(r"^\s*(?:ROOT )?" + re.escape(name)
+                             + r" = (\w+)\[", text, re.M)
+            assert made and made.group(1) == "bf16", (name, line[:120])
+    rounded = re.findall(r"reduce-precision\([^)]*\), exponent_bits=8, "
+                         r"mantissa_bits=7", text)
+    assert 3 <= len(rounded) <= 6, len(rounded)
+
+
+def test_wide_deep_train_step_on_planes_compiles_for_v5e(v5e):
+    """The whole one-device train step of a planar ``WideDeepStore`` at the
+    widths of ``criteo_wide_deep`` (cap 384, 33 channels pulled, 34 pushed,
+    two tiles a grid step, a 1024-pair overflow list), the table as 66
+    planes: the split kernel pair with the tower between. The v5e compiler
+    forms no ``(nb, 66)``, ``(nb, 34)`` or ``(nb, 33)`` array anywhere, and
+    neither transposes nor copies anything of a plane's size or more: the
+    operand is rounded into place, the pushes stay where the kernel wrote
+    them (the overflow rows scattered in place), the 66 planes are donated
+    onto the 66 results. The tower's matmuls still carry its name. What
+    ``criteo_wide_deep.replay_uniform`` steps."""
+    import re
+    from wormhole_tpu.data.crec import CRec2Info
+    from wormhole_tpu.learners import table as tbl
+    from wormhole_tpu.learners.store import TableCheckpoint
+    from wormhole_tpu.models.wide_deep import WideDeepConfig, WideDeepStore
+    # 1018 tiles keep a plane out of VMEM, as at the cell's 1024
+    k, hidden, nb = 32, (1024, 512, 256), 2 * 509 * tilemm.TILE
+    store = WideDeepStore(WideDeepConfig(num_buckets=2 * tilemm.TILE, dim=k,
+                                         hidden=hidden))
+    info = CRec2Info(nnz=39, block_rows=12 * tilemm.RSUB,
+                     total_rows=12 * tilemm.RSUB, nb=nb, ovf_cap=1024,
+                     subblocks=12, cap=384)
+    spec = info.spec
+    step = store._tile_step(info, "train", True)
+    assert store.step_kernel[0] == "split" and "spill" in store.step_kernel[1]
+    one_chip = SingleDeviceSharding(v5e.devices[0])
+
+    def on(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    mlp = jax.tree.map(lambda a: on(a.shape, a.dtype), store.mlp)
+    plane = on(tbl.plane_shape(nb), jnp.float32)
+    compiled = step.lower(
+        tbl.PlaneTable([plane] * (2 * (1 + k))), mlp, mlp,
+        {"pw": on(spec.pairs_shape, jnp.uint32),
+         "labels": on((spec.block_rows,), jnp.uint8),
+         "ovf_b": on((1024,), jnp.uint32), "ovf_r": on((1024,), jnp.uint32)},
+        on((), jnp.int32), on((), jnp.float32),
+        on((TableCheckpoint.MACC_LEN,), jnp.float32)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 2
+    for width in (2 * (1 + k), k + 2, k + 1):
+        assert f"[{nb},{width}]" not in text, width
+    moved = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%[\w.\-]+ = (\w+)\[([\d,]+)\]\S* "
+                     r"(transpose|copy)\(", line)
+        if m and np.prod([int(d) for d in m.group(2).split(",")]) >= nb:
+            moved.append(line.strip()[:100])
+    assert moved == []
+    tower = [line for line in text.splitlines()
+             if " convolution(" in line and "wd_tower" in line]
+    assert len(tower) >= 7, len(tower)
+    # the planes are donated onto the results; beside them the step holds
+    # the tiled pushes (the operand's buffer is free by then) and little
+    # else: nothing table-sized a second time
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * (1 + k) * 4 * nb
+    assert mem.temp_size_in_bytes < 1.1 * (k + 2) * 4 * nb

@@ -1021,7 +1021,7 @@ def cut_overflow(ovf_b: np.ndarray, ovf_r: np.ndarray, parts: int,
     nb_local)`` in the list's order, the bucket made local to the range.
     A stable partition: every pair is in exactly one part and unused
     slots in none."""
-    from wormhole_tpu.ops.tilemm import UNUSED
+    from wormhole_tpu.ops.overflow import UNUSED
     valid = ovf_b != UNUSED
     b, r = ovf_b[valid], ovf_r[valid]
     owner = b // np.uint32(nb_local)
@@ -1125,16 +1125,16 @@ class HotRoom:
     def form(self, ovf_b: np.ndarray, ovf_r: np.ndarray,
              subblocks: int) -> Optional[dict]:
         """``{"ovf_u", "ovf_pw"}`` for a block's overflow list as its
-        arrays stand (room-long, unused slots ``0xFFFFFFFF`` from the
+        arrays stand (room-long, unused slots ``UNUSED`` from the
         first on), or None where the list is empty or stays COO. One
         native pass where the process can load it
         (native/tile_encode.cc), else the numpy specification: the same
         bits."""
-        from wormhole_tpu.ops import tilemm
-        n = int(np.count_nonzero(ovf_b != tilemm.UNUSED))
+        from wormhole_tpu.ops import overflow
+        n = overflow.pairs(ovf_b)
         if not n:
             return None
-        if (ovf_b[:n] == tilemm.UNUSED).any():
+        if (ovf_b[:n] == overflow.UNUSED).any():
             raise ValueError("an overflow list with a hole in it: unused "
                              "slots must follow the pairs")
         took = self._take(ovf_b[:n], ovf_r[:n], len(ovf_b), subblocks)
@@ -1142,10 +1142,10 @@ class HotRoom:
             return None
         uniq, rank, cell_max = took
         tiles, vtiles = self.fit(len(uniq), cell_max, subblocks)
-        ovf_u, ovf_pw = _hot_encoder()[1](uniq, rank, ovf_r[:n], subblocks,
-                                          tiles, vtiles)
+        form = _hot_encoder()[1](uniq, rank, ovf_r[:n], subblocks, tiles,
+                                 vtiles)
         self._count(hot_blocks=1, hot_buckets=len(uniq))
-        return {"ovf_u": ovf_u, "ovf_pw": ovf_pw}
+        return dict(zip(overflow.HOT, form))
 
     def form_shards(self, lists: list, parts: int, nb_local: int,
                     subblocks: int) -> Optional[list]:
@@ -1166,8 +1166,9 @@ class HotRoom:
         pair is all padding. Counted a member that brought a list, as
         :meth:`form` counts a block."""
         from wormhole_tpu.data import native
-        from wormhole_tpu.ops.tilemm import UNUSED
-        if not any(len(ovf_b) and ovf_b[0] != UNUSED for ovf_b, _r in lists):
+        from wormhole_tpu.ops import overflow
+        if not any(len(ovf_b) and ovf_b[0] != overflow.UNUSED
+                   for ovf_b, _r in lists):
             return None      # unused slots follow the pairs: no pair here
         cut = native.get_hot_cutter() or cut_overflow
         every = [part for ovf_b, ovf_r in lists
@@ -1188,8 +1189,8 @@ class HotRoom:
                         for (uniq, rank, _c), (_b, r) in zip(ranked, every)))
         self._count(hot_blocks=listed,
                     hot_buckets=sum(len(u) for u, _k, _c in ranked))
-        return [{"ovf_u": np.stack(us[i:i + parts]),
-                 "ovf_pw": np.stack(pws[i:i + parts])}
+        return [dict(zip(overflow.HOT, (np.stack(us[i:i + parts]),
+                                        np.stack(pws[i:i + parts]))))
                 for i in range(0, len(every), parts)]
 
     def drain(self) -> dict:
@@ -1430,12 +1431,13 @@ def mesh_pads(info, is_tile: bool):
     pads are one all-0xFF buffer (sentinel keys AND pad labels are
     0xFF). Read-only by contract: every padded group shares them."""
     if is_tile:
+        from wormhole_tpu.ops.overflow import UNUSED
         from wormhole_tpu.ops.tilemm import PADWORD
         spec = info.spec
         return {
             "pw": np.full(spec.pairs_shape, PADWORD, np.uint32),
             "labels": np.full(info.block_rows, PAD_LABEL, np.uint8),
-            "ovf_b": np.full(max(info.ovf_cap, 1), 0xFFFFFFFF, np.uint32),
+            "ovf_b": np.full(max(info.ovf_cap, 1), UNUSED, np.uint32),
             "ovf_r": np.zeros(max(info.ovf_cap, 1), np.uint32),
         }
     return np.full(info.block_bytes, 0xFF, np.uint8)
@@ -1517,47 +1519,6 @@ def widen_overflow(views: list) -> list:
             v = dict(v, ovf_b=ob, ovf_r=orow)
         out.append(v)
     return out
-
-
-def spread_overflow(ovf_b: np.ndarray, ovf_r: np.ndarray
-                    ) -> Tuple[np.ndarray, np.ndarray, np.ndarray,
-                               np.ndarray]:
-    """``(ovf_b, ovf_r, uniq, ovf_k)`` of one overflow list, for a store
-    whose spill step walks the list a slot at a time
-    (models/fm.FMStore.put_block): the list with its slots in another
-    order, pairs and unused slots alike (new arrays; the same pairs, each
-    once), its distinct buckets in ascending order (the unused slots'
-    ``0xFFFFFFFF`` is none of them), and each slot's index in those (0 for
-    an unused slot).
-
-    The order: every bucket's slots evenly spaced over the list, the
-    ``j``-th of a bucket's ``c`` slots near ``(j + phase) / c`` of the way
-    through, the phase the bucket's own. The encoder lays a list out cell
-    by cell, and a cell's pairs past the cap are mostly its hottest bucket
-    over and over, so a step that gathers a value a slot in that order
-    asks for one address hundreds of times in a row. The distinct buckets:
-    a click-log block's list names 25,000 of them in 1.5M pairs, a dozen
-    of them 1-3% of the list each, and a gather a slot from a table plane
-    follows which addresses those are and where the plane lies (chip, nine
-    plane gathers of 1,638,400 slots: PERF.md section 6, PR 47); read once
-    a bucket, the planes are asked for 25,000 values.
-    Sums over a list do not depend on its order but for the order of
-    float32 additions. Two sorts of the list, 0.2 s for 1.6M slots."""
-    from wormhole_tpu.ops.tilemm import UNUSED
-    n = len(ovf_b)
-    by_bucket = np.argsort(ovf_b, kind="stable")
-    sb = ovf_b[by_bucket]
-    start = np.flatnonzero(np.concatenate(([True], sb[1:] != sb[:-1])))
-    count = np.diff(np.concatenate((start, [n])))
-    run = np.repeat(np.arange(len(start)), count)
-    phase = (np.arange(len(start)) * 0.6180339887498949) % 1.0
-    at = (np.arange(n) - start[run] + phase[run]) / count[run]
-    order = np.argsort(at, kind="stable")
-    uniq = sb[start]
-    ovf_k = np.where(sb != UNUSED, run, 0).astype(np.uint32)[order]
-    order = by_bucket[order]
-    return (np.take(ovf_b, order), np.take(ovf_r, order),
-            uniq[uniq != UNUSED], ovf_k)
 
 
 def mesh_group_labels(views: list, info, is_tile: bool) -> np.ndarray:
@@ -1674,6 +1635,7 @@ class MeshGroupFeed:
         a train group's lists their hot form a shard where ``hot`` takes
         them, any other online group's lists one width, and an eval
         pass its label lanes."""
+        from wormhole_tpu.ops import overflow
         views, rows = item
         if len(views) < self.D:
             views = views + [self._pads] * (self.D - len(views))
@@ -1681,7 +1643,7 @@ class MeshGroupFeed:
             with trace.span("meshfeed:hot", cat="feed"):
                 views = self._hot_views(views)
         widened = False
-        if self.online and "ovf_pw" not in views[0]:
+        if self.online and not overflow.is_hot(views[0]):
             with trace.span("meshfeed:widen", cat="feed"):
                 wide = widen_overflow(views)
             widened, views = wide is not views, wide
@@ -1702,8 +1664,9 @@ class MeshGroupFeed:
 
     def _transfer(self, item):
         # inside the DeviceFeed's <name>:put stage and its span
+        from wormhole_tpu.ops import overflow
         views, labels, rows, widened = item
-        hot = self.is_tile and "ovf_pw" in views[0]
+        hot = self.is_tile and overflow.is_hot(views[0])
         if self.is_tile and "ovf_b" in views[0]:
             self.overflow_slots += self.D * len(views[0]["ovf_b"])
             self.widened_groups += widened

@@ -24,6 +24,7 @@ the scatter, halving-to-quartering the collective bytes.
 
 from __future__ import annotations
 
+import contextlib
 import os
 from dataclasses import dataclass
 from functools import partial
@@ -37,9 +38,10 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from wormhole_tpu.data.feed import SparseBatch
 from wormhole_tpu.learners import table as tbl
 from wormhole_tpu.learners.handles import FTRLHandle, Handle
+from wormhole_tpu.ops import overflow
 from wormhole_tpu.ops.loss import create_loss
 from wormhole_tpu.ops.spmv import spmv_times, spmv_trans_times
-from wormhole_tpu.ops.metrics import accuracy, auc
+from wormhole_tpu.ops.metrics import accuracy, auc, margin_hist
 from wormhole_tpu.parallel.mesh import MODEL_AXIS, MeshRuntime
 from wormhole_tpu.utils.timer import Timer
 
@@ -176,12 +178,6 @@ def zero_grad_push_is_identity(handle: Handle) -> bool:
     return handle.penalty.lambda1 == 0.0 and handle.penalty.lambda2 == 0.0
 
 
-def _has_list(block: dict) -> bool:
-    """Does this tile block bring an overflow list, as COO pairs or in
-    its hot form (``put_block`` ships one of the two)?"""
-    return "ovf_b" in block or "ovf_pw" in block
-
-
 def _nudge_zero_dual(dual, labels, row_mask):
     """Replace exactly-zero duals of real rows with a signed 1e-30 so
     structural touch survives sigmoid saturation (see
@@ -238,11 +234,10 @@ def mesh_tile_geometry(rt, spec):
 
 def shard_range_mask(ovb, off, nb_local):
     """(valid, local_idx) of overflow COO buckets owned by this model
-    shard: the 0xFFFFFFFF pad sentinel and out-of-range buckets mask
-    out; idx is clamped to 0 where invalid (callers zero the values)."""
+    shard: unused slots and out-of-range buckets mask out; idx is
+    clamped to 0 where invalid (callers zero the values)."""
     bi = ovb.astype(jnp.int32)
-    valid = ((ovb != jnp.uint32(0xFFFFFFFF))
-             & (bi >= off) & (bi < off + nb_local))
+    valid = (ovb != overflow.UNUSED) & (bi >= off) & (bi < off + nb_local)
     return valid, jnp.where(valid, bi - off, 0)
 
 
@@ -384,10 +379,9 @@ def mesh_group_shardings(rt: MeshRuntime, is_tile: bool, hot: bool = False):
     if not is_tile:
         return lane
     _Pm, Pblk, specs = mesh_step_specs(rt.have_model, hot=hot)
-    lists = ("ovf_u", "ovf_pw") if hot else ("ovf_b", "ovf_r")
     return {"pw": NamedSharding(rt.mesh, Pblk), "labels": lane,
             **{k: NamedSharding(rt.mesh, spec)
-               for k, spec in zip(lists, specs[3:])}}
+               for k, spec in zip(overflow.names(hot), specs[3:])}}
 
 
 def mesh_ovf_zeros(D: int, oc: int) -> np.ndarray:
@@ -425,6 +419,69 @@ class StoreConfig:
                                      # plane cache inside the fused grid
                                      # (auto = VMEM budget model decides;
                                      # ops/tilemm.resolve_step_kernel)
+
+
+@dataclass(frozen=True)
+class TileStep:
+    """One variant of a store's one-device tile step, as
+    :meth:`TableCheckpoint._tile_step` resolved it, and the parts of the
+    step's program that no model owns: what a store's ``_tile_body`` is
+    handed to build its half around."""
+    spec: object          # the block geometry's TileSpec
+    oc: int               # the list's room; 0: the block brings no list
+    kind: str             # "train" | "eval"
+    fused: bool           # the one-grid kernel (train only), else the pair
+    cache: bool           # ... with the phase-shared one-hot cache
+    in_place: bool        # ... that updates the table inside the kernel
+    objv_fn: object
+
+    def decode(self, block):
+        """(pair words, labels, row_mask, list) of a block's arrays, the
+        list in the form the block brings it (ops/overflow.py: a form is
+        a pytree structure, so each is a program of the step's jit);
+        None where the step takes none."""
+        lab_u8 = block["labels"]
+        row_mask = (lab_u8 != jnp.uint8(255)).astype(jnp.float32)
+        labels = jnp.minimum(lab_u8, 1).astype(jnp.float32)
+        lst = overflow.of(block) if self.oc else None
+        return block["pw"], labels, row_mask, lst
+
+    def metrics(self, margin, labels, row_mask, objv=None):
+        """(objv, num_ex, acc, pos, neg) of a step's margins: identical
+        ops downstream of the margin buffer in every variant, so the
+        fused steps keep the split step's metric bits. ``objv``: the
+        loss where the store formed it ahead of its updates."""
+        if objv is None:
+            objv = self.objv_fn(margin, labels, row_mask)
+        num_ex = jnp.sum(row_mask)
+        acc = accuracy(labels, margin, row_mask)
+        pos, neg = margin_hist(labels, margin, row_mask)
+        return objv, num_ex, acc, pos, neg
+
+    def evaluate(self, margin, labels, row_mask):
+        """The eval step's outputs from its margins."""
+        return (*self.metrics(margin, labels, row_mask), margin)
+
+    def metric_row(self, wdelta2, margin, labels, row_mask, objv=None):
+        """(the packed metric row [objv, num_ex, acc, wdelta2, pos[bins],
+        neg[bins]], num_ex) of a train step."""
+        objv, num_ex, acc, pos, neg = self.metrics(margin, labels,
+                                                   row_mask, objv)
+        return jnp.concatenate([
+            jnp.stack([objv, num_ex, acc, wdelta2]), pos, neg]), num_ex
+
+    def finish(self, new, wdelta2, margin, labels, row_mask, t, macc):
+        """The train step's outputs from the new table and the margins.
+        Per-step metrics ADD into the donated on-device accumulator
+        ``macc``: the step returns no host-visible value at all, so the
+        steady-state loop fetches ONE (4+2*bins,) buffer a display
+        window. num_ex rides along as the caller's completion ticket:
+        unlike t+1/macc it never re-enters the donated step chain, so
+        block_until_ready on it stays legal after later steps dispatch
+        (donation is real on committed multi-device layouts, not just
+        TPU)."""
+        packed, num_ex = self.metric_row(wdelta2, margin, labels, row_mask)
+        return new, t + 1, macc + packed, num_ex
 
 
 class TableCheckpoint:
@@ -527,19 +584,9 @@ class TableCheckpoint:
         mesh, planes or not: the mesh step takes its list operands
         whatever they hold, and its groups come through
         ``crec.place_mesh_group``, not through here.)"""
-        if isinstance(block, dict) and "ovf_pw" in block:
-            # the list rides in its hot form (data/crec.HotRoom): the
-            # step reads that and nothing of the pairs themselves
-            block = {k: v for k, v in block.items()
-                     if k not in ("ovf_b", "ovf_r")}
-        elif (self._planar and self._on_one_device
-              and isinstance(block, dict) and "ovf_b" in block):
-            ovf, unused = block["ovf_b"], np.uint32(0xFFFFFFFF)
-            # writers fill the list from the front: one look settles
-            # a list that has pairs, a scan only one that seems empty
-            if not (ovf[:1] != unused).any() and not (ovf != unused).any():
-                block = {k: v for k, v in block.items()
-                         if k not in ("ovf_b", "ovf_r")}
+        if isinstance(block, dict):
+            block = overflow.crossing(
+                block, drop_empty=self._planar and self._on_one_device)
         return jax.device_put(block)
 
     def state_pytree(self):
@@ -666,6 +713,127 @@ class TableCheckpoint:
             from wormhole_tpu.parallel.transport import MeshTransport
             tx = self._mesh_tx = MeshTransport(site="mesh/step")
         return tx
+
+    # -- the one-device tile step: the ladder every store shares -------------
+    #
+    # One fused program over a tile-grouped crec2 block (data/crec.py v2 +
+    # ops/tilemm.py). What is the same for every model is here, once: the
+    # cache of built steps, the choice of kernel, the block's decoding and
+    # the metric tail (TileStep), the record of what was chosen, and the
+    # dispatch. A store supplies its model: how pulls become a margin, how
+    # duals become pushes, its update pass and which fused kernels it has
+    # (``_tile_body``), and states the few facts below about itself.
+
+    def _step_kernel_args(self, info, oc: int) -> dict:
+        """What this store tells ``tilemm.resolve_step_kernel`` beside
+        the conf's two knobs and the spec: ``ovf_cap`` and, for a
+        multi-channel model, its widths."""
+        return {"ovf_cap": oc}
+
+    def _in_place_why(self) -> Optional[str]:
+        """``step_kernel``'s second field where this store's fused step
+        updates the table inside its kernel; None where it has no such
+        kernel."""
+        return None
+
+    def _tile_body(self, ts: TileStep):
+        """The store's model half: the function ``step`` that
+        :meth:`_tile_step` jits, built around ``ts``."""
+        raise NotImplementedError
+
+    def _tile_extra(self, train: bool) -> tuple:
+        """State the tile step takes between the table and the block
+        (and, ``train``, donates and returns): wide&deep's tower."""
+        return ()
+
+    def _take_tile_extra(self, extra) -> None:
+        """Keep what a train step returned for :meth:`_tile_extra`."""
+
+    def _fused_span(self) -> str:
+        """The host span a fused train step's dispatch runs under."""
+        raise NotImplementedError
+
+    def _count_step(self, block: dict, info) -> None:
+        """What a store counts of a train step (counts, not seconds),
+        asked once ``step_kernel`` says which variant the block takes."""
+
+    def _tile_step(self, info, kind: str, spill: bool = True):
+        """The jitted single-device tile step for a block geometry:
+        ``step(table, *extra, block, t, tau, macc)`` (train) or
+        ``step(table, *extra, block)`` (eval). ``spill``: the block
+        brings an overflow list, as COO pairs or in its hot form (the jit
+        has a program for each). Every variant computes on the float32
+        (T, A_HI, B_LO) planes; a planar table IS those planes and is
+        returned as such, a stacked one (bfloat16, a serving snapshot)
+        is sliced into them and stacked again inside the step. Sets
+        ``step_kernel`` to ``(kernel, why, one-hot cache)`` of the
+        variant."""
+        key = (info, kind, spill)
+        steps = vars(self).setdefault("_tile_cache", {})
+        records = vars(self).setdefault("_tile_kernel", {})
+        if key not in steps:
+            from wormhole_tpu.ops import tilemm
+            train = kind == "train"
+            oc = info.ovf_cap if spill else 0
+            res = tilemm.resolve_step_kernel(
+                self.cfg.tile_step_kernel, spec=info.spec,
+                onehot_cache=self.cfg.tile_onehot_cache,
+                **self._step_kernel_args(info, oc))
+            # The fused one-grid step replaces the fwd/bwd pallas pair
+            # when the geometry admits it; the in-place update
+            # additionally needs a kernel that has it, no list (its
+            # scatter needs the gradient in HBM) and a single process
+            # (multihost gradients cross the wire before the update: the
+            # gradient-emitting fused variant covers both).
+            fused = res.kernel == "fused" and train
+            why = self._in_place_why() if fused and oc == 0 else None
+            in_place = why is not None and jax.process_count() == 1
+            body = self._tile_body(TileStep(
+                info.spec, oc, kind, fused, fused and res.cache, in_place,
+                self.objv_fn))
+            # table, extra state, clock and accumulator are donated where
+            # the step returns them (train)
+            n = len(self._tile_extra(train))
+            steps[key] = jax.jit(body, donate_argnums=(
+                (*range(n + 1), n + 2, n + 4) if train else ()))
+            if not train:
+                records[key] = ("split", "eval is forward-only",
+                                "onehot_cache=off:eval is forward-only")
+            else:
+                # the record names the kernel the knob names, fused or
+                # split; the fused step that updates the table in place
+                # says so where the split step gives its reason
+                records[key] = ("fused" if fused else "split",
+                                why if in_place else res.why,
+                                res.cache_record)
+        self.step_kernel = records[key]
+        return steps[key]
+
+    def tile_train_step(self, block: dict, info, tau: float = 0.0):
+        """Fused crec2-block step over a typed block dict (crec.block2_views
+        shipped to device). Metrics accumulate ON DEVICE (fetch_metrics);
+        the returned device scalar (this step's example count) exists
+        only so callers can gate the staleness window on real completion
+        — the clock itself is donated into the next step, so it is NOT
+        safe to block on."""
+        step = self._tile_step(info, "train", overflow.has_list(block))
+        self._count_step(block, info)
+        if self.step_kernel[0] == "fused":
+            from wormhole_tpu.obs import trace
+            span = trace.span(self._fused_span(), cat="tile")
+        else:
+            span = contextlib.nullcontext()
+        with span:
+            self.slots, *extra, t_new, self._macc, ticket = step(
+                self._tile_table(), *self._tile_extra(True), block,
+                self._t_device(), self._tau_const(tau), self._macc_buf())
+        self._take_tile_extra(extra)
+        self._advance_t(t_new)
+        return ticket
+
+    def tile_eval_step(self, block: dict, info):
+        return self._tile_step(info, "eval", overflow.has_list(block))(
+            self._tile_table(), *self._tile_extra(False), block)
 
 
 class ShardedStore(TableCheckpoint):
@@ -909,7 +1077,6 @@ class ShardedStore(TableCheckpoint):
         if fn is not None:
             return fn
         exact_dense = zero_grad_push_is_identity(self.handle)
-        from wormhole_tpu.ops.metrics import margin_hist
         from wormhole_tpu.parallel.mesh import DATA_AXIS, shard_map_compat
         handle, objv_fn, dual_fn = self.handle, self.objv_fn, self.dual_fn
         mesh = self.rt.mesh
@@ -1025,81 +1192,21 @@ class ShardedStore(TableCheckpoint):
     # FTRL variant touches the table only inside its kernel, the others in
     # one elementwise pass over the planes and the gradient.
 
-    def _tile_step(self, info, kind: str, spill: bool = True):
-        """The jitted single-device tile step for a block geometry:
-        ``step(table, block, t, tau, macc)`` (train) or ``step(table,
-        block)`` (eval). ``spill``: the block brings an overflow list,
-        as COO pairs or in its hot form (the jit has a program for
-        each). Every variant computes on the float32 (T, A_HI, B_LO)
-        planes; a planar table IS those planes and is returned as such,
-        a stacked one (bfloat16, a serving snapshot) is
-        sliced into them and stacked again inside the step."""
-        key = (info, kind, spill)
-        fn = getattr(self, "_tile_cache", {}).get(key)
-        if fn is not None:
-            self.step_kernel = self._tile_kernel[key]
-            return fn
-        exact_dense = zero_grad_push_is_identity(self.handle)
+    def _in_place_why(self) -> Optional[str]:
+        # the in-place kernel runs FTRLHandle.update on a tile
+        return IN_PLACE if isinstance(self.handle, FTRLHandle) else None
+
+    def _fused_span(self) -> str:
+        return ("tilemm:fused_cached"
+                if self.step_kernel[2] == "onehot_cache=on"
+                else "tilemm:fused_step")
+
+    def _tile_body(self, ts: TileStep):
         from wormhole_tpu.ops import tilemm
-        from wormhole_tpu.ops.metrics import margin_hist
-        handle, objv_fn, dual_fn = self.handle, self.objv_fn, self.dual_fn
-        spec = info.spec
-        oc = info.ovf_cap if spill else 0
+        exact_dense = zero_grad_push_is_identity(self.handle)
+        handle, dual_fn = self.handle, self.dual_fn
+        spec, oc, fused, cache = ts.spec, ts.oc, ts.fused, ts.cache
         loss_name = self.cfg.loss
-        # The fused one-grid step replaces the fwd/bwd pallas pair when
-        # the geometry admits it; the in-place slot update additionally
-        # needs an FTRL handle, no spill (the COO scatter needs the grad
-        # in HBM) and a single process (multihost gradients cross the
-        # wire before the update — the grad-emitting fused variant
-        # covers both).
-        res = tilemm.resolve_step_kernel(
-            getattr(self.cfg, "tile_step_kernel", "auto"), ovf_cap=oc,
-            spec=spec,
-            onehot_cache=getattr(self.cfg, "tile_onehot_cache", "auto"))
-        fused = res.kernel == "fused" and kind == "train"
-        cache = fused and res.cache
-        fused_update = (fused and oc == 0
-                        and isinstance(handle, FTRLHandle)
-                        and jax.process_count() == 1)
-
-        def planes_of(table):
-            if isinstance(table, tbl.PlaneTable):
-                return table.planes
-            return tbl.split(table.astype(jnp.float32))
-
-        def table_of(planes, like):
-            if isinstance(like, tbl.PlaneTable):
-                return tbl.PlaneTable(planes)
-            return tbl.join(planes).astype(like.dtype)
-
-        def decode(block):
-            lab_u8 = block["labels"]
-            row_mask = (lab_u8 != jnp.uint8(255)).astype(jnp.float32)
-            labels = jnp.minimum(lab_u8, 1).astype(jnp.float32)
-            # the overflow list in the form the block brings it: hot
-            # (ovf_u, ovf_pw: data/crec.HotRoom chose it) or COO. A form
-            # is a pytree structure, so each is a program of this jit
-            names = (("ovf_u", "ovf_pw") if "ovf_pw" in block
-                     else ("ovf_b", "ovf_r"))
-            lst = {k: block[k] for k in names} if oc else None
-            return block["pw"], labels, row_mask, lst
-
-        def finish(new, wdelta2, margin, labels, row_mask, t, macc):
-            # shared metric tail — identical ops downstream of the
-            # margin buffer in every variant, so the fused paths keep
-            # the split path's metric bits
-            objv = objv_fn(margin, labels, row_mask)
-            num_ex = jnp.sum(row_mask)
-            acc = accuracy(labels, margin, row_mask)
-            pos, neg = margin_hist(labels, margin, row_mask)
-            packed = jnp.concatenate([
-                jnp.stack([objv, num_ex, acc, wdelta2]), pos, neg])
-            # num_ex rides along as the caller's completion ticket:
-            # unlike t+1/macc it never re-enters the donated step
-            # chain, so block_until_ready on it stays legal after
-            # later steps dispatch (donation is real on committed
-            # multi-device layouts, not just TPU)
-            return new, t + 1, macc + packed, num_ex
 
         # The phases XLA runs around the kernels are jits of their own,
         # so that the device trace's ops say which phase they belong to
@@ -1112,19 +1219,15 @@ class ShardedStore(TableCheckpoint):
         # and the multi-channel kernel pair, or a slot a pair.
         @jax.jit
         def tile_ovf_gather(w, lst):
-            if "ovf_pw" in lst:
-                return tilemm.hot_margin_rows(w, lst["ovf_u"],
-                                              lst["ovf_pw"], spec)
-            return tilemm.spill_margin_rows(w, lst["ovf_b"], lst["ovf_r"],
-                                            spec)
+            helper, first, second = overflow.pick(
+                lst, tilemm.spill_margin_rows, tilemm.hot_margin_rows)
+            return helper(w, first, second, spec)
 
         @jax.jit
         def tile_ovf_scatter(grad, dual, lst):
-            if "ovf_pw" in lst:
-                return tilemm.hot_grad_scatter(grad, dual, lst["ovf_u"],
-                                               lst["ovf_pw"], spec)
-            return tilemm.spill_grad_scatter(grad, dual, lst["ovf_b"],
-                                             lst["ovf_r"], spec)
+            helper, first, second = overflow.pick(
+                lst, tilemm.spill_grad_scatter, tilemm.hot_grad_scatter)
+            return helper(grad, dual, first, second, spec)
 
         @jax.jit
         def tile_table_update(planes, grad, t, tau):
@@ -1132,24 +1235,18 @@ class ShardedStore(TableCheckpoint):
                 handle, planes, grad.reshape(planes[0].shape),
                 t.astype(jnp.float32), tau, exact_dense)
 
-        if fused_update:
-            @partial(jax.jit, donate_argnums=(0, 2, 4))
+        if ts.in_place:
             def step(table, block, t, tau, macc):
-                pw, labels, row_mask, _lst = decode(block)
+                pw, labels, row_mask, _lst = ts.decode(block)
                 margin, new, wdelta2 = tilemm.fused_step_update(
-                    pw, planes_of(table), labels, row_mask, spec,
+                    pw, tbl.planes_of(table), labels, row_mask, spec,
                     loss_name, handle, cache=cache)
-                return finish(table_of(new, table), wdelta2, margin,
-                              labels, row_mask, t, macc)
-        elif kind == "train":
-            # per-step metrics ADD into a donated on-device accumulator:
-            # the step returns no host-visible value at all, so the
-            # steady-state loop fetches ONE (4+2*bins,) buffer per display
-            # window instead of stacking per-step vectors
-            @partial(jax.jit, donate_argnums=(0, 2, 4))
+                return ts.finish(tbl.table_like(new, table), wdelta2,
+                                 margin, labels, row_mask, t, macc)
+        elif ts.kind == "train":
             def step(table, block, t, tau, macc):
-                pw, labels, row_mask, lst = decode(block)
-                planes = planes_of(table)
+                pw, labels, row_mask, lst = ts.decode(block)
+                planes = tbl.planes_of(table)
                 w = handle.weights(tbl.PlaneTable(planes))
                 # the overflow pairs' margins, pre-aggregated onto
                 # their rows: ONE grid add on the split path, one extra
@@ -1180,40 +1277,17 @@ class ShardedStore(TableCheckpoint):
                 if oc:
                     grad = tile_ovf_scatter(grad, dual, lst)
                 new, wdelta2 = tile_table_update(planes, grad, t, tau)
-                return finish(table_of(new, table), wdelta2, margin,
-                              labels, row_mask, t, macc)
+                return ts.finish(tbl.table_like(new, table), wdelta2,
+                                 margin, labels, row_mask, t, macc)
         else:
-            @jax.jit
             def step(table, block):
-                pw, labels, row_mask, lst = decode(block)
-                w = handle.weights(tbl.PlaneTable(planes_of(table)))
+                pw, labels, row_mask, lst = ts.decode(block)
+                w = handle.weights(tbl.PlaneTable(tbl.planes_of(table)))
                 margin = tilemm.forward_margins(pw, w, spec)
                 if oc:
                     margin = margin + tile_ovf_gather(w, lst)
-                objv = objv_fn(margin, labels, row_mask)
-                num_ex = jnp.sum(row_mask)
-                acc = accuracy(labels, margin, row_mask)
-                pos, neg = margin_hist(labels, margin, row_mask)
-                return objv, num_ex, acc, pos, neg, margin
+                return ts.evaluate(margin, labels, row_mask)
 
-        if not hasattr(self, "_tile_cache"):
-            self._tile_cache = {}
-        if not hasattr(self, "_tile_kernel"):
-            self._tile_kernel = {}
-        if kind != "train":
-            resolved, why = "split", "eval is forward-only"
-            cache_rec = "onehot_cache=off:eval is forward-only"
-        else:
-            # the record names the kernel the knob names, fused or split;
-            # the fused step that updates the table in place says so
-            # where the split step gives its reason
-            why, cache_rec = res.why, res.cache_record
-            resolved = "fused" if fused else "split"
-            if fused_update:
-                why = IN_PLACE
-        self._tile_kernel[key] = (resolved, why, cache_rec)
-        self.step_kernel = self._tile_kernel[key]
-        self._tile_cache[key] = step
         return step
 
     # -- tile step over a data x model mesh ---------------------------------
@@ -1237,7 +1311,6 @@ class ShardedStore(TableCheckpoint):
             return fn
         exact_dense = zero_grad_push_is_identity(self.handle)
         from wormhole_tpu.ops import tilemm
-        from wormhole_tpu.ops.metrics import margin_hist
         from wormhole_tpu.parallel.mesh import DATA_AXIS, shard_map_compat
         handle, objv_fn, dual_fn = self.handle, self.objv_fn, self.dual_fn
         mesh = self.rt.mesh
@@ -1347,12 +1420,11 @@ class ShardedStore(TableCheckpoint):
         cross-shard sums included; returns the step clock scalar."""
         oc = info.ovf_cap
         D = self.rt.data_axis_size
-        hot = "ovf_pw" in blocks
+        hot = overflow.is_hot(blocks)
         step = self._tile_step_mesh(info, "train", hot)
         z = mesh_ovf_zeros(D, oc)
         nb_local = mesh_tile_geometry(self.rt, info.spec)[0]
-        lists = ((blocks["ovf_u"], blocks["ovf_pw"]) if hot
-                 else (blocks.get("ovf_b", z), blocks.get("ovf_r", z)))
+        lists = [blocks.get(k, z) for k in overflow.names(hot)]
         self.slots, t_new, self._macc = self.mesh_transport().dispatch(
             step, self._mesh_table(tile=True), blocks["pw"],
             blocks["labels"], *lists,
@@ -1373,37 +1445,6 @@ class ShardedStore(TableCheckpoint):
             blocks.get("ovf_b", z), blocks.get("ovf_r", z),
             ici_bytes=mesh_step_ici_bytes(
                 self.rt, margin_elems=info.block_rows, train=False))
-
-    def tile_train_step(self, block: dict, info, tau: float = 0.0):
-        """Fused crec2-block step over a typed block dict (crec.block2_views
-        shipped to device). Metrics accumulate ON DEVICE (fetch_metrics);
-        the returned device scalar (this step's example count) exists
-        only so callers can gate the staleness window on real completion
-        — the clock itself is donated into the next step, so it is NOT
-        safe to block on."""
-        step = self._tile_step(info, "train", _has_list(block))
-        if self.step_kernel[0].startswith("fused"):
-            from wormhole_tpu.obs import trace
-            if self.step_kernel[2] == "onehot_cache=on":
-                with trace.span("tilemm:fused_cached", cat="tile"):
-                    self.slots, t_new, self._macc, ticket = step(
-                        self._tile_table(), block, self._t_device(),
-                        self._tau_const(tau), self._macc_buf())
-            else:
-                with trace.span("tilemm:fused_step", cat="tile"):
-                    self.slots, t_new, self._macc, ticket = step(
-                        self._tile_table(), block, self._t_device(),
-                        self._tau_const(tau), self._macc_buf())
-        else:
-            self.slots, t_new, self._macc, ticket = step(
-                self._tile_table(), block, self._t_device(),
-                self._tau_const(tau), self._macc_buf())
-        self._advance_t(t_new)
-        return ticket
-
-    def tile_eval_step(self, block: dict, info):
-        return self._tile_step(info, "eval", _has_list(block))(
-            self._tile_table(), block)
 
     # -- split pull/push pipeline (delay-tolerant DT2 path) -----------------
     #

@@ -33,10 +33,12 @@ import numpy as np
 from wormhole_tpu.data.feed import SparseBatch
 from wormhole_tpu.learners import table as tbl
 from wormhole_tpu.learners.store import (TableCheckpoint,
+                                          TileStep,
                                           factor_table,
                                           mesh_ovf_zeros,
                                           mesh_step_ici_bytes,
                                           mesh_tile_geometry)
+from wormhole_tpu.ops import overflow
 from wormhole_tpu.ops.loss import create_loss
 from wormhole_tpu.ops.metrics import accuracy, auc
 from wormhole_tpu.ops.penalty import L1L2
@@ -114,18 +116,6 @@ class FMAdaGrad:
 # step_kernel's second field when the fused tile step is the in-place one
 IN_PLACE = "in place: the AdaGrad update runs inside the kernel"
 
-# the arrays of a tile block that are its overflow list: hot (ovf_u,
-# ovf_pw), or COO (ovf_b, ovf_r, and ovf_u, ovf_k where put_block made them)
-LIST_ARRAYS = ("ovf_b", "ovf_r", "ovf_u", "ovf_k", "ovf_pw")
-
-
-def _list_array(block: dict):
-    """The array a block's overflow list is known by, in whichever form
-    it crossed (the hot form's pair words, else the COO buckets); None
-    where the block brings no list."""
-    return block.get("ovf_pw", block.get("ovf_b"))
-
-
 def fm_margin(theta: jax.Array, batch: SparseBatch) -> jax.Array:
     """theta (kpad, 1+k): col 0 = w, cols 1: = v. Returns (mb,) margins."""
     w = theta[:, 0]
@@ -159,12 +149,9 @@ class FMStore(TableCheckpoint):
         # ShardedStore counts them
         self.timer = Timer()
         # pairs on the overflow list of each block now on the device, by
-        # the id of the list's device array (_list_array; put_block,
+        # the id of the list's device array (overflow.array; put_block,
         # _count_step)
         self._listed = {}
-        # the widest ovf_u a COO list has crossed with, in tiles
-        # (put_block; a hot form's room is data/crec.HotRoom's)
-        self._distinct_tiles = 1
         # One device and whole tiles: the tile steps take this table as
         # one float32 (T, A_HI, B_LO) plane a channel (w, v_1..v_k, cg_w,
         # cg_v_1..k; learners/table.py), the multi-channel kernel's own
@@ -288,55 +275,30 @@ class FMStore(TableCheckpoint):
     # no pair in it stays on the host (put_block), so its block takes
     # the first step.
 
-    def _tile_step(self, info, kind: str, spill: bool = True):
-        """The jitted single-device tile step for a block geometry:
-        ``step(table, block, t, tau, macc)`` (train) or ``step(table,
-        block)`` (eval). ``spill``: the block brings an overflow list,
-        hot or COO. Every variant computes on the float32 (T, A_HI, B_LO)
-        channel planes; a planar table IS those planes and is returned
-        as such, a stacked one is sliced into them and stacked again
-        inside the step (ShardedStore._tile_step's contract)."""
-        key = (info, kind, spill)
-        fn = getattr(self, "_tile_cache", {}).get(key)
-        if fn is not None:
-            self.step_kernel = self._tile_kernel[key]
-            return fn
+    def _step_kernel_args(self, info, oc: int) -> dict:
+        return {"ovf_cap": oc, "channels": self.cfg.dim + 2}
+
+    def _in_place_why(self) -> str:
+        return IN_PLACE
+
+    def _fused_span(self) -> str:
+        return "tilemm:fused_multi"
+
+    def _tile_body(self, ts: TileStep):
         from wormhole_tpu.ops import tilemm
         from wormhole_tpu.ops.loss import opaque_one
-        from wormhole_tpu.ops.metrics import margin_hist
         cfg = self.cfg
         k = cfg.dim
-        objv_fn, dual_fn = self.objv_fn, self.dual_fn
+        dual_fn = self.dual_fn
         adagrad = FMAdaGrad(cfg.lr_alpha, cfg.lr_beta, cfg.l2_v,
                             L1L2(cfg.l1, cfg.l2))
-        spec = info.spec
-        oc = info.ovf_cap if spill else 0
-        res = tilemm.resolve_step_kernel(
-            getattr(cfg, "tile_step_kernel", "auto"), ovf_cap=oc,
-            spec=spec, channels=k + 2,
-            onehot_cache=getattr(cfg, "tile_onehot_cache", "auto"))
-        fused = res.kernel == "fused" and kind == "train"
-        # without an overflow list the update runs inside the kernel, a
-        # tile at a time (the COO scatter needs the pushes in HBM)
-        in_place = fused and oc == 0 and jax.process_count() == 1
-
-        def decode(block):
-            lab_u8 = block["labels"]
-            row_mask = (lab_u8 != jnp.uint8(255)).astype(jnp.float32)
-            labels = jnp.minimum(lab_u8, 1).astype(jnp.float32)
-            # the overflow list in the form the block brings it: hot
-            # (ovf_u, ovf_pw: data/crec.HotRoom chose it), or COO, with
-            # its distinct buckets and each slot's index in them (ovf_u,
-            # ovf_k) where put_block made them
-            lst = ({n: block[n] for n in LIST_ARRAYS if n in block}
-                   if oc else None)
-            return block["pw"], labels, row_mask, lst
+        spec, oc = ts.spec, ts.oc
 
         def forward(planes, block):
             # the split kernel pair's forward half: the operand is ONE
             # XLA op over the w and v planes (fm_operand, which the fused
             # step runs tile by tile in VMEM)
-            pw, labels, row_mask, lst = decode(block)
+            pw, labels, row_mask, lst = ts.decode(block)
             one = opaque_one(row_mask)
             theta = planes[:1 + k]
             pulls = tilemm.plane_pulls(
@@ -354,7 +316,7 @@ class FMStore(TableCheckpoint):
         # The phases XLA runs around the kernel are jits of their own, so
         # that the device trace's ops say which phase they belong to (the
         # profiler keeps the path of an op under a nested jit, not under
-        # a bare named scope; ShardedStore._tile_step does the same):
+        # a bare named scope; ShardedStore._tile_body does the same):
         # fm_ovf_pull (the listed buckets' w and v gathered plane by plane,
         # the pairs' pull channels formed unrounded and summed onto their
         # rows), fm_ovf_scatter (the pairs' dual channels added into the
@@ -362,24 +324,19 @@ class FMStore(TableCheckpoint):
         # first two take the list in either form: through the hot tile
         # and the multi-channel kernel pair at 3(k + 2) parts (the planes
         # read and the push planes added to once a distinct bucket), or
-        # a slot a pair (a block put by other hands brings no ovf_u, and
-        # its pull reads the planes a slot at a time).
+        # a slot a pair.
         @jax.jit
         def fm_ovf_pull(theta, lst, one):
-            if "ovf_pw" in lst:
-                return tilemm.fm_hot_pull_rows(theta, lst["ovf_u"],
-                                               lst["ovf_pw"], spec, one)
-            return tilemm.fm_spill_pull_rows(
-                theta, lst["ovf_b"], lst["ovf_r"], spec, one,
-                (lst["ovf_u"], lst["ovf_k"]) if "ovf_k" in lst else None)
+            helper, first, second = overflow.pick(
+                lst, tilemm.fm_spill_pull_rows, tilemm.fm_hot_pull_rows)
+            return helper(theta, first, second, spec, one)
 
         @jax.jit
         def fm_ovf_scatter(push, dvals, lst):
-            if "ovf_pw" in lst:
-                return tilemm.hot_push_scatter_planes(
-                    push, dvals, lst["ovf_u"], lst["ovf_pw"], spec)
-            return tilemm.spill_push_scatter_planes(
-                push, dvals, lst["ovf_b"], lst["ovf_r"], spec)
+            helper, first, second = overflow.pick(
+                lst, tilemm.spill_push_scatter_planes,
+                tilemm.hot_push_scatter_planes)
+            return helper(push, dvals, first, second, spec)
 
         @jax.jit
         def fm_table_update(planes, push):
@@ -398,49 +355,33 @@ class FMStore(TableCheckpoint):
 
         def update(table, planes, push, margin, labels, row_mask, t, macc):
             new, wdelta2 = fm_table_update(tuple(planes), tuple(push))
-            return finish(tbl.table_like(new, table), wdelta2, margin,
-                          labels, row_mask, t, macc)
+            return ts.finish(tbl.table_like(new, table), wdelta2, margin,
+                             labels, row_mask, t, macc)
 
-        def finish(new, wdelta2, margin, labels, row_mask, t, macc):
-            # the metric tail: identical ops downstream of the margins in
-            # every variant
-            objv = objv_fn(margin, labels, row_mask)
-            num_ex = jnp.sum(row_mask)
-            from wormhole_tpu.ops.metrics import accuracy
-            acc = accuracy(labels, margin, row_mask)
-            pos, neg = margin_hist(labels, margin, row_mask)
-            packed = jnp.concatenate([
-                jnp.stack([objv, num_ex, acc, wdelta2]), pos, neg])
-            # num_ex = completion ticket; the clock/macc outputs are
-            # donated into the next step (see ShardedStore._tile_step)
-            return new, t + 1, macc + packed, num_ex
-
-        if in_place:
+        if ts.in_place:
             # all 2(1+k) planes go into the kernel as they are, aliased
             # onto its outputs: phase 1 rounds the operand from the w and
             # v tiles, phase 2 updates each tile from its accumulator.
             # Neither the pushes nor anything else table-sized exists
             # outside the call (chip: 74.7 ms a step against 81.1 with
             # the update as an XLA pass, PERF.md section 6, PR 33)
-            @partial(jax.jit, donate_argnums=(0, 2, 4))
             def step(table, block, t, tau, macc):
-                pw, labels, row_mask, _lst = decode(block)
+                pw, labels, row_mask, _lst = ts.decode(block)
                 margin, new, wdelta2 = tilemm.fused_fm_step_update(
                     pw, tbl.planes_of(table), labels, row_mask, spec, k,
                     cfg.loss, adagrad)
-                return finish(tbl.table_like(new, table), wdelta2, margin,
-                              labels, row_mask, t, macc)
-        elif fused:
+                return ts.finish(tbl.table_like(new, table), wdelta2,
+                                 margin, labels, row_mask, t, macc)
+        elif ts.fused:
             # the kernel reads the w and v planes and writes a push plane
             # a channel, for the one update pass in XLA. With an overflow
             # list the pre-aggregated spill pulls ride in as an extra grid
             # operand (summed into the boundary pulls) and the kernel
             # emits the (rows, ch) dual channels, so the listed pairs'
             # pushes are added in XLA first
-            @partial(jax.jit, donate_argnums=(0, 2, 4))
             def step(table, block, t, tau, macc):
                 planes = tbl.planes_of(table)
-                pw, labels, row_mask, lst = decode(block)
+                pw, labels, row_mask, lst = ts.decode(block)
                 theta = planes[:1 + k]
                 if oc:
                     sp = fm_ovf_pull(theta, lst, opaque_one(row_mask))
@@ -453,8 +394,7 @@ class FMStore(TableCheckpoint):
                         pw, theta, labels, row_mask, spec, k, cfg.loss)
                 return update(table, planes, push, margin, labels,
                               row_mask, t, macc)
-        elif kind == "train":
-            @partial(jax.jit, donate_argnums=(0, 2, 4))
+        elif ts.kind == "train":
             def step(table, block, t, tau, macc):
                 planes = tbl.planes_of(table)
                 pw, labels, row_mask, lst, s, margin = forward(planes,
@@ -469,31 +409,11 @@ class FMStore(TableCheckpoint):
                 return update(table, planes, push, margin, labels,
                               row_mask, t, macc)
         else:
-            @jax.jit
             def step(table, block):
                 (_, labels, row_mask, _, _,
                  margin) = forward(tbl.planes_of(table), block)
-                objv = objv_fn(margin, labels, row_mask)
-                num_ex = jnp.sum(row_mask)
-                from wormhole_tpu.ops.metrics import accuracy
-                acc = accuracy(labels, margin, row_mask)
-                pos, neg = margin_hist(labels, margin, row_mask)
-                return objv, num_ex, acc, pos, neg, margin
+                return ts.evaluate(margin, labels, row_mask)
 
-        if not hasattr(self, "_tile_cache"):
-            self._tile_cache = {}
-        if not hasattr(self, "_tile_kernel"):
-            self._tile_kernel = {}
-        if kind != "train":
-            self._tile_kernel[key] = (
-                "split", "eval is forward-only",
-                "onehot_cache=off:eval is forward-only")
-        else:
-            self._tile_kernel[key] = ("fused" if fused else "split",
-                                      IN_PLACE if in_place else res.why,
-                                      res.cache_record)
-        self.step_kernel = self._tile_kernel[key]
-        self._tile_cache[key] = step
         return step
 
     def _tile_step_mesh(self, info, kind: str):
@@ -647,47 +567,27 @@ class FMStore(TableCheckpoint):
                 train=False))
 
     def put_block(self, block):
-        """TableCheckpoint.put_block of a block whose overflow list, if it
-        has pairs, crosses in ONE of two forms. Hot, where the feed's
-        data/crec.HotRoom took the list (a train block's long list of few
-        buckets: ``ovf_u``, ``ovf_pw``; the COO arrays stay behind). Else
-        COO as data/crec.spread_overflow makes it: its slots spread, and
-        with them ``ovf_u`` (its distinct buckets in whole tiles,
-        tilemm.hot_buckets; the tiles never fewer than an earlier list's,
-        a shape being a compile of the spill step) and ``ovf_k`` (each
-        slot's index in them), so that the spill step reads a plane once a
-        listed bucket and not once a pair. Either way the pairs on the
-        list are counted here, from ``ovf_b`` while the list is host
-        memory, and the count is kept for as long as the device copy of
-        the list lives (a resident block is put once and stepped every
-        pass)."""
+        """TableCheckpoint.put_block, with the pairs on the block's list
+        counted: from ``ovf_b`` while the list is host memory, whichever
+        form crosses, and the count kept for as long as the device copy
+        of the list lives (a resident block is put once and stepped
+        every pass)."""
         pairs = 0
         if isinstance(block, dict) and "ovf_b" in block:
-            pairs = int(np.count_nonzero(
-                block["ovf_b"] != np.uint32(0xFFFFFFFF)))
-        if pairs and "ovf_pw" not in block:
-            from wormhole_tpu.data.crec import spread_overflow
-            from wormhole_tpu.ops import tilemm
-            ovf_b, ovf_r, uniq, ovf_k = spread_overflow(block["ovf_b"],
-                                                        block["ovf_r"])
-            self._distinct_tiles = max(self._distinct_tiles,
-                                       -(-len(uniq) // tilemm.TILE))
-            block = dict(block, ovf_b=ovf_b, ovf_r=ovf_r, ovf_k=ovf_k,
-                         ovf_u=tilemm.hot_buckets(uniq,
-                                                  self._distinct_tiles))
+            pairs = overflow.pairs(block["ovf_b"])
         dev = super().put_block(block)
         if pairs:
-            lst = _list_array(dev)
+            lst = overflow.array(dev)
             self._listed[id(lst)] = pairs
             weakref.finalize(lst, self._listed.pop, id(lst), None)
         return dev
 
-    def _count_step(self, block: dict) -> None:
+    def _count_step(self, block: dict, info) -> None:
         """Which variant a train block took and the pairs its list holds,
         into the timer and the registry: counts, not seconds."""
         from wormhole_tpu.obs import metrics
         spill_c, in_place_c, pairs_c = metrics.fm_step_metrics()
-        lst = _list_array(block)
+        lst = overflow.array(block)
         if lst is None:
             if self.step_kernel[1] == IN_PLACE:
                 self.timer.add("fm_in_place_blocks", 1)
@@ -698,30 +598,6 @@ class FMStore(TableCheckpoint):
         self.timer.add("fm_listed_pairs", pairs)
         spill_c.inc()
         pairs_c.inc(pairs)
-
-    def tile_train_step(self, block: dict, info, tau: float = 0.0):
-        """Fused crec2-block FM step; metrics accumulate ON DEVICE
-        (fetch_metrics, same harvest pipeline as ShardedStore). Returns
-        the non-donated completion ticket, never the clock."""
-        step = self._tile_step(info, "train",
-                               _list_array(block) is not None)
-        self._count_step(block)
-        if self.step_kernel[0] == "fused":
-            from wormhole_tpu.obs import trace
-            with trace.span("tilemm:fused_multi", cat="tile"):
-                self.slots, t_new, self._macc, ticket = step(
-                    self._tile_table(), block, self._t_device(),
-                    self._tau_const(tau), self._macc_buf())
-        else:
-            self.slots, t_new, self._macc, ticket = step(
-                self._tile_table(), block, self._t_device(),
-                self._tau_const(tau), self._macc_buf())
-        self._advance_t(t_new)
-        return ticket
-
-    def tile_eval_step(self, block: dict, info):
-        step = self._tile_step(info, "eval", _list_array(block) is not None)
-        return step(self._tile_table(), block)
 
     # -- ShardedStore surface ------------------------------------------------
 

@@ -32,6 +32,7 @@ import numpy as np
 from wormhole_tpu.data.feed import SparseBatch
 from wormhole_tpu.learners import table as tbl
 from wormhole_tpu.learners.store import (TableCheckpoint,
+                                          TileStep,
                                           factor_table,
                                           mesh_ovf_zeros,
                                           mesh_step_ici_bytes,
@@ -242,48 +243,55 @@ class WideDeepStore(TableCheckpoint):
     # of form rides in the step's own update pass and is no pass of its
     # own (so no ``table_cross``), at the price of one more program.
 
-    def _tile_step(self, info, kind: str, spill: bool = True):
-        """The jitted single-device tile step for a block geometry:
-        ``step(table, mlp, accum, block, t, tau, macc)`` (train) or
-        ``step(table, mlp, block)`` (eval). ``spill``: the block brings
-        a COO overflow list. Every variant updates the float32
-        (T, A_HI, B_LO) channel planes; a planar table IS those planes,
-        a stacked one is sliced into them inside the step. A store that
-        keeps planes gets planes back either way; one that does not, the
-        stacked array (ShardedStore._tile_step's contract)."""
-        key = (info, kind, spill)
-        fn = getattr(self, "_tile_cache", {}).get(key)
-        if fn is not None:
-            self.step_kernel = self._tile_kernel[key]
-            return fn
-        from wormhole_tpu.ops import tilemm
-        from wormhole_tpu.ops.metrics import margin_hist
-        cfg = self.cfg
-        k = cfg.dim
-        oc = info.ovf_cap if spill else 0
-        # the MLP runs in-kernel at the fused phase boundary when
-        # its row-blocked weights fit the VMEM budget; a file whose blocks
+    def _step_kernel_args(self, info, oc: int) -> dict:
+        # the MLP runs in-kernel at the fused phase boundary when its
+        # row-blocked weights fit the VMEM budget; a file whose blocks
         # can spill (with a list or, this once, without) and oversized
         # hidden widths fall back split with a recorded reason
-        res = tilemm.resolve_step_kernel(
-            getattr(cfg, "tile_step_kernel", "auto"), ovf_cap=info.ovf_cap,
-            deep=True, spec=info.spec, dim=k, hidden=tuple(cfg.hidden),
-            channels=k + 2,
-            onehot_cache=getattr(cfg, "tile_onehot_cache", "auto"))
-        fused = res.kernel == "fused" and kind == "train"
+        k = self.cfg.dim
+        return {"ovf_cap": info.ovf_cap, "deep": True, "dim": k,
+                "hidden": tuple(self.cfg.hidden), "channels": k + 2}
+
+    def _fused_span(self) -> str:
+        return "tilemm:mlp_phase"
+
+    def _tile_table(self):
+        # as it stands: a stacked array is taken by the step itself
+        return self._table
+
+    def _tile_extra(self, train: bool) -> tuple:
+        return (self.mlp, self.mlp_accum) if train else (self.mlp,)
+
+    def _take_tile_extra(self, extra) -> None:
+        self.mlp, self.mlp_accum = extra
+
+    def _count_step(self, block: dict, info) -> None:
+        # the tower's FLOPs, forward and backward, and the dense update's
+        # bytes
+        self.timer.add("tower_flops", tower_flops(
+            info.spec.block_rows, self.cfg.dim, tuple(self.cfg.hidden)))
+        self.timer.add("dense_param_bytes", self._dense_bytes)
+
+    def _tile_body(self, ts: TileStep):
+        """``step(table, mlp, accum, block, t, tau, macc)`` (train) or
+        ``step(table, mlp, block)`` (eval). Every variant updates the
+        float32 (T, A_HI, B_LO) channel planes; a planar table IS those
+        planes, a stacked one is sliced into them inside the step. A
+        store that keeps planes gets planes back either way; one that
+        does not, the stacked array."""
+        from wormhole_tpu.ops import tilemm
+        cfg = self.cfg
+        k = cfg.dim
         n_layers = self.n_layers
-        objv_fn = self.objv_fn
         _, dual_fn = create_loss(cfg.loss)
-        spec = info.spec
+        spec, oc = ts.spec, ts.oc
         keeps_planes = self._planar
 
         def decode(block):
-            lab_u8 = block["labels"]
-            row_mask = (lab_u8 != jnp.uint8(255)).astype(jnp.float32)
-            labels = jnp.minimum(lab_u8, 1).astype(jnp.float32)
-            ovf_b = block["ovf_b"] if oc else None
-            ovf_r = block["ovf_r"] if oc else None
-            return block["pw"], labels, row_mask, ovf_b, ovf_r
+            pw, labels, row_mask, lst = ts.decode(block)
+            ovf_b, ovf_r = (lst["ovf_b"], lst["ovf_r"]) if oc else (None,
+                                                                   None)
+            return pw, labels, row_mask, ovf_b, ovf_r
 
         # The phases are jits of their own inside the step, named for
         # what they do, so that the device trace's ops say which phase
@@ -377,29 +385,26 @@ class WideDeepStore(TableCheckpoint):
                    row_mask, t, macc):
             # shared update/metric tail downstream of the push planes
             # and MLP grads — structurally identical XLA in the fused
-            # and split programs, so the update bits agree between them
-            objv = objv_fn(margin, labels, row_mask)
+            # and split programs, so the update bits agree between them.
+            # TileStep.finish with this store's own state among the
+            # outputs: the loss ahead of the two updates, the metric row
+            # after them
+            objv = ts.objv_fn(margin, labels, row_mask)
             new, d0_sq = wd_table_update(planes, push)
             mlp_new, accum = wd_dense_update(mlp, accum, g_mlp)
-            num_ex = jnp.sum(row_mask)
-            acc = accuracy(labels, margin, row_mask)
-            pos, neg = margin_hist(labels, margin, row_mask)
-            packed = jnp.concatenate([
-                jnp.stack([objv, num_ex, acc, d0_sq]), pos, neg])
-            # num_ex = completion ticket; the clock/macc outputs are
-            # donated into the next step (see ShardedStore._tile_step)
+            packed, num_ex = ts.metric_row(d0_sq, margin, labels, row_mask,
+                                           objv)
             new = (tbl.PlaneTable(new) if keeps_planes
                    else tbl.table_like(new, table))
             return new, mlp_new, accum, t + 1, macc + packed, num_ex
 
-        if fused:
+        if ts.fused:
             # one grid: embedding pulls, in-kernel MLP forward/backward at
             # the phase boundary, dual, channel pushes and MLP param
             # grads in a single dispatch (resolve_step_kernel admits
             # this only for spill-free blocks within the VMEM budget).
             # Its kernel takes (nb, 1+k) and gives (nb, k+2): formed from
             # the planes and sliced back into them here
-            @partial(jax.jit, donate_argnums=(0, 1, 2, 4, 6))
             def step(table, mlp, accum, block, t, tau, macc):
                 planes = tbl.planes_of(table)
                 pw, labels, row_mask, _ovf_b, _ovf_r = decode(block)
@@ -409,8 +414,7 @@ class WideDeepStore(TableCheckpoint):
                     spec, k, tuple(cfg.hidden), cfg.loss)
                 return finish(table, planes, mlp, accum, tbl.split(push),
                               g_mlp, margin, labels, row_mask, t, macc)
-        elif kind == "train":
-            @partial(jax.jit, donate_argnums=(0, 1, 2, 4, 6))
+        elif ts.kind == "train":
             def step(table, mlp, accum, block, t, tau, macc):
                 (pw, labels, row_mask, ovf_b, ovf_r, pooled, vjp,
                  margin) = forward(table, mlp, block)
@@ -423,29 +427,11 @@ class WideDeepStore(TableCheckpoint):
                               push, g_mlp, margin, labels, row_mask, t,
                               macc)
         else:
-            @jax.jit
             def step(table, mlp, block):
                 (_, labels, row_mask, _, _, _, _,
                  margin) = forward(table, mlp, block)
-                objv = objv_fn(margin, labels, row_mask)
-                num_ex = jnp.sum(row_mask)
-                acc = accuracy(labels, margin, row_mask)
-                pos, neg = margin_hist(labels, margin, row_mask)
-                return objv, num_ex, acc, pos, neg, margin
+                return ts.evaluate(margin, labels, row_mask)
 
-        if not hasattr(self, "_tile_cache"):
-            self._tile_cache = {}
-        if not hasattr(self, "_tile_kernel"):
-            self._tile_kernel = {}
-        if kind != "train":
-            self._tile_kernel[key] = (
-                "split", "eval is forward-only",
-                "onehot_cache=off:eval is forward-only")
-        else:
-            self._tile_kernel[key] = ("fused" if fused else "split",
-                                      res.why, res.cache_record)
-        self.step_kernel = self._tile_kernel[key]
-        self._tile_cache[key] = step
         return step
 
     def _tile_step_mesh(self, info, kind: str):
@@ -614,35 +600,6 @@ class WideDeepStore(TableCheckpoint):
             ici_bytes=mesh_step_ici_bytes(
                 self.rt, margin_elems=info.block_rows * ch,
                 train=False))
-
-    def tile_train_step(self, block: dict, info, tau: float = 0.0):
-        """Fused crec2-block wide&deep step; metrics accumulate ON DEVICE
-        (fetch_metrics, same harvest pipeline as ShardedStore). Returns
-        the non-donated completion ticket, never the clock."""
-        step = self._tile_step(info, "train", "ovf_b" in block)
-        if self.step_kernel[0] == "fused":
-            from wormhole_tpu.obs import trace
-            with trace.span("tilemm:mlp_phase", cat="tile"):
-                (self.slots, self.mlp, self.mlp_accum, t_new, self._macc,
-                 ticket) = step(self.slots, self.mlp, self.mlp_accum,
-                                block, self._t_device(),
-                                self._tau_const(tau), self._macc_buf())
-        else:
-            (self.slots, self.mlp, self.mlp_accum, t_new, self._macc,
-             ticket) = step(self.slots, self.mlp, self.mlp_accum, block,
-                            self._t_device(), self._tau_const(tau),
-                            self._macc_buf())
-        self._advance_t(t_new)
-        # counts a step, not seconds: the tower's FLOPs, forward and
-        # backward, and the dense update's bytes
-        self.timer.add("tower_flops", tower_flops(
-            info.spec.block_rows, self.cfg.dim, tuple(self.cfg.hidden)))
-        self.timer.add("dense_param_bytes", self._dense_bytes)
-        return ticket
-
-    def tile_eval_step(self, block: dict, info):
-        return self._tile_step(info, "eval", "ovf_b" in block)(
-            self.slots, self.mlp, block)
 
     # -- ShardedStore surface ------------------------------------------------
 

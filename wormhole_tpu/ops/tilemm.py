@@ -23,7 +23,7 @@ Cost is pairs x tile_size x 2 flops — independent of nb — ~600 GFLOP per
 100K-row criteo block of MXU instead of ~77ms of serialized scatter
 (round-2 BENCH). The kernels are VPU/relayout-sensitive, not just
 MXU-bound; two layout rules brought them from 21% to >50% of the
-MXU-pass floor (measured round 3, scripts/ktune.py):
+MXU-pass floor (measured on the chip, round 3):
 
   1. every dot is a plain A@B (contract lanes of lhs with sublanes of
      rhs) — the "transposed" one-hots (rhiT, ohhiT) are BUILT in that
@@ -78,6 +78,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from wormhole_tpu.ops.overflow import UNUSED
 
 A_HI = 128          # bucket hi digit (one-hot width, MXU-native)
 B_LO = 128          # bucket lo digit
@@ -270,11 +272,11 @@ def encode_block(buckets: np.ndarray, rows: np.ndarray,
 def cap_overflow(ovb: np.ndarray, ovr: np.ndarray,
                  ovf_cap: int) -> Tuple[np.ndarray, np.ndarray]:
     """An overflow list at the fixed width every consumer wants: exactly
-    ``ovf_cap`` long, unused slots carrying 0xFFFFFFFF buckets (the
+    ``ovf_cap`` long, unused slots carrying ``UNUSED`` buckets (the
     kernels' no-op sentinel) and row 0. A list longer than ``ovf_cap``
     is cut to its first ``ovf_cap`` entries: the caller compares the
     true count first."""
-    ob = np.full(max(ovf_cap, 0), 0xFFFFFFFF, np.uint32)
+    ob = np.full(max(ovf_cap, 0), UNUSED, np.uint32)
     orow = np.zeros(max(ovf_cap, 0), np.uint32)
     keep = min(len(ovb), ovf_cap)
     ob[:keep] = ovb[:keep]
@@ -286,7 +288,6 @@ def cap_overflow(ovb: np.ndarray, ovr: np.ndarray,
 
 HOT_CAP = 512       # C': slots of a (subblock, virtual tile) cell
 HOT_CH = 3          # bfloat16 channels a float32 value splits into
-UNUSED = np.uint32(0xFFFFFFFF)   # an unused slot of ovf_b / ovf_u
 
 
 def hot_spec(tiles: int, subblocks: int) -> TileSpec:
@@ -1021,8 +1022,8 @@ def _build_bwd_multi(spec: TileSpec, ch: int, tiled: bool = False):
 def spill_margin_rows(w: jax.Array, ovf_b: jax.Array, ovf_r: jax.Array,
                       spec: TileSpec) -> jax.Array:
     """(block_rows,) f32 pre-aggregated spill margins: each valid COO
-    pair's w lands on its row (0xFFFFFFFF-sentinel slots add 0)."""
-    valid = ovf_b != jnp.uint32(0xFFFFFFFF)
+    pair's w lands on its row (``UNUSED`` slots add 0)."""
+    valid = ovf_b != UNUSED
     wv = jnp.where(valid, w[jnp.where(valid, ovf_b, 0).astype(jnp.int32)],
                    0.0)
     return jnp.zeros(spec.block_rows, w.dtype).at[
@@ -1032,7 +1033,7 @@ def spill_margin_rows(w: jax.Array, ovf_b: jax.Array, ovf_r: jax.Array,
 def spill_pull_rows(w: jax.Array, ovf_b: jax.Array, ovf_r: jax.Array,
                     spec: TileSpec) -> jax.Array:
     """(block_rows, ch) multi-channel variant of spill_margin_rows."""
-    valid = ovf_b != jnp.uint32(0xFFFFFFFF)
+    valid = ovf_b != UNUSED
     idx = jnp.where(valid, ovf_b, 0).astype(jnp.int32)
     wv = jnp.where(valid[:, None], w[idx], 0.0)
     return jnp.zeros((spec.block_rows, w.shape[1]), w.dtype).at[
@@ -1045,7 +1046,7 @@ def spill_grad_scatter(g: jax.Array, dual_rows: jax.Array,
     """Scatter each spill pair's dual into the (nb,) gradient — the
     grad-side COO tail shared by backward_grad and the fused spill
     branch."""
-    valid = ovf_b != jnp.uint32(0xFFFFFFFF)
+    valid = ovf_b != UNUSED
     d = jnp.where(valid,
                   dual_rows[ovf_r.astype(jnp.int32) % spec.block_rows],
                   0.0)
@@ -1057,7 +1058,7 @@ def spill_push_scatter(g: jax.Array, dual_rows: jax.Array,
                        spec: TileSpec) -> jax.Array:
     """(nb, ch) variant of spill_grad_scatter (backward_pushes' tail;
     the FM steps on planes use spill_push_scatter_planes)."""
-    valid = ovf_b != jnp.uint32(0xFFFFFFFF)
+    valid = ovf_b != UNUSED
     d = jnp.where(valid[:, None],
                   dual_rows[ovf_r.astype(jnp.int32) % spec.block_rows],
                   0.0)
@@ -1227,25 +1228,13 @@ def plane_pushes(pw: jax.Array, dual_rows: jax.Array,
 
 
 def fm_spill_pull_rows(planes, ovf_b: jax.Array, ovf_r: jax.Array,
-                       spec: TileSpec, one, distinct=None) -> jax.Array:
+                       spec: TileSpec, one) -> jax.Array:
     """spill_pull_rows from the w and v planes: the listed buckets'
     values are gathered plane by plane and their pull channels formed
-    from those (float32, unrounded, as the stacked path pulls them).
-    ``distinct``: ``(ovf_u, ovf_k)``, the list's distinct buckets in
-    hot_buckets' layout and each slot's index in them. Each bucket is
-    then read from its plane once and the slots read those few thousand
-    values: the same values, and a gather that no longer asks a plane for
-    one hot address tens of thousands of times (a click-log list names
-    25,000 buckets in 1.5M pairs)."""
-    valid = ovf_b != jnp.uint32(0xFFFFFFFF)
-    if distinct is None:
-        idx = jnp.where(valid, ovf_b, 0).astype(jnp.int32)
-        got = [p.reshape(-1)[idx] for p in planes]
-    else:
-        ovf_u, ovf_k = distinct
-        at = jnp.where(ovf_u != UNUSED, ovf_u, 0).astype(jnp.int32)
-        idx = ovf_k.astype(jnp.int32)
-        got = [p.reshape(-1)[at][idx] for p in planes]
+    from those (float32, unrounded, as the stacked path pulls them)."""
+    valid = ovf_b != UNUSED
+    idx = jnp.where(valid, ovf_b, 0).astype(jnp.int32)
+    got = [p.reshape(-1)[idx] for p in planes]
     wv = jnp.where(valid[:, None],
                    jnp.stack(fm_pull_channels(got[0], got[1:], one), axis=1),
                    0.0)
@@ -1256,7 +1245,7 @@ def fm_spill_pull_rows(planes, ovf_b: jax.Array, ovf_r: jax.Array,
 def spill_push_scatter_planes(push, dual_rows: jax.Array, ovf_b: jax.Array,
                               ovf_r: jax.Array, spec: TileSpec) -> tuple:
     """spill_push_scatter into push planes, a channel at a time."""
-    valid = ovf_b != jnp.uint32(0xFFFFFFFF)
+    valid = ovf_b != UNUSED
     idx = jnp.where(valid, ovf_b, 0).astype(jnp.int32)
     d = jnp.where(valid[:, None],
                   dual_rows[ovf_r.astype(jnp.int32) % spec.block_rows],
@@ -1310,7 +1299,7 @@ def plane_spill_pull_rows(planes, ovf_b: jax.Array, ovf_r: jax.Array,
     """spill_pull_rows from channel planes: the listed buckets' values
     are gathered plane by plane (float32, unrounded, as the stacked
     path pulls them) and summed onto their rows."""
-    valid = ovf_b != jnp.uint32(0xFFFFFFFF)
+    valid = ovf_b != UNUSED
     idx = jnp.where(valid, ovf_b, 0).astype(jnp.int32)
     wv = jnp.where(valid[:, None],
                    jnp.stack([p.reshape(-1)[idx] for p in planes], axis=1),
@@ -1350,7 +1339,7 @@ def spill_push_scatter_lanes(g: jax.Array, dual_rows: jax.Array,
     if 8 * ovf_b.shape[0] > g.shape[0] * g.shape[1]:
         return spill_push_scatter_planes(push_planes(g), dual_rows, ovf_b,
                                          ovf_r, spec)
-    valid = ovf_b != jnp.uint32(0xFFFFFFFF)
+    valid = ovf_b != UNUSED
     idx = jnp.where(valid, ovf_b, 0).astype(jnp.int32)
     d = jnp.where(valid[:, None],
                   dual_rows[ovf_r.astype(jnp.int32) % spec.block_rows],
@@ -1492,7 +1481,7 @@ def _onehot_cache_decision(resolved: str, knob: str,
     """The cache half of resolve_step_kernel. Structural exclusions
     (split resolution, multi-channel, K>1 chains) hold even under a
     forced ``on``; the VMEM budget model only gates ``auto`` — ``on``
-    overrides it so ktune/bench can measure past the model."""
+    overrides it so a measurement can go past the model."""
     if knob == "off":
         return False, "forced off"
     if resolved != "fused":

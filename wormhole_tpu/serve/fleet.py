@@ -215,8 +215,12 @@ class SnapshotPublisher:
             else:
                 self._base = out["params"]
                 self.full_frames += 1
-            self.version = int(frame["meta"][2])
             self.frames += 1
+            # last: a reader that sees the version sees the base and
+            # the counts of the frame that brought it (the replicas
+            # report it as soon as they decode, before the broadcast
+            # returns here)
+            self.version = int(frame["meta"][2])
             if self._metrics is not None:
                 self._metrics[0].inc()
                 self._metrics[1].set(self.version)

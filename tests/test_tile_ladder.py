@@ -32,8 +32,8 @@ EVAL = ("split", "eval is forward-only",
 PHASES = {
     "linear": ("tile_ovf_gather", "tile_ovf_scatter", "tile_table_update"),
     "fm": ("fm_ovf_pull", "fm_ovf_scatter", "fm_table_update"),
-    "wide_deep": ("wd_pull", "wd_tower", "wd_push", "wd_table_update",
-                  "wd_dense_update"),
+    "wide_deep": ("wd_pull", "wd_ovf_pull", "wd_tower", "wd_push",
+                  "wd_ovf_scatter", "wd_table_update", "wd_dense_update"),
 }
 MAKE = {"linear": _store, "fm": _fm_store, "wide_deep": _wd_store}
 DIM = 4                        # of the two embedding stores' factors
@@ -71,10 +71,12 @@ def _expected(name: str, kind: str, lst: str, kernel: str):
         "fused" if fused else "split",
         why[name] if in_place else res.why, res.cache_record)
     if name == "wide_deep":
-        phases = {"wd_pull", "wd_tower"}        # the forward half
+        # the forward half, the list's pull inside wd_pull
+        phases = {"wd_pull", "wd_tower"} | ({"wd_ovf_pull"} if oc else set())
         if kind == "train":
             phases = {"wd_table_update", "wd_dense_update"} | (
-                set() if fused else phases | {"wd_push"})
+                set() if fused else phases | {"wd_push"} | (
+                    {"wd_ovf_scatter"} if oc else set()))
     else:
         pull, scatter, update = PHASES[name]
         phases = {pull} if oc else set()

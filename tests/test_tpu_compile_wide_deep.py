@@ -124,3 +124,83 @@ def test_wide_deep_train_step_on_planes_compiles_for_v5e(v5e):
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= 2 * (1 + k) * 4 * nb
     assert mem.temp_size_in_bytes < 1.1 * (k + 2) * 4 * nb
+
+
+def test_wide_deep_spill_step_compiles_at_the_click_log_cells_list_width(v5e):
+    """``criteo_wide_deep_clicklog.replay_fields``'s train step for the v5e
+    at the cell's own sizes: 66 planes of ``2**24`` buckets, cap 384, the
+    published tower, and the mix's 1,638,400-slot COO list (eleven thousand
+    times the longest list any other wide&deep cell brings) with its
+    distinct buckets beside it in three tiles, as ``put_block`` sends a
+    click-log block's 39-41 thousand. The compiler
+    accepts it inside the chip's memory beside the cell's 0.35 GB of resident
+    blocks with room to spare; the list's two halves keep their names in the
+    optimized HLO (each is a jit of its own inside ``wd_pull`` / ``wd_push``,
+    so the device trace can tell them from the kernel pair and the tower),
+    every gather and scatter of the program sits under one of them, and
+    nothing in it is the table stacked as ``(nb, 66)``. A minute."""
+    import json
+    import os
+    import re
+    from wormhole_tpu.data.crec import CRec2Info, default_cap
+    from wormhole_tpu.learners import table as tbl
+    from wormhole_tpu.learners.store import TableCheckpoint
+    from wormhole_tpu.models.wide_deep import WideDeepConfig, WideDeepStore
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "criteo_wide_deep_clicklog", "config.json")) as f:
+        config = json.load(f)
+    with open(os.path.join(root, "benchmark", "traffic",
+                           "replay_fields.json")) as f:
+        room = int(json.load(f)["ovf_cap"])
+    k, hidden = int(config["dim"]), tuple(config["hidden"])
+    nb = int(config["num_buckets"])
+    assert (k, hidden, nb, room) == (32, (1024, 512, 256), 1 << 24, 1638400)
+    store = WideDeepStore(WideDeepConfig(num_buckets=2 * tilemm.TILE, dim=k,
+                                         hidden=hidden))
+    info = CRec2Info(nnz=39, block_rows=12 * tilemm.RSUB,
+                     total_rows=12 * tilemm.RSUB, nb=nb, ovf_cap=room,
+                     subblocks=12, cap=default_cap(39, nb))
+    assert info.cap == config["tile"]["cap"]
+    spec = info.spec
+    step = store._tile_step(info, "train", True)
+    assert store.step_kernel[0] == "split" and "spill" in store.step_kernel[1]
+    one_chip = SingleDeviceSharding(v5e.devices[0])
+
+    def on(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    mlp = jax.tree.map(lambda a: on(a.shape, a.dtype), store.mlp)
+    plane = on(tbl.plane_shape(nb), jnp.float32)
+    compiled = step.lower(
+        tbl.PlaneTable([plane] * (2 * (1 + k))), mlp, mlp,
+        {"pw": on(spec.pairs_shape, jnp.uint32),
+         "labels": on((spec.block_rows,), jnp.uint8),
+         "ovf_b": on((room,), jnp.uint32), "ovf_r": on((room,), jnp.uint32),
+         "ovf_d": on((3 * tilemm.TILE,), jnp.uint32),
+         "ovf_k": on((room,), jnp.uint32)},
+        on((), jnp.int32), on((), jnp.float32),
+        on((TableCheckpoint.MACC_LEN,), jnp.float32)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 2
+    for phase in ("wd_ovf_pull", "wd_ovf_scatter", "wd_table_update",
+                  "wd_tower"):
+        assert re.search(r"jit\(%s\)" % phase, text), phase
+    # two gathers a plane under the list's pull (the distinct buckets'
+    # values from the plane, the slots' from those), k + 2 plane
+    # scatter-adds under its scatter (a list this long goes a plane at a
+    # time), and no gather or scatter anywhere else in the step
+    for op, phase, n in (("gather", "wd_ovf_pull", 2 * (1 + k)),
+                         ("scatter", "wd_ovf_scatter", k + 2)):
+        lines = [ln for ln in text.splitlines()
+                 if re.search(r" = \S+ %s\(" % op, ln)]
+        assert all("jit(wd_ovf_" in ln for ln in lines), op
+        assert sum("jit(%s)" % phase in ln for ln in lines) >= n, op
+    assert not re.findall(r"f32\[%d,\d+\]" % nb, text)
+    # the 66 planes are donated onto the 66 results; arguments and
+    # temporaries (the 34 push planes, the list's gathered rows and duals)
+    # leave a third of the chip's 15.75 GB free
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * (1 + k) * 4 * nb
+    assert mem.temp_size_in_bytes < 5.5e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 10.5e9

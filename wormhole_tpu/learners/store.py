@@ -26,6 +26,7 @@ from __future__ import annotations
 
 import contextlib
 import os
+import weakref
 from dataclasses import dataclass
 from functools import partial
 from typing import Optional, Tuple
@@ -509,6 +510,19 @@ class TableCheckpoint:
     # store's does; FM's and wide&deep's read the COO lanes.
     mesh_hot_overflow = False
 
+    # The pairs on the overflow list of each block now on the device, by
+    # the id of the list's device array (``put_block``, ``_listed_pairs``).
+    # A store that counts them (FM's and wide&deep's ``_count_step``) sets
+    # a dict where it builds its Timer; None: nothing is counted.
+    _listed = None
+
+    # The widest room, in whole tiles, that a COO list's distinct buckets
+    # have crossed with (``put_block``, ``overflow.distinct``). A store
+    # whose spill step reads a plane once a listed bucket (wide&deep's)
+    # starts it at 1 where it builds its Timer; None: a COO list crosses
+    # as its two arrays alone.
+    _distinct_tiles = None
+
     @classmethod
     def can_be_planar(cls, runtime: Optional[MeshRuntime], dtype,
                       num_buckets: int) -> bool:
@@ -583,11 +597,45 @@ class TableCheckpoint:
         six to nine minutes at 2**28, PERF.md. So does a table on a
         mesh, planes or not: the mesh step takes its list operands
         whatever they hold, and its groups come through
-        ``crec.place_mesh_group``, not through here.)"""
+        ``crec.place_mesh_group``, not through here.)
+        A store that keeps ``_distinct_tiles`` has a long COO list of its
+        planes cross with its distinct buckets (``overflow.distinct``).
+        A store that keeps ``_listed`` has the pairs on the list
+        counted here: from ``ovf_b`` while the list is host memory,
+        whichever form crosses, and the count kept by the id of the
+        list's device array (``overflow.array``) for as long as that
+        lives (a resident block is put once and stepped every pass)."""
+        pairs = 0
         if isinstance(block, dict):
+            if self._listed is not None and overflow.COO[0] in block:
+                pairs = overflow.pairs(block[overflow.COO[0]])
             block = overflow.crossing(
                 block, drop_empty=self._planar and self._on_one_device)
-        return jax.device_put(block)
+            block = self._with_distinct(block)
+        dev = jax.device_put(block)
+        lst = overflow.array(dev) if pairs else None
+        if lst is not None:
+            self._listed[id(lst)] = pairs
+            weakref.finalize(lst, self._listed.pop, id(lst), None)
+        return dev
+
+    def _with_distinct(self, block: dict) -> dict:
+        """A block about to cross, its COO list with ``ovf_d`` and
+        ``ovf_k`` beside it where this store's step reads them (planes
+        on one device) and the list is long enough to bring them."""
+        ovf_b = block.get(overflow.COO[0])
+        if (self._distinct_tiles is None or ovf_b is None
+                or not (self._planar and self._on_one_device)):
+            return block
+        made = overflow.distinct(ovf_b, self._distinct_tiles, tbl.TILE)
+        if made is None:
+            return block
+        self._distinct_tiles = len(made[0]) // tbl.TILE
+        return dict(block, **dict(zip(overflow.DISTINCT, made)))
+
+    def _listed_pairs(self, block: dict) -> int:
+        """The pairs ``put_block`` counted on this device block's list."""
+        return self._listed.get(id(overflow.array(block)), 0)
 
     def state_pytree(self):
         slots = self._table

@@ -22,7 +22,6 @@ scattered back. Same bounded-staleness driver as the linear learner
 
 from __future__ import annotations
 
-import weakref
 from dataclasses import dataclass
 from functools import partial
 from typing import Optional
@@ -148,9 +147,8 @@ class FMStore(TableCheckpoint):
         # crossings of the table's format (scope "table_cross"), as
         # ShardedStore counts them
         self.timer = Timer()
-        # pairs on the overflow list of each block now on the device, by
-        # the id of the list's device array (overflow.array; put_block,
-        # _count_step)
+        # _count_step adds the pairs on a spill block's list, which
+        # TableCheckpoint.put_block counts for a store that keeps this
         self._listed = {}
         # One device and whole tiles: the tile steps take this table as
         # one float32 (T, A_HI, B_LO) plane a channel (w, v_1..v_k, cg_w,
@@ -566,22 +564,6 @@ class FMStore(TableCheckpoint):
                 self.rt, margin_elems=info.block_rows * ch,
                 train=False))
 
-    def put_block(self, block):
-        """TableCheckpoint.put_block, with the pairs on the block's list
-        counted: from ``ovf_b`` while the list is host memory, whichever
-        form crosses, and the count kept for as long as the device copy
-        of the list lives (a resident block is put once and stepped
-        every pass)."""
-        pairs = 0
-        if isinstance(block, dict) and "ovf_b" in block:
-            pairs = overflow.pairs(block["ovf_b"])
-        dev = super().put_block(block)
-        if pairs:
-            lst = overflow.array(dev)
-            self._listed[id(lst)] = pairs
-            weakref.finalize(lst, self._listed.pop, id(lst), None)
-        return dev
-
     def _count_step(self, block: dict, info) -> None:
         """Which variant a train block took and the pairs its list holds,
         into the timer and the registry: counts, not seconds."""
@@ -593,7 +575,7 @@ class FMStore(TableCheckpoint):
                 self.timer.add("fm_in_place_blocks", 1)
                 in_place_c.inc()
             return
-        pairs = self._listed.get(id(lst), 0)
+        pairs = self._listed_pairs(block)
         self.timer.add("fm_spill_blocks", 1)
         self.timer.add("fm_listed_pairs", pairs)
         spill_c.inc()

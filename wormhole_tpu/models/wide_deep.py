@@ -37,6 +37,7 @@ from wormhole_tpu.learners.store import (TableCheckpoint,
                                           mesh_ovf_zeros,
                                           mesh_step_ici_bytes,
                                           mesh_tile_geometry)
+from wormhole_tpu.ops import overflow
 from wormhole_tpu.ops.loss import create_loss
 from wormhole_tpu.ops.metrics import accuracy, auc
 from wormhole_tpu.ops.spmv import spmv_times
@@ -98,6 +99,12 @@ class WideDeepStore(TableCheckpoint):
         # dense_param_bytes: counts, not seconds) and, as every
         # TableCheckpoint, any crossing of the table's form (table_cross)
         self.timer = Timer()
+        # _count_step adds the pairs on a spill block's list, which
+        # TableCheckpoint.put_block counts for a store that keeps this
+        self._listed = {}
+        # the spill step reads a plane once a listed bucket where
+        # put_block sent a long list's distinct buckets beside it
+        self._distinct_tiles = 1
         k, nb = cfg.dim, cfg.num_buckets
         rng = np.random.default_rng(cfg.seed)
         # v must break symmetry; w and accumulators start at 0
@@ -271,6 +278,21 @@ class WideDeepStore(TableCheckpoint):
         self.timer.add("tower_flops", tower_flops(
             info.spec.block_rows, self.cfg.dim, tuple(self.cfg.hidden)))
         self.timer.add("dense_param_bytes", self._dense_bytes)
+        # whether the train block brought a list (the spill step's two
+        # list phases run) and the pairs on it, into the timer and the
+        # registry: a resident click-log shard whose listless count moves
+        # is stepping blocks that lost their lists
+        from wormhole_tpu.obs import metrics
+        spill_c, pairs_c, listless_c = metrics.wd_step_metrics()
+        if not overflow.has_list(block):
+            self.timer.add("wd_listless_blocks", 1)
+            listless_c.inc()
+            return
+        pairs = self._listed_pairs(block)
+        self.timer.add("wd_spill_blocks", 1)
+        self.timer.add("wd_listed_pairs", pairs)
+        spill_c.inc()
+        pairs_c.inc(pairs)
 
     def _tile_body(self, ts: TileStep):
         """``step(table, mlp, accum, block, t, tau, macc)`` (train) or
@@ -291,22 +313,40 @@ class WideDeepStore(TableCheckpoint):
             pw, labels, row_mask, lst = ts.decode(block)
             ovf_b, ovf_r = (lst["ovf_b"], lst["ovf_r"]) if oc else (None,
                                                                    None)
-            return pw, labels, row_mask, ovf_b, ovf_r
+            distinct = (tuple(lst[name] for name in overflow.DISTINCT)
+                        if oc and overflow.DISTINCT[0] in lst else None)
+            return pw, labels, row_mask, ovf_b, ovf_r, distinct
 
         # The phases are jits of their own inside the step, named for
         # what they do, so that the device trace's ops say which phase
         # they belong to: wd_pull, wd_tower (forward under the scope
         # wd_tower_forward, its vjp under wd_tower_backward), wd_push,
-        # wd_table_update, wd_dense_update. A nested jit and not a bare
-        # jax.named_scope (as the mesh step has, learners/store.py): the
-        # profiler's op metadata keeps the path of an op inside a nested
-        # jit and drops a bare scope's (jit(fwd) in the kept traces). XLA
-        # inlines them: the step is one program as before.
+        # wd_table_update, wd_dense_update; and inside wd_pull and
+        # wd_push the list's halves, wd_ovf_pull (the listed buckets' 1+k
+        # values gathered plane by plane and summed onto their rows: a
+        # slot a pair, or once a listed bucket where the list brings its
+        # distinct buckets) and wd_ovf_scatter (the pairs' k+2 dual
+        # channels added into the kernel's pushes, a slot a pair). A
+        # nested jit and not a bare jax.named_scope (as the mesh step
+        # has, learners/store.py): the profiler's op metadata keeps the
+        # path of an op inside a nested jit and drops a bare scope's
+        # (jit(fwd) in the kept traces). XLA inlines them: the step is
+        # one program as before.
         # At the kernels' edges a planar table takes the helpers over
         # planes; a stacked one (``stacked``, static) the (nb, ch) helpers
         # it had, which transpose the operand and the pushes
+        @jax.jit
+        def wd_ovf_pull(theta, ovf_b, ovf_r, distinct):
+            return tilemm.plane_spill_pull_rows(theta, ovf_b, ovf_r, spec,
+                                                distinct)
+
+        @jax.jit
+        def wd_ovf_scatter(push, dvals, ovf_b, ovf_r):
+            return tilemm.spill_push_scatter_lanes(push, dvals, ovf_b,
+                                                   ovf_r, spec)
+
         @partial(jax.jit, static_argnums=(0,))
-        def wd_pull(stacked, theta, pw, ovf_b, ovf_r):
+        def wd_pull(stacked, theta, pw, ovf_b, ovf_r, distinct):
             if stacked:
                 return tilemm.forward_pulls(pw, tbl.join(theta), spec,
                                             ovf_b, ovf_r)
@@ -315,8 +355,7 @@ class WideDeepStore(TableCheckpoint):
             pulls = tilemm.plane_pulls(pw, tilemm.plane_operand(theta),
                                        spec)
             if oc:
-                pulls = pulls + tilemm.plane_spill_pull_rows(
-                    theta, ovf_b, ovf_r, spec)
+                pulls = pulls + wd_ovf_pull(theta, ovf_b, ovf_r, distinct)
             return pulls
 
         @jax.jit
@@ -334,8 +373,7 @@ class WideDeepStore(TableCheckpoint):
             # (T, A_HI, (k+2)*B_LO): a channel's plane is a lane slice
             push = tilemm.tiled_pushes(pw, dvals, spec)
             if oc:
-                return tilemm.spill_push_scatter_lanes(
-                    push, dvals, ovf_b, ovf_r, spec)
+                return wd_ovf_scatter(push, dvals, ovf_b, ovf_r)
             return tilemm.push_planes(push)
 
         @jax.jit
@@ -371,9 +409,9 @@ class WideDeepStore(TableCheckpoint):
             return not isinstance(table, tbl.PlaneTable)
 
         def forward(table, mlp, block):
-            pw, labels, row_mask, ovf_b, ovf_r = decode(block)
+            pw, labels, row_mask, ovf_b, ovf_r, distinct = decode(block)
             pulls = wd_pull(is_stacked(table), tbl.planes_of(table)[:1 + k],
-                            pw, ovf_b, ovf_r)
+                            pw, ovf_b, ovf_r, distinct)
             pooled = pulls[:, 1:]
             with jax.named_scope("wd_tower_forward"):
                 deep, vjp = jax.vjp(wd_tower, mlp, pooled)
@@ -407,7 +445,7 @@ class WideDeepStore(TableCheckpoint):
             # the planes and sliced back into them here
             def step(table, mlp, accum, block, t, tau, macc):
                 planes = tbl.planes_of(table)
-                pw, labels, row_mask, _ovf_b, _ovf_r = decode(block)
+                pw, labels, row_mask, *_list = decode(block)
                 # pull, tower and push are one kernel here
                 margin, push, g_mlp = tilemm.fused_wd_step(
                     pw, tbl.join(planes[:1 + k]), labels, row_mask, mlp,

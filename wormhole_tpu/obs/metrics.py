@@ -545,6 +545,26 @@ def fm_step_metrics(reg: Optional[Registry] = None):
                              "that took FM's spill step"))
 
 
+def wd_step_metrics(reg: Optional[Registry] = None):
+    """Whether each train block of the one-device wide&deep tile step
+    brought an overflow list (models/wide_deep.WideDeepStore) — single
+    declaration site, fetched per call like :func:`encode_counters`. A
+    block with a list takes the spill step's two list phases beside the
+    kernel pair (``wd_ovf_pull``, ``wd_ovf_scatter``: a gather and a
+    scatter-add a slot a plane); a resident click-log shard whose
+    listless count moves is stepping blocks that lost their lists."""
+    reg = reg if reg is not None else default_registry()
+    return (reg.counter("step/wd_spill_blocks",
+                        help="train blocks that took wide&deep's spill "
+                             "step (the block brought an overflow list)"),
+            reg.counter("step/wd_listed_pairs",
+                        help="pairs on the overflow lists of the blocks "
+                             "that took wide&deep's spill step"),
+            reg.counter("step/wd_listless_blocks",
+                        help="train blocks wide&deep stepped without an "
+                             "overflow list"))
+
+
 def mesh_feed_gauges(reg: Optional[Registry] = None):
     """The sharded mesh-feed (data/crec.MeshGroupFeed) telemetry —
     single declaration site (lint_knobs uniqueness contract), fetched

@@ -7,7 +7,10 @@ list, in one of two forms, each two arrays of the block's dict:
   pairs first and ``UNUSED`` buckets after them. What every writer and
   encoder makes (``tilemm.cap_overflow``), and what an eval pass, a short
   list and a list of mostly distinct buckets step as it is: a gather and
-  a scatter-add a slot.
+  a scatter-add a slot. A long one may cross with two arrays more
+  (``ovf_d``, ``ovf_k``: ``distinct``), its distinct buckets and each
+  slot's place among them, for a step that reads a plane once a listed
+  bucket and not once a slot.
 * hot (``ovf_u``, ``ovf_pw``): the list's distinct buckets in whole hot
   tiles (``UNUSED`` after them) and the pairs as pair words over their
   rank among those (``tilemm.encode_hot``), where the feed's
@@ -26,6 +29,11 @@ import numpy as np
 UNUSED = np.uint32(0xFFFFFFFF)   # an unused slot of ovf_b / ovf_u
 COO = ("ovf_b", "ovf_r")
 HOT = ("ovf_u", "ovf_pw")
+DISTINCT = ("ovf_d", "ovf_k")
+# a COO list crosses with its distinct buckets where it has at least this
+# many slots to each slot of their room: under that the room's own reads
+# outweigh what the slots' save
+DISTINCT_MIN_SLOTS = 4
 
 
 def names(hot: bool) -> tuple:
@@ -51,13 +59,44 @@ def has_list(block: dict) -> bool:
 
 
 def of(block: dict) -> dict:
-    """The block's list alone, in the form the block brings it."""
-    return {k: block[k] for k in names(is_hot(block))}
+    """The block's list alone, in the form the block brings it (a COO
+    list with its distinct buckets where it crossed with them)."""
+    hot = is_hot(block)
+    extra = () if hot or DISTINCT[0] not in block else DISTINCT
+    return {k: block[k] for k in names(hot) + extra}
 
 
 def pairs(ovf_b: np.ndarray) -> int:
     """The pairs on a COO list: its slots that are in use."""
     return int(np.count_nonzero(ovf_b != UNUSED))
+
+
+def distinct(ovf_b: np.ndarray, tiles: int, tile: int):
+    """``(ovf_d, ovf_k)`` of a COO list, or None where the list is too
+    short for them (``DISTINCT_MIN_SLOTS``). ``ovf_d``: the list's
+    distinct buckets in ascending order, padded with ``UNUSED`` to whole
+    tiles of ``tile`` buckets, ``tiles`` of them at least (a room is a
+    shape, and a shape a program of the step: the caller keeps the widest
+    it has met). ``ovf_k``: each slot's index in ``ovf_d``. A gather a
+    slot from a table plane follows WHICH addresses the list names: a
+    click-log list names 40,000 buckets in 1.1M pairs, a few of them tens
+    of thousands of times, and a step that asks the plane for every slot
+    runs 2% faster or slower by the seed that made the keys (chip, PR 51).
+    Read once a bucket, the plane is asked for 40,000 values and the
+    slots read those. An unused slot's index is dealt round the room, so
+    that the unused slots ask for no one address either; its value is
+    masked out by ``ovf_b`` as before."""
+    used = ovf_b != UNUSED
+    uniq, inv = np.unique(ovf_b[used], return_inverse=True)
+    room = max(tiles, -(-len(uniq) // tile)) * tile
+    if room * DISTINCT_MIN_SLOTS > len(ovf_b):
+        return None
+    ovf_d = np.full(room, UNUSED, np.uint32)
+    ovf_d[:len(uniq)] = uniq
+    ovf_k = np.empty(len(ovf_b), np.uint32)
+    ovf_k[used] = inv
+    ovf_k[~used] = np.arange(len(ovf_b) - len(inv)) % room
+    return ovf_d, ovf_k
 
 
 def crossing(block: dict, drop_empty: bool) -> dict:

@@ -1295,14 +1295,30 @@ def plane_operand(planes) -> jax.Array:
 
 
 def plane_spill_pull_rows(planes, ovf_b: jax.Array, ovf_r: jax.Array,
-                          spec: TileSpec) -> jax.Array:
+                          spec: TileSpec, distinct=None) -> jax.Array:
     """spill_pull_rows from channel planes: the listed buckets' values
     are gathered plane by plane (float32, unrounded, as the stacked
-    path pulls them) and summed onto their rows."""
+    path pulls them) and summed onto their rows. ``distinct``:
+    ``(ovf_d, ovf_k)``, the list's distinct buckets and each slot's index
+    in them (ops/overflow.distinct). A plane is then read once a listed
+    bucket and the slots read those few tiles of values: the same values,
+    so the same bits, and a gather whose time no longer follows which
+    addresses the list names."""
     valid = ovf_b != UNUSED
-    idx = jnp.where(valid, ovf_b, 0).astype(jnp.int32)
+    if distinct is None:
+        idx = jnp.where(valid, ovf_b, 0).astype(jnp.int32)
+
+        def take(flat):
+            return flat[idx]
+    else:
+        ovf_d, ovf_k = distinct
+        at = jnp.where(ovf_d != UNUSED, ovf_d, 0).astype(jnp.int32)
+        idx = ovf_k.astype(jnp.int32)
+
+        def take(flat):
+            return flat[at][idx]
     wv = jnp.where(valid[:, None],
-                   jnp.stack([p.reshape(-1)[idx] for p in planes], axis=1),
+                   jnp.stack([take(p.reshape(-1)) for p in planes], axis=1),
                    0.0)
     return jnp.zeros((spec.block_rows, wv.shape[1]), jnp.float32).at[
         ovf_r.astype(jnp.int32) % spec.block_rows].add(wv)

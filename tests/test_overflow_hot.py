@@ -19,8 +19,17 @@ The properties pinned here:
   * ``FMStore``'s spill step takes the same form (ISSUE 48): every one of
     its ``k + 2`` float32 channels as three parts through the same kernel
     pair, the same sums as its COO helpers, the same rule, counters and
-    oracle, and a list rounded to bfloat16 on its way is refused.
+    oracle, and a list rounded to bfloat16 on its way is refused;
+  * ``WideDeepStore``'s spill step takes it too (ISSUE 52): its 1 + k
+    planes pulled and k + 2 dual channels pushed as they stand, a call a
+    part where one call does not admit ``3c`` parts (three calls of 33 and
+    of 34 channels at the click-log cell's widths), the push's last step
+    the COO helper's own over the hot tiles' sums; a push that leaves a
+    channel out is off the oracle and a hot block that loses its list is
+    counted.
 """
+
+import contextlib
 
 import jax
 import jax.numpy as jnp
@@ -355,7 +364,10 @@ FTRL_HELPERS = ("hot_margin_rows", "hot_grad_scatter", "spill_margin_rows",
                 "spill_grad_scatter")
 FM_HELPERS = ("fm_hot_pull_rows", "hot_push_scatter_planes",
               "fm_spill_pull_rows", "spill_push_scatter_planes")
+WD_HELPERS = ("plane_hot_pull_rows", "hot_push_scatter_lanes",
+              "plane_spill_pull_rows", "spill_push_scatter_lanes")
 FM_DIM = 4
+WD_DIM = 4
 
 
 def _ftrl_app(path, **over):
@@ -378,6 +390,52 @@ def _fm_app(path, tile_step_kernel="fused", **over):
     store = FMStore(FMConfig(num_buckets=NB, dim=FM_DIM, seed=3,
                              tile_step_kernel=tile_step_kernel), rt)
     return AsyncSGD(Config(**kw), rt, store=store)
+
+
+def _wd_app(path, hidden=(8,), **over):
+    """``AsyncSGD`` over a ``WideDeepStore`` as ``models/wide_deep.build_app``
+    builds it, online tile path, one device, a ReLU layer in the tower
+    unless ``hidden`` is empty. A block with a list takes the split pair
+    whatever the knob says."""
+    from wormhole_tpu.learners.async_sgd import AsyncSGD
+    from wormhole_tpu.models.wide_deep import WideDeepConfig, WideDeepStore
+    from wormhole_tpu.utils.config import Config
+    from test_tile_online import single_device_rt
+    kw = dict(train_data=str(path), data_format="crec", num_buckets=NB,
+              tile_online="on", max_data_pass=1, disp_itv=1e12, max_delay=1,
+              pipeline_workers=0)
+    kw.update(over)
+    rt = single_device_rt()
+    store = WideDeepStore(WideDeepConfig(num_buckets=NB, dim=WD_DIM,
+                                         hidden=tuple(hidden), seed=3), rt)
+    return AsyncSGD(Config(**kw), rt, store=store)
+
+
+# a store's job, the helpers its two list phases may be traced with (the hot
+# push's last step IS the COO helper, over the hot tiles' sums: wide&deep's
+# hot program traces both), and its Timer's own counts
+STORES = {
+    "fm": dict(app=_fm_app, helpers=FM_HELPERS,
+               hot={"fm_hot_pull_rows", "hot_push_scatter_planes"},
+               coo={"fm_spill_pull_rows", "spill_push_scatter_planes"},
+               spill="fm_spill_blocks", pairs="fm_listed_pairs",
+               other="fm_in_place_blocks"),
+    "wd": dict(app=_wd_app, helpers=WD_HELPERS,
+               hot={"plane_hot_pull_rows", "hot_push_scatter_lanes",
+                    "spill_push_scatter_lanes"},
+               coo={"plane_spill_pull_rows", "spill_push_scatter_lanes"},
+               spill="wd_spill_blocks", pairs="wd_listed_pairs",
+               other="wd_listless_blocks"),
+}
+
+
+@pytest.fixture(params=list(STORES))
+def store(request):
+    """``STORES``' entry for FM's job or wide&deep's, the latter with its
+    15 and 18 parts cut a call a part as the cell's 99 and 102 are."""
+    if request.param == "wd":
+        request.getfixturevalue("a_call_a_part")
+    return STORES[request.param]
 
 
 def _run(tmp_path, name, blocks, monkeypatch, min_room, app=_ftrl_app,
@@ -499,15 +557,66 @@ def test_zipf_ftrl_run_lands_on_the_oracle(tmp_path, monkeypatch, kernel):
 # -- FMStore's list through the same pair (ISSUE 48) --------------------------
 
 FM_LISTS = ["skewed", "two_small_hot_tiles", "one_pair", "empty"]
+# the store whose helpers a case runs: FM's (planes in, planes out, the
+# formed channel, ONE call) or wide&deep's (the planes as they stand, the
+# pushes tiled, a call a part)
+HELPER_CASES = [("fm", kind) for kind in FM_LISTS] + [
+    ("wd", "skewed"), ("wd", "two_small_hot_tiles"), ("wd", "one_pair")]
+# tiles_step * (ch + 6) of one call (tilemm.MULTI_BUDGET) at which the
+# helper cases' 9 and 12 parts (and the app cases' 15 and 18) do not go
+# through one call and their 3 to 6 channels do: the cut the click-log
+# cell's 99 and 102 parts take at the shipped 128
+SMALL_BUDGET = 24
 
 
-@pytest.fixture(scope="module", params=FM_LISTS)
+@contextlib.contextmanager
+def _small_budget():
+    """The hot helpers cut a call a part at the tests' few channels; the
+    kernel builders' caches hold nothing built under the other budget."""
+    builders = (tilemm._build_fwd_multi, tilemm._build_bwd_multi)
+    for b in builders:
+        b.cache_clear()
+    budget, tilemm.MULTI_BUDGET = tilemm.MULTI_BUDGET, SMALL_BUDGET
+    try:
+        yield
+    finally:
+        tilemm.MULTI_BUDGET = budget
+        for b in builders:
+            b.cache_clear()
+
+
+@pytest.fixture
+def a_call_a_part():
+    with _small_budget():
+        yield
+
+
+def _pallas_calls(fn, *args) -> int:
+    """The kernel calls ``fn`` traces to, each call site counted."""
+    def count(jaxpr) -> int:
+        n = 0
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                n += 1
+                continue
+            for v in eqn.params.values():
+                for sub in v if isinstance(v, (tuple, list)) else (v,):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        n += count(sub)
+        return n
+    return count(jax.make_jaxpr(fn)(*args).jaxpr)
+
+
+@pytest.fixture(scope="module", params=HELPER_CASES,
+                ids=lambda c: c[1] if c[0] == "fm" else "-".join(c))
 def fm_helper_case(request):
     """A list of one kind with fifty pairs planted on rows and buckets of
     their own, over ``w`` and ``v`` planes whose lone buckets hold values of
-    every class of ``split3``, and both paths' pulls and pushes."""
+    every class of ``split3``, and both paths' pulls and pushes, through
+    one store's helpers."""
     from wormhole_tpu.ops.loss import opaque_one
-    kind = request.param
+    store, kind = request.param
     rng = np.random.default_rng(29)
     k = 2
     ovf_b, ovf_r, lone_b, lone_r = _with_lone_pairs(*_list(kind, rng))
@@ -536,19 +645,40 @@ def fm_helper_case(request):
     dev = jax.device_put
     shape = (NB // tilemm.TILE, tilemm.A_HI, tilemm.B_LO)
     theta = [dev(p.reshape(shape)) for p in planes]
-    one = opaque_one(dev(np.ones(3, np.float32)))
-    hot_p = tilemm.fm_hot_pull_rows(theta, dev(ovf_u), dev(ovf_pw), SPEC, one)
-    coo_p = tilemm.fm_spill_pull_rows(theta, dev(coo_b), dev(coo_r), SPEC,
-                                      one)
     push = tuple(dev(p.reshape(shape)) for p in push0)
-    hot_g = tilemm.hot_push_scatter_planes(push, dev(dual), dev(ovf_u),
-                                           dev(ovf_pw), SPEC)
-    coo_g = tilemm.spill_push_scatter_planes(push, dev(dual), dev(coo_b),
-                                             dev(coo_r), SPEC)
-    q = sum(v.astype(np.float64) ** 2 for v in planes[1:])
+    channels = [p.astype(np.float64) for p in planes]
+    if store == "fm":
+        one = opaque_one(dev(np.ones(3, np.float32)))
+        hot_p = tilemm.fm_hot_pull_rows(theta, dev(ovf_u), dev(ovf_pw), SPEC,
+                                        one)
+        coo_p = tilemm.fm_spill_pull_rows(theta, dev(coo_b), dev(coo_r),
+                                          SPEC, one)
+        hot_g = tilemm.hot_push_scatter_planes(push, dev(dual), dev(ovf_u),
+                                               dev(ovf_pw), SPEC)
+        coo_g = tilemm.spill_push_scatter_planes(push, dev(dual), dev(coo_b),
+                                                 dev(coo_r), SPEC)
+        channels.append(sum(v.astype(np.float64) ** 2 for v in planes[1:]))
+    else:
+        # wide&deep pulls its 1 + k planes as they stand and adds into the
+        # push kernel's own tiled output; 9 and 12 parts, a call a part
+        tiled = jnp.concatenate(push, axis=-1)
+        hot = (dev(ovf_u), dev(ovf_pw))
+        with _small_budget():
+            calls = (_pallas_calls(lambda *a: tilemm.plane_hot_pull_rows(
+                         *a, SPEC), theta, *hot),
+                     _pallas_calls(lambda *a: tilemm.hot_push_scatter_lanes(
+                         *a, SPEC), tiled, dev(dual), *hot))
+            hot_p = tilemm.plane_hot_pull_rows(theta, *hot, SPEC)
+            hot_g = tilemm.hot_push_scatter_lanes(tiled, dev(dual), *hot,
+                                                  SPEC)
+        assert calls == (tilemm.HOT_CH, tilemm.HOT_CH)
+        coo_p = tilemm.plane_spill_pull_rows(theta, dev(coo_b), dev(coo_r),
+                                             SPEC)
+        coo_g = tilemm.spill_push_scatter_lanes(tiled, dev(dual), dev(coo_b),
+                                                dev(coo_r), SPEC)
     return dict(
         kind=kind, k=k, ovf_b=ovf_b, ovf_r=ovf_r, dual=dual, push0=push0,
-        channels=[p.astype(np.float64) for p in planes] + [q],
+        channels=channels,
         hot_p=np.asarray(hot_p), coo_p=np.asarray(coo_p),
         hot_g=[np.asarray(g).reshape(-1) for g in hot_g],
         coo_g=[np.asarray(g).reshape(-1) for g in coo_g])
@@ -561,7 +691,8 @@ def test_fm_hot_pulls_are_the_coo_pulls(fm_helper_case):
     were formed from unrounded factors), a few ulps of the summed
     magnitudes where it has many."""
     c = fm_helper_case
-    assert c["hot_p"].shape == c["coo_p"].shape == (ROWS, c["k"] + 2)
+    assert c["hot_p"].shape == c["coo_p"].shape == (ROWS,
+                                                    len(c["channels"]))
     per_row = np.bincount(c["ovf_r"], minlength=ROWS)
     assert (per_row == 1).sum() >= 50
     if c["kind"] in ("skewed", "two_small_hot_tiles"):
@@ -599,6 +730,28 @@ def test_fm_hot_pushes_are_the_coo_pushes(fm_helper_case):
             assert np.all(np.abs(got - exact) <= bound)
 
 
+@pytest.mark.parametrize("c, calls", [
+    (1, 1),             # FTRL's 3 parts
+    (10, 1),            # FM's 30 at the cells' dim 8
+    (19, 1), (20, 3),   # the last count one call admits, the first it does not
+    (33, 3), (34, 3),   # wide&deep's 99 and 102 at the cells' dim 32
+])
+def test_the_parts_go_through_one_call_or_a_call_a_part(c, calls):
+    """The cut is read from the operand's shape: ``3c`` parts against what
+    one call of the multi-channel pair admits at the hot spec's two tiles a
+    step. What fits stays ONE call each way (the other stores' programs are
+    the parent's), what does not is three calls of ``c`` channels."""
+    hs = tilemm.hot_spec(8, S)
+    assert hs.tiles_step == 2 and tilemm._hot_calls(hs, c) == calls
+    sds = jax.ShapeDtypeStruct
+    tile = sds((1, tilemm.A_HI, tilemm.B_LO), jnp.float32)
+    pw = sds(hs.pairs_shape, jnp.uint32)
+    assert _pallas_calls(lambda vals, pw: tilemm._hot_pull(vals, pw, 8, hs),
+                         [tile] * c, pw) == calls
+    assert _pallas_calls(lambda d, pw: tilemm._hot_push(d, pw, 1, 8, hs),
+                         sds((ROWS, c), jnp.float32), pw) == calls
+
+
 def test_the_ftrl_helpers_are_the_one_channel_case(helper_case):
     """One pair of helpers, parameterised by the channels: FTRL's margins
     and gradient are FM's pulls and pushes of a lone channel, to the bit."""
@@ -626,11 +779,12 @@ def test_the_ftrl_helpers_are_the_one_channel_case(helper_case):
                                      "coo_by_distinct"])
 def test_each_outcome_reaches_its_fm_program_and_counter(tmp_path,
                                                          monkeypatch,
-                                                         outcome):
-    """``HotRoom``'s three outcomes through an ``FMStore`` job: what
-    crosses, which helpers its spill step is traced with, and the Timer's
-    counts, the store's own among them (the pairs are counted from the COO
-    list on the host whichever form crosses)."""
+                                                         outcome, store):
+    """``HotRoom``'s three outcomes through an ``FMStore`` job and through
+    a ``WideDeepStore`` job: what crosses, which helpers the spill step is
+    traced with, and the Timer's counts, the store's own among them (the
+    pairs are counted from the COO list on the host whichever form
+    crosses)."""
     rng = np.random.default_rng(17)
     n = tilemm.RSUB
     make = _one_tile_keys if outcome == "coo_by_distinct" else _zipf_keys
@@ -640,38 +794,37 @@ def test_each_outcome_reaches_its_fm_program_and_counter(tmp_path,
     small = outcome != "coo_by_size"
     app, shipped, traced = _run(tmp_path, outcome, blocks, monkeypatch,
                                 1024 if small else crec.HOT_MIN_ROOM,
-                                app=_fm_app, helpers=FM_HELPERS)
+                                app=store["app"], helpers=store["helpers"])
     t = app.timer.totals
     assert app.timer is app.store.timer
     if outcome == "hot":
         assert all(set(b) == {"pw", "labels", "ovf_u", "ovf_pw"}
                    for b in shipped)
-        assert set(traced) == {"fm_hot_pull_rows", "hot_push_scatter_planes"}
+        assert set(traced) == store["hot"]
         assert t["overflow_hot_blocks"] == 2 and t["overflow_coo_blocks"] == 0
         assert t["overflow_hot_buckets"] == sum(len(np.unique(b))
                                                 for b in lists)
     else:
         assert all(set(b) == {"pw", "labels", "ovf_b", "ovf_r"}
                    for b in shipped)
-        assert set(traced) == {"fm_spill_pull_rows",
-                               "spill_push_scatter_planes"}
+        assert set(traced) == store["coo"]
         assert t["overflow_hot_blocks"] == 0 and t["overflow_coo_blocks"] == 2
-    assert t["fm_spill_blocks"] == 2 and "fm_in_place_blocks" not in t
-    assert t["fm_listed_pairs"] == sum(len(b) for b in lists)
-    assert t["online_overflow_pairs"] == t["fm_listed_pairs"]
+    assert t[store["spill"]] == 2 and store["other"] not in t
+    assert t[store["pairs"]] == sum(len(b) for b in lists)
+    assert t["online_overflow_pairs"] == t[store["pairs"]]
     assert app.timer.counts.get("table_cross", 0) == 0
 
 
-def test_fm_eval_pass_keeps_the_coo_list(tmp_path, monkeypatch):
+def test_fm_eval_pass_keeps_the_coo_list(tmp_path, monkeypatch, store):
     rng = np.random.default_rng(19)
     blocks = [_zipf_keys(rng, tilemm.RSUB)]
     app, shipped, traced = _run(tmp_path, "fmev", blocks, monkeypatch, 1024,
-                                app=_fm_app, helpers=FM_HELPERS,
+                                app=store["app"], helpers=store["helpers"],
                                 val_data=str(tmp_path / "fmev.crec"))
     assert "ovf_pw" in shipped[0] and "ovf_b" not in shipped[0]
     assert set(shipped[-1]) == {"pw", "labels", "ovf_b", "ovf_r"}
-    assert traced.count("fm_spill_pull_rows") == 1    # the eval program
-    assert traced.count("fm_hot_pull_rows") == 1
+    pulls = sorted(h for h in store["helpers"] if "pull" in h)
+    assert [traced.count(h) for h in pulls] == [1, 1]   # train hot, eval COO
     assert app.timer.totals["overflow_hot_blocks"] == 1
     # handed a hot block, the eval step gives the COO block's margins but
     # for the order of a row's float32 sums
@@ -776,3 +929,183 @@ def test_zipf_fm_run_lands_on_the_oracle(tmp_path, monkeypatch, kernel):
     assert bad.timer.totals["overflow_hot_blocks"] == 3
     rms, far = _fm_gap(_fm_table(bad), t_coo, t_init)
     assert rms > 2e-4 and far > 1e-3, (rms, far)
+
+
+# -- WideDeepStore's list through the same pair (ISSUE 52) --------------------
+
+def _bf16(x):
+    """float64 values rounded to bfloat16, as the tower rounds its
+    operands (``tilemm._tower_mm``)."""
+    return np.asarray(jnp.asarray(x, jnp.float32).astype(jnp.bfloat16)
+                      .astype(jnp.float32)).astype(np.float64)
+
+
+def _wd64(blocks, nb, k, cfg, fresh):
+    """float64 wide&deep with AdaGrad over whole blocks (the tile step's
+    rule: every bucket a block touched, once; weight decay on ``v``), from
+    a fresh store's own ``v0`` and tower, the tower's matmul operands
+    rounded to bfloat16 as the program states them: ``(w, v)``."""
+    table = np.asarray(fresh.slots).astype(np.float64)
+    w, v = table[:, 0].copy(), table[:, 1:1 + k].copy()
+    cg_w, cg_v = np.zeros(nb), np.zeros((nb, k))
+    mlp = {n: np.asarray(p).astype(np.float64) for n, p in fresh.mlp.items()}
+    acc = {n: np.zeros_like(p) for n, p in mlp.items()}
+    layers = fresh.n_layers
+    for keys, labels in blocks:
+        rr, cc = np.nonzero(keys != crec.SENTINEL_KEY)
+        b = fold_keys32(keys[rr, cc], nb).astype(np.int64)
+        wide, pooled = np.zeros(len(keys)), np.zeros((len(keys), k))
+        np.add.at(wide, rr, w[b])
+        np.add.at(pooled, rr, v[b])
+        hs, h = [], pooled
+        for i in range(layers):
+            hs.append(h)
+            h = _bf16(h) @ _bf16(mlp[f"W{i}"]) + mlp[f"b{i}"]
+            if i + 1 < layers:
+                h = np.maximum(h, 0.0)
+        margin = wide + h[:, 0]
+        y = 2.0 * labels - 1.0
+        dual = -y / (1 + np.exp(y * margin))
+        g, g_mlp = dual[:, None], {}
+        for i in reversed(range(layers)):
+            g_mlp[f"W{i}"] = _bf16(hs[i]).T @ _bf16(g)
+            g_mlp[f"b{i}"] = g.sum(axis=0)
+            g = _bf16(g) @ _bf16(mlp[f"W{i}"]).T
+            if i:
+                g = g * (hs[i] > 0)
+        g_w, push = np.zeros(nb), np.zeros((nb, k))
+        np.add.at(g_w, b, dual[rr])
+        np.add.at(push, b, g[rr])
+        touched = np.bincount(b, minlength=nb) > 0
+        g_v = push + cfg.l2_v * v * touched[:, None]
+        for x, a, gx, t in ((w, cg_w, g_w, touched),
+                            (v, cg_v, g_v, touched[:, None])):
+            new = np.sqrt(a * a + gx * gx)
+            step = cfg.lr_alpha / (cfg.lr_beta + new) * gx
+            a[...] = np.where(t, new, a)
+            x[...] = np.where(t, x - step, x)
+        for n in mlp:
+            acc[n] = np.sqrt(acc[n] ** 2 + g_mlp[n] ** 2)
+            mlp[n] = mlp[n] - cfg.lr_alpha_dense / (cfg.lr_beta
+                                                     + acc[n]) * g_mlp[n]
+    return w, v
+
+
+def _wd_table(app):
+    return np.asarray(app.store.slots)[:, :1 + WD_DIM]
+
+
+def test_a_stacked_wide_deep_table_takes_a_hot_list_over_its_planes(
+        tmp_path, monkeypatch, a_call_a_part):
+    """The hot helpers know planes alone. A stacked ``(nb, 2(1+k))`` array
+    assigned to ``slots`` (a restored checkpoint, a seeding hook) that meets
+    a hot block is not sent through the ``(nb, ch)`` helpers it takes a COO
+    list with: the step reads ``planes_of``'s slices at the kernels' edges,
+    so it gives the planar store's table and tower to the bit, and hands
+    planes back as any step of a store that keeps them does, with no
+    crossing counted. No feed of a cell meets this."""
+    from wormhole_tpu.learners import table as tbl
+    from wormhole_tpu.models.wide_deep import WideDeepStore
+    from wormhole_tpu.ops import overflow
+    rng = np.random.default_rng(31)
+    app, shipped, _ = _run(tmp_path, "st", [_zipf_keys(rng, tilemm.RSUB)],
+                           monkeypatch, 1024, app=_wd_app, helpers=())
+    assert overflow.is_hot(shipped[0])
+    info = online_info(NNZ, tilemm.RSUB, NB)
+    planar, stacked = (WideDeepStore(app.store.cfg) for _ in range(2))
+    assert isinstance(planar.slots, tbl.PlaneTable)
+    stacked.slots = jnp.asarray(np.asarray(planar.slots))
+    for store in (planar, stacked):
+        store.tile_train_step(shipped[0], info)
+        assert store.step_kernel[0] == "split"
+        assert store.timer.counts.get("table_cross", 0) == 0
+    assert isinstance(stacked.slots, tbl.PlaneTable)
+    assert np.asarray(stacked.slots).tobytes() == np.asarray(
+        planar.slots).tobytes()
+    assert np.abs(np.asarray(planar.slots)
+                  - np.asarray(WideDeepStore(app.store.cfg).slots)).max() > 0
+    for name, p in planar.mlp.items():
+        assert np.asarray(stacked.mlp[name]).tobytes() == np.asarray(
+            p).tobytes()
+
+
+def test_zipf_wd_run_lands_on_the_oracle(tmp_path, monkeypatch,
+                                         a_call_a_part):
+    """Three blocks of Zipf keys at 2**16 buckets through ``AsyncSGD`` and a
+    ``WideDeepStore`` (a block with a list steps the split pair; the tower
+    one linear layer: with a ReLU a unit that the kernels' bfloat16 pulls
+    turn on or off moves a bucket's ``v`` by a whole step, 9% of the
+    table's change by rms against float64, and no entry-wise limit holds):
+    through the hot tiles, a call a part, ``w`` and ``v`` are the COO
+    path's but for the order of float32 sums, and both land on float64
+    wide&deep within the limits FM's run is held to. Two planted faults are
+    each caught: a hot push that leaves the last dual channel of ``v`` out
+    (every listed pair of it) is off the oracle in that column, tenfold and
+    more; a hot block that loses its list where it crosses is counted
+    listless and not as a spill block."""
+    from wormhole_tpu.models.wide_deep import WideDeepStore
+    from wormhole_tpu.ops import overflow
+    rng = np.random.default_rng(23)
+    blocks = [_zipf_keys(rng, tilemm.RSUB) for _ in range(3)]
+    from functools import partial
+    wd = dict(app=partial(_wd_app, hidden=()), helpers=WD_HELPERS)
+    hot, _, traced = _run(tmp_path, "hot", blocks, monkeypatch, 1024, **wd)
+    assert hot.timer.totals["overflow_hot_blocks"] == 3
+    assert hot.store.step_kernel[0] == "split"
+    assert "plane_spill_pull_rows" not in traced
+    coo, _, _ = _run(tmp_path, "coo", blocks, monkeypatch, 1 << 30, **wd)
+    assert coo.timer.totals["overflow_coo_blocks"] == 3
+    listed = hot.timer.totals["wd_listed_pairs"]
+    assert listed == coo.timer.totals["wd_listed_pairs"]
+    assert listed > 0.1 * 3 * tilemm.RSUB * NNZ
+    for app in (hot, coo):
+        assert app.timer.totals["wd_spill_blocks"] == 3
+        assert "wd_listless_blocks" not in app.timer.totals
+    t_hot, t_coo = _wd_table(hot), _wd_table(coo)
+    fresh = WideDeepStore(hot.store.cfg)
+    t_init = np.asarray(fresh.slots)[:, :1 + WD_DIM]
+    # 3e-7 here; 2.04e-5 with a ReLU layer, the parts in one call or in
+    # three alike: what the order of a hot bucket's float32 sums moves,
+    # through the tower
+    rms, far = _fm_gap(t_hot, t_coo, t_init)
+    assert rms < 2e-5 and far < 1e-4, (rms, far)
+    w64, v64 = _wd64(blocks, NB, WD_DIM, hot.store.cfg, fresh)
+    t64 = np.concatenate([w64[:, None], v64], axis=1)
+    moved = np.abs(t64[:, 0]) > 1e-6
+    assert moved.sum() > 100
+    for t in (t_hot, t_coo):
+        np.testing.assert_allclose(t[moved], t64[moved], rtol=0.02,
+                                   atol=2e-3)
+    # the listed buckets' last embedding column against the oracle's
+    info = online_info(NNZ, tilemm.RSUB, NB)
+    listed_b = np.unique(np.concatenate(
+        [crec.encode_tile_pairs(k, NB, info.spec)[1] for k, _l in blocks]))
+
+    def last_column_gap(table):
+        d = table[listed_b, WD_DIM] - t64[listed_b, WD_DIM]
+        change = t64[listed_b, WD_DIM] - t_init[listed_b, WD_DIM]
+        return np.sqrt((d ** 2).mean() / (change ** 2).mean())
+    sound = last_column_gap(t_hot)
+    assert sound < 0.02, sound
+
+    real_push = tilemm._hot_push
+
+    def short(dual_rows, *rest):
+        return real_push(dual_rows.at[:, WD_DIM].set(0.0), *rest)
+    monkeypatch.setattr(tilemm, "_hot_push", short)
+    bad, _, _ = _run(tmp_path, "bad", blocks, monkeypatch, 1024, **wd)
+    monkeypatch.setattr(tilemm, "_hot_push", real_push)
+    assert bad.timer.totals["overflow_hot_blocks"] == 3
+    assert last_column_gap(_wd_table(bad)) > max(10 * sound, 0.2)
+
+    real_crossing = overflow.crossing
+
+    def lost(block, drop_empty):
+        return {k: v for k, v in real_crossing(block, drop_empty).items()
+                if k not in overflow.HOT}
+    monkeypatch.setattr(overflow, "crossing", lost)
+    gone, shipped, _ = _run(tmp_path, "gone", blocks, monkeypatch, 1024, **wd)
+    assert all(set(b) == {"pw", "labels"} for b in shipped)
+    assert gone.timer.totals["overflow_hot_blocks"] == 3      # the feed's
+    assert gone.timer.totals["wd_listless_blocks"] == 3       # the store's
+    assert "wd_spill_blocks" not in gone.timer.totals

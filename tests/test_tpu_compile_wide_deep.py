@@ -4,11 +4,13 @@ DESCRIBED TPU v5e, without a chip (see ``test_tpu_compile.py``)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from jax.sharding import SingleDeviceSharding
 
 from wormhole_tpu.ops import tilemm
 
-from tpu_compile_helpers import compiled_not_interpreted, v5e  # noqa: F401
+from tpu_compile_helpers import (_hot_form,  # noqa: F401
+                                 compiled_not_interpreted, v5e)
 
 
 def test_wide_deep_train_step_compiles_with_the_stated_tower_precision(v5e):
@@ -204,3 +206,84 @@ def test_wide_deep_spill_step_compiles_at_the_click_log_cells_list_width(v5e):
     assert mem.alias_size_in_bytes >= 2 * (1 + k) * 4 * nb
     assert mem.temp_size_in_bytes < 5.5e9
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 10.5e9
+
+
+@pytest.mark.parametrize("vtiles", [96, 112])
+def test_wide_deep_hot_spill_step_compiles_at_the_click_log_cells_rooms(
+        v5e, vtiles):
+    """``criteo_wide_deep_clicklog.replay_fields``'s TRAIN step as it runs
+    since ISSUE 52, for the v5e at the cell's own sizes: 66 planes of
+    ``2**24`` buckets, cap 384, the published tower, and the block's list
+    in its hot form at the two rooms the mix's seeds land on (three hot
+    tiles of 96 or 112 virtual tiles each: ``HotRoom`` on the host). The
+    list's two halves hold the hot kernel pair under their own names, a
+    call a part: three Mosaic calls of 33 channels under ``wd_ovf_pull``
+    and three of 34 under ``wd_ovf_scatter`` beside the main pair's two,
+    which is how ``wd_overflow_ms_per_step.replay`` and
+    ``kernel_ms_per_step.replay`` find them. Nothing in the program is as
+    long as the COO list's 1,638,400 slots: every gather and scatter sits
+    under one of the two names and moves a hot tile's 49,152 slots. Two
+    minutes a room."""
+    import json
+    import os
+    import re
+    from wormhole_tpu.data.crec import CRec2Info, default_cap
+    from wormhole_tpu.learners import table as tbl
+    from wormhole_tpu.learners.store import TableCheckpoint
+    from wormhole_tpu.models.wide_deep import WideDeepConfig, WideDeepStore
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "criteo_wide_deep_clicklog", "config.json")) as f:
+        config = json.load(f)
+    with open(os.path.join(root, "benchmark", "traffic",
+                           "replay_fields.json")) as f:
+        room = int(json.load(f)["ovf_cap"])
+    k, hidden = int(config["dim"]), tuple(config["hidden"])
+    nb = int(config["num_buckets"])
+    store = WideDeepStore(WideDeepConfig(num_buckets=2 * tilemm.TILE, dim=k,
+                                         hidden=hidden))
+    info = CRec2Info(nnz=39, block_rows=12 * tilemm.RSUB,
+                     total_rows=12 * tilemm.RSUB, nb=nb, ovf_cap=room,
+                     subblocks=12, cap=default_cap(39, nb))
+    spec = info.spec
+    step = store._tile_step(info, "train", True)
+    assert store.step_kernel[0] == "split" and "spill" in store.step_kernel[1]
+    one_chip = SingleDeviceSharding(v5e.devices[0])
+
+    def on(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    tiles = 3
+    hs = tilemm.hot_spec(tiles * vtiles, spec.subblocks)
+    assert tilemm._hot_calls(hs, 1 + k) == tilemm._hot_calls(hs, k + 2) == 3
+    u, pw = _hot_form(spec, tiles, vtiles)
+    mlp = jax.tree.map(lambda a: on(a.shape, a.dtype), store.mlp)
+    plane = on(tbl.plane_shape(nb), jnp.float32)
+    compiled = step.lower(
+        tbl.PlaneTable([plane] * (2 * (1 + k))), mlp, mlp,
+        {"pw": on(spec.pairs_shape, jnp.uint32),
+         "labels": on((spec.block_rows,), jnp.uint8),
+         "ovf_u": on(*u), "ovf_pw": on(*pw)},
+        on((), jnp.int32), on((), jnp.float32),
+        on((TableCheckpoint.MACC_LEN,), jnp.float32)).compile()
+    text = compiled.as_text()
+    calls = [ln for ln in text.splitlines()
+             if re.search(r" = \S+ custom-call\(", ln)
+             and "tpu_custom_call" in ln]
+    assert len(calls) == 2 + 3 + 3, len(calls)
+    for phase, ch in (("wd_ovf_pull", 1 + k), ("wd_ovf_scatter", k + 2)):
+        mine = [ln for ln in calls if "jit(%s)" % phase in ln]
+        assert len(mine) == 3, (phase, len(mine))
+        assert all("%d]" % (ch * tilemm.B_LO) in ln.split(" custom-call(")[0]
+                   for ln in mine), phase
+    for op in ("gather", "scatter"):
+        lines = [ln for ln in text.splitlines()
+                 if re.search(r" = \S+ %s\(" % op, ln)]
+        assert lines and all("jit(wd_ovf_" in ln for ln in lines), op
+    assert str(room) not in text
+    assert not re.findall(r"f32\[%d,\d+\]" % nb, text)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * (1 + k) * 4 * nb
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 10.5e9
+    print("memory", vtiles, mem.argument_size_in_bytes / 1e9,
+          mem.temp_size_in_bytes / 1e9)

@@ -89,6 +89,10 @@ from wormhole_tpu.ops.tilemm import mlp_forward, tower_flops  # noqa: E402,F401
 class WideDeepStore(TableCheckpoint):
     """Sharded embedding table + replicated MLP, fused joint train step."""
 
+    # the one-device spill TRAIN step takes its list in the hot form too
+    # (data/crec.HotRoom): AsyncSGD hands the feeds a room
+    hot_overflow = True
+
     def __init__(self, cfg: WideDeepConfig,
                  runtime: Optional[MeshRuntime] = None):
         self.cfg = cfg
@@ -309,13 +313,10 @@ class WideDeepStore(TableCheckpoint):
         spec, oc = ts.spec, ts.oc
         keeps_planes = self._planar
 
-        def decode(block):
-            pw, labels, row_mask, lst = ts.decode(block)
-            ovf_b, ovf_r = (lst["ovf_b"], lst["ovf_r"]) if oc else (None,
-                                                                   None)
-            distinct = (tuple(lst[name] for name in overflow.DISTINCT)
-                        if oc and overflow.DISTINCT[0] in lst else None)
-            return pw, labels, row_mask, ovf_b, ovf_r, distinct
+        def coo(lst):
+            # a COO list's two arrays for the (nb, ch) helpers' own tails
+            return ((None, None) if lst is None
+                    else tuple(lst[name] for name in overflow.COO))
 
         # The phases are jits of their own inside the step, named for
         # what they do, so that the device trace's ops say which phase
@@ -323,39 +324,52 @@ class WideDeepStore(TableCheckpoint):
         # wd_tower_forward, its vjp under wd_tower_backward), wd_push,
         # wd_table_update, wd_dense_update; and inside wd_pull and
         # wd_push the list's halves, wd_ovf_pull (the listed buckets' 1+k
-        # values gathered plane by plane and summed onto their rows: a
-        # slot a pair, or once a listed bucket where the list brings its
-        # distinct buckets) and wd_ovf_scatter (the pairs' k+2 dual
-        # channels added into the kernel's pushes, a slot a pair). A
-        # nested jit and not a bare jax.named_scope (as the mesh step
-        # has, learners/store.py): the profiler's op metadata keeps the
-        # path of an op inside a nested jit and drops a bare scope's
-        # (jit(fwd) in the kept traces). XLA inlines them: the step is
-        # one program as before.
+        # values read plane by plane and summed onto their rows) and
+        # wd_ovf_scatter (the pairs' k+2 dual channels added into the
+        # kernel's pushes). Both take the list in the form it crossed in
+        # (ops/overflow.py): hot, a train block's long list of few
+        # buckets, a plane read and the pushes added to once a distinct
+        # bucket and the pairs through the multi-channel kernel pair
+        # over the hot tiles, every float32 value as three bfloat16
+        # parts, a call a part; or COO, a slot a pair (a plane read once
+        # a listed bucket where the list brings its distinct buckets):
+        # a short list, one of mostly distinct buckets, every eval
+        # block's. A nested jit and not a bare jax.named_scope (as the
+        # mesh step has, learners/store.py): the profiler's op metadata
+        # keeps the path of an op inside a nested jit and drops a bare
+        # scope's (jit(fwd) in the kept traces). XLA inlines them: the
+        # step is one program as before.
         # At the kernels' edges a planar table takes the helpers over
         # planes; a stacked one (``stacked``, static) the (nb, ch) helpers
         # it had, which transpose the operand and the pushes
         @jax.jit
-        def wd_ovf_pull(theta, ovf_b, ovf_r, distinct):
-            return tilemm.plane_spill_pull_rows(theta, ovf_b, ovf_r, spec,
-                                                distinct)
+        def wd_ovf_pull(theta, lst):
+            helper, first, second = overflow.pick(
+                lst, tilemm.plane_spill_pull_rows,
+                tilemm.plane_hot_pull_rows)
+            # a long COO list brings its distinct buckets beside it
+            distinct = overflow.distinct_of(lst)
+            return helper(theta, first, second, spec,
+                          *(() if distinct is None else (distinct,)))
 
         @jax.jit
-        def wd_ovf_scatter(push, dvals, ovf_b, ovf_r):
-            return tilemm.spill_push_scatter_lanes(push, dvals, ovf_b,
-                                                   ovf_r, spec)
+        def wd_ovf_scatter(push, dvals, lst):
+            helper, first, second = overflow.pick(
+                lst, tilemm.spill_push_scatter_lanes,
+                tilemm.hot_push_scatter_lanes)
+            return helper(push, dvals, first, second, spec)
 
         @partial(jax.jit, static_argnums=(0,))
-        def wd_pull(stacked, theta, pw, ovf_b, ovf_r, distinct):
+        def wd_pull(stacked, theta, pw, lst):
             if stacked:
                 return tilemm.forward_pulls(pw, tbl.join(theta), spec,
-                                            ovf_b, ovf_r)
+                                            *coo(lst))
             # the operand rounded once from the w and v planes; the
             # overflow pairs' values gathered from the planes, unrounded
             pulls = tilemm.plane_pulls(pw, tilemm.plane_operand(theta),
                                        spec)
             if oc:
-                pulls = pulls + wd_ovf_pull(theta, ovf_b, ovf_r, distinct)
+                pulls = pulls + wd_ovf_pull(theta, lst)
             return pulls
 
         @jax.jit
@@ -363,17 +377,17 @@ class WideDeepStore(TableCheckpoint):
             return mlp_forward(m, x, n_layers)
 
         @partial(jax.jit, static_argnums=(0,))
-        def wd_push(stacked, pw, dual, g_pooled, row_mask, ovf_b, ovf_r):
+        def wd_push(stacked, pw, dual, g_pooled, row_mask, lst):
             dvals = jnp.concatenate(
                 [dual[:, None], g_pooled, row_mask[:, None]], axis=1)
             if stacked:
                 return tbl.split(tilemm.backward_pushes(
-                    pw, dvals, spec, ovf_b, ovf_r))
+                    pw, dvals, spec, *coo(lst)))
             # the pushes stay as the kernel writes them,
             # (T, A_HI, (k+2)*B_LO): a channel's plane is a lane slice
             push = tilemm.tiled_pushes(pw, dvals, spec)
             if oc:
-                return wd_ovf_scatter(push, dvals, ovf_b, ovf_r)
+                return wd_ovf_scatter(push, dvals, lst)
             return tilemm.push_planes(push)
 
         @jax.jit
@@ -405,19 +419,22 @@ class WideDeepStore(TableCheckpoint):
                 lambda p, g, a: p - cfg.lr_alpha_dense
                 / (cfg.lr_beta + a) * g, mlp, g_mlp, accum), accum
 
-        def is_stacked(table) -> bool:
-            return not isinstance(table, tbl.PlaneTable)
+        def is_stacked(table, lst) -> bool:
+            # the hot helpers know planes alone: a stacked table that
+            # meets a hot list (no feed of a cell hands it one) takes the
+            # planes' edges over planes_of's slices
+            return not (isinstance(table, tbl.PlaneTable)
+                        or (oc and overflow.is_hot(lst)))
 
         def forward(table, mlp, block):
-            pw, labels, row_mask, ovf_b, ovf_r, distinct = decode(block)
-            pulls = wd_pull(is_stacked(table), tbl.planes_of(table)[:1 + k],
-                            pw, ovf_b, ovf_r, distinct)
+            pw, labels, row_mask, lst = ts.decode(block)
+            pulls = wd_pull(is_stacked(table, lst),
+                            tbl.planes_of(table)[:1 + k], pw, lst)
             pooled = pulls[:, 1:]
             with jax.named_scope("wd_tower_forward"):
                 deep, vjp = jax.vjp(wd_tower, mlp, pooled)
             margin = pulls[:, 0] + deep
-            return (pw, labels, row_mask, ovf_b, ovf_r, pooled, vjp,
-                    margin)
+            return pw, labels, row_mask, lst, pooled, vjp, margin
 
         def finish(table, planes, mlp, accum, push, g_mlp, margin, labels,
                    row_mask, t, macc):
@@ -445,7 +462,7 @@ class WideDeepStore(TableCheckpoint):
             # the planes and sliced back into them here
             def step(table, mlp, accum, block, t, tau, macc):
                 planes = tbl.planes_of(table)
-                pw, labels, row_mask, *_list = decode(block)
+                pw, labels, row_mask, _lst = ts.decode(block)
                 # pull, tower and push are one kernel here
                 margin, push, g_mlp = tilemm.fused_wd_step(
                     pw, tbl.join(planes[:1 + k]), labels, row_mask, mlp,
@@ -454,19 +471,19 @@ class WideDeepStore(TableCheckpoint):
                               g_mlp, margin, labels, row_mask, t, macc)
         elif ts.kind == "train":
             def step(table, mlp, accum, block, t, tau, macc):
-                (pw, labels, row_mask, ovf_b, ovf_r, pooled, vjp,
+                (pw, labels, row_mask, lst, pooled, vjp,
                  margin) = forward(table, mlp, block)
                 dual = dual_fn(margin, labels, row_mask)
                 with jax.named_scope("wd_tower_backward"):
                     g_mlp, g_pooled = vjp(dual)
-                push = wd_push(is_stacked(table), pw, dual, g_pooled,
-                               row_mask, ovf_b, ovf_r)
+                push = wd_push(is_stacked(table, lst), pw, dual, g_pooled,
+                               row_mask, lst)
                 return finish(table, tbl.planes_of(table), mlp, accum,
                               push, g_mlp, margin, labels, row_mask, t,
                               macc)
         else:
             def step(table, mlp, block):
-                (_, labels, row_mask, _, _, _, _,
+                (_, labels, row_mask, _, _, _,
                  margin) = forward(table, mlp, block)
                 return ts.evaluate(margin, labels, row_mask)
 

@@ -66,6 +66,12 @@ def of(block: dict) -> dict:
     return {k: block[k] for k in names(hot) + extra}
 
 
+def distinct_of(lst: dict):
+    """``(ovf_d, ovf_k)`` where the list crossed with them, else None."""
+    return (tuple(lst[k] for k in DISTINCT) if DISTINCT[0] in lst
+            else None)
+
+
 def pairs(ovf_b: np.ndarray) -> int:
     """The pairs on a COO list: its slots that are in use."""
     return int(np.count_nonzero(ovf_b != UNUSED))

@@ -903,6 +903,9 @@ def _bwd_multi_kernel(spec: TileSpec, ch: int, pw_ref, dual_ref, g_ref):
         g_ref[tb] = acc
 
 
+MULTI_BUDGET = 128  # tiles_step * (ch + 6) of one multi-channel call
+
+
 def _multi_spec(spec: TileSpec, ch: int) -> TileSpec:
     """Shrink tiles_step so the unrolled kernel body stays near the ch=1
     compile budget. The round-5 batched kernels carry ~(2 + GS + ch)
@@ -912,7 +915,8 @@ def _multi_spec(spec: TileSpec, ch: int) -> TileSpec:
     tiles_step=16 at ch=10 with the OLD kernels measured >10 min."""
     import dataclasses
     tb = max((t for t in (16, 8, 4, 2)
-              if spec.tiles % t == 0 and t * (ch + 6) <= 128), default=1)
+              if spec.tiles % t == 0 and t * (ch + 6) <= MULTI_BUDGET),
+             default=1)
     # a spec made for fewer tiles a step keeps them (make_spec never
     # is: its own tiles_step is the largest divisor, so this changes
     # none of its callers): the hot tile's pair, whose lowering time is
@@ -1002,10 +1006,11 @@ def _build_bwd_multi(spec: TileSpec, ch: int, tiled: bool = False):
 #
 # The list as (bucket, row) pairs, a gather and a scatter slot a pair:
 # what a short list takes, and one whose buckets are mostly distinct
-# (data/crec.HotRoom's rule), every list in eval, on a mesh, over a
-# stacked multi-channel table and in wide&deep's step. A long list of
-# few buckets comes through the hot tile helpers further down (FTRL's
-# one channel) and fm_hot_pull_rows / hot_push_scatter_planes (FM's
+# (data/crec.HotRoom's rule), every list in eval, on a mesh and over a
+# stacked multi-channel table. A long list of few buckets comes through
+# the hot tile helpers further down (FTRL's one channel), through
+# fm_hot_pull_rows / hot_push_scatter_planes (FM's k + 2) and through
+# plane_hot_pull_rows / hot_push_scatter_lanes (wide&deep's 1 + k and
 # k + 2).
 #
 # One shared aggregation for both step formulations: the spill pairs are
@@ -1109,21 +1114,43 @@ def _hot_dims(ovf_u: jax.Array, ovf_pw: jax.Array, spec: TileSpec):
             jnp.where(valid, ovf_u, 0).astype(jnp.int32))
 
 
+def _hot_calls(hs: TileSpec, c: int) -> int:
+    """The calls of a hot kernel that ``c`` float32 channels go through:
+    ONE with all ``3c`` parts where the multi-channel pair admits that
+    many at the hot spec's tiles a step (``_multi_spec``'s budget: FTRL's
+    3 parts, FM's 30), else one a part, ``c`` channels each (wide&deep's
+    99 and 102: three calls at 33 and 34 channels, what the store's main
+    pair runs; one call's resident row grid alone would be 39 MB)."""
+    one = hs.tiles_step * (HOT_CH * c + 6) <= MULTI_BUDGET
+    return 1 if one else HOT_CH
+
+
 def _hot_pull(values, ovf_pw: jax.Array, vtiles: int,
               hs: TileSpec) -> jax.Array:
     """``(block_rows, c)`` row sums of the hot form's pairs over ``c``
     float32 channels, each a ``(tiles, A_HI, B_LO)`` hot tile: the
     ``3c`` parts ride part-major on the lanes (every channel's hi, then
     every mid, then every lo), every virtual tile a copy of its hot
-    tile, and a channel's three sums add as ``(hi + mid) + lo``."""
+    tile, and a channel's three sums add as ``(hi + mid) + lo``. Where
+    one call does not admit ``3c`` parts (``_hot_calls``) the cut falls
+    between the parts: a call a part, three calls in the program (they
+    compile side by side; as one traced body, a ``lax.map``, the trace's
+    op line would hold the ``while`` beside the calls inside it, and
+    every reader that sums a step's ops would count them twice)."""
     c = len(values)
     parts = zip(*(split3(v) for v in values))
-    wt = jnp.repeat(jax.lax.concatenate(
-        [x.astype(jnp.bfloat16) for part in parts for x in part], 2),
-        vtiles, axis=0)
-    p = _build_fwd_multi(hs, HOT_CH * c, True)(ovf_pw, wt)
-    p = p.reshape(-1, HOT_CH, c)
-    return (p[:, 0] + p[:, 1]) + p[:, 2]
+    if _hot_calls(hs, c) == 1:
+        wt = jnp.repeat(jax.lax.concatenate(
+            [x.astype(jnp.bfloat16) for part in parts for x in part], 2),
+            vtiles, axis=0)
+        p = _build_fwd_multi(hs, HOT_CH * c, True)(ovf_pw, wt)
+        p = p.reshape(-1, HOT_CH, c)
+        return (p[:, 0] + p[:, 1]) + p[:, 2]
+    fwd = _build_fwd_multi(hs, c, True)
+    hi, mid, lo = (fwd(ovf_pw, jnp.repeat(jax.lax.concatenate(
+        [x.astype(jnp.bfloat16) for x in part], 2), vtiles, axis=0))
+        for part in parts)
+    return (hi + mid) + lo
 
 
 def _hot_push(dual_rows: jax.Array, ovf_pw: jax.Array, tiles: int,
@@ -1131,12 +1158,20 @@ def _hot_push(dual_rows: jax.Array, ovf_pw: jax.Array, tiles: int,
     """``(tiles, A_HI, c, B_LO)`` hot tiles of the pairs' ``(block_rows,
     c)`` float32 duals: the push kernel sums each of the ``3c`` parts a
     virtual tile, a channel's three add as ``(hi + mid) + lo``, and a
-    hot tile's virtual tiles are summed."""
+    hot tile's virtual tiles are summed (a call a part where one does not
+    admit ``3c``, ``_hot_calls``: a part's virtual tiles are summed
+    first there, and the three sums added)."""
     c = dual_rows.shape[1]
-    push = _build_bwd_multi(hs, HOT_CH * c, True)(
-        ovf_pw, jnp.concatenate(split3(dual_rows), axis=1))
-    p = push.reshape(tiles, vtiles, A_HI, HOT_CH, c, B_LO)
-    return ((p[:, :, :, 0] + p[:, :, :, 1]) + p[:, :, :, 2]).sum(axis=1)
+    if _hot_calls(hs, c) == 1:
+        push = _build_bwd_multi(hs, HOT_CH * c, True)(
+            ovf_pw, jnp.concatenate(split3(dual_rows), axis=1))
+        p = push.reshape(tiles, vtiles, A_HI, HOT_CH, c, B_LO)
+        return ((p[:, :, :, 0] + p[:, :, :, 1]) + p[:, :, :, 2]).sum(axis=1)
+    bwd = _build_bwd_multi(hs, c, True)
+    hi, mid, lo = (bwd(ovf_pw, part).reshape(
+        tiles, vtiles, A_HI, c, B_LO).sum(axis=1)
+        for part in split3(dual_rows))
+    return (hi + mid) + lo
 
 
 def hot_margin_rows(w: jax.Array, ovf_u: jax.Array, ovf_pw: jax.Array,
@@ -1244,14 +1279,26 @@ def fm_spill_pull_rows(planes, ovf_b: jax.Array, ovf_r: jax.Array,
 
 def spill_push_scatter_planes(push, dual_rows: jax.Array, ovf_b: jax.Array,
                               ovf_r: jax.Array, spec: TileSpec) -> tuple:
-    """spill_push_scatter into push planes, a channel at a time."""
+    """spill_push_scatter into push planes, a channel at a time (a
+    slot's row is its row of ``dual_rows``)."""
     valid = ovf_b != UNUSED
     idx = jnp.where(valid, ovf_b, 0).astype(jnp.int32)
     d = jnp.where(valid[:, None],
-                  dual_rows[ovf_r.astype(jnp.int32) % spec.block_rows],
+                  dual_rows[ovf_r.astype(jnp.int32) % dual_rows.shape[0]],
                   0.0)
     return tuple(p.reshape(-1).at[idx].add(d[:, c]).reshape(p.shape)
                  for c, p in enumerate(push))
+
+
+def _hot_values(planes, ovf_u: jax.Array, ovf_pw: jax.Array,
+                spec: TileSpec):
+    """``(values, vtiles, hot spec)``: every plane read once a distinct
+    bucket of the hot form (``tiles * TILE`` slots, the unused 0.0), as
+    ``(tiles, A_HI, B_LO)`` hot tiles."""
+    tiles, vtiles, hs, valid, idx = _hot_dims(ovf_u, ovf_pw, spec)
+    got = [jnp.where(valid, p.reshape(-1)[idx], 0.0)
+           .reshape(tiles, A_HI, B_LO) for p in planes]
+    return got, vtiles, hs
 
 
 def fm_hot_pull_rows(planes, ovf_u: jax.Array, ovf_pw: jax.Array,
@@ -1262,9 +1309,7 @@ def fm_hot_pull_rows(planes, ovf_u: jax.Array, ovf_pw: jax.Array,
     unrounded factors, as the COO helper forms it a pair), and every
     channel runs through the hot tile as three parts: the float32
     values to the bit, a row's pairs summed in the MXU's accumulator."""
-    tiles, vtiles, hs, valid, idx = _hot_dims(ovf_u, ovf_pw, spec)
-    got = [jnp.where(valid, p.reshape(-1)[idx], 0.0)
-           .reshape(tiles, A_HI, B_LO) for p in planes]
+    got, vtiles, hs = _hot_values(planes, ovf_u, ovf_pw, spec)
     return _hot_pull(fm_pull_channels(got[0], got[1:], one), ovf_pw,
                      vtiles, hs)
 
@@ -1324,6 +1369,17 @@ def plane_spill_pull_rows(planes, ovf_b: jax.Array, ovf_r: jax.Array,
         ovf_r.astype(jnp.int32) % spec.block_rows].add(wv)
 
 
+def plane_hot_pull_rows(planes, ovf_u: jax.Array, ovf_pw: jax.Array,
+                        spec: TileSpec) -> jax.Array:
+    """plane_spill_pull_rows from the list's hot form: every plane is
+    read once a distinct bucket and its values run through the hot tile
+    as they stand, three parts each (fm_hot_pull_rows without FM's
+    formed channel): the float32 values to the bit, a row's pairs summed
+    in the MXU's accumulator."""
+    got, vtiles, hs = _hot_values(planes, ovf_u, ovf_pw, spec)
+    return _hot_pull(got, ovf_pw, vtiles, hs)
+
+
 def tiled_pushes(pw: jax.Array, dual_rows: jax.Array,
                  spec: TileSpec) -> jax.Array:
     """backward_pushes as the kernel writes them: float32
@@ -1358,12 +1414,28 @@ def spill_push_scatter_lanes(g: jax.Array, dual_rows: jax.Array,
     valid = ovf_b != UNUSED
     idx = jnp.where(valid, ovf_b, 0).astype(jnp.int32)
     d = jnp.where(valid[:, None],
-                  dual_rows[ovf_r.astype(jnp.int32) % spec.block_rows],
+                  dual_rows[ovf_r.astype(jnp.int32) % dual_rows.shape[0]],
                   0.0)
     lane = jax.lax.broadcasted_iota(jnp.int32, (d.shape[0], g.shape[-1]), 1)
     rows = jnp.where(lane % B_LO == (idx % B_LO)[:, None],
                      jnp.repeat(d, B_LO, axis=1), 0.0)
     return push_planes(g.at[idx // TILE, (idx % TILE) // B_LO].add(rows))
+
+
+def hot_push_scatter_lanes(g: jax.Array, dual_rows: jax.Array,
+                           ovf_u: jax.Array, ovf_pw: jax.Array,
+                           spec: TileSpec) -> tuple:
+    """spill_push_scatter_lanes from the list's hot form: a bucket's
+    duals are summed a channel in the hot tiles, and those sums are a
+    COO list of their own, ``tiles * TILE`` slots of distinct buckets:
+    slot ``r`` names bucket ``ovf_u[r]`` and row ``r`` of the sums (an
+    unused slot adds 0.0 at bucket 0). That list is added into the
+    tiled pushes as any COO list is."""
+    tiles, vtiles, hs, _valid, _idx = _hot_dims(ovf_u, ovf_pw, spec)
+    gu = _hot_push(dual_rows, ovf_pw, tiles, vtiles, hs)
+    sums = gu.transpose(0, 1, 3, 2).reshape(tiles * TILE, -1)
+    return spill_push_scatter_lanes(
+        g, sums, ovf_u, jnp.arange(tiles * TILE, dtype=jnp.uint32), spec)
 
 
 # ---------------------------------------------------------------------------
